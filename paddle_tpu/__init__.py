@@ -19,54 +19,13 @@ _warnings.filterwarnings(
 
 import jax as _jax
 
-# jax < 0.6 exposes shard_map only under jax.experimental (and spells
-# check_vma as check_rep); the codebase is written against the stable
-# ``jax.shard_map`` surface, so alias it here — before any subpackage
-# that shard_maps is imported.
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map_compat(f=None, /, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        # the old replication checker predates the vma type system this
-        # codebase is written against and rejects valid programs (e.g.
-        # cond branches with different inferred replication — the error
-        # itself recommends check_rep=False). It is a static lint with no
-        # numeric effect, so default it off under old jax.
-        kw.setdefault("check_rep", False)
-        if f is None:  # decorator form: jax.shard_map(mesh=..., ...)
-            return lambda g: _exp_shard_map(g, **kw)
-        return _exp_shard_map(f, **kw)
-
-    _jax.shard_map = _shard_map_compat
-
-# jax < 0.5 has no lax.axis_size; psum of the python literal 1 over the
-# named axis is the classic spelling and is evaluated statically (returns
-# a python int), so `range(axis_size)` keeps working.
-from jax import lax as _lax
-if not hasattr(_lax, "axis_size"):
-    _lax.axis_size = lambda axis_name: _lax.psum(1, axis_name)
-
-# jax < 0.6 has no jax.typeof; get_aval is the same lookup (callers here
-# only probe optional attrs like .vma on the result, with defaults)
-if not hasattr(_jax, "typeof"):
-    from jax.core import get_aval as _get_aval
-    _jax.typeof = _get_aval
-
-# jax < 0.6 has no lax.pcast / vma type system; marking a value
-# device-varying is meaningless there (the old check_rep machinery infers
-# replication itself), so the compat spelling is identity
-if not hasattr(_lax, "pcast"):
-    _lax.pcast = lambda x, axes, to=None: x
-
 # Under a launcher/spawn (PADDLE_TRAINERS_NUM > 1) the distributed runtime
-# must come up before the first XLA-backend touch below. The retry loop
-# lives in distributed/env.py (bootstrap_pre_backend); importing the
-# paddle_tpu.distributed *package* this early would pull in
-# backend-touching modules, so load the env module standalone under its
-# canonical name — the package's later `from .env import ...` reuses this
-# sys.modules entry, keeping exactly one copy of the bootstrap.
+# must come up before the first XLA-backend touch. The retry loop lives in
+# distributed/env.py (bootstrap_pre_backend); load the env module
+# standalone under its canonical name rather than through the
+# paddle_tpu.distributed package (whose import runs most of the
+# framework's imports first) — the package's later `from .env import ...`
+# reuses this sys.modules entry, keeping exactly one copy of the bootstrap.
 import os as _os
 if (int(_os.environ.get("PADDLE_TRAINERS_NUM", "1")) > 1
         and not _os.environ.get("_PADDLE_TPU_DIST_INITIALIZED")):
@@ -86,26 +45,6 @@ if (int(_os.environ.get("PADDLE_TRAINERS_NUM", "1")) > 1
 # force full precision for f32 — the AMP/bf16 path (paddle_tpu.amp) is the MXU
 # perf path and is unaffected by this setting.
 _jax.config.update("jax_default_matmul_precision", "highest")
-
-# Fleet-wide persistent compilation cache (serving/cache.py owns the full
-# story): when PADDLE_TPU_COMPILE_CACHE names a root, point JAX's own
-# persistent cache at <root>/xla HERE — before the first import-time jit —
-# so a warm process start performs zero XLA backend compiles at all, not
-# just zero for serving signatures. Inlined (not imported from
-# serving.cache, which would be circular this early); the values match
-# enable_persistent_compilation(), whose later idempotent update is a
-# no-op.
-_cc_root = _os.environ.get("PADDLE_TPU_COMPILE_CACHE", "").strip()
-if _cc_root:
-    try:
-        _cc_dir = _os.path.join(_os.path.expanduser(_cc_root), "xla")
-        _os.makedirs(_cc_dir, exist_ok=True)
-        _jax.config.update("jax_compilation_cache_dir", _cc_dir)
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        _jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass                     # serving.cache warns with the details
-del _cc_root
 
 from .core import (  # noqa: F401
     Tensor, Parameter, no_grad, enable_grad, is_grad_enabled, set_grad_enabled,

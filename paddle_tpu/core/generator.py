@@ -20,12 +20,15 @@ import jax
 class Generator:
     def __init__(self, seed: int = 0):
         self._seed = int(seed)
-        self._key = jax.random.PRNGKey(self._seed)
+        # the key is built on first use: PRNGKey initialises the XLA
+        # backend, and a process that only imports the package (launcher,
+        # spawn/DataLoader parent) must not take the chip from its children
+        self._key = None
         self._lock = threading.Lock()
 
     def manual_seed(self, seed: int):
         self._seed = int(seed)
-        self._key = jax.random.PRNGKey(self._seed)
+        self._key = None
         return self
 
     seed = manual_seed
@@ -33,15 +36,21 @@ class Generator:
     def initial_seed(self) -> int:
         return self._seed
 
+    def _current_key(self):
+        if self._key is None:
+            self._key = jax.random.PRNGKey(self._seed)
+        return self._key
+
     def split(self, n: int = 1):
         """Return n fresh subkeys, advancing the state."""
         with self._lock:
-            keys = jax.random.split(self._key, n + 1)
+            keys = jax.random.split(self._current_key(), n + 1)
             self._key = keys[0]
             return keys[1] if n == 1 else keys[1:]
 
     def get_state(self):
-        return np.asarray(self._key)
+        with self._lock:
+            return np.asarray(self._current_key())
 
     def set_state(self, state):
         self._key = jax.numpy.asarray(state, dtype=jax.numpy.uint32)

@@ -156,15 +156,12 @@ def bootstrap_pre_backend():
     coordinator = (endpoints[0] or None) if endpoints else None
     num_processes = int(os.environ["PADDLE_TRAINERS_NUM"])
     process_id = int(os.environ.get("PADDLE_TRAINER_ID", "0"))
-    try:
-        # the CPU backend refuses multiprocess computations unless a CPU
-        # collectives transport is selected, and the choice must land
-        # before initialize(); TPU/GPU runs are unaffected (their
-        # collectives ride ICI/NCCL, and any CPU-backend side computation
-        # gets a working transport instead of INVALID_ARGUMENT)
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # older jax: flag absent
-        pass
+    # the CPU backend refuses multiprocess computations unless a CPU
+    # collectives transport is selected, and the choice must land
+    # before initialize(); TPU/GPU runs are unaffected (their
+    # collectives ride ICI/NCCL, and any CPU-backend side computation
+    # gets a working transport instead of INVALID_ARGUMENT)
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     _initialize_distributed_with_retry(coordinator, num_processes, process_id)
     _LOG.info(
         "jax.distributed initialized: coordinator=%s process_id=%d "

@@ -118,9 +118,7 @@ def _vary_tree(t, axes):
         axes = (axes,)
 
     def one(a):
-        vma = getattr(jax.typeof(a), "vma", None)
-        if vma is None:      # jax < 0.6: no vma system, nothing to mark
-            return a
+        vma = jax.typeof(a).vma
         missing = tuple(ax for ax in axes if ax not in vma)
         if not missing:
             return a
@@ -175,12 +173,8 @@ def _rotating_schedule(axis, vary_axes, S, M, carry_aval, out_aval,
     # pmean is an identity that satisfies out_specs=P())
     def finalize(b):
         b = lax.psum(jnp.where(rank == S - 1, b, jnp.zeros_like(b)), axis)
-        vma = getattr(jax.typeof(b), "vma", None)
-        # jax < 0.6 cannot report which axes still vary: pmean over all of
-        # them — identity for the already-replicated ones (see above), the
-        # real dp average otherwise, and it satisfies the old rep checker
-        rest = tuple(ax for ax in vary_axes
-                     if vma is None or ax in vma)
+        vma = jax.typeof(b).vma
+        rest = tuple(ax for ax in vary_axes if ax in vma)
         return lax.pmean(b, rest) if rest else b
     return jax.tree_util.tree_map(finalize, outbuf)
 

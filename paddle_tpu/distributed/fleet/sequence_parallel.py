@@ -469,15 +469,14 @@ def ring_flash_attention(q, k, v, mesh=None, axis: str = "sp",
     the axis size, returns the same sharding. Differentiable — the
     attached custom_vjp runs the flash recomputation schedule around the
     ring (:func:`_ring_flash_bwd_local`), so ``jax.grad`` through this is
-    the long-context training fast path. ``interpret`` defaults to True
-    off-TPU so CPU-mesh tests run the kernel in interpret mode."""
+    the long-context training fast path. ``interpret=None`` leaves the
+    choice to ``core.pallas_mode`` (interpreted off-TPU, so CPU-mesh tests
+    run the kernel's math)."""
     m = mesh or _mesh.ensure_mesh()
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))  # noqa: PTA001 -- head dim is a static shape, a trace-time python int
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
     return _ring_callable("flash", m, axis, causal, scale, batch_axes,
-                          interpret=bool(interpret))(q, k, v)  # noqa: PTA001 -- interpret is a trace-time python flag (platform check above), never a traced value
+                          interpret=interpret)(q, k, v)
 
 
 def _ring_flash_impl(qq, kk, vv, axis="sp", causal=False, batch_axes=None):

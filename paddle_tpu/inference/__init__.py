@@ -113,11 +113,7 @@ class Predictor:
     def __init__(self, config: Config):
         prefix = config._prefix
         from jax import export as jax_export
-        from ..serving.cache import default_cache, persistent_root
-        # activate env-configured persistent compilation BEFORE the first
-        # compile this predictor triggers, so even parameter-upload utility
-        # programs land in (and later load from) the fleet-wide cache
-        persistent_root()
+        from ..serving.cache import default_cache
         with open(prefix + ".pdmodel", "rb") as f:
             self._exported = jax_export.deserialize(f.read())
         # compiled-callable cache keyed on (artifact, input shapes/dtypes):

@@ -18,29 +18,37 @@ StatRegistry, replacing hand-computed bench numbers with live stats.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 from ..core import monitor as _monitor
 
 
-def default_peak_flops() -> float:
-    """Peak FLOP/s of the local accelerator, bench.py's convention:
-    197 TFLOP/s for the TPU bench target, 1 TFLOP/s as the CPU-proxy
-    normalizer. Override with ``PADDLE_TPU_PEAK_FLOPS`` (FLOP/s)."""
-    env = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
-    if env:
-        return float(env)
-    try:
-        import jax
-        platform = jax.devices()[0].platform
-    except Exception:
-        platform = "cpu"
-    if platform == "tpu":
-        return 197.0e12
-    if platform == "gpu":
-        return 394.0e12
-    return 1.0e12
+#: Peak dense bf16 FLOP/s per chip, keyed by ``jax.Device.device_kind``.
+#: The one peaks table of the repo (bench.py reads it too). Sources:
+#: "TPU v5 lite" is how JAX names a TPU v5e chip — 197 TFLOP/s bf16,
+#: Google Cloud documentation, "TPU v5e".
+PEAK_FLOPS_BY_DEVICE_KIND = {
+    "TPU v5 lite": 197.0e12,
+}
+
+
+def default_peak_flops() -> Optional[float]:
+    """Peak FLOP/s of the local accelerator from
+    :data:`PEAK_FLOPS_BY_DEVICE_KIND`. The CPU has no peak (None — no MFU
+    is published for it); an accelerator that is not in the table is an
+    error, never a default."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    peak = PEAK_FLOPS_BY_DEVICE_KIND.get(dev.device_kind)
+    if peak is None:
+        raise KeyError(
+            f"no peak FLOP/s listed for device_kind {dev.device_kind!r} "
+            f"(platform {dev.platform!r}); add it with its source to "
+            f"PEAK_FLOPS_BY_DEVICE_KIND in "
+            f"paddle_tpu/observability/stepmeter.py")
+    return peak
 
 
 def compiled_flops(fn, *args, jit_kwargs: Optional[dict] = None,
@@ -53,10 +61,7 @@ def compiled_flops(fn, *args, jit_kwargs: Optional[dict] = None,
     try:
         compiled = jax.jit(fn, **(jit_kwargs or {})).lower(
             *args, **kwargs).compile()
-        costs = compiled.cost_analysis()
-        if isinstance(costs, (list, tuple)):  # older jax returns [dict]
-            costs = costs[0] if costs else {}
-        flops = float(costs.get("flops", 0.0))
+        flops = float(compiled.cost_analysis().get("flops", 0.0))
     except Exception:
         return None
     if flops <= 0.0:
@@ -74,6 +79,7 @@ class StepMeter:
     def __init__(self, peak_flops: Optional[float] = None,
                  registry: Optional["_monitor.StatRegistry"] = None,
                  prefix: str = "train"):
+        # None on the CPU: step() then records wall time and FLOPs only
         self.peak_flops = (float(peak_flops) if peak_flops
                            else default_peak_flops())
         self.registry = (registry if registry is not None
@@ -97,14 +103,15 @@ class StepMeter:
 
     def step(self, wall_s: float, flops: Optional[float] = None
              ) -> Optional[float]:
-        """Record one step; returns the step's MFU (None if flops or wall
-        are unknown). ``flops`` overrides the sticky per-signature value
-        (e.g. a step that ran a different compiled program)."""
+        """Record one step; returns the step's MFU (None if flops, wall
+        or the device's peak are unknown). ``flops`` overrides the sticky
+        per-signature value (e.g. a step that ran a different compiled
+        program)."""
         reg = self.registry
         p = self.prefix
         reg.observe(f"{p}.step_ms", wall_s * 1e3)
         f = flops if flops is not None else self.flops_per_step
-        if not f or wall_s <= 0.0:
+        if not f or wall_s <= 0.0 or self.peak_flops is None:
             return None
         mfu = f / wall_s / self.peak_flops
         self.last_mfu = mfu

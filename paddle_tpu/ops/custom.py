@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from .dispatch import apply, OP_REGISTRY
+from ..core.pallas_mode import resolve_interpret
 from ..core.tensor import Tensor
 
 
@@ -55,8 +56,8 @@ def register_pallas_op(name: str, kernel_call: Callable, module=None):
     ``kernel_call(*raws, interpret=...)`` must accept ``interpret`` so the
     op runs everywhere (interpret=True off-TPU)."""
     def fn(*raws, **attrs):
-        on_tpu = jax.devices()[0].platform == "tpu"
-        return kernel_call(*raws, interpret=not on_tpu, **attrs)
+        return kernel_call(*raws, interpret=resolve_interpret(name),
+                           **attrs)
     fn.__doc__ = kernel_call.__doc__
     return register_op(name, fn, module=module)
 
@@ -146,7 +147,7 @@ def _nms_unroll(k: int) -> int:
     return u if u >= 1 and k % u == 0 else 1
 
 
-def pallas_greedy_nms(iou, valid, thr, interpret=False, unroll=None):
+def pallas_greedy_nms(iou, valid, thr, interpret=None, unroll=None):
     """Greedy NMS over score-sorted candidates as ONE Pallas kernel.
 
     iou [k,k] f32 (symmetric, sorted by score desc), valid [k] int32,
@@ -165,7 +166,8 @@ def pallas_greedy_nms(iou, valid, thr, interpret=False, unroll=None):
     out = pl.pallas_call(
         functools.partial(_nms_kernel, unroll=int(unroll)),
         out_shape=jax.ShapeDtypeStruct((k, 1), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret("greedy_nms", interpret),
+        name="greedy_nms",
     )(iou.astype(jnp.float32), valid.reshape(k, 1).astype(jnp.int32),
       thr.reshape(1, 1).astype(jnp.float32))
     return out.reshape(k)

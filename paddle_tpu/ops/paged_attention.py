@@ -29,8 +29,9 @@ lane).
 
 Tuner family ``paged_attn`` (``paddle_tpu.tuner.paged_key``): the one
 knob is ``block_h``, how many heads share a grid step's DMA and compute
-block. ``default_winners.json`` carries committed entries; unknown
-shapes fall back to a dividing heuristic.
+block — a multiple of the sublane tile that divides the head count, or
+all the heads (``_sanitize_block_h``). ``default_winners.json`` carries
+committed entries; unknown shapes fall back to a dividing heuristic.
 """
 from __future__ import annotations
 
@@ -42,18 +43,22 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..core.pallas_mode import resolve_interpret
+
 __all__ = ["paged_attention"]
 
 _NEG_INF = -1e30
 
 
-def _sanitize_block_h(block_h, num_heads: int) -> int:
-    """Largest divisor of ``num_heads`` that is <= the requested block
-    (the grid needs H % block_h == 0)."""
+def _sanitize_block_h(block_h, num_heads: int, itemsize: int = 4) -> int:
+    """Largest legal head block <= the request
+    (``tuner.space.paged_block_h_legal``), else all the heads — which is
+    always legal."""
+    from ..tuner.space import paged_block_h_legal
     b = max(1, min(int(block_h), num_heads))  # noqa: PTA001 -- block_h is a python config int (tuner winner / heuristic), never a traced value
-    while num_heads % b:
+    while b > 0 and not paged_block_h_legal(b, num_heads, itemsize):
         b -= 1
-    return b
+    return b if b > 0 else num_heads
 
 
 def _tuned_block_h(num_heads, head_dim, page_size, dtype):
@@ -76,6 +81,12 @@ def _tuned_block_h(num_heads, head_dim, page_size, dtype):
 def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                        acc_ref, m_ref, l_ref, *, scale, page_size,
                        pages_per_seq, block_h):
+    """One page of one sequence's head block per grid step. With a single
+    query row there is nothing for the MXU to amortize, so the whole
+    recurrence stays on the VPU in the arena's own ``[page, heads, D]``
+    layout: q.k is a multiply and a lane reduction, softmax statistics
+    reduce over the major (page) axis, p.v is a lane broadcast and a
+    major-axis sum — no transpose, no batched dot, no relayout."""
     import jax.experimental.pallas as pl
 
     s = pl.program_id(0)
@@ -88,35 +99,26 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0].astype(jnp.float32) * scale          # [bh, D]
-    kt = jnp.transpose(k_ref[0].astype(jnp.float32),
-                       (1, 0, 2))                     # [bh, page, D]
-    vt = jnp.transpose(v_ref[0].astype(jnp.float32),
-                       (1, 0, 2))
-    # scores: head-batched q·k over the page rows -> [bh, page]
-    s_blk = lax.dot_general(q[:, None, :], kt,
-                            (((2,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)[:, 0, :]
-    j = p * page_size + lax.broadcasted_iota(jnp.int32,
-                                             (block_h, page_size), 1)
+    kblk = k_ref[0].astype(jnp.float32)               # [page, bh, D]
+    vblk = v_ref[0].astype(jnp.float32)
+    s_blk = jnp.sum(kblk * q[None], axis=-1, keepdims=True)  # [page, bh, 1]
+    j = p * page_size + lax.broadcasted_iota(
+        jnp.int32, (page_size, block_h, 1), 0)
     valid = j <= len_ref[s]
     s_blk = jnp.where(valid, s_blk, _NEG_INF)
-    m_prev = m_ref[:, 0]                              # [bh]
-    l_prev = l_ref[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=1))
+    m_prev = m_ref[:, :1]                             # [bh, 1]
+    l_prev = l_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=0))
     alpha = jnp.exp(m_prev - m_new)
-    pexp = jnp.where(valid, jnp.exp(s_blk - m_new[:, None]), 0.0)
-    l_new = l_prev * alpha + jnp.sum(pexp, axis=1)
-    acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                    + lax.dot_general(pexp, vt,
-                                      (((1,), (1,)), ((0,), (0,))),
-                                      preferred_element_type=jnp.float32))
-    m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+    pexp = jnp.where(valid, jnp.exp(s_blk - m_new[None]), 0.0)
+    l_new = l_prev * alpha + jnp.sum(pexp, axis=0)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.sum(pexp * vblk, axis=0)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(p == pages_per_seq - 1)
     def _finalize():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
                     ).astype(o_ref.dtype)
 
 
@@ -141,17 +143,15 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
     s_n, num_heads, head_dim = q.shape
     page_size = k_arena.shape[1]
     pages_per_seq = block_tables.shape[1]
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
     if scale is None:
         scale = 1.0 / np.sqrt(head_dim)
     if block_h is None:
         block_h = _tuned_block_h(num_heads, head_dim, page_size, q.dtype)
     if block_h is None:
-        # heuristic: 8 heads per step keeps the f32 sublane tile full on
-        # TPU; off-TPU any divisor is fine
-        block_h = 8 if not interpret else num_heads
-    block_h = _sanitize_block_h(block_h, num_heads)
+        # heuristic: one full f32 sublane tile of heads per step
+        block_h = 8
+    block_h = _sanitize_block_h(block_h, num_heads,
+                                jnp.dtype(k_arena.dtype).itemsize)
 
     kernel = functools.partial(
         _paged_attn_kernel, scale=scale, page_size=page_size,
@@ -184,5 +184,6 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, num_heads, head_dim),
                                        q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret("paged_attn", interpret),
+        name="paged_attn",
     )(bt_flat, positions.astype(jnp.int32), q, k_arena, v_arena)

@@ -13,8 +13,9 @@ its running (acc, m, l) statistics in VMEM/registers while scanning k/v
 blocks with ``lax.fori_loop``. Causal masking and tail padding are mask
 arithmetic inside the score block — shapes stay static.
 
-Runs in interpret mode off-TPU so tests are hardware-independent
-(ops/custom.py register_pallas_op convention).
+Runs in interpret mode off-TPU so tests are hardware-independent; every
+``interpret`` argument below is an optional override that
+``core.pallas_mode.resolve_interpret`` settles at the ``pallas_call``.
 """
 from __future__ import annotations
 
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .dispatch import apply
+from ..core.pallas_mode import resolve_interpret
 
 __all__ = ["flash_attention"]
 
@@ -175,10 +177,9 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
         qb = to_bh(q, s_pad)
         kb = to_bh(kk, kv_pad)
         vb = to_bh(vv, kv_pad)
-        on_tpu = jax.devices()[0].platform == "tpu"
         # real kv length for the padding mask: padded keys sit at
         # index >= skv
-        out = _fa_core(qb, kb, vb, causal, sc, bq, bk, not on_tpu, skv)
+        out = _fa_core(qb, kb, vb, causal, sc, bq, bk, None, skv)
         out = out[:, :s, :].reshape(b, h, s, d)
         return jnp.moveaxis(out, 1, 2)
     return apply("flash_attention", impl, query, key, value), None
@@ -302,7 +303,8 @@ def _fa_fwd_with_lse(qb, kb, vb, causal, sc, bq, bk, interpret, true_kv):
                    pl.BlockSpec((1, 1, bq), lambda b, i: (b, 0, i))],
         out_shape=[jax.ShapeDtypeStruct((bh, s_pad, d), qb.dtype),
                    jax.ShapeDtypeStruct((bh, 1, s_pad), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret("flash_fwd", interpret),
+        name="flash_fwd",
     )(qb, kb, vb)
 
 
@@ -368,7 +370,8 @@ def _fa_bwd_with_lse(qb, kb, vb, do, out, lse, causal, sc, bq, bk,
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s_pad, d), dq_dt),
-        interpret=interpret,
+        interpret=resolve_interpret("flash_bwd_dq", interpret),
+        name="flash_bwd_dq",
     )(qb, kb, vb, do, lse, delta)
 
     dkv_kernel = functools.partial(
@@ -389,7 +392,8 @@ def _fa_bwd_with_lse(qb, kb, vb, do, out, lse, causal, sc, bq, bk,
                    pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0))],
         out_shape=[jax.ShapeDtypeStruct((bh, kv_pad, d), dk_dt),
                    jax.ShapeDtypeStruct((bh, kv_pad, d), dv_dt)],
-        interpret=interpret,
+        interpret=resolve_interpret("flash_bwd_dkv", interpret),
+        name="flash_bwd_dkv",
     )(qb, kb, vb, do, lse, delta)
     return dq, dk, dv
 

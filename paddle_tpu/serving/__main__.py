@@ -118,6 +118,9 @@ def main(argv=None) -> int:
                     help="autoscaler controller tick period")
     args = ap.parse_args(argv)
 
+    from .cache import place_jax_compilation_cache
+    place_jax_compilation_cache()
+
     if args.cmd == "serve-llm":
         return _serve_llm(args)
 

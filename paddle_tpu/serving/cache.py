@@ -11,17 +11,23 @@ with hit/miss/evict counters published to the default StatRegistry
 (``serving.executable_cache.*`` on ``/metricsz``; zero misses after
 warmup is the steady state).
 
-Persistence (fleet-wide, survives restarts) is two tiers under one root
-(``PADDLE_TPU_COMPILE_CACHE`` or :func:`enable_persistent_compilation`):
+Persistence (fleet-wide, survives restarts) is two independent tiers:
 
-* ``<root>/xla`` — JAX's own persistent compilation cache
-  (``jax_compilation_cache_dir``): every ``jit`` in the process, not
-  just serving, skips XLA backend compiles that any earlier process
-  already paid for.
-* ``<root>/executables`` — :class:`PersistentExecutableStore`: whole
-  serialized AOT executables keyed by the cache's own process-stable
-  signature tokens, loaded by ``get_or_compile(..., persist_key=...)``
-  without issuing a compile request at all.
+* JAX's own persistent compilation cache: every ``jit`` in the process,
+  not just serving, skips XLA backend compiles that any earlier process
+  already paid for. It lives where ``JAX_COMPILATION_CACHE_DIR`` says —
+  JAX reads that variable itself and no code here overrides it. Entry
+  points (``chip_smoke.py``, ``bench.py``, ``python -m
+  paddle_tpu.serving``) call :func:`place_jax_compilation_cache` so that,
+  with the variable unset, the cache sits at one fixed git-ignored path
+  in the checkout (:data:`IN_CHECKOUT_JAX_CACHE`) — the directory is part
+  of the cache key, so it must never move.
+* :class:`PersistentExecutableStore` under
+  ``$PADDLE_TPU_COMPILE_CACHE/executables`` (or
+  :func:`enable_persistent_compilation`): whole serialized AOT
+  executables keyed by the cache's own process-stable signature tokens,
+  loaded by ``get_or_compile(..., persist_key=...)`` without issuing a
+  compile request at all.
 """
 from __future__ import annotations
 
@@ -47,7 +53,7 @@ _PERSIST_ENV = "PADDLE_TPU_COMPILE_CACHE"
 _STAT_PREFIX = "serving.executable_cache."
 
 #: bump when the on-disk executable entry format changes
-_STORE_VERSION = 1
+_STORE_VERSION = 2
 
 
 def signature_of(arrays: Sequence[Any]) -> SigT:
@@ -58,49 +64,55 @@ def signature_of(arrays: Sequence[Any]) -> SigT:
 
 # -- persistent compilation (fleet-wide, survives restarts) -------------------
 
+#: where JAX's persistent compilation cache goes when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset: <checkout>/.jax_cache
+#: (git-ignored; never ~/.cache, a tempfile name, a pid or a timestamp)
+IN_CHECKOUT_JAX_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def place_jax_compilation_cache() -> str:
+    """Called once by an entry point before its first compile; returns the
+    directory JAX's persistent compilation cache uses.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set the directory is left to JAX;
+    otherwise it becomes :data:`IN_CHECKOUT_JAX_CACHE`. Either way the
+    min-compile-time/min-entry-size floors are dropped so every
+    executable qualifies."""
+    import jax
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if not placed:
+        placed = IN_CHECKOUT_JAX_CACHE
+        jax.config.update("jax_compilation_cache_dir", placed)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return placed
+
+
 _PERSIST_ROOT: Optional[str] = None
 _PERSIST_LOCK = threading.Lock()
 _PERSIST_RESOLVED = False
 
 
 def enable_persistent_compilation(path: Optional[str] = None) -> str:
-    """Turn on the on-disk compilation tiers and return the cache root.
-
-    Wires ``jax_compilation_cache_dir`` at ``<root>/xla`` (with the
-    min-compile-time/min-entry-size floors dropped so every executable
-    qualifies) and anchors the :class:`PersistentExecutableStore` at
-    ``<root>/executables``. Idempotent; the first caller's root wins.
-    Default root: ``$PADDLE_TPU_COMPILE_CACHE`` or
-    ``~/.cache/paddle_tpu/compile``.
-    """
+    """Anchor the :class:`PersistentExecutableStore` at
+    ``<root>/executables`` and return the root: ``path`` or
+    ``$PADDLE_TPU_COMPILE_CACHE``. Idempotent; the first caller's root
+    wins. JAX's own compilation cache is not touched (see the module
+    docstring)."""
     global _PERSIST_ROOT, _PERSIST_RESOLVED
     with _PERSIST_LOCK:
         if _PERSIST_ROOT is not None:
             return _PERSIST_ROOT
-        root = (path or os.environ.get(_PERSIST_ENV, "").strip()
-                or os.path.join(os.path.expanduser("~/.cache/paddle_tpu"),
-                                "compile"))
-        root = os.path.expanduser(root)
-        try:
-            import jax
-            os.makedirs(os.path.join(root, "xla"), exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir",
-                              os.path.join(root, "xla"))
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-            # jax latches "no cache" on the first compile; any import-time
-            # jit before this point would otherwise pin the cache off for
-            # the whole process
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc)
-            _cc.reset_cache()
-        except Exception as e:   # unwritable dir / exotic jax build
-            warnings.warn(f"persistent compilation cache disabled: {e}")
-        _PERSIST_ROOT = root
+        root = path or os.environ.get(_PERSIST_ENV, "").strip()
+        if not root:
+            raise ValueError(
+                "enable_persistent_compilation: pass a path or set "
+                f"{_PERSIST_ENV}")
+        _PERSIST_ROOT = os.path.expanduser(root)
         _PERSIST_RESOLVED = True
-        return root
+        return _PERSIST_ROOT
 
 
 def persistent_root() -> Optional[str]:
@@ -130,8 +142,11 @@ class PersistentExecutableStore:
     """Whole serialized executables on disk, keyed by process-stable
     cache-key strings.
 
-    Entries are ``pickle((payload, in_tree, out_tree))`` from
-    ``jax.experimental.serialize_executable`` under a sha256 filename of
+    Entries are ``pickle((payload, in_tree, out_tree, device_ids))`` —
+    the first three from ``jax.experimental.serialize_executable``, the
+    last the ids of the devices the executable was compiled for (it is
+    loaded onto exactly those, not onto every local device) — under a
+    sha256 filename of
     (key, jax version, backend platform, store version) — a jax upgrade
     or platform change simply misses instead of loading an incompatible
     executable. All failure modes (corrupt file, version skew, unpickla-
@@ -155,12 +170,17 @@ class PersistentExecutableStore:
 
     def load(self, key: str):
         """The deserialized executable for ``key``, or None."""
+        import jax
         from jax.experimental import serialize_executable as _se
         path = self._path(key)
         try:
             with open(path, "rb") as f:
-                payload, in_tree, out_tree = pickle.loads(f.read())
-            exe = _se.deserialize_and_load(payload, in_tree, out_tree)
+                payload, in_tree, out_tree, device_ids = pickle.loads(
+                    f.read())
+            by_id = {d.id: d for d in jax.devices()}
+            exe = _se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         except FileNotFoundError:
             _mon.stat_add(_STAT_PREFIX + "disk_misses", 1)
             return None
@@ -185,7 +205,9 @@ class PersistentExecutableStore:
         optimization, never state."""
         from jax.experimental import serialize_executable as _se
         try:
-            blob = pickle.dumps(_se.serialize(compiled))
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
+            blob = pickle.dumps(_se.serialize(compiled) + (device_ids,))
         except Exception:
             return False            # lazy jit wrapper etc. — memory-only
         path = self._path(key)

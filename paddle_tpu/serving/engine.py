@@ -81,11 +81,6 @@ class DrainableEngineBase:
 
     def _init_serving_base(self, registry: Optional[_mon.StatRegistry],
                            stat_prefix: str):
-        # activate env-configured persistent compilation before this
-        # engine's first compile (no-op when PADDLE_TPU_COMPILE_CACHE is
-        # unset and enable_persistent_compilation() was never called)
-        from .cache import persistent_root
-        persistent_root()
         self._registry = registry or _mon.default_registry()
         self._prefix = stat_prefix
         self._draining = threading.Event()

@@ -463,4 +463,3 @@ class PagedBatcher(ContinuousBatcher):
         # the XLA cost probe compiles the SLOT decode program, which the
         # paged engine never runs; skip rather than mis-measure
         self._decode_flops = 0.0
-        self._peak_flops = 1.0

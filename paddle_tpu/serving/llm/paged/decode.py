@@ -335,7 +335,8 @@ class GPTPagedDecoder(GPTStaticDecoder):
     model façade, same ExecutableCache accounting, but ``new_kv``
     returns a :class:`PagedKVCache` and every compiled program threads
     its block table. ``attn_impl``: ``"auto"`` picks the Pallas kernel
-    on TPU (dense arenas) and the gather lane elsewhere."""
+    on TPU (dense arenas) and the gather lane elsewhere; the lane taken
+    is ``self.attn_impl`` and the engine's ``stats()["paged_attn_impl"]``."""
 
     kv_layout = "paged"
 
@@ -365,7 +366,7 @@ class GPTPagedDecoder(GPTStaticDecoder):
                 "the paged kernel lane reads dense arenas; int8 pages "
                 "use attn_impl='gather' (dequantize in-graph)")
         if attn_impl == "auto":
-            on_tpu = jax.devices()[0].platform == "tpu"
+            on_tpu = jax.default_backend() == "tpu"
             attn_impl = ("kernel" if on_tpu and kv_dtype != "int8"
                          else "gather")
         self.attn_impl = attn_impl

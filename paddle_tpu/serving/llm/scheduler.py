@@ -633,7 +633,7 @@ class ContinuousBatcher:
         self._stat_observe("tpot_ms", dt * 1000.0)
         self._stat_add("tokens_generated", n)
         self._stat_set("tokens_per_sec", n / dt)
-        if self._decode_flops:
+        if self._decode_flops and self._peak_flops:
             # tick wall time includes the sanctioned token fetch, so this
             # is delivered MFU, not device-only MFU
             self._stat_set("mfu", self._decode_flops / dt / self._peak_flops)
@@ -1244,6 +1244,8 @@ class LLMEngine(DrainableEngineBase):
             "prefix_store": (self._prefix_store.stats()
                              if self._prefix_store is not None else None),
             "kv_layout": self._config.kv_layout,
+            # the lane "auto" resolved to (None on the slot plane)
+            "paged_attn_impl": getattr(self._decoder, "attn_impl", None),
             "pages": ({"total": self._batcher.kv.pool.num_pages,
                        "free": self._batcher.kv.pool.free_pages,
                        "cow_splits": self._batcher.kv.cow_splits,

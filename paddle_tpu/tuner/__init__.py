@@ -9,10 +9,10 @@ Pallas kernels resolve their block/grid configuration per
 
 1. **in-process memo** — after the first resolution a key costs one dict
    lookup on the kernel-call path (zero measurable overhead),
-2. **on-disk winner cache** — ``PADDLE_TPU_TUNE_CACHE`` (default
-   ``~/.cache/paddle_tpu/tuning/``), versioned JSON written by
-   ``tools/autotune.py`` (or by tune-on-miss), shared by every process
-   that mounts it — replicas and restarts reuse each other's search,
+2. **on-disk winner cache** — only when ``PADDLE_TPU_TUNE_CACHE`` names
+   a directory: versioned JSON written by ``tools/autotune.py`` (or by
+   tune-on-miss), shared by every process that mounts it — replicas and
+   restarts reuse each other's search,
 3. **committed defaults** — ``default_winners.json`` ships winners for
    the bench-model shapes so CI and cold fleets never tune from scratch,
 4. **heuristic fallback** — the historical hardcoded config, so an empty
@@ -223,8 +223,6 @@ def autotune_flash(batch_heads: int, q_len: int, kv_len: int,
     import jax.numpy as jnp
     from ..ops import pallas_attention as fa
 
-    if interpret is None:
-        interpret = _platform() != "tpu"
     jdt = jnp.dtype(dtype)
     q16, k16 = _ceil16(q_len), _ceil16(kv_len)
     cands = flash_candidates(q_len, kv_len, head_dim,
@@ -292,8 +290,6 @@ def autotune_paged_attn(num_seqs: int, num_heads: int, head_dim: int,
     import jax.numpy as jnp
     from ..ops.paged_attention import paged_attention
 
-    if interpret is None:
-        interpret = _platform() != "tpu"
     jdt = jnp.dtype(dtype)
     num_pages = num_seqs * pages_per_seq
     kq = jax.random.PRNGKey(0)

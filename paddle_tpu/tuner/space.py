@@ -99,8 +99,22 @@ def flash_candidates(q_len: int, kv_len: int, head_dim: int,
 
 #: head-block candidates for the paged decode-attention family
 #: (ops/paged_attention.py): how many heads share one grid step's page
-#: DMA and dot. Must divide num_heads (the grid is H // block_h).
-PAGED_BLOCK_H = (1, 2, 4, 8, 16, 32)
+#: DMA and compute block; the head count itself is always a candidate.
+PAGED_BLOCK_H = (8, 16, 32)
+
+#: sublane tile rows by itemsize (pallas_guide.md "Tiling Constraints")
+SUBLANE_ROWS = {4: 8, 2: 16, 1: 32}
+
+
+def paged_block_h_legal(block_h: int, num_heads: int,
+                        itemsize: int = 4) -> bool:
+    """The K/V block is ``(1, page_size, block_h, D)``: ``block_h`` must
+    divide the head count (the grid is H // block_h) and, sitting
+    second-to-last, be a multiple of the dtype's sublane tile or the
+    whole head axis."""
+    return (block_h > 0 and num_heads % block_h == 0
+            and (block_h == num_heads
+                 or block_h % SUBLANE_ROWS[itemsize] == 0))
 
 
 def paged_attn_vmem_bytes(block_h: int, page_size: int, head_dim: int,
@@ -119,15 +133,15 @@ def paged_attn_vmem_bytes(block_h: int, page_size: int, head_dim: int,
 
 def paged_attn_candidates(num_heads: int, head_dim: int, page_size: int,
                           itemsize: int = 4) -> List[Dict[str, int]]:
-    """block_h candidates for a paged decode-attention shape: divisors of
-    ``num_heads`` only (the grid needs exact head tiling), VMEM pruned —
-    though at decode page sizes the footprint is tiny, so pruning only
-    bites on pathological page_size * head_dim products."""
-    out = [{"block_h": b} for b in PAGED_BLOCK_H
-           if b <= num_heads and num_heads % b == 0
+    """block_h candidates for a paged decode-attention shape: the legal
+    head blocks only (:func:`paged_block_h_legal`), VMEM pruned — though
+    at decode page sizes the footprint is tiny, so pruning only bites on
+    pathological page_size * head_dim products."""
+    out = [{"block_h": b} for b in sorted({*PAGED_BLOCK_H, num_heads})
+           if paged_block_h_legal(b, num_heads, itemsize)
            and paged_attn_vmem_bytes(b, page_size, head_dim,
                                      itemsize) <= VMEM_BUDGET]
-    return out or [{"block_h": 1}]
+    return out or [{"block_h": num_heads}]
 
 
 #: candidate block sizes for the compressed-allreduce quantize stage.
@@ -148,5 +162,7 @@ def nms_candidates(k: int) -> List[Dict[str, int]]:
     """Unroll factors for the greedy-NMS fori_loop (ops/custom.py): the
     loop body is tiny, so unrolling amortizes loop overhead until the
     unrolled body overflows instruction budget. Only exact divisors of
-    the candidate count keep the trip arithmetic trivial."""
+    the candidate count keep the trip arithmetic trivial. Mosaic lowers
+    only ``unroll=1`` and ``unroll=k`` ("Only unroll=num_steps and
+    unroll=1 supported"); the search drops the rest as unbuildable."""
     return [{"unroll": u} for u in (1, 2, 4, 8) if u <= max(1, k)]

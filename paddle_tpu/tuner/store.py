@@ -1,7 +1,9 @@
 """Versioned on-disk winner cache for the Pallas kernel autotuner.
 
 Layout: one JSON file per platform under the tune-cache directory
-(``PADDLE_TPU_TUNE_CACHE`` or ``~/.cache/paddle_tpu/tuning/``):
+(``PADDLE_TPU_TUNE_CACHE``; with the variable unset there is no disk tier
+and winners resolve from the committed defaults alone, so what a kernel
+compiles to depends only on files git holds):
 
     winners-<platform>.json
     {"version": 1, "platform": "tpu",
@@ -38,11 +40,11 @@ _DEFAULTS_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                               "default_winners.json")
 
 
-def cache_dir() -> str:
+def cache_dir() -> Optional[str]:
+    """The disk tier's directory, or None when ``PADDLE_TPU_TUNE_CACHE``
+    is unset (committed defaults only)."""
     d = os.environ.get(_ENV_DIR, "").strip()
-    if d:
-        return os.path.expanduser(d)
-    return os.path.join(os.path.expanduser("~/.cache/paddle_tpu"), "tuning")
+    return os.path.expanduser(d) if d else None
 
 
 def _load_table(path: str, what: str) -> Dict[str, Dict[str, Any]]:
@@ -90,8 +92,9 @@ class WinnerStore:
     def __init__(self, platform: str, directory: Optional[str] = None):
         self.platform = platform
         self.directory = directory or cache_dir()
-        self.path = os.path.join(self.directory,
-                                 f"winners-{platform}.json")
+        self.path = (os.path.join(self.directory,
+                                  f"winners-{platform}.json")
+                     if self.directory else None)
         self._lock = threading.Lock()
         self._entries: Optional[Dict[str, Dict[str, Any]]] = None
         self._defaults: Optional[Dict[str, Dict[str, Any]]] = None
@@ -103,7 +106,8 @@ class WinnerStore:
             if self._entries is None:
                 self._defaults = _load_table(_DEFAULTS_FILE,
                                              "default-winners table")
-                self._entries = _load_table(self.path, "tuning cache")
+                self._entries = (_load_table(self.path, "tuning cache")
+                                 if self.path else {})
 
     def lookup(self, key: str) -> Optional[Dict[str, Any]]:
         """The winning config dict for ``key``, or None. Disk entries
@@ -124,13 +128,19 @@ class WinnerStore:
                us: Optional[float] = None) -> None:
         """Persist a winner: update memory, then atomically rewrite the
         platform file (tmp + rename). I/O failures warn, never raise —
-        tuning results are an optimization, not state."""
+        tuning results are an optimization, not state. Without a disk
+        tier the winner lives in this process only (warned)."""
         self._ensure_loaded()
         entry: Dict[str, Any] = {"config": dict(config)}
         if us is not None:
             entry["us"] = float(us)
         with self._lock:
             self._entries[key] = entry
+            if self.path is None:
+                warnings.warn(
+                    f"paddle_tpu.tuner: {_ENV_DIR} is not set; winner for "
+                    f"{key} is kept in memory only")
+                return
             payload = {"version": CACHE_VERSION, "platform": self.platform,
                        "entries": self._entries}
             tmp = self.path + ".tmp"

@@ -43,9 +43,8 @@ SITES = ("ckpt_fetch", "ckpt_shard_write", "ckpt_pre_rename",
 # occurrence in {1, 2} of every site is guaranteed to fire.
 TRAIN_SCRIPT = """
     import os, sys
-    os.environ.pop("JAX_PLATFORMS", None)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, "/root/repo")
     import numpy as np
     import paddle_tpu as paddle
@@ -91,9 +90,8 @@ def _write_script(tmp_path):
 # zero-checkpoint state.
 RESAVE_SCRIPT = """
     import os, sys
-    os.environ.pop("JAX_PLATFORMS", None)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, "/root/repo")
     import numpy as np
     from paddle_tpu.incubate.checkpoint import commit_checkpoint

@@ -59,9 +59,8 @@ TRAIN_SCRIPT = """
             os.environ["PADDLE_TPU_FAULT_SPEC"] = spec
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={2 // nprocs}")
-    os.environ.pop("JAX_PLATFORMS", None)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, REPO)
     import numpy as np
     import paddle_tpu as paddle

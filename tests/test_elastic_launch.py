@@ -147,9 +147,8 @@ class TestElasticSupervisor:
 
 TRAIN_SCRIPT = """
     import os, sys
-    os.environ.pop("JAX_PLATFORMS", None)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, "/root/repo")
     import numpy as np
     import paddle_tpu as paddle

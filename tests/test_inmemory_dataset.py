@@ -91,9 +91,8 @@ def test_queue_dataset_streams(tmp_path):
 
 GLOBAL_SHUFFLE_SCRIPT = textwrap.dedent("""
     import json, os, sys
-    os.environ.pop("JAX_PLATFORMS", None)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, {repo!r})
     import numpy as np
     import paddle_tpu.distributed as dist

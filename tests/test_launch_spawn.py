@@ -12,9 +12,8 @@ import pytest
 
 TRAIN_SCRIPT = textwrap.dedent("""
     import os, sys
-    os.environ.pop("JAX_PLATFORMS", None)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, {repo!r})
     import paddle_tpu.distributed as dist
 

@@ -30,9 +30,8 @@ DIST_TRAIN = textwrap.dedent("""
     per_proc_devices = 8 // nprocs
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={{per_proc_devices}}")
-    os.environ.pop("JAX_PLATFORMS", None)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, {repo!r})
     import numpy as np
     import paddle_tpu as paddle

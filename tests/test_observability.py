@@ -353,8 +353,10 @@ class TestMetricszHTTP:
             assert "paddle_tpu_serving_llm_tokens_generated_total" \
                 in families
             assert "paddle_tpu_serving_llm_decode_tick_ms" in families
-            # measure_mfu published a live MFU gauge
-            assert "paddle_tpu_serving_llm_mfu" in families
+            # measure_mfu published the decode step's FLOPs; the CPU has
+            # no peak in the table, so no MFU gauge comes from it
+            assert "paddle_tpu_serving_llm_decode_flops_per_tick" in families
+            assert "paddle_tpu_serving_llm_mfu" not in families
         finally:
             srv.shutdown()
             srv.server_close()
@@ -493,9 +495,32 @@ class TestStepMeter:
                                        mac_convention=False)
         assert raw == pytest.approx(2 * n ** 3, rel=0.05)
 
-    def test_peak_flops_env_override(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "123e9")
-        assert stepmeter.default_peak_flops() == 123e9
+    def test_peak_table_is_keyed_by_device_kind(self, monkeypatch):
+        import types
+        import jax
+
+        def fake(platform, kind):
+            dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+            monkeypatch.setattr(jax, "devices", lambda *a: [dev])
+
+        assert stepmeter.default_peak_flops() is None       # the CPU: no peak
+        fake("tpu", "TPU v5 lite")
+        assert stepmeter.default_peak_flops() == 197.0e12
+        fake("tpu", "TPU v99")
+        with pytest.raises(KeyError, match="TPU v99"):
+            stepmeter.default_peak_flops()
+        fake("gpu", "NVIDIA H100")                 # no accelerator default
+        with pytest.raises(KeyError, match="H100"):
+            stepmeter.default_peak_flops()
+
+    def test_cpu_meter_publishes_no_mfu(self):
+        reg = StatRegistry()
+        m = stepmeter.StepMeter(registry=reg)              # CPU: peak None
+        m.set_flops_per_step(5e8)
+        assert m.step(0.5) is None
+        assert reg.get("train.flops_per_step") == 5e8
+        assert reg.histogram("train.step_ms")["count"] == 1
+        assert m.last_mfu is None
 
     def test_hapi_attach_step_meter_publishes_live_stats(self):
         reg = StatRegistry()

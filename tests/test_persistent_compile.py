@@ -51,22 +51,13 @@ def _export_artifact(tmp_path):
 
 @pytest.fixture()
 def persist_env(tmp_path, monkeypatch):
-    """Fresh persistence root + reset process-wide cache state; restores
-    the jax compilation-cache config afterwards so later tests are
-    unaffected."""
-    import jax
-    saved = {k: getattr(jax.config, k) for k in
-             ("jax_compilation_cache_dir",
-              "jax_persistent_cache_min_compile_time_secs",
-              "jax_persistent_cache_min_entry_size_bytes")}
+    """Fresh executable-store root + reset process-wide cache state."""
     monkeypatch.setenv("PADDLE_TPU_COMPILE_CACHE", str(tmp_path))
     cache_mod._reset_persistence_for_tests()
     cache_mod._reset_default_cache_for_tests()
     yield tmp_path
     cache_mod._reset_persistence_for_tests()
     cache_mod._reset_default_cache_for_tests()
-    for k, v in saved.items():
-        jax.config.update(k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -107,8 +98,13 @@ print(json.dumps({
 def _run_child(prefix, cache_root, tmp_path):
     script = tmp_path / "child.py"
     script.write_text(_CHILD)
+    # two tiers, placed separately: the executable store by the repo's
+    # variable, jax's own cache by jax's
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PADDLE_TPU_COMPILE_CACHE=str(cache_root),
+               JAX_COMPILATION_CACHE_DIR=str(cache_root / "xla"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1",
                PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH",
                                                              ""))
     proc = subprocess.run([sys.executable, str(script), prefix],
@@ -138,6 +134,9 @@ def test_warm_start_performs_zero_xla_compiles(tmp_path):
     assert warm["compile_requests"] == warm["xla_cache_hits"]
     # same numbers out of both processes
     assert warm["out_sum"] == cold["out_sum"]
+    # each tier wrote under its own directory and nowhere else
+    assert sorted(os.listdir(cache_root)) == ["executables", "xla"]
+    assert os.listdir(cache_root / "xla")
 
 
 # ---------------------------------------------------------------------------

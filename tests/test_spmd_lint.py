@@ -141,9 +141,8 @@ def test_pta011_clean_on_fleet_code():
 
 def _schedule_of(fn, *args, n=4, axis="r", in_specs=P("r"),
                  out_specs=P("r")):
-    from jax.experimental.shard_map import shard_map
-    wrapped = shard_map(fn, mesh=_mesh(n, axis), in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    wrapped = jax.shard_map(fn, mesh=_mesh(n, axis), in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     return passes.collective_schedule(jax.make_jaxpr(wrapped)(*args))
 
 
@@ -318,10 +317,9 @@ def test_pta012_killable_by_noqa(tmp_path, monkeypatch):
 
 
 def test_pta012_end_to_end_on_seeded_broken_ring():
-    from jax.experimental.shard_map import shard_map
     from tests.fixtures.spmd_seeded import broken_ring_body
-    fn = shard_map(broken_ring_body, mesh=_mesh(4, "r"),
-                   in_specs=P("r"), out_specs=P("r"), check_rep=False)
+    fn = jax.shard_map(broken_ring_body, mesh=_mesh(4, "r"),
+                       in_specs=P("r"), out_specs=P("r"), check_vma=False)
     spec = AuditSpec(fn=fn, make_args=lambda v: (
         jnp.full((8, 4), float(v), jnp.float32),))
     st = audit_spec("seeded_ring", spec)

@@ -10,7 +10,7 @@ framework (stdlib only — it must run before anything heavy imports) with
 - inline ``# noqa: PTA###`` suppressions,
 - a checked-in baseline (tools/analyze/baseline.json) so pre-existing
   findings don't block CI while newly introduced ones do,
-- ``--json`` machine output and check_bench_regression-style exit codes
+- ``--json`` machine output and gate-style exit codes
   (0 clean, 1 new findings, 2 internal error).
 
 Rules (see docs/static_analysis.md):

@@ -1,6 +1,6 @@
 """Driver: ``python -m tools.analyze [options] [paths...]``.
 
-Exit codes (check_bench_regression-style):
+Exit codes:
     0   clean — no findings beyond the baseline
     1   new findings (or --write-baseline wrote nothing because of an error)
     2   internal error in the analyzer itself

@@ -140,8 +140,9 @@ def run_audit(names: Optional[List[str]] = None) -> TraceReport:
     try:
         import jax
         try:
-            # some images install accelerator plugins that override the
-            # env var; the config knob wins if no backend is live yet
+            # jax may have been imported before the setdefault above (the
+            # env var is read at import); the config knob still wins if no
+            # backend is live yet
             jax.config.update("jax_platforms", "cpu")
         except Exception:
             pass  # backend already initialized — platform field records it

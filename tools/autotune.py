@@ -4,10 +4,10 @@
 Searches block/grid configurations for the flash-attention family
 (``paddle_tpu.ops.pallas_attention``, ring-flash chunks) and the
 greedy-NMS kernel (``ops/custom.py``) by timing the real kernels, and
-writes the winners into the on-disk cache (``PADDLE_TPU_TUNE_CACHE`` or
-``~/.cache/paddle_tpu/tuning/``) that every kernel call consults — run
-it once per platform/fleet and the searched configs are free forever
-after.
+writes the winners into the on-disk cache (``PADDLE_TPU_TUNE_CACHE``;
+unset, winners reach later processes only through ``--emit-defaults``)
+that every kernel call consults — run it once per platform/fleet and the
+searched configs are free forever after.
 
     python tools/autotune.py                 # tune this platform's lane
     python tools/autotune.py --quick         # small shapes (CPU/CI lane)
@@ -106,7 +106,7 @@ def tune_flash_lane(shapes, trials, batch_heads, bwd=False):
     return results
 
 
-def tune_nms_lane(ks, trials, interpret):
+def tune_nms_lane(ks, trials):
     import jax
     import jax.numpy as jnp
     from paddle_tpu import tuner
@@ -127,9 +127,7 @@ def tune_nms_lane(ks, trials, interpret):
             # (unroll is baked into the kernel); the tuner times fresh
             # compiles on purpose and never reuses these traces.
             fn = jax.jit(lambda a, b, c, u=int(cand["unroll"]):  # noqa: PTA008 -- per-candidate kernels differ; tuner intentionally compiles each once
-                         _custom.pallas_greedy_nms(a, b, c,
-                                                   interpret=interpret,
-                                                   unroll=u))
+                         _custom.pallas_greedy_nms(a, b, c, unroll=u))
             return lambda: fn(iou, valid, thr)
 
         best, best_t, _ = _runner.search(tuner.nms_candidates(k),
@@ -225,7 +223,6 @@ def main(argv=None):
     platform = jax.devices()[0].platform
     on_tpu = platform == "tpu"
     quick = args.quick or (not on_tpu and not args.full)
-    interpret = not on_tpu
     flash_shapes = QUICK_FLASH_SHAPES if quick else BENCH_FLASH_SHAPES
     flash_bwd_shapes = (QUICK_FLASH_BWD_SHAPES if quick
                         else BENCH_FLASH_BWD_SHAPES)
@@ -249,7 +246,7 @@ def main(argv=None):
     if args.only in (None, "paged"):
         tuned.update(tune_paged_lane(paged_shapes, args.trials))
     if args.only in (None, "nms"):
-        tuned.update(tune_nms_lane(nms_ks, args.trials, interpret))
+        tuned.update(tune_nms_lane(nms_ks, args.trials))
     if args.only in (None, "compress"):
         tuned.update(tune_compress_lane(compress_sizes, args.trials))
 

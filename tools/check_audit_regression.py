@@ -12,10 +12,9 @@ quantize/dequantize stages must fuse in-graph) and
 compares the per-entrypoint counts that move MFU — host transfers inside
 the compiled region, large closed-over control-flow constants, missed
 donation, retraces, and the HLO copy fraction — against the committed
-``bench_audit_baseline.json``. The throughput gate
-(check_bench_regression.py) sees a regression only after a TPU round;
-this one catches the *cause* (a fusion break on the step path) on CPU in
-CI, before any chip time is spent.
+``bench_audit_baseline.json``. A throughput measurement sees a
+regression only after a chip run; this gate catches the *cause* (a fusion
+break on the step path) on CPU in CI, before any chip time is spent.
 
 Usage:
     python tools/check_audit_regression.py              # run audit + gate

@@ -81,7 +81,6 @@ def compile_full_size():
                                + f" --xla_force_host_platform_device_count={N_DEV}")
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
