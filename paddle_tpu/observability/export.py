@@ -40,6 +40,10 @@ def to_trace_events(spans: List[Dict], pid: int = 0,
         args = dict(s.get("attrs") or {})
         if s.get("depth"):
             args["depth"] = s["depth"]
+        if s.get("id"):
+            # self time = dur less the events whose parent_id is this id
+            args["span_id"] = s["id"]
+            args["parent_id"] = s.get("parent", 0)
         if args:
             ev["args"] = args
         events.append(ev)

@@ -24,6 +24,7 @@ is recorded so exporters can map onto wall time.
 """
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
@@ -41,6 +42,10 @@ _ENABLED = [False]
 DEFAULT_CAPACITY = int(os.environ.get("PADDLE_TPU_TRACE_CAPACITY", "8192"))
 
 _tls = threading.local()
+
+#: span ids, unique in the process; ``next()`` on a count is GIL-atomic.
+#: 0 is "no span" (the ``parent`` of a span opened at depth 0).
+_SPAN_IDS = itertools.count(1)
 
 
 def _stack() -> list:
@@ -78,7 +83,7 @@ class Span:
     disabled, defeating the fast path)."""
 
     __slots__ = ("name", "attrs", "t0_ns", "t1_ns", "tid", "thread_name",
-                 "depth", "_tracer", "_ann")
+                 "depth", "id", "parent", "_tracer", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str,
                  attrs: Optional[Dict] = None):
@@ -90,6 +95,8 @@ class Span:
         self.tid = 0
         self.thread_name = ""
         self.depth = 0
+        self.id = 0
+        self.parent = 0
         self._ann = None
 
     def set_attr(self, key, value):
@@ -104,6 +111,10 @@ class Span:
         self.thread_name = t.name
         stack = _stack()
         self.depth = len(stack)
+        # the enclosing span on this thread: a span's self time is its
+        # duration less what the spans that name it as parent cover
+        self.id = next(_SPAN_IDS)
+        self.parent = stack[-1].id if stack else 0
         stack.append(self)
         if _profiler._ACTIVE[0]:
             try:
@@ -170,6 +181,8 @@ class SpanTracer:
             "tid": s.tid,
             "thread": s.thread_name,
             "depth": s.depth,
+            "id": s.id,
+            "parent": s.parent,
             "attrs": s.attrs,
         })
 

@@ -42,7 +42,6 @@ from typing import Optional
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
 from ...request import RequestTooLarge
@@ -196,90 +195,74 @@ class PagedBatcher(ContinuousBatcher):
     def _admit_paged(self, req: GenerationRequest, entry, reuse_n: int,
                      ext_n: int, cow_src: Optional[int]):
         """The committed admission: slot + page mapping + prefill +
-        first-token delivery (the paged ``_admit_inner``)."""
+        first-token delivery (the paged lane's ``ContinuousBatcher.admit``)."""
         t0 = self._clock()
+        # queue and parking in _pending since it was enqueued
+        self._stat_add("queue_wait_s", t0 - req.t_enqueue)
         page = self.kv.page_size
-        slot = self.kv.alloc()
-        req.weights_version = self.weights_version
-        self._reqs[slot] = req
-        self._slot_samp[slot] = req.sampling
-        self._samp_vecs = pack_sampling(self._slot_samp)
-        samp1 = pack_sampling([req.sampling])
-        slot_arr = jnp.asarray([slot], jnp.int32)
-        if reuse_n > 0:
-            for pid in entry.page_ids[:reuse_n // page]:
-                self.kv.adopt_shared_page(slot, pid)
-            self.prefix_store.note_shared(
-                (reuse_n // page) * self.kv.page_nbytes())
-        if cow_src is not None:
-            self.kv.adopt_copied_page(slot, cow_src)
-            self.prefix_store.note_copied(self.kv.page_nbytes())
-            self._stat_add("prefix.cow_splits", 1)
-        self.kv.ensure_pages(slot, req.prompt_len)
-        if entry is not None:
-            req._prefix_entry = entry       # stays pinned until release
-            tail = req.prompt[ext_n:]
-            lt = self.config.bucket_for(int(tail.size))
-            padded = np.zeros((1, lt), np.int32)
-            padded[0, :tail.size] = tail
-            nxt, self._finished = self.decoder.tail_prefill(
-                self.kv, self._params, jnp.asarray(padded),
-                jnp.asarray([int(tail.size)], jnp.int32),
-                jnp.asarray([ext_n], jnp.int32), slot_arr,
-                self._finished, samp1, self._next_key())
-            self._stat_add("prefix.reused_tokens", ext_n)
-        else:
-            lp = self.config.bucket_for(req.prompt_len)
-            padded = np.zeros((1, lp), np.int32)
-            padded[0, :req.prompt_len] = req.prompt
-            nxt, self._finished = self.decoder.prefill(
-                self.kv, self._params, jnp.asarray(padded),
-                jnp.asarray([req.prompt_len], jnp.int32), slot_arr,
-                self._finished, samp1, self._next_key())
-            if self.prefix_store is not None:
-                # miss: claim the page-aligned head BY REFERENCE — the
-                # store retains the sequence's own pages, nothing moves
-                n = (req.prompt_len // page) * page
-                if n >= page:
-                    ins = self.prefix_store.insert(
-                        req.prompt[:n],
-                        self.kv.slot_page_ids(slot)[:n // page],
-                        self.decoder.prefix_sig(self.kv))
-                    if ins is not None:
-                        req._prefix_entry = ins
-        if self.spec is not None:
-            lp = self.config.bucket_for(req.prompt_len)
-            dpad = np.zeros((1, lp), np.int32)
-            dpad[0, :req.prompt_len] = req.prompt
-            self.spec.draft_prefill(
-                self.kv_draft, self._draft_params, jnp.asarray(dpad),
-                jnp.asarray([req.prompt_len], jnp.int32), slot_arr,
-                self.kv.lengths, self._finished, samp1, self._next_key())
-        self._last = self._last.at[jnp.asarray([slot])].set(nxt)
-        tok = int(np.asarray(jax.device_get(nxt))[0])  # noqa: PTA002 -- one [1]-token fetch per admission; first-token delivery (TTFT) needs the value on host
-        now = self._clock()
-        self._stat_observe("prefill_ms", (now - t0) * 1000.0)
-        self._stat_observe("ttft_ms", (now - req.t_enqueue) * 1000.0)
-        self._stat_add("prefills", 1)
-        if not req._emit(tok):
-            self._forget(slot, req)
-            return
-        req._t_last = now
-        self._stat_add("tokens_generated", 1)
-        self._maybe_finish(slot, req, tok)
+        with self.phase("admit_pages"):
+            slot = self.kv.alloc()
+            req.weights_version = self.weights_version
+            self._reqs[slot] = req
+            self._slot_samp[slot] = req.sampling
+            self._samp_vecs = pack_sampling(self._slot_samp)
+            samp1 = pack_sampling([req.sampling])
+            slot_arr = jnp.asarray([slot], jnp.int32)
+            if reuse_n > 0:
+                for pid in entry.page_ids[:reuse_n // page]:
+                    self.kv.adopt_shared_page(slot, pid)
+                self.prefix_store.note_shared(
+                    (reuse_n // page) * self.kv.page_nbytes())
+            if cow_src is not None:
+                self.kv.adopt_copied_page(slot, cow_src)
+                self.prefix_store.note_copied(self.kv.page_nbytes())
+                self._stat_add("prefix.cow_splits", 1)
+            self.kv.ensure_pages(slot, req.prompt_len)
+        with self.phase("prefill", {"req": req.req_id}):
+            if entry is not None:
+                req._prefix_entry = entry   # stays pinned until release
+                tail = req.prompt[ext_n:]
+                lt = self.config.bucket_for(int(tail.size))
+                padded = np.zeros((1, lt), np.int32)
+                padded[0, :tail.size] = tail
+                nxt, self._finished = self.decoder.tail_prefill(
+                    self.kv, self._params, jnp.asarray(padded),
+                    jnp.asarray([int(tail.size)], jnp.int32),
+                    jnp.asarray([ext_n], jnp.int32), slot_arr,
+                    self._finished, samp1, self._next_key())
+                self._stat_add("prefix.reused_tokens", ext_n)
+            else:
+                lp = self.config.bucket_for(req.prompt_len)
+                padded = np.zeros((1, lp), np.int32)
+                padded[0, :req.prompt_len] = req.prompt
+                nxt, self._finished = self.decoder.prefill(
+                    self.kv, self._params, jnp.asarray(padded),
+                    jnp.asarray([req.prompt_len], jnp.int32), slot_arr,
+                    self._finished, samp1, self._next_key())
+                if self.prefix_store is not None:
+                    # miss: claim the page-aligned head BY REFERENCE — the
+                    # store retains the sequence's own pages, nothing moves
+                    n = (req.prompt_len // page) * page
+                    if n >= page:
+                        ins = self.prefix_store.insert(
+                            req.prompt[:n],
+                            self.kv.slot_page_ids(slot)[:n // page],
+                            self.decoder.prefix_sig(self.kv))
+                        if ins is not None:
+                            req._prefix_entry = ins
+            if self.spec is not None:
+                self._draft_prefill(req, slot_arr, samp1)
+            self._last = self._last.at[jnp.asarray([slot])].set(nxt)
+        self._deliver_first_token(req, slot, nxt, t0)
 
     # -- per-tick capacity ---------------------------------------------------
     def tick(self) -> int:
-        self._drain_pending()
-        self._stat_set("pages_pending_requests", len(self._pending))
-        if not self._reqs:
-            self._publish_pages()
-            return 0
-        self._ensure_decode_capacity()
-        if not self._reqs:              # capacity pass may evict
-            self._publish_pages()
-            return 0
-        n = super().tick()
+        with self.phase("tick_capacity"):
+            self._drain_pending()
+            self._stat_set("pages_pending_requests", len(self._pending))
+            if self._reqs:
+                self._ensure_decode_capacity()
+        n = super().tick()     # 0 when the capacity pass evicted the last
         self._publish_pages()
         return n
 

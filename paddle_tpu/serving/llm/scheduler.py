@@ -352,6 +352,45 @@ class LLMEngineConfig:
             f"bucket ({self.prefill_buckets[-1]})")
 
 
+#: phases of the worker the batcher times: span name and counter (under the
+#: engine's stat prefix); ``prefill``'s counter says what the span bounds,
+#: the dispatch of the prefill program, not its device time
+_PHASE_COUNTERS = {
+    "loop": ("serving.llm/loop", "worker.loop_s"),
+    "idle_wait": ("serving.llm/idle_wait", "worker.idle_wait_s"),
+    "admit": ("serving.llm/admit", "worker.admit_s"),
+    "admit_pages": ("serving.llm/admit_pages", "worker.admit_pages_s"),
+    "prefill": ("serving.llm/prefill", "worker.prefill_dispatch_s"),
+    "first_token_fetch": ("serving.llm/first_token_fetch",
+                          "worker.first_token_fetch_s"),
+    "tick_capacity": ("serving.llm/tick_capacity", "worker.tick_capacity_s"),
+    "tick_dispatch": ("serving.llm/tick_dispatch", "worker.tick_dispatch_s"),
+    "tick_fetch": ("serving.llm/tick_fetch", "worker.tick_fetch_s"),
+    "tick_emit": ("serving.llm/tick_emit", "worker.tick_emit_s"),
+}
+
+
+class _Phase:
+    """One timed phase of the worker: always adds its elapsed seconds to
+    its counter, and is the tracer's span (the shared no-op while tracing
+    is off) of the same extent."""
+
+    __slots__ = ("_add", "_counter", "_span", "_t0")
+
+    def __init__(self, add, counter: str, span):
+        self._add = add
+        self._counter = counter
+        self._span = span
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, exc_type, exc, tb):
+        self._add(self._counter, time.perf_counter() - self._t0)
+        return self._span.__exit__(exc_type, exc, tb)
+
+
 class ContinuousBatcher:
     """Slot-level scheduling state + the per-tick device interaction.
 
@@ -427,90 +466,114 @@ class ContinuousBatcher:
     def _stat_observe(self, name, v):
         self._registry.observe(f"{self._prefix}.{name}", v)
 
+    # -- worker phases -------------------------------------------------------
+    def phase(self, name: str, attrs: Optional[Dict] = None) -> _Phase:
+        """``with self.phase("tick_fetch"): ...`` times one phase of the
+        worker (a key of ``_PHASE_COUNTERS``): its seconds always go to the
+        ``worker.*_s`` counter, and with the tracer on it is also the span
+        ``serving.llm/<name>``, so the counter and the span bound the same
+        statements."""
+        span_name, counter = _PHASE_COUNTERS[name]
+        return _Phase(self._stat_add, counter, _otrace.span(span_name, attrs))
+
     # -- scheduling ----------------------------------------------------------
     def admit(self, req: GenerationRequest):
         """Prefill ``req`` into a free slot and deliver its first token.
         The caller guarantees ``free_slots > 0`` and a bucket-fitting
         prompt (``submit`` validated both)."""
-        with _otrace.span("serving.llm/prefill"):
-            self._admit_inner(req)
-
-    def _admit_inner(self, req: GenerationRequest):
         t0 = self._clock()
-        slot = self.kv.alloc()
-        req.weights_version = self.weights_version
-        self._reqs[slot] = req
-        self._slot_samp[slot] = req.sampling
-        self._samp_vecs = pack_sampling(self._slot_samp)
-        samp1 = pack_sampling([req.sampling])
-        slot_arr = jnp.asarray([slot], jnp.int32)
-        entry, reuse_n = None, 0
-        if self.prefix_store is not None:
-            # cap: at least one prompt token must prefill (logits source)
-            entry, reuse_n = self.prefix_store.lookup(
-                req.prompt, req.prompt_len - 1,
-                self.decoder.prefix_sig(self.kv))
-            # the PADDED tail bucket must fit behind the reused head —
-            # dynamic_update_slice clamps out-of-range starts, which would
-            # silently corrupt the reused rows. Shrink reuse block-wise
-            # until offset + tail_bucket fits (rarely more than one step).
-            while reuse_n > 0 and reuse_n + self.config.bucket_for(
-                    req.prompt_len - reuse_n) > self.config.max_seq:
-                reuse_n -= self.prefix_store.block_tokens
-            if entry is not None and reuse_n <= 0:
-                self.prefix_store.unpin(entry)
-                entry, reuse_n = None, 0
-        if reuse_n > 0:
-            # hit: bulk-copy the cached head, prefill only the tail bucket
-            self.decoder.insert_prefix(
-                self.kv, entry.k[:, :reuse_n], entry.v[:, :reuse_n], slot)
-            self.prefix_store.note_copied(
-                int(entry.k[:, :reuse_n].nbytes
-                    + entry.v[:, :reuse_n].nbytes))
-            req._prefix_entry = entry       # stays pinned until release
-            tail = req.prompt[reuse_n:]
-            lt = self.config.bucket_for(int(tail.size))
-            padded = np.zeros((1, lt), np.int32)
-            padded[0, :tail.size] = tail
-            nxt, self._finished = self.decoder.tail_prefill(
-                self.kv, self._params, jnp.asarray(padded),
-                jnp.asarray([int(tail.size)], jnp.int32),
-                jnp.asarray([reuse_n], jnp.int32), slot_arr,
-                self._finished, samp1, self._next_key())
-            self._stat_add("prefix.reused_tokens", reuse_n)
-        else:
-            lp = self.config.bucket_for(req.prompt_len)
-            padded = np.zeros((1, lp), np.int32)
-            padded[0, :req.prompt_len] = req.prompt
-            nxt, self._finished = self.decoder.prefill(
-                self.kv, self._params, jnp.asarray(padded),
-                jnp.asarray([req.prompt_len], jnp.int32), slot_arr,
-                self._finished, samp1, self._next_key())
+        # how long the request sat in the queue since it was enqueued
+        self._stat_add("queue_wait_s", t0 - req.t_enqueue)
+        with self.phase("admit_pages"):
+            slot = self.kv.alloc()
+            req.weights_version = self.weights_version
+            self._reqs[slot] = req
+            self._slot_samp[slot] = req.sampling
+            self._samp_vecs = pack_sampling(self._slot_samp)
+            samp1 = pack_sampling([req.sampling])
+            slot_arr = jnp.asarray([slot], jnp.int32)
+            entry, reuse_n = None, 0
             if self.prefix_store is not None:
-                # miss: export the block-aligned head for future requests
-                blk = self.prefix_store.block_tokens
-                n = (req.prompt_len // blk) * blk
-                if n >= blk:
-                    k_h, v_h = self.kv.host_slot_kv(slot, n)
-                    ins = self.prefix_store.insert(
-                        req.prompt[:n], k_h, v_h,
-                        self.decoder.prefix_sig(self.kv))
-                    if ins is not None:
-                        req._prefix_entry = ins
-        if self.spec is not None:
-            # the draft cache never reuses prefixes (the draft is cheap and
-            # its K/V is not stored); full-prompt prefill, keep K/V only
-            lp = self.config.bucket_for(req.prompt_len)
-            dpad = np.zeros((1, lp), np.int32)
-            dpad[0, :req.prompt_len] = req.prompt
-            self.spec.draft_prefill(
-                self.kv_draft, self._draft_params, jnp.asarray(dpad),
-                jnp.asarray([req.prompt_len], jnp.int32), slot_arr,
-                self.kv.lengths, self._finished, samp1, self._next_key())
-        self._last = self._last.at[jnp.asarray([slot])].set(nxt)
-        # The admission-time fetch of the first generated token: streaming
-        # TTFT requires it on host, and it doubles as the finish probe.
-        tok = int(np.asarray(jax.device_get(nxt))[0])  # noqa: PTA002 -- one [1]-token fetch per admission; first-token delivery (TTFT) needs the value on host
+                # cap: at least one prompt token must prefill (logits
+                # source)
+                entry, reuse_n = self.prefix_store.lookup(
+                    req.prompt, req.prompt_len - 1,
+                    self.decoder.prefix_sig(self.kv))
+                # the PADDED tail bucket must fit behind the reused head —
+                # dynamic_update_slice clamps out-of-range starts, which
+                # would silently corrupt the reused rows. Shrink reuse
+                # block-wise until offset + tail_bucket fits (rarely more
+                # than one step).
+                while reuse_n > 0 and reuse_n + self.config.bucket_for(
+                        req.prompt_len - reuse_n) > self.config.max_seq:
+                    reuse_n -= self.prefix_store.block_tokens
+                if entry is not None and reuse_n <= 0:
+                    self.prefix_store.unpin(entry)
+                    entry, reuse_n = None, 0
+        with self.phase("prefill", {"req": req.req_id}):
+            if reuse_n > 0:
+                # hit: bulk-copy the cached head, prefill only the tail
+                # bucket
+                self.decoder.insert_prefix(
+                    self.kv, entry.k[:, :reuse_n], entry.v[:, :reuse_n],
+                    slot)
+                self.prefix_store.note_copied(
+                    int(entry.k[:, :reuse_n].nbytes
+                        + entry.v[:, :reuse_n].nbytes))
+                req._prefix_entry = entry   # stays pinned until release
+                tail = req.prompt[reuse_n:]
+                lt = self.config.bucket_for(int(tail.size))
+                padded = np.zeros((1, lt), np.int32)
+                padded[0, :tail.size] = tail
+                nxt, self._finished = self.decoder.tail_prefill(
+                    self.kv, self._params, jnp.asarray(padded),
+                    jnp.asarray([int(tail.size)], jnp.int32),
+                    jnp.asarray([reuse_n], jnp.int32), slot_arr,
+                    self._finished, samp1, self._next_key())
+                self._stat_add("prefix.reused_tokens", reuse_n)
+            else:
+                lp = self.config.bucket_for(req.prompt_len)
+                padded = np.zeros((1, lp), np.int32)
+                padded[0, :req.prompt_len] = req.prompt
+                nxt, self._finished = self.decoder.prefill(
+                    self.kv, self._params, jnp.asarray(padded),
+                    jnp.asarray([req.prompt_len], jnp.int32), slot_arr,
+                    self._finished, samp1, self._next_key())
+                if self.prefix_store is not None:
+                    # miss: export the block-aligned head for future
+                    # requests
+                    blk = self.prefix_store.block_tokens
+                    n = (req.prompt_len // blk) * blk
+                    if n >= blk:
+                        k_h, v_h = self.kv.host_slot_kv(slot, n)
+                        ins = self.prefix_store.insert(
+                            req.prompt[:n], k_h, v_h,
+                            self.decoder.prefix_sig(self.kv))
+                        if ins is not None:
+                            req._prefix_entry = ins
+            if self.spec is not None:
+                self._draft_prefill(req, slot_arr, samp1)
+            self._last = self._last.at[jnp.asarray([slot])].set(nxt)
+        self._deliver_first_token(req, slot, nxt, t0)
+
+    def _draft_prefill(self, req: GenerationRequest, slot_arr, samp1):
+        # the draft cache never reuses prefixes (the draft is cheap and
+        # its K/V is not stored); full-prompt prefill, keep K/V only
+        lp = self.config.bucket_for(req.prompt_len)
+        dpad = np.zeros((1, lp), np.int32)
+        dpad[0, :req.prompt_len] = req.prompt
+        self.spec.draft_prefill(
+            self.kv_draft, self._draft_params, jnp.asarray(dpad),
+            jnp.asarray([req.prompt_len], jnp.int32), slot_arr,
+            self.kv.lengths, self._finished, samp1, self._next_key())
+
+    def _deliver_first_token(self, req: GenerationRequest, slot: int, nxt,
+                             t0: float):
+        """The end of every admission, on either lane: fetch the first
+        generated token (streaming TTFT requires it on host, and it doubles
+        as the finish probe), record the admission and deliver."""
+        with self.phase("first_token_fetch"):
+            tok = int(np.asarray(jax.device_get(nxt))[0])  # noqa: PTA002 -- one [1]-token fetch per admission; first-token delivery (TTFT) needs the value on host
         now = self._clock()
         self._stat_observe("prefill_ms", (now - t0) * 1000.0)
         self._stat_observe("ttft_ms", (now - req.t_enqueue) * 1000.0)
@@ -552,40 +615,45 @@ class ContinuousBatcher:
 
     def _spec_tick(self) -> int:
         t0 = self._clock()
-        self._finished, self._last, out_dev = self.spec.step(
-            self.kv, self.kv_draft, self._params, self._draft_params,
-            self._finished, self._last, self._samp_vecs, self._next_key())
+        with self.phase("tick_dispatch"):
+            self._finished, self._last, out_dev = self.spec.step(
+                self.kv, self.kv_draft, self._params, self._draft_params,
+                self._finished, self._last, self._samp_vecs,
+                self._next_key())
         # THE one host fetch of the tick: the packed [S, k+2]
         # (count | tokens...) matrix — same budget as the plain tick's
         # next-token vector, just wider.
-        out = np.asarray(jax.device_get(out_dev))  # noqa: PTA002 -- the single per-tick packed emit fetch; token streaming requires host delivery
-        n = len(self._reqs)
-        dt = max(self._clock() - t0, 1e-9)
-        total = 0
-        for slot, req in list(self._reqs.items()):
-            if req.expired:
-                self._evict(slot, req)
-                continue
-            n_emit = int(out[slot, 0])
-            toks = out[slot, 1:1 + n_emit]
-            if not req.sampling.do_sample:
-                # acceptance accounting is a greedy-lane concept; sampling
-                # slots take one verified token per tick by construction
-                self._spec_proposed += self.spec.k
-                self._spec_accepted += n_emit - 1
-                self._stat_add("spec.proposed", self.spec.k)
-                self._stat_add("spec.accepted", n_emit - 1)
-            total += self._emit_many(slot, req, toks)
-        self._stat_observe("decode_tick_ms", dt * 1000.0)
-        # per-token time: the tick advanced each slot by total/n tokens on
-        # average, so normalize to stay comparable with the plain tick
-        self._stat_observe("tpot_ms", dt * 1000.0 * n / max(1, total))
-        self._stat_add("tokens_generated", total)
-        self._stat_set("tokens_per_sec", total / dt)
-        self._stat_add("spec.ticks", 1)
-        if self._spec_proposed:
-            self._stat_set("spec.acceptance_rate",
-                           self._spec_accepted / self._spec_proposed)
+        with self.phase("tick_fetch"):
+            out = np.asarray(jax.device_get(out_dev))  # noqa: PTA002 -- the single per-tick packed emit fetch; token streaming requires host delivery
+        with self.phase("tick_emit"):
+            n = len(self._reqs)
+            dt = max(self._clock() - t0, 1e-9)
+            total = 0
+            for slot, req in list(self._reqs.items()):
+                if req.expired:
+                    self._evict(slot, req)
+                    continue
+                n_emit = int(out[slot, 0])
+                toks = out[slot, 1:1 + n_emit]
+                if not req.sampling.do_sample:
+                    # acceptance accounting is a greedy-lane concept;
+                    # sampling slots take one verified token per tick by
+                    # construction
+                    self._spec_proposed += self.spec.k
+                    self._spec_accepted += n_emit - 1
+                    self._stat_add("spec.proposed", self.spec.k)
+                    self._stat_add("spec.accepted", n_emit - 1)
+                total += self._emit_many(slot, req, toks)
+            self._stat_observe("decode_tick_ms", dt * 1000.0)
+            # per-token time: the tick advanced each slot by total/n tokens
+            # on average, so normalize to stay comparable with the plain
+            # tick
+            self._stat_observe("tpot_ms", dt * 1000.0 * n / max(1, total))
+            self._stat_add("tokens_generated", total)
+            self._stat_add("spec.ticks", 1)
+            if self._spec_proposed:
+                self._stat_set("spec.acceptance_rate",
+                               self._spec_accepted / self._spec_proposed)
         return n
 
     def _emit_many(self, slot: int, req: GenerationRequest, toks) -> int:
@@ -619,38 +687,41 @@ class ContinuousBatcher:
         if self.config.measure_mfu and self._decode_flops is None:
             self._measure_decode_flops()
         t0 = self._clock()
-        nxt, self._finished = self.decoder.decode_step(
-            self.kv, self._params, self._finished, self._last,
-            self._samp_vecs, self._next_key())
-        self._last = nxt
+        with self.phase("tick_dispatch"):
+            nxt, self._finished = self.decoder.decode_step(
+                self.kv, self._params, self._finished, self._last,
+                self._samp_vecs, self._next_key())
+            self._last = nxt
         # THE one host fetch of the tick: the [num_slots] next-token
         # vector. Streaming delivery and host-side finish detection both
         # consume it, so this sync is the feature, not an accident.
-        toks = np.asarray(jax.device_get(nxt))  # noqa: PTA002 -- the single per-tick [num_slots] fetch; token streaming requires host delivery
-        n = len(self._reqs)
-        dt = max(self._clock() - t0, 1e-9)
-        self._stat_observe("decode_tick_ms", dt * 1000.0)
-        self._stat_observe("tpot_ms", dt * 1000.0)
-        self._stat_add("tokens_generated", n)
-        self._stat_set("tokens_per_sec", n / dt)
-        if self._decode_flops and self._peak_flops:
-            # tick wall time includes the sanctioned token fetch, so this
-            # is delivered MFU, not device-only MFU
-            self._stat_set("mfu", self._decode_flops / dt / self._peak_flops)
-        now = self._clock()
-        for slot, req in list(self._reqs.items()):
-            if req.expired:
-                self._evict(slot, req)
-                continue
-            tok = int(toks[slot])
-            if not req._emit(tok):
-                self._forget(slot, req)
-                continue
-            if req._t_last is not None:
-                self._stat_observe("intertoken_ms",
-                                   (now - req._t_last) * 1000.0)
-            req._t_last = now
-            self._maybe_finish(slot, req, tok)
+        with self.phase("tick_fetch"):
+            toks = np.asarray(jax.device_get(nxt))  # noqa: PTA002 -- the single per-tick [num_slots] fetch; token streaming requires host delivery
+        with self.phase("tick_emit"):
+            n = len(self._reqs)
+            dt = max(self._clock() - t0, 1e-9)
+            self._stat_observe("decode_tick_ms", dt * 1000.0)
+            self._stat_observe("tpot_ms", dt * 1000.0)
+            self._stat_add("tokens_generated", n)
+            if self._decode_flops and self._peak_flops:
+                # tick wall time includes the sanctioned token fetch, so
+                # this is delivered MFU, not device-only MFU
+                self._stat_set("mfu",
+                               self._decode_flops / dt / self._peak_flops)
+            now = self._clock()
+            for slot, req in list(self._reqs.items()):
+                if req.expired:
+                    self._evict(slot, req)
+                    continue
+                tok = int(toks[slot])
+                if not req._emit(tok):
+                    self._forget(slot, req)
+                    continue
+                if req._t_last is not None:
+                    self._stat_observe("intertoken_ms",
+                                       (now - req._t_last) * 1000.0)
+                req._t_last = now
+                self._maybe_finish(slot, req, tok)
         return n
 
     def _measure_decode_flops(self):
@@ -1255,69 +1326,12 @@ class LLMEngine(DrainableEngineBase):
 
     # -- worker --------------------------------------------------------------
     def _worker_loop(self):
-        cfg = self._config
+        batcher = self._batcher
         try:
-            while True:
-                # between-tick control plane: migration export/import
-                # closures run here, on the worker, never mid-tick
-                while self._ctl:
-                    fn, box, ev = self._ctl.popleft()
-                    try:
-                        box["ret"] = fn()
-                    except BaseException as e:  # noqa: BLE001 -- boxed and re-raised on the calling thread
-                        box["exc"] = e
-                    finally:
-                        ev.set()
-                if self._killed.is_set():
-                    # hard-kill: queued requests were failed by kill()
-                    # itself. With recovery armed, in-flight sequences are
-                    # EVACUATED (futures pending, for the router's replay);
-                    # otherwise aborted as before. Either way this is a
-                    # commanded death, not a worker crash, so no re-raise /
-                    # no noisy daemon-thread traceback.
-                    n = self._batcher.active
-                    if self.journal is not None:
-                        self._evacuated.extend(self._batcher.evacuate())
-                    else:
-                        self._batcher.abort_all(
-                            lambda req: EngineKilled(
-                                f"engine hard-killed ({self._kill_reason}) "
-                                f"with request {req.req_id} in flight after "
-                                f"{len(req.tokens)} tokens"))
-                    _flight.record_event(
-                        "engine_killed",
-                        {"engine": self._prefix,
-                         "reason": self._kill_reason,
-                         "aborted": 0 if self.journal is not None else n,
-                         "evacuated": n if self.journal is not None else 0})
-                    return
-                if self._guard is not None and self._guard.preempted \
-                        and not self._draining.is_set():
-                    self._stat_add("preemption_drains", 1)
-                    self.begin_drain()
-                elif self._draining.is_set() and not self._queue.closed:
-                    # flag set by the async-signal-safe handler; complete
-                    # the drain outside signal context
-                    self._queue.close()
-                free = self._batcher.free_slots
-                if free > 0:
-                    timeout = 0.0 if self._batcher.active else cfg.idle_poll
-                    for req in self._queue.take_many(free, timeout=timeout):
-                        self._batcher.admit(req)
-                self._stat_set("queue_depth", len(self._queue))
-                self._stat_set("deadline_evicted_queued",
-                               self._queue.evicted_expired)
-                self._stat_set("slots_in_use", self._batcher.active)
-                if self._batcher.active:
-                    self._batcher.tick()
-                    if self.journal is not None and self._batcher.active:
-                        # O(1) reference enqueue; the journal's flush
-                        # thread does the copying (async-dispatch
-                        # discipline: the tick never pays for durability)
-                        self.journal.note(self._batcher._reqs.values())
-                elif self._draining.is_set() and len(self._queue) == 0:
-                    break
-                self._publish_cache_stats()
+            stop = False
+            while not stop:
+                with batcher.phase("loop"):
+                    stop = self._loop_once()
         except BaseException as e:  # worker death must not strand futures
             _flight.record_event(
                 "llm_worker_death",
@@ -1343,6 +1357,79 @@ class LLMEngine(DrainableEngineBase):
                                      {"engine": self._prefix})
                 _flight.dump_if_armed("sigterm_drain")
             self._stopped.set()
+
+    def _loop_once(self) -> bool:
+        """One iteration of the worker: control plane, admission, one
+        decode tick. True when the worker is to exit (killed, or drained
+        dry)."""
+        # between-tick control plane: migration export/import closures run
+        # here, on the worker, never mid-tick
+        while self._ctl:
+            fn, box, ev = self._ctl.popleft()
+            try:
+                box["ret"] = fn()
+            except BaseException as e:  # noqa: BLE001 -- boxed and re-raised on the calling thread
+                box["exc"] = e
+            finally:
+                ev.set()
+        if self._killed.is_set():
+            # hard-kill: queued requests were failed by kill() itself.
+            # With recovery armed, in-flight sequences are EVACUATED
+            # (futures pending, for the router's replay); otherwise aborted
+            # as before. Either way this is a commanded death, not a worker
+            # crash, so no re-raise / no noisy daemon-thread traceback.
+            n = self._batcher.active
+            if self.journal is not None:
+                self._evacuated.extend(self._batcher.evacuate())
+            else:
+                self._batcher.abort_all(
+                    lambda req: EngineKilled(
+                        f"engine hard-killed ({self._kill_reason}) "
+                        f"with request {req.req_id} in flight after "
+                        f"{len(req.tokens)} tokens"))
+            _flight.record_event(
+                "engine_killed",
+                {"engine": self._prefix,
+                 "reason": self._kill_reason,
+                 "aborted": 0 if self.journal is not None else n,
+                 "evacuated": n if self.journal is not None else 0})
+            return True
+        if self._guard is not None and self._guard.preempted \
+                and not self._draining.is_set():
+            self._stat_add("preemption_drains", 1)
+            self.begin_drain()
+        elif self._draining.is_set() and not self._queue.closed:
+            # flag set by the async-signal-safe handler; complete the
+            # drain outside signal context
+            self._queue.close()
+        free = self._batcher.free_slots
+        if free > 0:
+            if self._batcher.active:
+                reqs = self._queue.take_many(free, timeout=0.0)
+            else:   # nothing to tick: this take may block, for a request
+                with self._batcher.phase("idle_wait"):
+                    reqs = self._queue.take_many(
+                        free, timeout=self._config.idle_poll)
+            for req in reqs:
+                with self._batcher.phase(
+                        "admit", {"req": req.req_id,
+                                  "prompt_len": req.prompt_len}):
+                    self._batcher.admit(req)
+        self._stat_set("queue_depth", len(self._queue))
+        self._stat_set("deadline_evicted_queued",
+                       self._queue.evicted_expired)
+        self._stat_set("slots_in_use", self._batcher.active)
+        if self._batcher.active:
+            self._batcher.tick()
+            if self.journal is not None and self._batcher.active:
+                # O(1) reference enqueue; the journal's flush thread does
+                # the copying (async-dispatch discipline: the tick never
+                # pays for durability)
+                self.journal.note(self._batcher._reqs.values())
+        elif self._draining.is_set() and len(self._queue) == 0:
+            return True
+        self._publish_cache_stats()
+        return False
 
     def _publish_cache_stats(self):
         s = self._cache.stats()
