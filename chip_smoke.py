@@ -26,6 +26,7 @@ timings (one run, compile included where it says so), not benchmark results.
 from __future__ import annotations
 
 import argparse
+import functools
 import glob
 import json
 import os
@@ -152,21 +153,24 @@ def phase_kernels(sizes):
     slots, page, pps = sizes["slots"], sizes["page"], sizes["pages_per_seq"]
     n_pages = slots * pps
     qd = jax.random.normal(ks[4], (slots, heads, hd), jnp.float32)
-    ka = jax.random.normal(ks[5], (n_pages + 1, page, heads, hd), jnp.float32)
-    va = jax.random.normal(ks[6], (n_pages + 1, page, heads, hd), jnp.float32)
+    layers, li = 2, 1      # the kernel reads one layer out of a whole arena
+    ka = jax.random.normal(ks[5], (n_pages + 1, layers, page, heads, hd),
+                           jnp.float32)
+    va = jax.random.normal(ks[6], ka.shape, jnp.float32)
     bt = jnp.asarray(np.random.RandomState(SEED).permutation(n_pages)
                      .reshape(slots, pps), jnp.int32)
     pos = jnp.asarray(np.linspace(0, pps * page - 1, slots), jnp.int32)
 
     def gather_lane(a, b, c, t, p):
-        kd = paged_gather_rows(b, t)                       # [S, max, H, D]
-        vd = paged_gather_rows(c, t)
+        kd = paged_gather_rows(b, t, li)                   # [S, max, H, D]
+        vd = paged_gather_rows(c, t, li)
         s = jnp.einsum("shd,sthd->sht", a, kd) * scale
         j = jnp.arange(kd.shape[1])[None, None, :]
         s = jnp.where(j <= p[:, None, None], s, -1e30)
         return jnp.einsum("sht,sthd->shd", jax.nn.softmax(s, axis=-1), vd)
 
-    got = jax.jit(paged_attention)(qd, ka, va, bt, pos)
+    got = jax.jit(functools.partial(paged_attention, layer=li))(
+        qd, ka, va, bt, pos)
     ref = jax.jit(gather_lane)(qd, ka, va, bt, pos)
     err = float(jnp.max(jnp.abs(got - ref)))
     check(err <= PAGED_ATOL,

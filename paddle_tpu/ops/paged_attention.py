@@ -12,6 +12,14 @@ pages the sequence owns, one per grid step, with the online-softmax
 running statistics (m, l, acc) carried across the page axis in VMEM
 scratch — the flash-attention recurrence over a gathered key axis.
 
+The kernel reads the WHOLE ``[P+1, L, page, H, D]`` arena, all layers of
+it: the layer index rides the scalar-prefetch channel beside the page id
+and the layer axis of the block is squeezed, so a grid step fetches the
+same contiguous ``[page, block_h, D]`` chunk a single-layer arena would
+give, no layer is ever cut out of the arena for the call (the decode
+step updates the arena in place), and every layer's call is one and the
+same Mosaic kernel.
+
 Grid: ``(S, H // block_h, pages_per_seq)`` — the page axis is innermost,
 so on TPU (sequential grid) the scratch accumulators persist across one
 sequence-head-block's page walk and reset via ``@pl.when(p == 0)``.
@@ -78,15 +86,16 @@ def _tuned_block_h(num_heads, head_dim, page_size, dtype):
     return b if b > 0 else None
 
 
-def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_ref, m_ref, l_ref, *, scale, page_size,
+def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
+                       o_ref, acc_ref, m_ref, l_ref, *, scale, page_size,
                        pages_per_seq, block_h):
     """One page of one sequence's head block per grid step. With a single
     query row there is nothing for the MXU to amortize, so the whole
     recurrence stays on the VPU in the arena's own ``[page, heads, D]``
     layout: q.k is a multiply and a lane reduction, softmax statistics
     reduce over the major (page) axis, p.v is a lane broadcast and a
-    major-axis sum — no transpose, no batched dot, no relayout."""
+    major-axis sum — no transpose, no batched dot, no relayout.
+    ``layer_ref`` is read by the index maps only."""
     import jax.experimental.pallas as pl
 
     s = pl.program_id(0)
@@ -123,15 +132,18 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_attention(q, k_arena, v_arena, block_tables, positions,
-                    scale=None, block_h=None, interpret=None):
+                    layer=0, scale=None, block_h=None, interpret=None):
     """Single-token decode attention through a paged KV arena.
 
     ``q``: ``[S, H, D]`` (one query per sequence, already projected);
-    ``k_arena``/``v_arena``: ``[num_pages + 1, page_size, H, D]``
-    single-layer arena views (dense — int8 arenas take the gather lane,
-    which dequantizes in-graph); ``block_tables``: ``[S, pages_per_seq]``
-    int32; ``positions``: ``[S]`` int32 — query ``s`` attends logical
-    rows ``j <= positions[s]``. Returns ``[S, H, D]`` in ``q.dtype``.
+    ``k_arena``/``v_arena``: the whole ``[num_pages + 1, num_layers,
+    page_size, H, D]`` arenas (dense — int8 arenas take the gather
+    lane, which dequantizes in-graph); ``layer``: which layer's rows to
+    attend over, an int or an int32 scalar (a kernel *input*, so every
+    layer runs the same compiled kernel); ``block_tables``:
+    ``[S, pages_per_seq]`` int32; ``positions``: ``[S]`` int32 — query
+    ``s`` attends logical rows ``j <= positions[s]``. Returns
+    ``[S, H, D]`` in ``q.dtype``.
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -141,7 +153,7 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
             "paged_attention kernel reads dense arenas only — the int8 "
             "lane uses the gather implementation (dequantize in-graph)")
     s_n, num_heads, head_dim = q.shape
-    page_size = k_arena.shape[1]
+    page_size = k_arena.shape[2]
     pages_per_seq = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / np.sqrt(head_dim)
@@ -158,20 +170,21 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
         pages_per_seq=pages_per_seq, block_h=block_h)
     bt_flat = block_tables.reshape(-1).astype(jnp.int32)
 
-    def _q_map(s, h, p, bt_ref, len_ref):
+    def _q_map(s, h, p, bt_ref, len_ref, layer_ref):
         return (s, h, 0)
 
-    def _kv_map(s, h, p, bt_ref, len_ref):
-        # the block-table walk: physical page id -> arena block index
-        return (bt_ref[s * pages_per_seq + p], 0, h, 0)
+    def _kv_map(s, h, p, bt_ref, len_ref, layer_ref):
+        # the block-table walk: (physical page id, layer) -> arena block
+        return (bt_ref[s * pages_per_seq + p], layer_ref[0], 0, h, 0)
 
+    kv_block = (1, None, page_size, block_h, head_dim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(s_n, num_heads // block_h, pages_per_seq),
         in_specs=[
             pl.BlockSpec((1, block_h, head_dim), _q_map),
-            pl.BlockSpec((1, page_size, block_h, head_dim), _kv_map),
-            pl.BlockSpec((1, page_size, block_h, head_dim), _kv_map),
+            pl.BlockSpec(kv_block, _kv_map),
+            pl.BlockSpec(kv_block, _kv_map),
         ],
         out_specs=pl.BlockSpec((1, block_h, head_dim), _q_map),
         scratch_shapes=[
@@ -186,4 +199,5 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
                                        q.dtype),
         interpret=resolve_interpret("paged_attn", interpret),
         name="paged_attn",
-    )(bt_flat, positions.astype(jnp.int32), q, k_arena, v_arena)
+    )(bt_flat, positions.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, k_arena, v_arena)

@@ -7,6 +7,15 @@ table. The forward math is untouched — only where K/V rows live changes
 slot row; gather back through the block table instead of reading the
 slot row directly).
 
+The arena is written where it lies. Every program threads the whole
+``[P+1, L, page, H, D]`` arrays through its layer loop as ONE value:
+rows scatter in by ``(page, layer, offset)``, attention reads by
+``(page, layer)``, and no layer is cut out of the arena or stacked back
+into it. The raw builders stay pure functions of their arguments; the
+jitted getters donate both arenas, so XLA updates them in place and a
+tick moves the rows it writes, not the arena (the arrays passed in are
+deleted: ``PagedKVCache.swap`` installs the outputs).
+
 Bitwise parity with the slot path (the acceptance contract): the cache
 enforces ``max_seq % page_size == 0``, so ``paged_gather_rows``
 reconstructs a ``[S, max_seq, H, D]`` tensor shape-identical to a slot
@@ -38,8 +47,7 @@ import jax.numpy as jnp
 from ..decode import (GPTDecodeSpec, GPTStaticDecoder, _AUDIT_SPEC,
                       _AUDIT_TOP_K, _audit_params, _block_prefill,
                       _layer_norm, _mm, _sample)
-from ..kvcache import (dequantize_kv, is_quantized_kv, kv_layer_view,
-                       kv_stack_layers, valid_mask)
+from ..kvcache import dequantize_kv, is_quantized_kv, valid_mask
 from .pool import (PagedKVCache, paged_gather_rows,
                    paged_write_prompt_rows, paged_write_rows,
                    pages_for_tokens)
@@ -57,12 +65,12 @@ def _write_page_index(block_tables, positions, page_size):
     return pid, positions % page_size
 
 
-def _paged_block_decode(spec, lp, h, kb, vb, block_tables, pid, ppos,
-                        positions, mask, scale, attn_impl):
+def _paged_block_decode(spec, lp, h, kbuf, vbuf, li, block_tables, pid,
+                        ppos, positions, mask, scale, attn_impl):
     """One pre-norm block for a single new token per slot — the paged
-    twin of ``decode._block_decode``. ``kb``/``vb``: this layer's
-    ``[P+1, page, H, D]`` arena view; the token's K/V is scattered at
-    (``pid``, ``ppos``) before attending."""
+    twin of ``decode._block_decode``. ``kbuf``/``vbuf``: the whole
+    ``[P+1, L, page, H, D]`` arenas; the token's K/V is scattered at
+    (``pid``, ``li``, ``ppos``) before layer ``li`` attends."""
     s = h.shape[0]
     x = _layer_norm(h, lp["n1w"], lp["n1b"], spec.ln_epsilon)
     q = (_mm(x, lp["qw"]) + lp["qb"]).reshape(s, spec.num_heads,
@@ -71,15 +79,18 @@ def _paged_block_decode(spec, lp, h, kb, vb, block_tables, pid, ppos,
                                                spec.head_dim)
     vn = (_mm(x, lp["vw"]) + lp["vb"]).reshape(s, spec.num_heads,
                                                spec.head_dim)
-    kb = paged_write_rows(kb, kn, pid, ppos)
-    vb = paged_write_rows(vb, vn, pid, ppos)
+    kbuf = paged_write_rows(kbuf, kn, pid, ppos, li)
+    vbuf = paged_write_rows(vbuf, vn, pid, ppos, li)
     if attn_impl == "kernel":
         from ....ops.paged_attention import paged_attention
-        out = paged_attention(q, kb, vb, block_tables, positions,
+        out = paged_attention(q, kbuf, vbuf, block_tables, positions,
+                              layer=li,
                               scale=scale).reshape(s, spec.hidden_size)
     else:
-        kd = dequantize_kv(paged_gather_rows(kb, block_tables), h.dtype)
-        vd = dequantize_kv(paged_gather_rows(vb, block_tables), h.dtype)
+        kd = dequantize_kv(paged_gather_rows(kbuf, block_tables, li),
+                           h.dtype)
+        vd = dequantize_kv(paged_gather_rows(vbuf, block_tables, li),
+                           h.dtype)
         qh = (q * scale)[:, :, None, :]                   # [S, H, 1, D]
         kt = jnp.transpose(kd, (0, 2, 1, 3))              # [S, H, max, D]
         vt = jnp.transpose(vd, (0, 2, 1, 3))
@@ -91,10 +102,29 @@ def _paged_block_decode(spec, lp, h, kb, vb, block_tables, pid, ppos,
     h = h + (_mm(out, lp["ow"]) + lp["ob"])
     x = _layer_norm(h, lp["n2w"], lp["n2b"], spec.ln_epsilon)
     ffn = jax.nn.gelu(_mm(x, lp["w1"]) + lp["b1"], approximate=False)
-    return h + (_mm(ffn, lp["w2"]) + lp["b2"]), kb, vb
+    return h + (_mm(ffn, lp["w2"]) + lp["b2"]), kbuf, vbuf
 
 
 # -- the compiled programs ---------------------------------------------------
+
+def jit_donating_arenas(raw, arenas):
+    """``jax.jit`` of a raw paged program, donating the positional
+    arguments ``arenas`` (its ``kbuf`` and ``vbuf``): with the in-place
+    row writes this is what lets XLA alias each arena to its output
+    instead of copying it. Carries the getters' ``trace_counter``; the
+    compiled module keeps the raw program's name (``jit__step``,
+    ``jit__prefill``, ``jit__tail``), which dumps and traces are read by."""
+    counter = {"traces": 0}
+
+    @functools.wraps(raw)
+    def _fn(*args):
+        counter["traces"] += 1
+        return raw(*args)
+
+    fn = jax.jit(_fn, donate_argnums=arenas)
+    fn.trace_counter = counter
+    return fn
+
 
 def build_paged_decode_step(spec: GPTDecodeSpec, max_top_k: int,
                             page_size: int, attn_impl: str = "gather"):
@@ -106,7 +136,10 @@ def build_paged_decode_step(spec: GPTDecodeSpec, max_top_k: int,
       -> (kbuf, vbuf, lengths+1, finished, next_tokens)
 
     The block table is read-only inside the step (page mapping is host
-    policy, applied between ticks); arenas flow through functionally.
+    policy, applied between ticks). The raw step is a pure function;
+    the arenas run through the layer loop as one value each, written by
+    scatter only, so the jitted, donating step
+    (``get_paged_decode_step``) updates them in place.
     """
     if attn_impl not in ("gather", "kernel"):
         raise ValueError(f"attn_impl must be 'gather' or 'kernel', got "
@@ -123,16 +156,10 @@ def build_paged_decode_step(spec: GPTDecodeSpec, max_top_k: int,
         mask = (valid_mask(positions, max_seq, h.dtype)
                 if attn_impl == "gather" else None)
         pid, ppos = _write_page_index(block_tables, positions, page_size)
-        new_k, new_v = [], []
         for li, lp in enumerate(params["layers"]):
-            h, kb, vb = _paged_block_decode(
-                spec, lp, h, kv_layer_view(kbuf, li),
-                kv_layer_view(vbuf, li), block_tables, pid, ppos,
+            h, kbuf, vbuf = _paged_block_decode(
+                spec, lp, h, kbuf, vbuf, li, block_tables, pid, ppos,
                 positions, mask, scale, attn_impl)
-            new_k.append(kb)
-            new_v.append(vb)
-        kbuf = kv_stack_layers(new_k)
-        vbuf = kv_stack_layers(new_v)
         h = _layer_norm(h, params["fnw"], params["fnb"], spec.ln_epsilon)
         lraw = (h @ params["tok"].T).astype(jnp.float32)          # [S, V]
         nxt = _sample(lraw, temperature, top_k, do_sample, key, max_top_k)
@@ -147,17 +174,11 @@ def build_paged_decode_step(spec: GPTDecodeSpec, max_top_k: int,
 def get_paged_decode_step(spec: GPTDecodeSpec, max_top_k: int,
                           page_size: int, attn_impl: str):
     """Jitted paged decode step; ``trace_counter`` contract matches
-    ``get_decode_step`` (one trace per (num_pages, num_slots) shape)."""
-    counter = {"traces": 0}
-    raw = build_paged_decode_step(spec, max_top_k, page_size, attn_impl)
-
-    def _step(*args):
-        counter["traces"] += 1
-        return raw(*args)
-
-    fn = jax.jit(_step)
-    fn.trace_counter = counter
-    return fn
+    ``get_decode_step`` (one trace per (num_pages, num_slots) shape).
+    Donates ``kbuf`` and ``vbuf``."""
+    return jit_donating_arenas(
+        build_paged_decode_step(spec, max_top_k, page_size, attn_impl),
+        arenas=(1, 2))
 
 
 def build_paged_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
@@ -210,16 +231,8 @@ def build_paged_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
 @functools.lru_cache(maxsize=64)
 def get_paged_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
                          page_size: int):
-    counter = {"traces": 0}
-    raw = build_paged_prefill_fn(spec, max_top_k, page_size)
-
-    def _prefill(*args):
-        counter["traces"] += 1
-        return raw(*args)
-
-    fn = jax.jit(_prefill)
-    fn.trace_counter = counter
-    return fn
+    return jit_donating_arenas(
+        build_paged_prefill_fn(spec, max_top_k, page_size), arenas=(3, 4))
 
 
 def build_paged_tail_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
@@ -266,8 +279,8 @@ def build_paged_tail_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
             # attention reads the gathered logical rows with the fresh
             # tail spliced in; the arenas are written once, after the
             # layer loop
-            row_k = paged_gather_rows(kv_layer_view(kbuf, li), bt_sel)
-            row_v = paged_gather_rows(kv_layer_view(vbuf, li), bt_sel)
+            row_k = paged_gather_rows(kbuf, bt_sel, li)
+            row_v = paged_gather_rows(vbuf, bt_sel, li)
 
             def _splice(row, new, st):
                 return jax.lax.dynamic_update_slice(row, new, (st, 0, 0))
@@ -318,16 +331,9 @@ def build_paged_tail_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
 @functools.lru_cache(maxsize=64)
 def get_paged_tail_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
                               page_size: int):
-    counter = {"traces": 0}
-    raw = build_paged_tail_prefill_fn(spec, max_top_k, page_size)
-
-    def _tail(*args):
-        counter["traces"] += 1
-        return raw(*args)
-
-    fn = jax.jit(_tail)
-    fn.trace_counter = counter
-    return fn
+    return jit_donating_arenas(
+        build_paged_tail_prefill_fn(spec, max_top_k, page_size),
+        arenas=(4, 5))
 
 
 class GPTPagedDecoder(GPTStaticDecoder):

@@ -25,12 +25,21 @@ Whatever lands in the trash page is garbage by construction and every
 read of it is masked by the per-slot length vector.
 
 Host bookkeeping (free list, refcounts) mirrors StaticKVCache's slot
-lifecycle: device arrays are only ever *replaced* by functional step
-outputs; ``alloc``/``release`` never touch the device beyond the O(1)
+lifecycle; ``alloc``/``release`` never touch the device beyond the O(1)
 block-table entry updates, which are jitted scalar scatters.
+
+The arenas are updated IN PLACE. Every jitted program that takes them
+(decode step, prefill, tail prefill, the speculative verify step, and
+the one-page maintenance programs below) *donates* them and writes rows
+by ``(page, layer, offset)`` into the whole 5-D array, so a tick moves
+the rows it writes and not the arena. The arrays handed to such a call
+are deleted by it: :class:`PagedKVCache` installs the outputs at once
+(``swap``), and nothing else may keep a reference to ``kv.k``/``kv.v``
+across a call.
 """
 from __future__ import annotations
 
+import functools
 import heapq
 from typing import List, Optional, Tuple
 
@@ -154,22 +163,24 @@ def _bt_reset_row(bt, slot, fill):
     return bt.at[slot].set(fill)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def _arena_copy_page(buf, dst, src):
     """Copy physical page ``src`` -> ``dst`` (both arenas' leaves): the
-    copy-on-write split. One traced program per arena shape."""
+    copy-on-write split. One traced program per arena shape; ``buf`` is
+    donated, so one page moves and not the arena."""
     def _cp(x):
         row = jax.lax.dynamic_index_in_dim(x, src, axis=0, keepdims=True)
         return jax.lax.dynamic_update_slice_in_dim(x, row, dst, axis=0)
     return jax.tree_util.tree_map(_cp, buf)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def _arena_write_page(buf, dst, page):
     """Install one host-shipped physical page at index ``dst`` — the
     import half of sequence migration. ``page`` carries a single page's
     rows per leaf (``[L, page, H, D]``, or the quantized ``q``/``s``
-    pair); scalar-indexed so one traced program serves every dst."""
+    pair); scalar-indexed so one traced program serves every dst.
+    ``buf`` is donated."""
     def _wr(x, p):
         return jax.lax.dynamic_update_slice_in_dim(
             x, p[None].astype(x.dtype), dst, axis=0)
@@ -183,18 +194,20 @@ def _len_set(lengths, slot, n):
 
 # -- functional writers / readers (used inside jitted programs) --------------
 
-def paged_write_rows(buf, rows, pids, ppos):
-    """Write one K or V row per entry into a single layer's arena view.
+def paged_write_rows(buf, rows, pids, ppos, layer):
+    """Write one K or V row per entry into layer ``layer`` of a whole
+    arena, where it lies.
 
-    ``buf``: ``[P+1, page, H, D]`` (or the quantized dict view);
+    ``buf``: ``[P+1, L, page, H, D]`` (or the quantized dict);
     ``rows``: ``[N, H, D]``; ``pids``/``ppos``: ``[N]`` int32 physical
     page + in-page offset. Rows routed to the trash page may collide —
-    they are junk by construction. One scatter per leaf."""
+    they are junk by construction. One scatter per leaf, and no view of
+    the layer: XLA updates a donated arena in place."""
     if is_quantized_kv(buf):
         qs = quantize_kv_rows(rows)            # q [N, H, D], s [N]
-        return {"q": buf["q"].at[pids, ppos].set(qs["q"]),
-                "s": buf["s"].at[pids, ppos].set(qs["s"])}
-    return buf.at[pids, ppos].set(rows)
+        return {"q": buf["q"].at[pids, layer, ppos].set(qs["q"]),
+                "s": buf["s"].at[pids, layer, ppos].set(qs["s"])}
+    return buf.at[pids, layer, ppos].set(rows)
 
 
 def paged_write_prompt_rows(buf, rows, pids, ppos):
@@ -214,19 +227,20 @@ def paged_write_prompt_rows(buf, rows, pids, ppos):
     return buf.at[pi, li, oi].set(rows)
 
 
-def paged_gather_rows(buf, block_tables):
-    """Reconstruct contiguous logical rows from a single layer's arena
-    view: ``[P+1, page, H, D]`` gathered through ``[S, PP]`` block
-    tables -> ``[S, PP*page, H, D]`` — shape-identical to a slot
+def paged_gather_rows(buf, block_tables, layer):
+    """Reconstruct contiguous logical rows of layer ``layer`` from a
+    whole arena: ``[P+1, L, page, H, D]`` gathered through ``[S, PP]``
+    block tables -> ``[S, PP*page, H, D]`` — shape-identical to a slot
     buffer's layer view, which is what makes the gather attention lane
-    bitwise-equal to the slot path."""
+    bitwise-equal to the slot path. One gather by (page, layer): the
+    layer is never cut out of the arena first."""
     if is_quantized_kv(buf):
-        q = buf["q"][block_tables]             # [S, PP, page, H, D]
-        s = buf["s"][block_tables]             # [S, PP, page]
+        q = buf["q"][block_tables, layer]      # [S, PP, page, H, D]
+        s = buf["s"][block_tables, layer]      # [S, PP, page]
         sh = q.shape
         return {"q": q.reshape(sh[0], sh[1] * sh[2], sh[3], sh[4]),
                 "s": s.reshape(sh[0], sh[1] * sh[2])}
-    g = buf[block_tables]
+    g = buf[block_tables, layer]
     sh = g.shape
     return g.reshape(sh[0], sh[1] * sh[2], sh[3], sh[4])
 
@@ -396,8 +410,9 @@ class PagedKVCache:
 
     def adopt_copied_page(self, slot: int, src_pid: int) -> int:
         """Copy-on-write split: allocate a private page, device-copy the
-        shared page's rows into it, and map it. The new occupant can now
-        write its divergent tail rows without touching sharers."""
+        shared page's rows into it (in place: the arenas are donated),
+        and map it. The new occupant can now write its divergent tail
+        rows without touching sharers."""
         pid = self.pool.alloc()
         dst = jnp.asarray(pid, jnp.int32)
         src = jnp.asarray(src_pid, jnp.int32)
@@ -444,10 +459,12 @@ class PagedKVCache:
         self.lengths = _len_set(self.lengths, jnp.asarray(slot, jnp.int32),
                                 jnp.asarray(n_tokens, jnp.int32))
 
-    # -- functional state threading ------------------------------------------
+    # -- state threading -----------------------------------------------------
     def swap(self, k, v, lengths):
-        """Install the arrays returned by a jitted prefill/decode call.
-        Shape-checked: a shape change would mean a recompile upstream."""
+        """Install the arrays returned by a jitted prefill/decode call —
+        the same buffers the call was given, updated in place (the
+        arrays it was given are deleted by the donation). Shape-checked:
+        a shape change would mean a recompile upstream."""
         def _shapes(buf):
             return [leaf.shape for leaf in jax.tree_util.tree_leaves(buf)]
         assert _shapes(k) == _shapes(self.k) \
@@ -455,7 +472,9 @@ class PagedKVCache:
         self.k, self.v, self.lengths = k, v, lengths
 
     def kv_bytes(self) -> int:
-        """Device bytes held by the K+V arenas (trash page included)."""
+        """Device bytes held by the K+V arenas (trash page included).
+        Shape arithmetic only, so it is safe from any thread: an array
+        a program has just taken by donation still knows its size."""
         return kv_nbytes(self.k) + kv_nbytes(self.v)
 
     def page_nbytes(self) -> int:

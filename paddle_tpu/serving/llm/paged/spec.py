@@ -5,7 +5,8 @@ keeps its own small slot-layout :class:`StaticKVCache` (draft contexts
 are tiny; paging them buys nothing), only the TARGET's K/V moves through
 the page arena. The verify step scatters all ``k+1`` candidate rows per
 slot through the block table (``[S*(k+1)]`` flattened physical indices)
-and gathers the full logical rows back for the multi-query attention,
+into the whole target arena, in place (the jitted step donates it), and
+gathers the full logical rows back for the multi-query attention,
 so greedy output stays bitwise identical to the slot spec step, which is
 itself bitwise the plain decoder (the composed parity test pins the
 chain: paged-spec == slot-spec == plain slot decode on greedy).
@@ -28,18 +29,18 @@ import jax.numpy as jnp
 from ..decode import _block_decode, _layer_norm, _sample
 from ..kvcache import valid_mask
 from ..spec import GPTDecodeSpec, GPTSpecDecoder
-from .decode import GPTPagedDecoder
+from .decode import GPTPagedDecoder, jit_donating_arenas
 from .pool import PagedKVCache, paged_gather_rows, paged_write_rows
 
 
-def _paged_block_verify(spec, lp, h, kb, vb, block_tables, pid_flat,
-                        ppos_flat, mask, scale):
+def _paged_block_verify(spec, lp, h, kbuf, vbuf, li, block_tables,
+                        pid_flat, ppos_flat, mask, scale):
     """``spec._block_verify`` with the K/V substrate paged: all T
-    candidate rows scatter through (``pid_flat``, ``ppos_flat``) —
-    the [S*T] physical coordinates of ``positions..positions+T-1`` —
-    then the full logical rows gather back for the attention. Dense
-    only (the spec engine path never runs over int8 KV; the config
-    gate predates paging)."""
+    candidate rows scatter into layer ``li`` of the whole arenas
+    through (``pid_flat``, ``ppos_flat``) — the [S*T] physical
+    coordinates of ``positions..positions+T-1`` — then the full logical
+    rows gather back for the attention. Dense only (the spec engine
+    path never runs over int8 KV; the config gate predates paging)."""
     s, t = h.shape[0], h.shape[1]
     x = _layer_norm(h, lp["n1w"], lp["n1b"], spec.ln_epsilon)
 
@@ -50,10 +51,12 @@ def _paged_block_verify(spec, lp, h, kb, vb, block_tables, pid_flat,
     kn = heads(x @ lp["kw"] + lp["kb"])
     vn = heads(x @ lp["vw"] + lp["vb"])
     flat = (s * t, spec.num_heads, spec.head_dim)
-    kb = paged_write_rows(kb, kn.reshape(flat), pid_flat, ppos_flat)
-    vb = paged_write_rows(vb, vn.reshape(flat), pid_flat, ppos_flat)
-    kg = paged_gather_rows(kb, block_tables)               # [S, max, H, D]
-    vg = paged_gather_rows(vb, block_tables)
+    kbuf = paged_write_rows(kbuf, kn.reshape(flat), pid_flat, ppos_flat,
+                            li)
+    vbuf = paged_write_rows(vbuf, vn.reshape(flat), pid_flat, ppos_flat,
+                            li)
+    kg = paged_gather_rows(kbuf, block_tables, li)         # [S, max, H, D]
+    vg = paged_gather_rows(vbuf, block_tables, li)
     qh = jnp.transpose(q * scale, (0, 2, 1, 3))            # [S, H, T, D]
     kt = jnp.transpose(kg, (0, 2, 1, 3))                   # [S, H, max, D]
     vt = jnp.transpose(vg, (0, 2, 1, 3))
@@ -64,7 +67,7 @@ def _paged_block_verify(spec, lp, h, kb, vb, block_tables, pid_flat,
     h = h + (out @ lp["ow"] + lp["ob"])
     x = _layer_norm(h, lp["n2w"], lp["n2b"], spec.ln_epsilon)
     ffn = jax.nn.gelu(x @ lp["w1"] + lp["b1"], approximate=False)
-    return h + (ffn @ lp["w2"] + lp["b2"]), kb, vb
+    return h + (ffn @ lp["w2"] + lp["b2"]), kbuf, vbuf
 
 
 def build_paged_spec_decode_step(tspec: GPTDecodeSpec,
@@ -142,15 +145,10 @@ def build_paged_spec_decode_step(tspec: GPTDecodeSpec,
         pid_flat = jnp.take_along_axis(block_tables, page_idx,
                                        axis=1).reshape(-1)     # [S*T]
         ppos_flat = (pos_mat % page_size).reshape(-1)
-        new_k, new_v = [], []
         for li, lp in enumerate(params_t["layers"]):
-            h, kb, vb = _paged_block_verify(
-                tspec, lp, h, kbuf_t[:, li], vbuf_t[:, li],
-                block_tables, pid_flat, ppos_flat, vmask, t_scale)
-            new_k.append(kb)
-            new_v.append(vb)
-        kbuf_t = jnp.stack(new_k, axis=1)
-        vbuf_t = jnp.stack(new_v, axis=1)
+            h, kbuf_t, vbuf_t = _paged_block_verify(
+                tspec, lp, h, kbuf_t, vbuf_t, li, block_tables,
+                pid_flat, ppos_flat, vmask, t_scale)
         h = _layer_norm(h, params_t["fnw"], params_t["fnb"],
                         tspec.ln_epsilon)
         lraw = (h @ params_t["tok"].T).astype(jnp.float32)     # [S, T, V]
@@ -187,17 +185,11 @@ def build_paged_spec_decode_step(tspec: GPTDecodeSpec,
 def get_paged_spec_decode_step(tspec: GPTDecodeSpec,
                                dspec: GPTDecodeSpec, k: int,
                                max_top_k: int, page_size: int):
-    counter = {"traces": 0}
-    raw = build_paged_spec_decode_step(tspec, dspec, k, max_top_k,
-                                       page_size)
-
-    def _step(*args):
-        counter["traces"] += 1
-        return raw(*args)
-
-    fn = jax.jit(_step)
-    fn.trace_counter = counter
-    return fn
+    """Jitted paged speculative step. Donates the TARGET arenas (the
+    paged ones); the draft's slot-layout buffers are the slot plane's."""
+    return jit_donating_arenas(
+        build_paged_spec_decode_step(tspec, dspec, k, max_top_k,
+                                     page_size), arenas=(2, 3))
 
 
 class GPTPagedSpecDecoder(GPTSpecDecoder):

@@ -294,8 +294,8 @@ def autotune_paged_attn(num_seqs: int, num_heads: int, head_dim: int,
     num_pages = num_seqs * pages_per_seq
     kq = jax.random.PRNGKey(0)
     q = jax.random.normal(kq, (num_seqs, num_heads, head_dim), jdt)
-    k_arena = jax.random.normal(
-        kq, (num_pages + 1, page_size, num_heads, head_dim), jdt)
+    k_arena = jax.random.normal(           # a one-layer arena
+        kq, (num_pages + 1, 1, page_size, num_heads, head_dim), jdt)
     v_arena = jax.random.normal(
         jax.random.PRNGKey(1), k_arena.shape, jdt)
     bt = jnp.arange(num_pages, dtype=jnp.int32).reshape(

@@ -198,11 +198,12 @@ def test_committed_tpu_winner_lowers(key, cfg):
     dt = jnp.dtype(dtype)
     if fam == "paged_attn":
         q = jnp.zeros((2, f["h"], f["d"]), dt)
-        arena = jnp.zeros((9, f["p"], f["h"], f["d"]), dt)
+        arena = jnp.zeros((9, 2, f["p"], f["h"], f["d"]), dt)
         bt = jnp.zeros((2, 4), jnp.int32)
         text = _lower_for_tpu(
             lambda a, k, v, t, p: paged_attention(
-                a, k, v, t, p, block_h=cfg["block_h"], interpret=False),
+                a, k, v, t, p, layer=1, block_h=cfg["block_h"],
+                interpret=False),
             q, arena, arena, bt, jnp.zeros((2,), jnp.int32))
         assert text.count('kernel_name = "paged_attn"') == 1
         return

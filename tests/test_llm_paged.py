@@ -232,7 +232,7 @@ class TestStepParity:
                 (mode, i)
             assert (np.asarray(ls) == np.asarray(lp)).all()
         # the gathered valid rows are the slot rows, bitwise
-        g = paged_gather_rows(kp[:, 0], bt)
+        g = paged_gather_rows(kp, bt, 0)
         sl = ks[:, 0]
         for si, ln in enumerate(np.asarray(ls)):
             assert (np.asarray(g[si, :ln])
@@ -252,14 +252,14 @@ class TestPagedAttentionKernel:
         num_pages = S * pp
         q = jnp.asarray(rng.standard_normal((S, H, D)), jnp.float32)
         ka = jnp.asarray(rng.standard_normal(
-            (num_pages + 1, page, H, D)), jnp.float32)
+            (num_pages + 1, 1, page, H, D)), jnp.float32)
         va = jnp.asarray(rng.standard_normal(ka.shape), jnp.float32)
         bt = jnp.arange(num_pages, dtype=jnp.int32).reshape(S, pp)
         positions = jnp.asarray([2, 7, 11], jnp.int32)
         out = paged_attention(q, ka, va, bt, positions, interpret=True)
         # reference: gather the pages dense, mask, softmax
-        kg = paged_gather_rows(ka, bt)           # [S, pp*page, H, D]
-        vg = paged_gather_rows(va, bt)
+        kg = paged_gather_rows(ka, bt, 0)        # [S, pp*page, H, D]
+        vg = paged_gather_rows(va, bt, 0)
         scale = 1.0 / np.sqrt(D)
         mask = (jnp.arange(pp * page)[None, :]
                 <= positions[:, None])           # [S, T]
@@ -272,8 +272,8 @@ class TestPagedAttentionKernel:
 
     def test_rejects_int8_arena(self):
         q = jnp.zeros((1, 2, 4), jnp.float32)
-        arena = {"q": jnp.zeros((3, 4, 2, 4), jnp.int8),
-                 "s": jnp.zeros((3, 4), jnp.float32)}
+        arena = {"q": jnp.zeros((3, 1, 4, 2, 4), jnp.int8),
+                 "s": jnp.zeros((3, 1, 4), jnp.float32)}
         bt = jnp.zeros((1, 2), jnp.int32)
         pos = jnp.zeros((1,), jnp.int32)
         with pytest.raises(ValueError, match="dense"):
