@@ -3,9 +3,12 @@
 The reference ships its NLP models through PaddleNLP (ERNIE/BERT/GPT built on
 python/paddle/nn/layer/transformer.py); this package provides the same model
 families natively so BASELINE configs 3 and 5 (BERT finetune, GPT hybrid
-parallel) are expressible inside the framework.
+parallel) are expressible inside the framework. ``lfm2`` is the LFM2-MoE
+family (short convolutions, grouped-query rotary attention, sparse
+experts), served on the paged engine.
 """
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM, GPTPretrainingCriterion  # noqa: F401
+from .lfm2 import LFM2Config, LFM2ForCausalLM  # noqa: F401
 from .bert import (BertConfig, BertModel,  # noqa: F401
                    BertForSequenceClassification,
                    ErnieConfig, ErnieModel,

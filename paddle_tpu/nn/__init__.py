@@ -26,6 +26,7 @@ from .transformer import (MultiHeadAttention, TransformerEncoderLayer,
                           FLASH_CROSSOVER)
 from .rnn import (RNNCellBase, SimpleRNNCell, LSTMCell, GRUCell, RNN,
                   SimpleRNN, LSTM, GRU, BiRNN)
+from .moe import RMSNorm, ExpertStack, MoEFeedForward  # noqa: F401
 from .beam_decode import BeamSearchDecoder, dynamic_decode  # noqa: F401
 from .clip import (ClipGradByGlobalNorm, ClipGradByNorm,  # noqa: F401
                    ClipGradByValue)

@@ -1,0 +1,90 @@
+"""RMSNorm and the sparse-expert feed-forward layer (top-k, sigmoid scores,
+no dropped tokens). The arithmetic is in ``ops/moe.py``; this is the
+``Layer`` that owns the parameters and is told which experts it holds."""
+from __future__ import annotations
+
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.dispatch import apply
+from ..ops import moe as _moe
+from . import initializer as I
+from .layer_base import Layer
+
+
+def rms_norm(x, w, eps: float):
+    """``x * rsqrt(mean(x^2, -1) + eps) * w`` in float32 (raw arrays)."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * w).astype(x.dtype)
+
+
+class RMSNorm(Layer):
+    """Root-mean-square norm over the last axis, no bias."""
+
+    def __init__(self, size: int, epsilon: float = 1e-5):
+        super().__init__()
+        self.epsilon = float(epsilon)
+        self.weight = self.create_parameter(
+            [size], default_initializer=I.Constant(1.0))
+
+    def forward(self, x):
+        eps = self.epsilon
+        return apply("rms_norm", lambda a, w: rms_norm(a, w, eps), x,
+                     self.weight)
+
+
+class ExpertStack(Layer):
+    """The SwiGLU experts held here, stacked: ``w1``/``w3`` ``[n, h, f]``
+    (gate and up), ``w2`` ``[n, f, h]`` (down). A checkpoint's
+    ``experts.<e>.w1.weight`` is row ``e - expert_lo`` of ``w1``."""
+
+    def __init__(self, num_held: int, hidden: int, width: int):
+        super().__init__()
+        init = I.Normal(0.0, 0.02)
+        self.w1 = self.create_parameter([num_held, hidden, width],
+                                        default_initializer=init)
+        self.w3 = self.create_parameter([num_held, hidden, width],
+                                        default_initializer=init)
+        self.w2 = self.create_parameter([num_held, width, hidden],
+                                        default_initializer=init)
+
+
+class MoEFeedForward(Layer):
+    """``sum over the top_k chosen e of w_e * SwiGLU_e(x)``.
+
+    Scores are ``sigmoid(x @ gate)``; the chosen set is the ``top_k`` of
+    score + ``expert_bias``; the weights are the scores alone, normalised
+    over the chosen (``norm_topk``) and scaled. ``held = (lo, n)`` says
+    which of the ``num_experts`` live here (default: all): routing is over
+    all of them and the result is the held experts' part of the sum."""
+
+    def __init__(self, hidden: int, width: int, num_experts: int,
+                 top_k: int, norm_topk: bool = True, scale: float = 1.0,
+                 held: Optional[Tuple[int, int]] = None):
+        super().__init__()
+        self.top_k, self.norm_topk, self.scale = top_k, norm_topk, scale
+        self.expert_lo, num_held = held or (0, num_experts)
+        if not (0 <= self.expert_lo
+                and self.expert_lo + num_held <= num_experts):
+            raise ValueError(f"held experts {held} outside 0..{num_experts}")
+        self.gate = Layer()
+        self.gate.weight = self.create_parameter(
+            [hidden, num_experts], default_initializer=I.Normal(0.0, 0.02))
+        self.expert_bias = self.create_parameter(
+            [num_experts], default_initializer=I.Constant(0.0))
+        self.experts = ExpertStack(num_held, hidden, width)
+
+    def forward(self, x):
+        kw = dict(top_k=self.top_k, norm_topk=self.norm_topk,
+                  scale=self.scale, expert_lo=self.expert_lo)
+
+        def _ffn(a, gate, bias, w1, w3, w2):
+            out, _counts = _moe.moe_feed_forward(
+                a.reshape(-1, a.shape[-1]), gate, bias, w1, w3, w2, **kw)
+            return out.reshape(a.shape)
+        e = self.experts
+        return apply("moe_feed_forward", _ffn, x, self.gate.weight,
+                     self.expert_bias, e.w1, e.w3, e.w2)

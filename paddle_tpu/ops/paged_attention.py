@@ -20,7 +20,21 @@ give, no layer is ever cut out of the arena for the call (the decode
 step updates the arena in place), and every layer's call is one and the
 same Mosaic kernel.
 
-Grid: ``(S, H // block_h, pages_per_seq)`` — the page axis is innermost,
+Grouped-query heads: with ``G = Hq // Hkv`` query heads to each KV head
+(query head ``j`` reads KV head ``j // G``), the block over heads walks KV
+heads, and each K/V block fetched serves its ``G`` query heads: ``q`` rides
+in as ``[S, G, Hkv, D]`` (group-major, so that every group is a
+``[block_h, D]`` slab laid out like a K row) and the recurrence runs once a
+group on the one K/V block. ``G = 1`` is plain multi-head attention.
+
+Fused rows: with ``v_arena=None`` the one arena's rows hold a head's key
+and value side by side (``[..., Hkv, 2 * D]``, what the cache keeps for a
+head size under the lane width); ``q`` is padded with zeros over the value's
+lanes, so the same multiply and lane reduction give ``q . k``, the block is
+read once and serves as both operands, and the value's lanes of the
+accumulator are the result.
+
+Grid: ``(S, Hkv // block_h, pages_per_seq)`` — the page axis is innermost,
 so on TPU (sequential grid) the scratch accumulators persist across one
 sequence-head-block's page walk and reset via ``@pl.when(p == 0)``.
 
@@ -86,18 +100,21 @@ def _tuned_block_h(num_heads, head_dim, page_size, dtype):
     return b if b > 0 else None
 
 
-def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
-                       o_ref, acc_ref, m_ref, l_ref, *, scale, page_size,
-                       pages_per_seq, block_h):
+def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, *refs,
+                       scale, page_size, pages_per_seq, block_h, groups):
     """One page of one sequence's head block per grid step. With a single
     query row there is nothing for the MXU to amortize, so the whole
     recurrence stays on the VPU in the arena's own ``[page, heads, D]``
     layout: q.k is a multiply and a lane reduction, softmax statistics
     reduce over the major (page) axis, p.v is a lane broadcast and a
-    major-axis sum — no transpose, no batched dot, no relayout.
+    major-axis sum — no transpose, no batched dot, no relayout. The K/V
+    block is read once and serves each of the ``groups`` query heads of its
+    KV heads in turn; without a ``v_ref`` (fused rows) it is both operands.
     ``layer_ref`` is read by the index maps only."""
     import jax.experimental.pallas as pl
 
+    v_ref = refs[0] if len(refs) == 5 else k_ref
+    o_ref, acc_ref, m_ref, l_ref = refs[-4:]
     s = pl.program_id(0)
     p = pl.program_id(2)
 
@@ -107,27 +124,28 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32) * scale          # [bh, D]
     kblk = k_ref[0].astype(jnp.float32)               # [page, bh, D]
-    vblk = v_ref[0].astype(jnp.float32)
-    s_blk = jnp.sum(kblk * q[None], axis=-1, keepdims=True)  # [page, bh, 1]
+    vblk = kblk if v_ref is k_ref else v_ref[0].astype(jnp.float32)
     j = p * page_size + lax.broadcasted_iota(
         jnp.int32, (page_size, block_h, 1), 0)
     valid = j <= len_ref[s]
-    s_blk = jnp.where(valid, s_blk, _NEG_INF)
-    m_prev = m_ref[:, :1]                             # [bh, 1]
-    l_prev = l_ref[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=0))
-    alpha = jnp.exp(m_prev - m_new)
-    pexp = jnp.where(valid, jnp.exp(s_blk - m_new[None]), 0.0)
-    l_new = l_prev * alpha + jnp.sum(pexp, axis=0)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.sum(pexp * vblk, axis=0)
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    for g in range(groups):
+        q = q_ref[0, g].astype(jnp.float32) * scale   # [bh, D]
+        s_blk = jnp.sum(kblk * q[None], axis=-1, keepdims=True)
+        s_blk = jnp.where(valid, s_blk, _NEG_INF)     # [page, bh, 1]
+        m_prev = m_ref[g, :, :1]                      # [bh, 1]
+        l_prev = l_ref[g, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=0))
+        alpha = jnp.exp(m_prev - m_new)
+        pexp = jnp.where(valid, jnp.exp(s_blk - m_new[None]), 0.0)
+        l_new = l_prev * alpha + jnp.sum(pexp, axis=0)
+        acc_ref[g] = acc_ref[g] * alpha + jnp.sum(pexp * vblk, axis=0)
+        m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
     @pl.when(p == pages_per_seq - 1)
     def _finalize():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :, :1], 1e-30)
                     ).astype(o_ref.dtype)
 
 
@@ -135,24 +153,36 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
                     layer=0, scale=None, block_h=None, interpret=None):
     """Single-token decode attention through a paged KV arena.
 
-    ``q``: ``[S, H, D]`` (one query per sequence, already projected);
+    ``q``: ``[S, Hq, D]`` (one query per sequence, already projected);
     ``k_arena``/``v_arena``: the whole ``[num_pages + 1, num_layers,
-    page_size, H, D]`` arenas (dense — int8 arenas take the gather
+    page_size, Hkv, D]`` arenas, ``Hq`` a multiple of ``Hkv`` and query
+    head ``j`` reading KV head ``j // (Hq // Hkv)`` (dense — int8 arenas
+    take the gather
     lane, which dequantizes in-graph); ``layer``: which layer's rows to
     attend over, an int or an int32 scalar (a kernel *input*, so every
     layer runs the same compiled kernel); ``block_tables``:
     ``[S, pages_per_seq]`` int32; ``positions``: ``[S]`` int32 — query
     ``s`` attends logical rows ``j <= positions[s]``. Returns
-    ``[S, H, D]`` in ``q.dtype``.
+    ``[S, Hq, D]`` in ``q.dtype``. ``v_arena=None``: ``k_arena`` holds fused
+    ``[K | V]`` rows of width ``2 * D``.
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    fused = v_arena is None
     if isinstance(k_arena, dict) or isinstance(v_arena, dict):
         raise ValueError(
             "paged_attention kernel reads dense arenas only — the int8 "
             "lane uses the gather implementation (dequantize in-graph)")
-    s_n, num_heads, head_dim = q.shape
+    if fused:       # zeros over the value's lanes; scale by the true D
+        scale = 1.0 / np.sqrt(q.shape[-1]) if scale is None else scale
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, q.shape[-1])))
+    s_n, q_heads, head_dim = q.shape
+    num_heads = k_arena.shape[3]                       # KV heads
+    if q_heads % num_heads:
+        raise ValueError(f"{q_heads} query heads are not a multiple of "
+                         f"the arena's {num_heads} KV heads")
+    groups = q_heads // num_heads
     page_size = k_arena.shape[2]
     pages_per_seq = block_tables.shape[1]
     if scale is None:
@@ -167,37 +197,40 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
 
     kernel = functools.partial(
         _paged_attn_kernel, scale=scale, page_size=page_size,
-        pages_per_seq=pages_per_seq, block_h=block_h)
+        pages_per_seq=pages_per_seq, block_h=block_h, groups=groups)
     bt_flat = block_tables.reshape(-1).astype(jnp.int32)
+    # group-major: q_g[s, g, h] is query head h * groups + g
+    q_g = jnp.swapaxes(q.reshape(s_n, num_heads, groups, head_dim), 1, 2)
 
     def _q_map(s, h, p, bt_ref, len_ref, layer_ref):
-        return (s, h, 0)
+        return (s, 0, h, 0)
 
     def _kv_map(s, h, p, bt_ref, len_ref, layer_ref):
         # the block-table walk: (physical page id, layer) -> arena block
         return (bt_ref[s * pages_per_seq + p], layer_ref[0], 0, h, 0)
 
+    q_block = (1, groups, block_h, head_dim)
     kv_block = (1, None, page_size, block_h, head_dim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(s_n, num_heads // block_h, pages_per_seq),
-        in_specs=[
-            pl.BlockSpec((1, block_h, head_dim), _q_map),
-            pl.BlockSpec(kv_block, _kv_map),
-            pl.BlockSpec(kv_block, _kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, block_h, head_dim), _q_map),
+        in_specs=[pl.BlockSpec(q_block, _q_map)]
+        + [pl.BlockSpec(kv_block, _kv_map)] * (1 if fused else 2),
+        out_specs=pl.BlockSpec(q_block, _q_map),
         scratch_shapes=[
-            pltpu.VMEM((block_h, head_dim), jnp.float32),   # acc
-            pltpu.VMEM((block_h, 128), jnp.float32),        # running max
-            pltpu.VMEM((block_h, 128), jnp.float32),        # running sum
+            pltpu.VMEM((groups, block_h, head_dim), jnp.float32),  # acc
+            pltpu.VMEM((groups, block_h, 128), jnp.float32),  # running max
+            pltpu.VMEM((groups, block_h, 128), jnp.float32),  # running sum
         ])
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_n, num_heads, head_dim),
+        out_shape=jax.ShapeDtypeStruct((s_n, groups, num_heads, head_dim),
                                        q.dtype),
         interpret=resolve_interpret("paged_attn", interpret),
         name="paged_attn",
     )(bt_flat, positions.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q, k_arena, v_arena)
+      jnp.asarray(layer, jnp.int32).reshape(1), q_g,
+      *((k_arena,) if fused else (k_arena, v_arena)))
+    out = jnp.swapaxes(out, 1, 2).reshape(s_n, q_heads, head_dim)
+    return out[..., head_dim // 2:] if fused else out

@@ -6,10 +6,14 @@ The vLLM/PagedAttention design grafted under the static-slot LLM stack:
 mirror the slot decode programs with the block table threaded through,
 ``prefix`` shares prefix pages by refcount (COW on divergence), and
 ``batcher`` admits on pages-at-current-lengths. Select with
-``LLMEngineConfig(kv_layout="paged")``.
+``LLMEngineConfig(kv_layout="paged")``. The engine picks the decoder family
+from the model it is given (``paged_decoder_class``): GPT by default,
+``lfm2`` for the LFM2-MoE family, whose cache also holds a per-slot
+convolution state beside the pages.
 """
 from .batcher import PagedBatcher
-from .decode import (GPTPagedDecoder, build_paged_decode_step,
+from .decode import (GPTPagedDecoder, paged_decoder_class,
+                     register_paged_decoder, build_paged_decode_step,
                      build_paged_prefill_fn, build_paged_tail_prefill_fn,
                      get_paged_decode_step, get_paged_prefill_fn,
                      get_paged_tail_prefill_fn)
@@ -17,6 +21,7 @@ from .pool import (PagedKVCache, PagePool, PagesExhausted,
                    paged_gather_rows, paged_write_prompt_rows,
                    paged_write_rows, pages_for_tokens)
 from .prefix import PagedPrefixEntry, PagedPrefixStore
+from .lfm2 import LFM2PagedDecoder
 from .spec import (GPTPagedSpecDecoder, build_paged_spec_decode_step,
                    get_paged_spec_decode_step)
 
@@ -35,6 +40,9 @@ __all__ = [
     "get_paged_prefill_fn",
     "get_paged_tail_prefill_fn",
     "GPTPagedDecoder",
+    "LFM2PagedDecoder",
+    "paged_decoder_class",
+    "register_paged_decoder",
     "build_paged_spec_decode_step",
     "get_paged_spec_decode_step",
     "GPTPagedSpecDecoder",

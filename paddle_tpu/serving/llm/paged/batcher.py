@@ -59,8 +59,8 @@ class PagedBatcher(ContinuousBatcher):
 
     def __init__(self, decoder: GPTPagedDecoder, config, registry,
                  clock=None, prefix_store=None, spec_decoder=None):
-        if not isinstance(decoder, GPTPagedDecoder):
-            raise TypeError("PagedBatcher needs a GPTPagedDecoder "
+        if getattr(decoder, "kv_layout", None) != "paged":
+            raise TypeError("PagedBatcher needs a paged decoder "
                             "(kv_layout='paged')")
         if prefix_store is not None:
             raise NotImplementedError(
@@ -79,6 +79,8 @@ class PagedBatcher(ContinuousBatcher):
                 stat_prefix=f"{config.stat_prefix}.prefix")
         self._stat_set("pages_free", self.kv.pool.free_pages)
         self._stat_set("pages_cow_splits", 0)
+        if hasattr(decoder, "publish_gauges"):   # a family's own state
+            decoder.publish_gauges(self.kv, self._stat_set)
 
     # -- introspection -------------------------------------------------------
     @property
@@ -320,8 +322,11 @@ class PagedBatcher(ContinuousBatcher):
         self._stat_add("evicted_midstream", 1)
 
     # -- live sequence migration (docs/fault_tolerance.md) -------------------
-    #: the paged substrate can ship sequences as page payloads
-    supports_export = True
+    @property
+    def supports_export(self) -> bool:
+        """The paged substrate can ship sequences as page payloads, for a
+        decoder family whose whole per-sequence state is pages."""
+        return getattr(self.decoder, "supports_export", True)
 
     def export_all(self):
         """Snapshot-and-detach every live sequence into host-side

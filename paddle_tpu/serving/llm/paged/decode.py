@@ -336,6 +336,27 @@ def get_paged_tail_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
         arenas=(4, 5))
 
 
+#: (model class, decoder class) of the families served on pages beside GPT
+_PAGED_DECODERS = []
+
+
+def register_paged_decoder(model_cls, decoder_cls):
+    """Serve instances of ``model_cls`` through ``decoder_cls`` on the
+    paged engine. A decoder class takes ``GPTPagedDecoder``'s constructor
+    arguments and answers the calls ``PagedBatcher`` makes (``new_kv``,
+    ``prefill``, ``decode_step``, ``params``, ``prefix_sig``,
+    ``check_config``)."""
+    _PAGED_DECODERS.append((model_cls, decoder_cls))
+
+
+def paged_decoder_class(model):
+    """The decoder family of ``model``: a registered one, GPT by default."""
+    for model_cls, decoder_cls in _PAGED_DECODERS:
+        if isinstance(model, model_cls):
+            return decoder_cls
+    return GPTPagedDecoder
+
+
 class GPTPagedDecoder(GPTStaticDecoder):
     """GPTStaticDecoder with the KV substrate swapped for pages: same
     model façade, same ExecutableCache accounting, but ``new_kv``
@@ -379,6 +400,11 @@ class GPTPagedDecoder(GPTStaticDecoder):
         self.page_size = int(page_size)
         self.num_pages = None if num_pages is None else int(num_pages)
         self._key = self._key + ("paged", self.page_size, self.attn_impl)
+
+    @staticmethod
+    def check_config(config):
+        """Raises for an engine option this family does not serve (GPT
+        serves them all)."""
 
     def new_kv(self, num_slots: int, max_seq: int) -> PagedKVCache:
         if max_seq > self.spec.max_position_embeddings:

@@ -1,0 +1,300 @@
+"""The paged programs of the LFM2-MoE family and ``LFM2PagedDecoder``.
+
+A second decoder family behind the same ``PagedBatcher``: the block is
+``models.lfm2.lfm2_block`` and only the cache views differ. The prefill runs
+whole prompts through ``FullSequence`` and stores what it recorded; the
+decode step's view (:class:`PagedStep`) keeps two kinds of per-sequence
+state side by side in one ``PagedKVCache``:
+
+- the KV arena ``[P+1, attention layers, page, KV heads, 2 * D]``: its
+  layer axis counts attention layers only, its head axis KV heads, and a
+  row holds the head's key and value side by side (``fused_kv`` of
+  ``paged/pool.py``: with D = 64 a K-only row would be half a lane row and
+  the device would lay the arena out in another order than the kernel
+  reads, a whole-arena copy in and out of every program);
+- the convolution state ``[slots, conv layers, L-1, hidden]``: the last
+  ``L-1`` inputs of each short convolution (hidden on the lane axis). The
+  prefill writes a slot's whole row from its own prompt (zeros where the
+  prompt is shorter than ``L-1``), the decode step rolls it in place, and
+  it is donated and returned with the arenas.
+
+The decode step also returns, packed behind the next tokens so that the
+tick's one fetch brings them, two counters: the distinct experts that
+received a token (summed over the expert layers) and the fullest expert's
+tokens in any layer.
+
+What this family does not do yet raises ``NotImplementedError`` at
+construction: a mesh, ``kv_layout="slot"``, ``prefix_cache``, ``spec_k > 0``,
+int8 weights or KV; the engine refuses sequence export/import for it
+(``supports_export``).
+"""
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from ....ops.paged_attention import paged_attention
+from ....models.lfm2 import (FullSequence, LFM2Config, LFM2ForCausalLM,
+                             lfm2_hidden)
+from ...cache import default_cache
+from ..decode import _sample
+from .decode import (_write_page_index, jit_donating_arenas,
+                     register_paged_decoder)
+from .pool import (PagedKVCache, paged_gather_rows,
+                   paged_write_prompt_rows, paged_write_rows)
+
+
+class PagedStep:
+    """The cache view of one decode step: one new token per slot, the past
+    in the KV pages and the convolution state. Holds the (traced) arenas
+    and state and replaces them as layers write: read them back when the
+    layers are done."""
+
+    def __init__(self, kvbuf, state, block_tables, positions,
+                 page_size: int, attn_impl: str):
+        self.kvbuf, self.state = kvbuf, state
+        self.block_tables, self.positions = block_tables, positions
+        self.attn_impl = attn_impl
+        self.pid, self.ppos = _write_page_index(block_tables, positions,
+                                                page_size)
+        max_seq = block_tables.shape[1] * page_size
+        self.mask = None if attn_impl == "kernel" else jnp.where(
+            jnp.arange(max_seq)[None] <= positions[:, None], 0.0, -1e9)
+
+    def conv(self, ci, z, kern):
+        window = jnp.concatenate([self.state[:, ci], z], axis=1)  # [S, L, h]
+        self.state = self.state.at[:, ci].set(window[:, 1:])
+        return sum(kern[:, j] * window[:, j]
+                   for j in range(kern.shape[1]))[:, None]
+
+    def attend(self, ai, q, k, v, scale):
+        s, _, hq, d = q.shape
+        self.kvbuf = paged_write_rows(
+            self.kvbuf, jnp.concatenate([k[:, 0], v[:, 0]], axis=-1),
+            self.pid, self.ppos, ai)
+        if self.attn_impl == "kernel":
+            return paged_attention(q[:, 0], self.kvbuf, None,
+                                   self.block_tables, self.positions,
+                                   layer=ai, scale=scale)[:, None]
+        kd, vd = jnp.split(paged_gather_rows(self.kvbuf, self.block_tables,
+                                             ai), 2, axis=-1)
+        qg = (q[:, 0] * scale).reshape(s, kd.shape[2], -1, d)  # [S,Hkv,G,D]
+        prod = jnp.einsum("skgd,smkd->skgm", qg, kd)
+        weights = jax.nn.softmax(prod + self.mask[:, None, None], axis=-1)
+        return jnp.einsum("skgm,smkd->skgd", weights, vd).reshape(
+            s, 1, hq, d)
+
+
+def build_lfm2_paged_decode_step(cfg: LFM2Config, max_top_k: int,
+                                 page_size: int, attn_impl: str = "gather"):
+    """The RAW paged decode step of this family.
+
+    step(params, kvbuf, state, block_tables, lengths, finished,
+         last_tokens, temperature, top_k, do_sample, eos, key)
+      -> (kvbuf, state, lengths+1, finished, next_tokens, fetch)
+
+    ``fetch`` is ``[S + 2]`` int32: the next tokens, then the experts that
+    received a token (summed over the expert layers) and the fullest
+    expert's tokens in any layer."""
+    if attn_impl not in ("gather", "kernel"):
+        raise ValueError(f"attn_impl must be 'gather' or 'kernel', got "
+                         f"{attn_impl!r}")
+
+    def _step(params, kvbuf, state, block_tables, lengths, finished,
+              last_tokens, temperature, top_k, do_sample, eos, key):
+        view = PagedStep(kvbuf, state, block_tables, lengths,
+                         page_size, attn_impl)
+        h, counts = lfm2_hidden(cfg, params, last_tokens[:, None],
+                                lengths[:, None], view)
+        lraw = (h[:, 0] @ params["tok"].T).astype(jnp.float32)     # [S, V]
+        nxt = _sample(lraw, temperature, top_k, do_sample, key, max_top_k)
+        nxt = jnp.where(finished & (eos >= 0), eos, nxt)
+        counts = jnp.stack(counts) if counts else jnp.zeros((1, 1), jnp.int32)
+        fetch = jnp.concatenate([nxt, jnp.stack(
+            [jnp.sum(counts > 0), jnp.max(counts)]).astype(jnp.int32)])
+        return (view.kvbuf, view.state, lengths + 1,
+                finished | ((nxt == eos) & (eos >= 0)), nxt, fetch)
+
+    return _step
+
+
+@functools.lru_cache(maxsize=64)
+def get_lfm2_paged_decode_step(cfg: LFM2Config, max_top_k: int,
+                               page_size: int, attn_impl: str):
+    return jit_donating_arenas(
+        build_lfm2_paged_decode_step(cfg, max_top_k, page_size, attn_impl),
+        arenas=(1, 2))
+
+
+def build_lfm2_paged_prefill_fn(cfg: LFM2Config, max_top_k: int,
+                                page_size: int):
+    """The RAW paged prefill: whole right-padded prompts through the block
+    with no past; the attention layers' K/V rows scatter through each
+    request's block-table row (padding to the trash page) and the
+    convolution layers' last inputs overwrite the slots' state rows."""
+
+    def _prefill(params, tokens, true_lens, kvbuf, state, block_tables,
+                 lengths, finished, slot_ids, temperature, top_k,
+                 do_sample, eos, key):
+        b, lp_len = tokens.shape
+        trash = kvbuf.shape[0] - 1
+        pos = jnp.arange(lp_len, dtype=jnp.int32)
+        view = FullSequence(true_lens)
+        h, _ = lfm2_hidden(cfg, params, tokens, pos[None], view)
+        kv_new = jnp.stack([jnp.concatenate(kv, axis=-1) for kv in view.kv],
+                           axis=2)                      # [B, Lp, La, H, 2D]
+        for i in range(b):
+            bt_row = block_tables[slot_ids[i]]
+            pid = jnp.where(pos < true_lens[i], bt_row[pos // page_size],
+                            trash)
+            kvbuf = paged_write_prompt_rows(kvbuf, kv_new[i], pid,
+                                            pos % page_size)
+        state = state.at[slot_ids].set(jnp.stack(view.conv_tails, axis=1))
+        lengths = lengths.at[slot_ids].set(true_lens)
+        last = jnp.take_along_axis(
+            h, (true_lens - 1)[:, None, None].astype(jnp.int32),
+            axis=1)[:, 0]                                      # [B, hidden]
+        lraw = (last @ params["tok"].T).astype(jnp.float32)
+        nxt = _sample(lraw, temperature, top_k, do_sample, key, max_top_k)
+        finished = finished.at[slot_ids].set((nxt == eos) & (eos >= 0))
+        return kvbuf, state, lengths, finished, nxt
+
+    return _prefill
+
+
+@functools.lru_cache(maxsize=64)
+def get_lfm2_paged_prefill_fn(cfg: LFM2Config, max_top_k: int,
+                              page_size: int):
+    return jit_donating_arenas(
+        build_lfm2_paged_prefill_fn(cfg, max_top_k, page_size),
+        arenas=(3, 4))
+
+
+class LFM2PagedDecoder:
+    """The façade ``PagedBatcher`` drives, for an ``LFM2ForCausalLM``: the
+    same calls as ``GPTPagedDecoder`` (``new_kv``, ``prefill``,
+    ``decode_step``, ``params``, ``prefix_sig``)."""
+
+    kv_layout = "paged"
+    #: the convolution state has no export/import path yet
+    supports_export = False
+
+    def __init__(self, model, max_top_k: int = 64, exec_cache=None,
+                 mesh=None, weight_dtype: str = "float32",
+                 kv_dtype: str = "float32", page_size: int = 16,
+                 num_pages: Optional[int] = None,
+                 attn_impl: str = "auto"):
+        if mesh is not None:
+            raise NotImplementedError(
+                "the LFM2 paged decoder does not serve over a mesh yet")
+        if weight_dtype != "float32" or kv_dtype != "float32":
+            raise NotImplementedError(
+                "the LFM2 paged decoder serves float32 weights and KV "
+                f"only (got weight_dtype={weight_dtype!r}, "
+                f"kv_dtype={kv_dtype!r})")
+        if attn_impl not in ("auto", "gather", "kernel"):
+            raise ValueError(
+                f"attn_impl must be 'auto', 'gather' or 'kernel', got "
+                f"{attn_impl!r}")
+        self.spec: LFM2Config = model.config
+        if not (self.spec.attn_layers and self.spec.conv_layers):
+            raise NotImplementedError(
+                "the LFM2 paged decoder needs at least one attention and "
+                "one convolution layer")
+        self._model = model
+        self.max_top_k = max(0, min(int(max_top_k), self.spec.vocab_size))
+        self.exec_cache = (exec_cache if exec_cache is not None
+                           else default_cache())
+        if attn_impl == "auto":
+            attn_impl = ("kernel" if jax.default_backend() == "tpu"
+                         else "gather")
+        self.attn_impl = attn_impl
+        self.page_size = int(page_size)
+        self.num_pages = None if num_pages is None else int(num_pages)
+        self._key = ("lfm2-paged", self.spec, self.max_top_k,
+                     self.page_size, self.attn_impl)
+
+    @staticmethod
+    def check_config(config):
+        """The engine options this family does not serve yet."""
+        for name, off in (("prefix_cache", False), ("spec_k", 0)):
+            if getattr(config, name) != off:
+                raise NotImplementedError(
+                    f"the LFM2 paged decoder does not support {name} yet "
+                    f"(the convolution state has no prefix-reuse or "
+                    f"rollback path)")
+
+    @property
+    def model(self):
+        return self._model
+
+    def params(self):
+        return self._model.param_tree()
+
+    def new_kv(self, num_slots: int, max_seq: int) -> PagedKVCache:
+        c = self.spec
+        if max_seq > c.max_position_embeddings:
+            raise ValueError(
+                f"max_seq {max_seq} exceeds the model's "
+                f"{c.max_position_embeddings} positions")
+        return PagedKVCache(
+            num_slots, len(c.attn_layers), max_seq, c.num_key_value_heads,
+            c.head_dim, dtype=self.params()["tok"].dtype,
+            page_size=self.page_size, num_pages=self.num_pages,
+            state_shape=(len(c.conv_layers), c.conv_L_cache - 1,
+                         c.hidden_size), fused_kv=True)
+
+    def publish_gauges(self, kv: PagedKVCache, stat_set):
+        stat_set("conv_state_bytes", kv.state_bytes())
+
+    def note_tick(self, extras, n_active: int, stat_add):
+        """The tick's counters, from the values fetched behind the tokens."""
+        stat_add("moe_experts_active", int(extras[0]))
+        stat_add("moe_load_max", int(extras[1]))
+        stat_add("moe_pairs_routed", n_active * self.spec.num_experts_per_tok
+                 * self.spec.num_expert_layers)
+
+    def prefix_sig(self, kv: PagedKVCache):
+        c = self.spec
+        return (len(c.attn_layers), c.num_key_value_heads, c.head_dim,
+                str(kv.dtype), self.page_size)
+
+    # -- compiled-program access --------------------------------------------
+    def decode_fn(self, num_slots: int, max_seq: int):
+        return self.exec_cache.get_or_compile(
+            self._key + ("decode", num_slots, max_seq),
+            lambda: get_lfm2_paged_decode_step(
+                self.spec, self.max_top_k, self.page_size, self.attn_impl))
+
+    def prefill_fn(self, batch: int, prompt_len: int):
+        return self.exec_cache.get_or_compile(
+            self._key + ("prefill", batch, prompt_len),
+            lambda: get_lfm2_paged_prefill_fn(self.spec, self.max_top_k,
+                                              self.page_size))
+
+    def prefill(self, kv: PagedKVCache, params, tokens, true_lens,
+                slot_ids, finished, samp_vecs, key):
+        fn = self.prefill_fn(tokens.shape[0], tokens.shape[1])
+        k, state, lengths, finished, nxt = fn(
+            params, tokens, true_lens, kv.k, kv.state, kv.block_tables,
+            kv.lengths, finished, slot_ids, *samp_vecs, key)
+        kv.swap(k, kv.v, lengths, state)
+        return nxt, finished
+
+    def decode_step(self, kv: PagedKVCache, params, finished, last_tokens,
+                    samp_vecs, key):
+        """Advance every slot one token: ``(next tokens, finished,
+        fetch)``, ``fetch`` the tokens with the tick's counters behind
+        them (what the host fetches)."""
+        fn = self.decode_fn(kv.num_slots, kv.max_seq)
+        k, state, lengths, finished, nxt, fetch = fn(
+            params, kv.k, kv.state, kv.block_tables, kv.lengths, finished,
+            last_tokens, *samp_vecs, key)
+        kv.swap(k, kv.v, lengths, state)
+        return nxt, finished, fetch
+
+
+register_paged_decoder(LFM2ForCausalLM, LFM2PagedDecoder)

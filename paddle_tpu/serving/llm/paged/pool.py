@@ -36,6 +36,25 @@ the rows it writes and not the arena. The arrays handed to such a call
 are deleted by it: :class:`PagedKVCache` installs the outputs at once
 (``swap``), and nothing else may keep a reference to ``kv.k``/``kv.v``
 across a call.
+
+Beside the pages a cache may hold a second kind of per-sequence state: a
+fixed-size ``state`` row per SLOT (``[num_slots, *state_shape]``; a short
+convolution's last inputs, say), for models whose layers do not all keep
+keys and values. It is addressed by slot, not by page: the slot's prefill
+overwrites the whole row (so a reused slot starts from its own prompt,
+never from the last tenant's), every program that takes the arenas takes,
+donates and returns it with them, and freeing the slot frees it.
+
+``fused_kv``: a head size under the 128-lane width (64, say) would give the
+arena a minor axis the device lays out compactly, in another order than the
+row-major one the paged kernel reads, and every program would copy the
+whole arena in and out. Such a cache keeps ONE arena whose rows hold a
+head's key and value side by side, ``[P+1, L, page, H, 2 * D]`` in ``k``
+(``v`` is an empty placeholder): the same bytes a page, a 128-wide minor
+axis, and one row read serves both. The decoder family asks for it, the
+cache does not choose it from ``head_dim``: the GPT programs read ``kbuf``
+and ``vbuf`` in each of their seven bodies, so a GPT with head size 64
+still keeps two arenas (and pays that copy) until those bodies are one.
 """
 from __future__ import annotations
 
@@ -265,7 +284,9 @@ class PagedKVCache:
     def __init__(self, num_slots: int, num_layers: int, max_seq: int,
                  num_heads: int, head_dim: int, dtype="float32",
                  kv_dtype: Optional[str] = None, page_size: int = 16,
-                 num_pages: Optional[int] = None):
+                 num_pages: Optional[int] = None,
+                 state_shape: Optional[Tuple[int, ...]] = None,
+                 fused_kv: bool = False):
         if num_slots < 1 or max_seq < 2:
             raise ValueError(
                 f"need num_slots >= 1 and max_seq >= 2, got "
@@ -299,8 +320,11 @@ class PagedKVCache:
         self.dtype = jnp.dtype(dtype)
         self.kv_dtype = kv_dtype
         self.quantized = kv_dtype == "int8"
+        self.fused_kv = bool(fused_kv)
+        if self.fused_kv and self.quantized:
+            raise ValueError("fused K|V rows are dense only")
         shape = (self.num_pages + 1, self.num_layers, self.page_size,
-                 self.num_heads, self.head_dim)
+                 self.num_heads, self.head_dim * (2 if fused_kv else 1))
         if self.quantized:
             def _zero_buf():
                 return {"q": jnp.zeros(shape, jnp.int8),
@@ -309,7 +333,10 @@ class PagedKVCache:
             def _zero_buf():
                 return jnp.zeros(shape, self.dtype)
         self.k = _zero_buf()
-        self.v = _zero_buf()
+        self.v = jnp.zeros((0,), self.dtype) if fused_kv else _zero_buf()
+        #: per-slot fixed-size state beside the pages (None: K and V only)
+        self.state = None if state_shape is None else jnp.zeros(
+            (self.num_slots,) + tuple(state_shape), self.dtype)
         self.block_tables = jnp.full(
             (self.num_slots, self.pages_per_seq), self.trash, jnp.int32)
         self.lengths = jnp.zeros((self.num_slots,), jnp.int32)
@@ -460,7 +487,7 @@ class PagedKVCache:
                                 jnp.asarray(n_tokens, jnp.int32))
 
     # -- state threading -----------------------------------------------------
-    def swap(self, k, v, lengths):
+    def swap(self, k, v, lengths, state=None):
         """Install the arrays returned by a jitted prefill/decode call —
         the same buffers the call was given, updated in place (the
         arrays it was given are deleted by the donation). Shape-checked:
@@ -468,8 +495,14 @@ class PagedKVCache:
         def _shapes(buf):
             return [leaf.shape for leaf in jax.tree_util.tree_leaves(buf)]
         assert _shapes(k) == _shapes(self.k) \
-            and _shapes(v) == _shapes(self.v), (_shapes(k), _shapes(self.k))
-        self.k, self.v, self.lengths = k, v, lengths
+            and _shapes(v) == _shapes(self.v) \
+            and _shapes(state) == _shapes(self.state), \
+            (_shapes(k), _shapes(self.k), _shapes(state))
+        self.k, self.v, self.lengths, self.state = k, v, lengths, state
+
+    def state_bytes(self) -> int:
+        """Device bytes of the per-slot state (0 without one)."""
+        return 0 if self.state is None else int(self.state.nbytes)
 
     def kv_bytes(self) -> int:
         """Device bytes held by the K+V arenas (trash page included).

@@ -688,17 +688,22 @@ class ContinuousBatcher:
             self._measure_decode_flops()
         t0 = self._clock()
         with self.phase("tick_dispatch"):
-            nxt, self._finished = self.decoder.decode_step(
+            nxt, self._finished, *packed = self.decoder.decode_step(
                 self.kv, self._params, self._finished, self._last,
                 self._samp_vecs, self._next_key())
             self._last = nxt
         # THE one host fetch of the tick: the [num_slots] next-token
-        # vector. Streaming delivery and host-side finish detection both
-        # consume it, so this sync is the feature, not an accident.
+        # vector (a decoder family may pack its tick counters behind the
+        # tokens, so that they ride the same fetch). Streaming delivery
+        # and host-side finish detection both consume it, so this sync is
+        # the feature, not an accident.
         with self.phase("tick_fetch"):
-            toks = np.asarray(jax.device_get(nxt))  # noqa: PTA002 -- the single per-tick [num_slots] fetch; token streaming requires host delivery
+            toks = np.asarray(jax.device_get(packed[0] if packed else nxt))  # noqa: PTA002 -- the single per-tick [num_slots] fetch; token streaming requires host delivery
         with self.phase("tick_emit"):
             n = len(self._reqs)
+            if packed:
+                self.decoder.note_tick(toks[self.config.num_slots:], n,
+                                       self._stat_add)
             dt = max(self._clock() - t0, 1e-9)
             self._stat_observe("decode_tick_ms", dt * 1000.0)
             self._stat_observe("tpot_ms", dt * 1000.0)
@@ -838,9 +843,9 @@ class ContinuousBatcher:
                     jnp.zeros((1, lp), jnp.int32),
                     jnp.asarray([lp], jnp.int32), slot0, self.kv.lengths,
                     self._finished, samp, self._next_key())
-        nxt, _ = self.decoder.decode_step(
+        nxt = self.decoder.decode_step(
             self.kv, self._params, self._finished, self._last,
-            self._samp_vecs, self._next_key())
+            self._samp_vecs, self._next_key())[0]
         if self.spec is not None:
             # the spec step needs headroom for k+1 candidate rows; warmup
             # state after the bucket loop has lengths == largest bucket,
@@ -888,8 +893,8 @@ class LLMEngine(DrainableEngineBase):
         self._cache = cache if cache is not None else default_cache()
         if self._config.kv_layout == "paged":
             # lazy import: paged/batcher imports this module's classes
-            from .paged import (GPTPagedDecoder, GPTPagedSpecDecoder,
-                                PagedBatcher)
+            from .paged import (GPTPagedSpecDecoder, PagedBatcher,
+                                paged_decoder_class)
             if mesh is not None:
                 raise NotImplementedError(
                     "kv_layout='paged' over a slot-sharded mesh is not "
@@ -899,7 +904,8 @@ class LLMEngine(DrainableEngineBase):
                     "paged engines share prefix pages inside their own "
                     "arena; an external PrefixStore cannot be attached "
                     "— set prefix_cache=True instead")
-            self._decoder = GPTPagedDecoder(
+            # the decoder family comes from the model (GPT the default)
+            self._decoder = paged_decoder_class(model)(
                 model, max_top_k=self._config.max_top_k,
                 exec_cache=self._cache,
                 weight_dtype=self._config.weight_dtype,
@@ -907,6 +913,7 @@ class LLMEngine(DrainableEngineBase):
                 page_size=self._config.page_size,
                 num_pages=self._config.num_pages,
                 attn_impl=self._config.paged_attn_impl)
+            self._decoder.check_config(self._config)
             spec_decoder = None
             if self._config.spec_k > 0:
                 if draft_model is None:
@@ -923,6 +930,11 @@ class LLMEngine(DrainableEngineBase):
             # arena); surface it on the engine like the host store
             self._prefix_store = self._batcher.prefix_store
         else:
+            from .paged import GPTPagedDecoder, paged_decoder_class
+            if paged_decoder_class(model) is not GPTPagedDecoder:
+                raise NotImplementedError(
+                    f"{type(model).__name__} is served on "
+                    f"kv_layout='paged' only")
             self._decoder = GPTStaticDecoder(
                 model, max_top_k=self._config.max_top_k,
                 exec_cache=self._cache,
@@ -1145,7 +1157,8 @@ class LLMEngine(DrainableEngineBase):
         if not self.supports_migration:
             raise NotImplementedError(
                 "sequence export requires the paged KV cache "
-                "(kv_layout='paged')")
+                "(kv_layout='paged') and a decoder family whose whole "
+                "per-sequence state is pages")
         action = fault_injector().fire("seq_export")
         if action == "slow_io":
             time.sleep(float(os.environ.get(
