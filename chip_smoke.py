@@ -360,7 +360,8 @@ def main(argv=None) -> int:
     # lowered modules land here as text, cold or warm (jax dumps before it
     # consults the persistent cache) — the Mosaic-call checks read them
     jax.config.update("jax_dump_ir_to", IR_DIR)
-    from paddle_tpu.core.pallas_mode import chosen_modes
+    from paddle_tpu.core.pallas_mode import (chosen_modes,
+                                             chosen_operand_dtypes)
     from paddle_tpu.serving.cache import place_jax_compilation_cache
     cache_dir = place_jax_compilation_cache()
 
@@ -399,6 +400,12 @@ def main(argv=None) -> int:
     modes = chosen_modes()
     for kname in TRAIN_KERNELS + (SERVE_KERNEL,):
         check(kname in modes, f"kernel {kname} was traced on the path")
+    # phase 0 and the autocast train step are the only flash callers here
+    operands = chosen_operand_dtypes()
+    for kname in TRAIN_KERNELS:
+        check(operands.get(kname) == (AMP_DTYPE,),
+              f"every {kname} was built with {AMP_DTYPE} MXU operands "
+              f"({operands.get(kname)})")
     if rehearsal:
         check(all(modes.values()), "rehearsal: every kernel interpreted "
                                    "(Mosaic lowering is not checked here)")

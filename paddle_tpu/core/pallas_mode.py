@@ -5,27 +5,37 @@ by the Pallas interpreter everywhere else (same numerics, so CPU tests
 cover the kernel's math). Every ``pallas_call`` in the package asks
 :func:`resolve_interpret` under a stable kernel name; the answer is
 recorded so a caller — ``chip_smoke.py``, a test — can assert that no
-kernel on its path was interpreted.
+kernel on its path was interpreted. A kernel whose dots follow its input's
+dtype (the flash family) records that operand dtype beside it, so the
+same callers can assert which width the MXU was fed.
 """
 from __future__ import annotations
 
 import threading
-from typing import Dict, Optional
+from typing import Dict, Optional, Set, Tuple
 
 import jax
+import numpy as np
 
 _LOCK = threading.Lock()
 _CHOSEN: Dict[str, bool] = {}
+_OPERANDS: Dict[str, Set[str]] = {}
 
 
-def resolve_interpret(kernel: str, requested: Optional[bool] = None) -> bool:
+def resolve_interpret(kernel: str, requested: Optional[bool] = None,
+                      operand_dtype=None) -> bool:
     """``interpret=`` for the ``pallas_call`` named ``kernel``: the
     caller's explicit ``requested`` value, else True exactly when the
-    default backend is not a TPU. Records the answer (trace time)."""
+    default backend is not a TPU. Records the answer (trace time), and
+    ``operand_dtype`` — the dtype the kernel's dots take their operands
+    in — when the caller names one."""
     interpret = (jax.default_backend() != "tpu" if requested is None
                  else requested)
     with _LOCK:
         _CHOSEN[kernel] = _CHOSEN.get(kernel, False) or interpret
+        if operand_dtype is not None:
+            _OPERANDS.setdefault(kernel, set()).add(
+                np.dtype(operand_dtype).name)
     return interpret
 
 
@@ -34,3 +44,11 @@ def chosen_modes() -> Dict[str, bool]:
     this process; True if any trace of that kernel was interpreted."""
     with _LOCK:
         return dict(_CHOSEN)
+
+
+def chosen_operand_dtypes() -> Dict[str, Tuple[str, ...]]:
+    """``{kernel name: sorted dtype names}`` of the MXU operand dtypes
+    every trace of that kernel so far in this process was built with
+    (kernels that name none are absent)."""
+    with _LOCK:
+        return {k: tuple(sorted(v)) for k, v in _OPERANDS.items()}

@@ -66,12 +66,42 @@ def _tuned_blocks(q_len, kv_len, head_dim, dtype, causal):
         return None
 
 
+def _mxu_dtype(*dtypes):
+    """Operand dtype of a flash kernel's dots: bfloat16 when every
+    operand arrives as bfloat16 (the MXU's native width; a product of
+    two bf16 values is exact in the f32 accumulator), else float32 — the
+    kernels then upcast their blocks exactly as they always have.
+    Accumulators and row statistics are float32 either way."""
+    if all(jnp.dtype(dt) == jnp.bfloat16 for dt in dtypes):
+        return jnp.dtype(jnp.bfloat16)
+    return jnp.dtype(jnp.float32)
+
+
+def _mxu_dot(mxu_dtype):
+    """``dot_general`` for a kernel whose operands are ``mxu_dtype``,
+    accumulating in float32. Native operands name ``Precision.DEFAULT``:
+    the package-wide ``highest`` asks Mosaic for a float32 contraction,
+    which it builds from several bf16 passes for float32 operands and
+    refuses ("Bad lhs type") for bf16 ones."""
+    return functools.partial(
+        lax.dot_general, preferred_element_type=jnp.float32,
+        precision=(None if mxu_dtype == jnp.float32
+                   else lax.Precision.DEFAULT))
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, scale, causal,
-               block_q, block_k, seq_len, kv_len):
+               block_q, block_k, seq_len, kv_len, mxu_dtype):
     import jax.experimental.pallas as pl
 
+    # float32 operands: q is scaled before QK^T. Native (bf16) operands:
+    # q goes to the MXU as given and the f32 scores are scaled, so no
+    # scaled q is ever rounded.
+    native = mxu_dtype != jnp.float32
+    dot = _mxu_dot(mxu_dtype)
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale                 # [bq, D]
+    q = q_ref[0].astype(mxu_dtype)                           # [bq, D]
+    if not native:
+        q = q * scale
     d = q.shape[-1]
     q_idx = qi * block_q + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
 
@@ -79,10 +109,11 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, scale, causal,
 
     def body(j, carry):
         acc, m, l = carry
-        kblk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        vblk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        kblk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(mxu_dtype)
+        vblk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(mxu_dtype)
+        s = dot(q, kblk, (((1,), (1,)), ((), ())))
+        if native:
+            s = s * scale
         k_idx = j * block_k + lax.broadcasted_iota(jnp.int32,
                                                    (block_q, block_k), 1)
         mask = k_idx < seq_len                               # tail padding
@@ -93,9 +124,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, scale, causal,
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new[:, None])
         l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
-            p, vblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_new = acc * alpha[:, None] + dot(
+            p.astype(mxu_dtype), vblk, (((1,), (0,)), ((), ())))
         return acc_new, m_new, l_new
 
     acc0 = jnp.zeros((block_q, d), jnp.float32)
@@ -189,12 +219,16 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, *, scale, causal, block_q, block_k, seq_len,
-                      kv_len):
+                      kv_len, mxu_dtype):
     import jax.experimental.pallas as pl
 
+    native = mxu_dtype != jnp.float32      # see _fa_kernel
+    dot = _mxu_dot(mxu_dtype)
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale
-    do = do_ref[0].astype(jnp.float32)                        # [bq, D]
+    q = q_ref[0].astype(mxu_dtype)
+    if not native:
+        q = q * scale
+    do = do_ref[0].astype(mxu_dtype)                          # [bq, D]
     lse = lse_ref[0, 0].astype(jnp.float32)                   # [bq]
     delta = delta_ref[0, 0].astype(jnp.float32)               # [bq]
     q_idx = qi * block_q + lax.broadcasted_iota(jnp.int32,
@@ -205,21 +239,21 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           // block_k)
 
     def body(j, dq):
-        kblk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        vblk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+        kblk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(mxu_dtype)
+        vblk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(mxu_dtype)
+        s = dot(q, kblk, (((1,), (1,)), ((), ())))
+        if native:
+            s = s * scale
         k_idx = j * block_k + lax.broadcasted_iota(jnp.int32,
                                                    (block_q, block_k), 1)
         mask = k_idx < seq_len
         if causal:
             mask = mask & (q_idx >= k_idx)
         p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)   # [bq, bk]
-        dp = lax.dot_general(do, vblk, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+        dp = dot(do, vblk, (((1,), (1,)), ((), ())))
         ds = p * (dp - delta[:, None])
-        return dq + lax.dot_general(ds, kblk, (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+        return dq + dot(ds.astype(mxu_dtype), kblk,
+                        (((1,), (0,)), ((), ())))
     dq = lax.fori_loop(0, n_k,
                        body, jnp.zeros((block_q, q.shape[-1]), jnp.float32))
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
@@ -227,39 +261,40 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, *, scale, causal, block_q, block_k,
-                       seq_len, q_len):
+                       seq_len, q_len, mxu_dtype):
     import jax.experimental.pallas as pl
 
+    native = mxu_dtype != jnp.float32      # see _fa_kernel
+    dot = _mxu_dot(mxu_dtype)
     ki = pl.program_id(1)
-    kblk = k_ref[0].astype(jnp.float32)                       # [bk, D]
-    vblk = v_ref[0].astype(jnp.float32)
+    kblk = k_ref[0].astype(mxu_dtype)                         # [bk, D]
+    vblk = v_ref[0].astype(mxu_dtype)
     k_idx = ki * block_k + lax.broadcasted_iota(jnp.int32,
                                                 (block_q, block_k), 1)
     n_q = q_len // block_q
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32) \
-            * scale
-        do = do_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
+        q = q_ref[0, pl.ds(i * block_q, block_q), :].astype(mxu_dtype)
+        if not native:
+            q = q * scale
+        do = do_ref[0, pl.ds(i * block_q, block_q), :].astype(mxu_dtype)
         lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)].astype(jnp.float32)
         delta = delta_ref[0, 0,
                           pl.ds(i * block_q, block_q)].astype(jnp.float32)
-        s = lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+        s = dot(q, kblk, (((1,), (1,)), ((), ())))
+        if native:
+            s = s * scale
         q_idx = i * block_q + lax.broadcasted_iota(jnp.int32,
                                                    (block_q, block_k), 0)
         mask = k_idx < seq_len
         if causal:
             mask = mask & (q_idx >= k_idx)
         p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-        dv2 = dv + lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, vblk, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+        dv2 = dv + dot(p.astype(mxu_dtype), do, (((0,), (0,)), ((), ())))
+        dp = dot(do, vblk, (((1,), (1,)), ((), ())))
         ds = p * (dp - delta[:, None])
-        dk2 = dk + lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
+        dk2 = dk + dot(ds.astype(mxu_dtype), q, (((0,), (0,)), ((), ())))
         return dk2, dv2
     if causal:
         # q blocks entirely above this k block see it masked; start there
@@ -268,6 +303,8 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         i0 = 0
     zero = jnp.zeros((block_k, kblk.shape[-1]), jnp.float32)
     dk, dv = lax.fori_loop(i0, n_q, body, (zero, zero))
+    if native:
+        dk = dk * scale           # the f32 lane folds it into q
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -288,9 +325,10 @@ def _fa_fwd_with_lse(qb, kb, vb, causal, sc, bq, bk, interpret, true_kv):
             f"flash attention core: block_q={bq} / block_k={bk} must "
             f"divide the (padded) sequence lengths ({s_pad}, {kv_pad}); "
             "pad the operands or pick a dividing block")
+    mxu_dt = _mxu_dtype(qb.dtype, kb.dtype, vb.dtype)
     kernel = functools.partial(
         _fa_kernel, scale=sc, causal=causal, block_q=bq, block_k=bk,
-        seq_len=true_kv, kv_len=kv_pad)
+        seq_len=true_kv, kv_len=kv_pad, mxu_dtype=mxu_dt)
     return pl.pallas_call(
         kernel,
         grid=(bh, s_pad // bq),
@@ -303,7 +341,7 @@ def _fa_fwd_with_lse(qb, kb, vb, causal, sc, bq, bk, interpret, true_kv):
                    pl.BlockSpec((1, 1, bq), lambda b, i: (b, 0, i))],
         out_shape=[jax.ShapeDtypeStruct((bh, s_pad, d), qb.dtype),
                    jax.ShapeDtypeStruct((bh, 1, s_pad), jnp.float32)],
-        interpret=resolve_interpret("flash_fwd", interpret),
+        interpret=resolve_interpret("flash_fwd", interpret, mxu_dt),
         name="flash_fwd",
     )(qb, kb, vb)
 
@@ -353,10 +391,11 @@ def _fa_bwd_with_lse(qb, kb, vb, do, out, lse, causal, sc, bq, bk,
         delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1)[:, None, :]                  # [bh, 1, s_pad]
     dq_dt, dk_dt, dv_dt = grad_dtypes or (qb.dtype, kb.dtype, vb.dtype)
+    mxu_dt = _mxu_dtype(qb.dtype, kb.dtype, vb.dtype, do.dtype)
 
     dq_kernel = functools.partial(
         _fa_bwd_dq_kernel, scale=sc, causal=causal, block_q=bq, block_k=bk,
-        seq_len=true_kv, kv_len=kv_pad)
+        seq_len=true_kv, kv_len=kv_pad, mxu_dtype=mxu_dt)
     dq = pl.pallas_call(
         dq_kernel,
         grid=(bh, s_pad // bq),
@@ -370,13 +409,13 @@ def _fa_bwd_with_lse(qb, kb, vb, do, out, lse, causal, sc, bq, bk,
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s_pad, d), dq_dt),
-        interpret=resolve_interpret("flash_bwd_dq", interpret),
+        interpret=resolve_interpret("flash_bwd_dq", interpret, mxu_dt),
         name="flash_bwd_dq",
     )(qb, kb, vb, do, lse, delta)
 
     dkv_kernel = functools.partial(
         _fa_bwd_dkv_kernel, scale=sc, causal=causal, block_q=bq,
-        block_k=bk, seq_len=true_kv, q_len=s_pad)
+        block_k=bk, seq_len=true_kv, q_len=s_pad, mxu_dtype=mxu_dt)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(bh, kv_pad // bk),
@@ -392,7 +431,7 @@ def _fa_bwd_with_lse(qb, kb, vb, do, out, lse, causal, sc, bq, bk,
                    pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0))],
         out_shape=[jax.ShapeDtypeStruct((bh, kv_pad, d), dk_dt),
                    jax.ShapeDtypeStruct((bh, kv_pad, d), dv_dt)],
-        interpret=resolve_interpret("flash_bwd_dkv", interpret),
+        interpret=resolve_interpret("flash_bwd_dkv", interpret, mxu_dt),
         name="flash_bwd_dkv",
     )(qb, kb, vb, do, lse, delta)
     return dq, dk, dv

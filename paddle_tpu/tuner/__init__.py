@@ -226,7 +226,8 @@ def autotune_flash(batch_heads: int, q_len: int, kv_len: int,
     jdt = jnp.dtype(dtype)
     q16, k16 = _ceil16(q_len), _ceil16(kv_len)
     cands = flash_candidates(q_len, kv_len, head_dim,
-                             itemsize=jdt.itemsize, require_divides=ring)
+                             itemsize=jdt.itemsize, require_divides=ring,
+                             bwd=bwd)
     kq = jax.random.PRNGKey(0)
     qb = jax.random.normal(kq, (batch_heads, q16, head_dim), jdt)
     kb = jax.random.normal(kq, (batch_heads, k16, head_dim), jdt)

@@ -14,15 +14,20 @@ import statistics
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax
+
+
+def _wait(out: Any) -> None:
+    """Block until every device value in ``out`` (one array or any
+    pytree of them: the backward lane returns ``(dq, dk, dv)``) is
+    ready, so that the wait is real."""
+    jax.block_until_ready(out)  # noqa: PTA002 -- tuner trial barrier: timing requires completion
+
 
 def time_once(run: Callable[[], Any]) -> float:
-    """One timed execution; ``run`` must return a device value (or
-    anything with ``block_until_ready``) so the wait is real."""
+    """One timed execution of ``run``, to the end of its device work."""
     t0 = time.perf_counter()
-    out = run()
-    blocker = getattr(out, "block_until_ready", None)
-    if blocker is not None:
-        blocker()  # noqa: PTA002 -- tuner trial barrier: timing requires completion
+    _wait(run())
     return time.perf_counter() - t0
 
 
@@ -33,10 +38,7 @@ def measure(run: Callable[[], Any], trials: int = 5,
     warmup that also absorbs the compile). Returns None when the
     candidate fails to build/run, or when early pruning fires."""
     try:
-        run_out = run()
-        blocker = getattr(run_out, "block_until_ready", None)
-        if blocker is not None:
-            blocker()  # noqa: PTA002 -- warmup barrier before timing
+        _wait(run())
         first = time_once(run)
     except Exception:
         return None

@@ -9,7 +9,7 @@ compile is attempted.
 """
 from __future__ import annotations
 
-from typing import Dict, Iterator, List, Tuple
+from typing import Dict, Iterator, List, Optional, Tuple
 
 #: per-core VMEM on current TPU generations (pallas_guide.md); trials
 #: budget 80% of it so the compiler keeps headroom for spills/semaphores
@@ -29,22 +29,59 @@ def _ceil_to(n: int, m: int) -> int:
 
 
 def flash_vmem_bytes(block_q: int, block_k: int, kv_len: int,
-                     head_dim: int, itemsize: int = 4) -> int:
-    """VMEM-resident bytes for one flash-attention program instance.
+                     head_dim: int, itemsize: int = 4, bwd: bool = False,
+                     q_len: Optional[int] = None) -> int:
+    """VMEM-resident bytes of one flash-attention program instance, for
+    the forward kernel or (``bwd``) the larger of the dQ and dK/dV ones
+    (ops/pallas_attention.py).
 
-    The forward kernel's BlockSpecs bring the q block, the FULL padded
-    K/V sequence, and the output block into VMEM; the score block,
-    accumulator and row stats live in registers/VMEM scratch. f32
-    accumulation dominates the scratch terms regardless of input dtype.
+    Two parts. **Pipeline buffers**: every BlockSpec operand and result
+    is held twice (Pallas double-buffers a block even when its index
+    repeats, as the whole-sequence K/V of the forward and dQ programs and
+    the whole-sequence Q/dO of the dK/dV program do): a kernel whose
+    resident pair alone reaches the budget builds at no block size.
+    **In-kernel values**: the float32 score block (P overwrites S), in
+    the backward a second one (dP, then dS), and the copy of P (and dS)
+    that is the MXU's operand — bfloat16 when the inputs are (the dots
+    take their operands in the input's dtype; 2-byte inputs are taken as
+    bfloat16), float32 otherwise, where it is the block Mosaic transposes
+    or keeps beside the next one — plus the float32 accumulators and row
+    statistics, which no input dtype narrows.
+
+    Checked against the compiler for a described v5e (16 MiB scoped; the
+    budget is 80% of it): every shape it refuses is over the budget here
+    (K/V of 2 x 4 MiB, float32 1024x1024 backward blocks, a 2048x2048
+    score block); 1024x1024 bfloat16 backward blocks, which it builds
+    with nothing to spare, are over it too.
     """
+    q_len = kv_len if q_len is None else q_len
     kv_pad = _ceil_to(kv_len, block_k)
+    q_pad = _ceil_to(q_len, block_q)
     q_blk = block_q * head_dim * itemsize
-    kv_res = 2 * kv_pad * head_dim * itemsize
-    scores = block_q * block_k * 4            # f32 score block
-    acc = block_q * head_dim * 4              # f32 accumulator
-    out = block_q * head_dim * itemsize
-    stats = 2 * block_q * 4                   # m / l rows
-    return q_blk + kv_res + scores + acc + out + stats
+    k_blk = block_k * head_dim * itemsize
+    score = block_q * block_k * 4             # one f32 [bq, bk] block
+    operand = block_q * block_k * itemsize    # P or dS as the dot takes it
+    if not bwd:
+        pipeline = 2 * (q_blk                       # q
+                        + 2 * kv_pad * head_dim * itemsize   # K, V whole
+                        + q_blk + block_q * 4)      # out, lse
+        values = (score + operand
+                  + block_q * head_dim * 4          # acc
+                  + 2 * block_q * 4)                # m, l
+        return pipeline + values
+    dq_prog = (2 * (2 * q_blk                       # q, dO
+                    + 2 * kv_pad * head_dim * itemsize       # K, V whole
+                    + 2 * block_q * 4               # lse, delta
+                    + q_blk)                        # dQ
+               + 2 * score + operand
+               + block_q * head_dim * 4)            # dQ accumulator
+    dkv_prog = (2 * (2 * q_pad * head_dim * itemsize         # Q, dO whole
+                     + 2 * k_blk                    # k, v
+                     + 2 * q_pad * 4                # lse, delta whole
+                     + 2 * k_blk)                   # dK, dV
+                + 2 * score + 2 * operand
+                + 2 * block_k * head_dim * 4)       # dK, dV accumulators
+    return max(dq_prog, dkv_prog)
 
 
 def blockspec_vmem_bytes(block_shapes, itemsize: int = 4) -> int:
@@ -66,10 +103,11 @@ def blockspec_vmem_bytes(block_shapes, itemsize: int = 4) -> int:
 
 def flash_candidates(q_len: int, kv_len: int, head_dim: int,
                      itemsize: int = 4,
-                     require_divides: bool = False
-                     ) -> List[Tuple[int, int]]:
+                     require_divides: bool = False,
+                     bwd: bool = False) -> List[Tuple[int, int]]:
     """(block_q, block_k) candidates for a flash-attention shape, VMEM
-    pruned. ``require_divides`` restricts to blocks that divide the
+    pruned by the forward's footprint or (``bwd``) the backward
+    programs'. ``require_divides`` restricts to blocks that divide the
     16-rounded lengths exactly — the ring-flash path calls the kernel
     core without a padding wrapper, so only exact divisors are legal
     there."""
@@ -86,8 +124,8 @@ def flash_candidates(q_len: int, kv_len: int, head_dim: int,
                 continue
             if require_divides and k16 % bk:
                 continue
-            if flash_vmem_bytes(bq, bk, kv_len, head_dim,
-                                itemsize) > VMEM_BUDGET:
+            if flash_vmem_bytes(bq, bk, kv_len, head_dim, itemsize,
+                                bwd=bwd, q_len=q_len) > VMEM_BUDGET:
                 continue
             out.append((bq, bk))
     if not out:

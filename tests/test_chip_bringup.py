@@ -154,6 +154,38 @@ def test_pallas_mode_records_what_ran():
     assert pallas_mode.chosen_modes()["probe"] is False
 
 
+@pytest.mark.parametrize("amp,want", [("bfloat16", ("bfloat16",)),
+                                      (None, ("float32",))],
+                         ids=["autocast-bf16", "float32"])
+def test_pallas_mode_records_the_flash_operand_dtype(monkeypatch, amp, want):
+    """A train step's flash kernels (forward, recompute's second forward,
+    dQ, dK/dV) feed the MXU the dtype they are given: bfloat16 under
+    autocast, float32 for a float32 model (chip_smoke.py asserts the
+    first on the chip)."""
+    import contextlib
+    import numpy as np
+    from paddle_tpu.distributed.fleet.utils import recompute
+    from paddle_tpu.models import (GPTConfig, GPTForCausalLM,
+                                   GPTPretrainingCriterion)
+    monkeypatch.setattr(pallas_mode, "_OPERANDS", {})
+    net = GPTForCausalLM(GPTConfig(
+        vocab_size=64, hidden_size=32, num_layers=2, num_heads=2,
+        max_position_embeddings=64, hidden_dropout_prob=0.0,
+        attention_dropout_prob=0.0, attn_impl="flash"))
+    for blk in net.gpt.decoder.layers:
+        blk.forward = (lambda *a, __f=blk.forward, **k:
+                       recompute(__f, *a, **k))
+    ids = paddle.to_tensor(np.arange(128, dtype=np.int32).reshape(2, 64) % 64)
+    cast = (paddle.amp.auto_cast(enable=True, dtype=amp) if amp
+            else contextlib.nullcontext())
+    with cast:
+        loss = GPTPretrainingCriterion()(net(ids), ids.astype("int64"))
+    loss.backward()
+    got = pallas_mode.chosen_operand_dtypes()
+    assert got == {k: want for k in ("flash_fwd", "flash_bwd_dq",
+                                     "flash_bwd_dkv")}
+
+
 def test_engine_stats_name_the_paged_lane():
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
     from paddle_tpu.serving.llm import LLMEngine, LLMEngineConfig

@@ -118,3 +118,125 @@ def test_block_size_boundaries_causal(bq, bk):
         got, want = np.asarray(got.numpy()), np.asarray(want)
         rel = np.abs(got - want).max() / (np.abs(want).max() + 1e-9)
         assert rel < 1e-4, rel
+
+
+# -- the dots follow the input's dtype (PR 27) --------------------------------
+
+#: stated tolerances per input dtype. float32: the file's own (above).
+#: bfloat16: chip_smoke.py's — forward 2e-2 absolute, gradients 2^-6 of
+#: the reference gradient's abs max (two bf16 ulps at its top).
+FWD_TOL = {"float32": dict(rtol=1e-4, atol=2e-5),
+           "bfloat16": dict(rtol=0.0, atol=2e-2)}
+GRAD_RTOL = {"float32": 1e-4, "bfloat16": 2.0 ** -6}
+
+
+@pytest.mark.parametrize("blocks", [(32, 64), (128, 128)],
+                         ids=["b32x64", "b128x128"])
+@pytest.mark.parametrize("S", [128, 200])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_matches_dense_float32_of_the_same_inputs(dtype, causal, S, blocks):
+    """Forward and dQ/dK/dV against dense float32 attention of the SAME
+    (already rounded) inputs: what the kernel's own arithmetic costs,
+    not what the inputs' rounding does."""
+    rng = np.random.RandomState(5)
+    B, H, D = 1, 2, 64
+    q, k, v, do = (jnp.asarray(rng.randn(B, S, H, D).astype(np.float32)
+                               * s, dtype)
+                   for s in (0.5, 0.5, 1.0, 1.0))
+    scale = 1 / np.sqrt(D)
+
+    def flash(a, b, c):
+        out, _ = F.flash_attention(paddle.Tensor(a), paddle.Tensor(b),
+                                   paddle.Tensor(c), causal=causal,
+                                   block_q=blocks[0], block_k=blocks[1])
+        return out._data
+
+    def dense(a, b, c):
+        return _dense(*(x.astype(jnp.float32) for x in (a, b, c)),
+                      causal, scale)
+
+    def with_grads(fn):
+        def loss(a, b, c):
+            o = fn(a, b, c)
+            return jnp.sum(o.astype(jnp.float32)
+                           * do.astype(jnp.float32)), o
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+
+    (_, o_k), g_k = with_grads(flash)(q, k, v)
+    (_, o_r), g_r = with_grads(dense)(q, k, v)
+    assert o_k.dtype == jnp.dtype(dtype)
+    np.testing.assert_allclose(np.asarray(o_k.astype(jnp.float32)),
+                               np.asarray(o_r), **FWD_TOL[dtype])
+    for name, gk, gr in zip(("dQ", "dK", "dV"), g_k, g_r):
+        assert gk.dtype == jnp.dtype(dtype), name
+        gk, gr = (np.asarray(x.astype(jnp.float32)) for x in (gk, gr))
+        err = np.abs(gk - gr).max()
+        assert err <= GRAD_RTOL[dtype] * np.abs(gr).max(), (name, err)
+
+
+def _kernel_dots(fn, *args):
+    """[(lhs dtype, rhs dtype, result dtype, precision)] of every
+    dot_general inside the Pallas kernels ``fn`` traces, and the text of
+    those kernels' jaxprs."""
+    dots, texts = [], []
+
+    def walk(jaxpr, in_kernel):
+        for eqn in jaxpr.eqns:
+            if in_kernel and eqn.primitive.name == "dot_general":
+                prec = eqn.params["precision"]
+                dots.append((*(v.aval.dtype.name for v in eqn.invars),
+                             eqn.outvars[0].aval.dtype.name,
+                             None if prec is None else
+                             tuple(str(p) for p in prec)))
+            for val in eqn.params.values():
+                sub = getattr(val, "jaxpr", val)
+                if hasattr(sub, "eqns"):
+                    kernel = in_kernel or eqn.primitive.name == "pallas_call"
+                    if kernel and not in_kernel:
+                        texts.append(str(sub))
+                    walk(sub, kernel)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, False)
+    return dots, "\n".join(texts)
+
+
+def _fwd_and_bwd(q, k, v, do):
+    from paddle_tpu.ops import pallas_attention as fa
+    out, lse = fa._fa_fwd_with_lse(q, k, v, True, 0.125, 64, 64, True, 128)
+    return fa._fa_bwd_with_lse(q, k, v, do, out, lse, True, 0.125, 64, 64,
+                               True, 128, grad_dtypes=(jnp.float32,) * 3)
+
+
+def test_bfloat16_inputs_reach_every_dot_as_bfloat16():
+    """All nine dots (2 forward, 3 dQ, 4 dK/dV) take bf16 operands and
+    accumulate in float32 at Precision.DEFAULT: under the package-wide
+    ``highest`` Mosaic refuses a bf16 contraction ("Bad lhs type", met
+    when the kernels were compiled for a described v5e, PR 27). The
+    ring's float32 ``grad_dtypes`` are still honoured."""
+    x = jnp.zeros((2, 128, 64), jnp.bfloat16)
+    dots, _ = _kernel_dots(_fwd_and_bwd, x, x, x, x)
+    assert len(dots) == 9
+    for lhs, rhs, out, prec in dots:
+        assert (lhs, rhs, out) == ("bfloat16", "bfloat16", "float32")
+        assert prec is None or set(prec) == {"DEFAULT"}, prec
+    grads = jax.eval_shape(_fwd_and_bwd, x, x, x, x)
+    assert [g.dtype for g in grads] == [jnp.float32] * 3
+
+
+@pytest.mark.parametrize("dtypes", [("float32",) * 4,
+                                    ("bfloat16", "float32", "bfloat16",
+                                     "bfloat16")],
+                         ids=["float32", "mixed"])
+def test_float32_kernels_are_what_they_were(dtypes):
+    """float32 (or mixed) inputs: every block is upcast and every dot is
+    float32 x float32 at the precision the package sets, as before PR 27
+    (whose parent the outputs and gradients match bitwise, PERF.md); a
+    float32 kernel holds no bfloat16 value at all."""
+    q, k, v, do = (jnp.zeros((2, 128, 64), dt) for dt in dtypes)
+    dots, text = _kernel_dots(_fwd_and_bwd, q, k, v, do)
+    assert len(dots) == 9
+    for lhs, rhs, out, prec in dots:
+        assert (lhs, rhs, out) == ("float32",) * 3
+        assert set(prec) == {"HIGHEST"}, prec
+    if set(dtypes) == {"float32"}:
+        assert "bf16" not in text and "f32" in text

@@ -235,13 +235,42 @@ class TestResolutionTiers:
 
 class TestCandidateSpace:
     def test_vmem_pruning(self):
-        # kv=12288 at d=128 f32 leaves <1 MiB after the resident K/V,
-        # so big score blocks must be pruned while small ones survive
-        cands = space.flash_candidates(12288, 12288, 128, itemsize=4)
-        assert cands and (512, 512) not in cands
+        # kv=5120 at d=128 f32 leaves under 3 MiB beside the resident,
+        # double-buffered K/V (10 MiB), so big score blocks must be
+        # pruned while small ones survive
+        cands = space.flash_candidates(5120, 5120, 128, itemsize=4)
+        assert (128, 128) in cands and (512, 512) not in cands
         for bq, bk in cands:
-            assert space.flash_vmem_bytes(bq, bk, 12288, 128,
+            assert space.flash_vmem_bytes(bq, bk, 5120, 128,
                                           4) <= space.VMEM_BUDGET
+
+    @pytest.mark.parametrize("args,bwd,fits", [
+        # what the compiler for a described v5e builds (True) and
+        # refuses for VMEM (False), PR 27; the two bfloat16 backward
+        # rows bracket the budget's 20% of headroom
+        ((512, 512, 4096, 64, 2), False, True),
+        ((1024, 1024, 4096, 64, 2), False, True),
+        ((2048, 2048, 4096, 64, 2), False, False),
+        ((128, 128, 8192, 128, 4), False, False),    # K/V alone: 16 MiB
+        ((512, 512, 16384, 64, 2), False, True),
+        ((512, 512, 16384, 128, 2), False, False),
+        ((512, 512, 4096, 64, 2), True, True),
+        ((512, 512, 8192, 128, 2), True, True),
+        ((1024, 1024, 4096, 64, 4), True, False),
+        ((256, 256, 8192, 128, 4), True, False),
+    ])
+    def test_vmem_model_agrees_with_the_v5e_compiler(self, args, bwd, fits):
+        assert (space.flash_vmem_bytes(*args, bwd=bwd)
+                <= space.VMEM_BUDGET) is fits
+
+    def test_backward_footprint_is_the_larger_program(self):
+        # more in flight than the forward at the same blocks (two score
+        # blocks, P and dS operand copies), and less once the operands
+        # are bfloat16
+        fwd = space.flash_vmem_bytes(256, 256, 4096, 64, 2)
+        bwd = space.flash_vmem_bytes(256, 256, 4096, 64, 2, bwd=True)
+        assert fwd < bwd < space.flash_vmem_bytes(256, 256, 4096, 64, 4,
+                                                  bwd=True)
 
     def test_require_divides(self):
         cands = space.flash_candidates(96, 96, 16, require_divides=True)
@@ -312,3 +341,50 @@ class TestNMSUnroll:
         tuner.record_winner(tuner.nms_key(18), {"unroll": 4})
         tuner.clear_memo()
         assert _nms_unroll(18) == 1
+
+
+def test_emit_defaults_keeps_notes_and_writes_measured_times(tmp_path):
+    """tools/autotune.py --emit-defaults: a retimed key keeps its curated
+    note and gains the winner's measured time; the time never lands in
+    the config the kernels read."""
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from tools.autotune import emit_defaults
+    path = tmp_path / "winners.json"
+    path.write_text(json.dumps({"version": 1, "platform": "defaults",
+                                "entries": {
+        "flash_fwd|tpu|bfloat16|d64|q64|k64|c1": {
+            "config": {"block_q": 16, "block_k": 16}, "note": "kept"},
+        "nms|cpu|k64": {"config": {"unroll": 4}}}}))
+    emit_defaults({"flash_fwd|tpu|bfloat16|d64|q64|k64|c1":
+                   {"block_q": 32, "block_k": 64, "us": 123.456}},
+                  str(path))
+    entries = json.loads(path.read_text())["entries"]
+    assert entries["flash_fwd|tpu|bfloat16|d64|q64|k64|c1"] == {
+        "config": {"block_q": 32, "block_k": 64}, "us": 123.5,
+        "note": "kept"}
+    assert entries["nms|cpu|k64"] == {"config": {"unroll": 4}}
+    assert store._load_table(str(path), "test table").keys() == \
+        entries.keys()
+
+
+def test_runner_waits_for_every_result_of_a_candidate():
+    """The backward lane's candidates return ``(dq, dk, dv)``: a wait on
+    the first attribute of the tuple found none and timed the dispatch
+    alone (376 us for a 6.5 ms program, PERF.md PR 27)."""
+    from paddle_tpu.tuner import runner
+    waited = []
+
+    class Result:
+        def block_until_ready(self):
+            waited.append(self)
+            return self
+
+    trio = (Result(), Result(), Result())
+    runner.time_once(lambda: trio)
+    assert waited == list(trio)
+    del waited[:]
+    assert runner.measure(lambda: {"a": trio[0], "b": [trio[1]]},
+                          trials=2) is not None
+    assert len(waited) == 2 * 3          # warm-up, then two timed runs

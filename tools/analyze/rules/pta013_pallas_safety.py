@@ -149,7 +149,8 @@ def iter_winner_footprints(root: str):
             bytes_ = space.flash_vmem_bytes(
                 int(cfg.get("block_q", 16)), int(cfg.get("block_k", 16)),
                 int(params.get("k", params.get("q", 16))),
-                int(params.get("d", 64)), itemsize)
+                int(params.get("d", 64)), itemsize,
+                bwd=family.endswith("bwd"), q_len=params.get("q"))
         yield key, family, bytes_, space.VMEM_BUDGET
 
 

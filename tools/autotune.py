@@ -102,7 +102,7 @@ def tune_flash_lane(shapes, trials, batch_heads, bwd=False):
               f"{len(win['results'])} candidates, "
               f"{time.time() - t0:.1f}s search)")
         results[key] = {"block_q": win["block_q"],
-                        "block_k": win["block_k"]}
+                        "block_k": win["block_k"], "us": win["us"]}
     return results
 
 
@@ -171,7 +171,9 @@ def tune_compress_lane(sizes, trials):
 
 def emit_defaults(tuned, path):
     """Merge this run's winners into the committed defaults table,
-    preserving curated entries and notes for keys not retuned."""
+    preserving curated entries and notes for keys not retuned. A lane
+    that reports its winner's time (``us``, beside the config's own
+    keys) has it written into the entry."""
     try:
         with open(path) as f:
             table = json.load(f)
@@ -180,7 +182,11 @@ def emit_defaults(tuned, path):
         entries = {}
     for key, cfg in sorted(tuned.items()):
         prev = entries.get(key, {})
+        cfg = dict(cfg)
+        us = cfg.pop("us", None)
         entry = {"config": cfg}
+        if us is not None:
+            entry["us"] = round(float(us), 1)
         if "note" in prev:
             entry["note"] = prev["note"]
         entries[key] = entry
