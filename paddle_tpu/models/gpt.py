@@ -15,14 +15,28 @@ TPU notes:
   reference mp_layers.py:96 ColumnParallelLinear / :169 RowParallelLinear /
   :29 VocabParallelEmbedding) — consumed by fleet's planner and the
   multi-chip dryrun.
+
+Serving runs the block as ONE function of a parameter pytree and a *cache
+view* (:func:`gpt_block`, :func:`gpt_hidden`; the twin of
+``models/lfm2.py``). A view answers what depends on where the sequence's
+past lives: ``view.attend(li, q, k, v, scale)`` is the attention of ``q``
+for layer ``li`` over the keys and values so far, ``k``/``v`` included,
+and the view keeps whatever it wrote. :class:`FullSequence` is the view
+with no past; the views over slot rows and pages live beside their caches
+(``serving/llm/kvcache.py``, ``serving/llm/paged/pool.py``). The ``Layer``
+graph above keeps its own forward (autocast, recompute and the flash
+kernels live there).
 """
 from __future__ import annotations
 
 import re
 from dataclasses import dataclass
-from typing import Dict, Optional
+from typing import Any, Dict, Optional
 
 import numpy as np
+
+import jax
+import jax.numpy as jnp
 
 from ..core.tensor import Tensor
 from .. import ops
@@ -170,6 +184,160 @@ class GPTPretrainingCriterion(Layer):
                                ops.reshape(tgt, [-1]), ignore_index=-100)
 
 
+# -- the block as a function of a cache view (what serving runs) --------------
+
+@dataclass(frozen=True)
+class GPTDecodeSpec:
+    """The static facts the compiled decode program is specialized on.
+
+    Frozen + hashable: it keys the process-wide jit-function caches, so
+    two engines (or ``generate`` calls) over same-shaped models share one
+    traced program family.
+    """
+    vocab_size: int
+    hidden_size: int
+    num_layers: int
+    num_heads: int
+    max_position_embeddings: int
+    ln_epsilon: float = 1e-5
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_heads
+
+    @classmethod
+    def from_model(cls, model) -> "GPTDecodeSpec":
+        c = model.gpt.config
+        return cls(vocab_size=c.vocab_size, hidden_size=c.hidden_size,
+                   num_layers=c.num_layers, num_heads=c.num_heads,
+                   max_position_embeddings=c.max_position_embeddings)
+
+
+def extract_gpt_params(model) -> Dict[str, Any]:
+    """The GPT parameter pytree as raw jnp arrays (references, not copies —
+    re-extract after an optimizer step to pick up new values)."""
+    gpt = model.gpt
+    layers = []
+    for lyr in gpt.decoder.layers:
+        a = lyr.self_attn
+        layers.append({
+            "qw": a.q_proj.weight._data, "qb": a.q_proj.bias._data,
+            "kw": a.k_proj.weight._data, "kb": a.k_proj.bias._data,
+            "vw": a.v_proj.weight._data, "vb": a.v_proj.bias._data,
+            "ow": a.out_proj.weight._data, "ob": a.out_proj.bias._data,
+            "w1": lyr.linear1.weight._data, "b1": lyr.linear1.bias._data,
+            "w2": lyr.linear2.weight._data, "b2": lyr.linear2.bias._data,
+            "n1w": lyr.norm1.weight._data, "n1b": lyr.norm1.bias._data,
+            "n2w": lyr.norm2.weight._data, "n2b": lyr.norm2.bias._data,
+        })
+    return {
+        "tok": gpt.word_embeddings.weight._data,
+        "pos": gpt.position_embeddings.weight._data,
+        "fnw": gpt.decoder.norm.weight._data,
+        "fnb": gpt.decoder.norm.bias._data,
+        "layers": tuple(layers),
+    }
+
+
+def _mm(x, w):
+    """``x @ w`` for a dense f32 weight or an int8 ``{"q", "s"}`` leaf.
+    The int8 path multiplies against the raw codes and applies the
+    per-out-channel scale to the product — exactly equal to dequantizing
+    first (scales distribute over the contraction), but the weight reads
+    stay int8, which is the memory-bandwidth win."""
+    if isinstance(w, dict):
+        return (x @ w["q"].astype(x.dtype)) * w["s"].astype(x.dtype)
+    return x @ w
+
+
+def _layer_norm(x, w, b, eps):
+    # mirrors F.layer_norm: mean/var over the last axis, rsqrt, scale+shift
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.var(x, axis=-1, keepdims=True)
+    return (x - mean) * jax.lax.rsqrt(var + eps) * w + b
+
+
+def masked_attention(q, k, v, mask, scale):
+    """Dense attention of ``q`` ``[B, T, H, D]`` (or ``[B, H, D]``: one
+    query a row) over ``k``/``v`` ``[B, M, H, D]`` under an additive
+    ``mask`` that broadcasts to ``[B, H, T, M]`` (-1e9 where a row is not
+    to be seen: its softmax weight is exactly 0.0 in f32). Returns the
+    shape of ``q``. Mirrors the dense branch of
+    ``nn.transformer.MultiHeadAttention`` operation for operation: every
+    view that reads whole rows attends through here, which is what keeps
+    them bitwise equal to one another."""
+    one = q.ndim == 3
+    if one:
+        q = q[:, None]
+    qh = jnp.transpose(q * scale, (0, 2, 1, 3))            # [B, H, T, D]
+    kt = jnp.transpose(k, (0, 2, 1, 3))                    # [B, H, M, D]
+    vt = jnp.transpose(v, (0, 2, 1, 3))
+    prod = jnp.matmul(qh, jnp.swapaxes(kt, -1, -2))        # [B, H, T, M]
+    weights = jax.nn.softmax(prod + mask, axis=-1)
+    out = jnp.transpose(jnp.matmul(weights, vt), (0, 2, 1, 3))
+    return out[:, 0] if one else out
+
+
+class FullSequence:
+    """The view with no past: whole right-padded prompts from position 0
+    under the additive causal triu the dense path materialises. Records
+    each layer's ``(k, v)`` ``[B, T, H, D]`` in ``kv``: what a cache has to
+    keep."""
+
+    def __init__(self):
+        self.kv, self.mask = [], None
+
+    def attend(self, li, q, k, v, scale):
+        self.kv.append((k, v))
+        if self.mask is None:
+            t = q.shape[1]
+            self.mask = jnp.triu(jnp.full((t, t), -1e9, q.dtype),
+                                 1)[None, None]
+        return masked_attention(q, k, v, self.mask, scale)
+
+
+def stack_kv(kv, axis: int):
+    """A view's per-layer ``[(k, v), ...]`` records as ``(K, V)``, the
+    layers stacked on a new ``axis``."""
+    ks, vs = zip(*kv)
+    return jnp.stack(ks, axis=axis), jnp.stack(vs, axis=axis)
+
+
+def gpt_block(spec: GPTDecodeSpec, lp, h, view, li: int):
+    """Pre-norm layer ``li`` on ``h`` ``[B, T, E]``, or ``[S, E]`` for one
+    token per slot: the decode tick has no ``T`` axis, because the chip's
+    compiler lays ``[S, 1, E]`` out in other tiles than ``[S, E]`` and
+    fuses the tick differently. Must mirror the framework's eval ops
+    exactly: ``F.layer_norm``, the dense attention branch,
+    ``F.gelu(approximate=False)``. The projections go through :func:`_mm`,
+    so ``lp`` may hold int8 weight leaves."""
+
+    def heads(z):                              # [B, T, H, D] or [S, H, D]
+        return z.reshape(h.shape[:-1] + (spec.num_heads, spec.head_dim))
+
+    x = _layer_norm(h, lp["n1w"], lp["n1b"], spec.ln_epsilon)
+    q = heads(_mm(x, lp["qw"]) + lp["qb"])
+    k = heads(_mm(x, lp["kw"]) + lp["kb"])
+    v = heads(_mm(x, lp["vw"]) + lp["vb"])
+    out = view.attend(li, q, k, v, 1.0 / np.sqrt(spec.head_dim))
+    h = h + (_mm(out.reshape(h.shape), lp["ow"]) + lp["ob"])
+    x = _layer_norm(h, lp["n2w"], lp["n2b"], spec.ln_epsilon)
+    ffn = jax.nn.gelu(_mm(x, lp["w1"]) + lp["b1"], approximate=False)
+    return h + (_mm(ffn, lp["w2"]) + lp["b2"])
+
+
+def gpt_hidden(spec: GPTDecodeSpec, params, tokens, positions, view):
+    """Final-norm hidden states ``[B, T, E]`` of ``tokens`` ``[B, T]`` at
+    ``positions`` (``[B, T]`` or ``[1, T]``; clipped into the learned
+    position table), the past read and written through ``view``; ``[S, E]``
+    of ``[S]`` tokens at ``[S]`` positions for the decode tick."""
+    posc = jnp.clip(positions, 0, spec.max_position_embeddings - 1)
+    h = params["tok"][tokens] + params["pos"][posc]
+    for li, lp in enumerate(params["layers"]):
+        h = gpt_block(spec, lp, h, view, li)
+    return _layer_norm(h, params["fnw"], params["fnb"], spec.ln_epsilon)
+
+
 # -- tensor-parallel plan -----------------------------------------------------
 
 _TP_RULES = (
@@ -212,8 +380,6 @@ def gpt_pipeline_fns(model: "GPTForCausalLM", num_stages: int):
     _send_meta); here they are fixed at build time. Dropout must be 0 (the
     engine threads no RNG through the schedule).
     """
-    import jax
-    import jax.numpy as jnp
     from ..jit.functionalize import build_pure
 
     cfg = model.gpt.config
@@ -326,8 +492,6 @@ def _gpt_generate_static(model, ids, max_length, decode_strategy, top_k,
     import numpy as np
     from ..core import generator as _gen
     from ..core.tensor import Tensor
-    import jax
-    import jax.numpy as jnp
     from ..serving.llm.decode import (GPTStaticDecoder, SamplingParams,
                                       pack_sampling)
 
@@ -398,8 +562,6 @@ def _gpt_generate(model, input_ids, max_length=32, decode_strategy="greedy",
     import numpy as np
     from ..core import generator as _gen
     from ..core.tensor import Tensor
-    import jax
-    import jax.numpy as jnp
 
     if decode_strategy not in ("greedy", "sampling"):
         raise ValueError(

@@ -8,12 +8,17 @@ every request ("Operator Fusion in XLA", arxiv 2301.13062). Parameters
 are passed as a pytree argument (not baked into the trace), so training
 and serving can share one executable across checkpoint reloads.
 
-The math mirrors the framework's dense eval path operation-for-operation
-(``nn.transformer.MultiHeadAttention`` dense branch, ``F.layer_norm``,
-``F.gelu(approximate=False)``, tied-embedding logits, and the sampling
-recipe of ``models.gpt._gpt_generate``), so static-slot decode emits the
-same tokens as the reference concat-cache path — the equivalence test in
-``tests/test_llm_serving.py`` asserts it token-for-token.
+Every program here and under ``paged/`` is the same three steps: build the
+cache view of where its K/V rows live (``kvcache.SlotRows`` / ``TailRows``,
+``models.gpt.FullSequence``, ``paged.pool.PagedRows``), run
+``models.gpt.gpt_hidden`` (the block, written once) through it, and end in
+:func:`sample_next`. The math mirrors the framework's dense eval path
+operation-for-operation (``nn.transformer.MultiHeadAttention`` dense
+branch, ``F.layer_norm``, ``F.gelu(approximate=False)``, tied-embedding
+logits, and the sampling recipe of ``models.gpt._gpt_generate``), so
+static-slot decode emits the same tokens as the reference concat-cache
+path — the equivalence test in ``tests/test_llm_serving.py`` asserts it
+token-for-token.
 
 Per-slot sampling state travels as device vectors (``temperature``,
 ``top_k``, ``do_sample``, ``eos``; eos < 0 means "no eos"), so requests
@@ -30,37 +35,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ...models.gpt import (FullSequence, GPTDecodeSpec, extract_gpt_params,
+                           gpt_hidden, stack_kv)
 from ..cache import ExecutableCache, default_cache
-from .kvcache import StaticKVCache, append_token_kv, dequantize_kv, \
-    is_quantized_kv, kv_layer_view, kv_max_seq, kv_stack_layers, \
+from .kvcache import SlotRows, StaticKVCache, TailRows, is_quantized_kv, \
     valid_mask, write_prompt_kv, write_prompt_kv_at
-
-
-@dataclass(frozen=True)
-class GPTDecodeSpec:
-    """The static facts the compiled decode program is specialized on.
-
-    Frozen + hashable: it keys the process-wide jit-function caches, so
-    two engines (or ``generate`` calls) over same-shaped models share one
-    traced program family.
-    """
-    vocab_size: int
-    hidden_size: int
-    num_layers: int
-    num_heads: int
-    max_position_embeddings: int
-    ln_epsilon: float = 1e-5
-
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
-
-    @classmethod
-    def from_model(cls, model) -> "GPTDecodeSpec":
-        c = model.gpt.config
-        return cls(vocab_size=c.vocab_size, hidden_size=c.hidden_size,
-                   num_layers=c.num_layers, num_heads=c.num_heads,
-                   max_position_embeddings=c.max_position_embeddings)
 
 
 @dataclass
@@ -76,32 +55,6 @@ class SamplingParams:
     def clamped_temperature(self) -> float:
         # same guard the reference generate applies host-side
         return max(float(self.temperature), 1e-6)
-
-
-def extract_gpt_params(model) -> Dict[str, Any]:
-    """The GPT parameter pytree as raw jnp arrays (references, not copies —
-    re-extract after an optimizer step to pick up new values)."""
-    gpt = model.gpt
-    layers = []
-    for lyr in gpt.decoder.layers:
-        a = lyr.self_attn
-        layers.append({
-            "qw": a.q_proj.weight._data, "qb": a.q_proj.bias._data,
-            "kw": a.k_proj.weight._data, "kb": a.k_proj.bias._data,
-            "vw": a.v_proj.weight._data, "vb": a.v_proj.bias._data,
-            "ow": a.out_proj.weight._data, "ob": a.out_proj.bias._data,
-            "w1": lyr.linear1.weight._data, "b1": lyr.linear1.bias._data,
-            "w2": lyr.linear2.weight._data, "b2": lyr.linear2.bias._data,
-            "n1w": lyr.norm1.weight._data, "n1b": lyr.norm1.bias._data,
-            "n2w": lyr.norm2.weight._data, "n2b": lyr.norm2.bias._data,
-        })
-    return {
-        "tok": gpt.word_embeddings.weight._data,
-        "pos": gpt.position_embeddings.weight._data,
-        "fnw": gpt.decoder.norm.weight._data,
-        "fnb": gpt.decoder.norm.bias._data,
-        "layers": tuple(layers),
-    }
 
 
 #: per-layer weight matrices that quantize to int8 (biases/norms stay f32
@@ -130,26 +83,6 @@ def quantize_gpt_params(params: Dict[str, Any]) -> Dict[str, Any]:
     return dict(params, layers=layers)
 
 
-def _mm(x, w):
-    """``x @ w`` for a dense f32 weight or an int8 ``{"q", "s"}`` leaf.
-    The int8 path multiplies against the raw codes and applies the
-    per-out-channel scale to the product — exactly equal to dequantizing
-    first (scales distribute over the contraction), but the weight reads
-    stay int8, which is the memory-bandwidth win."""
-    if isinstance(w, dict):
-        return (x @ w["q"].astype(x.dtype)) * w["s"].astype(x.dtype)
-    return x @ w
-
-
-# -- building blocks (must mirror the framework eval ops exactly) -----------
-
-def _layer_norm(x, w, b, eps):
-    # mirrors F.layer_norm: mean/var over the last axis, rsqrt, scale+shift
-    mean = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.var(x, axis=-1, keepdims=True)
-    return (x - mean) * jax.lax.rsqrt(var + eps) * w + b
-
-
 def _sample(lraw, temperature, top_k, do_sample, key, max_top_k):
     """Greedy argmax / temperature+top-k categorical, vectorized per slot.
 
@@ -172,64 +105,49 @@ def _sample(lraw, temperature, top_k, do_sample, key, max_top_k):
     return jnp.where(do_sample, sampled, greedy)
 
 
-def _block_decode(spec, lp, h, kb, vb, positions, mask, scale):
-    """One pre-norm transformer block for a single new token per slot.
-
-    ``h``: [S, E]; ``kb``/``vb``: this layer's [S, max_seq, H, D] cache;
-    returns (h, kb, vb) with the token's K/V written at ``positions``.
-    """
-    s = h.shape[0]
-    x = _layer_norm(h, lp["n1w"], lp["n1b"], spec.ln_epsilon)
-    q = (_mm(x, lp["qw"]) + lp["qb"]).reshape(s, spec.num_heads,
-                                              spec.head_dim)
-    kn = (_mm(x, lp["kw"]) + lp["kb"]).reshape(s, spec.num_heads,
-                                               spec.head_dim)
-    vn = (_mm(x, lp["vw"]) + lp["vb"]).reshape(s, spec.num_heads,
-                                               spec.head_dim)
-    kb, vb = append_token_kv(kb, vb, kn, vn, positions)
-    # int8 cache: dequantize in-register for the attention reads; the
-    # buffers themselves stay quantized
-    kd = dequantize_kv(kb, h.dtype)
-    vd = dequantize_kv(vb, h.dtype)
-    qh = (q * scale)[:, :, None, :]                       # [S, H, 1, D]
-    kt = jnp.transpose(kd, (0, 2, 1, 3))                  # [S, H, max, D]
-    vt = jnp.transpose(vd, (0, 2, 1, 3))
-    prod = jnp.matmul(qh, jnp.swapaxes(kt, -1, -2))       # [S, H, 1, max]
-    weights = jax.nn.softmax(prod + mask, axis=-1)
-    out = jnp.matmul(weights, vt)                         # [S, H, 1, D]
-    out = jnp.transpose(out, (0, 2, 1, 3)).reshape(s, spec.hidden_size)
-    h = h + (_mm(out, lp["ow"]) + lp["ob"])
-    x = _layer_norm(h, lp["n2w"], lp["n2b"], spec.ln_epsilon)
-    ffn = jax.nn.gelu(_mm(x, lp["w1"]) + lp["b1"], approximate=False)
-    return h + (_mm(ffn, lp["w2"]) + lp["b2"]), kb, vb
+def last_rows(h, lens):
+    """Row ``lens - 1`` of each right-padded sequence: ``[B, T, E]`` ->
+    ``[B, E]``."""
+    return jnp.take_along_axis(
+        h, (lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
 
 
-def _block_prefill(spec, lp, h, mask, scale):
-    """One pre-norm block over a whole [B, L, E] prompt; returns
-    (h, k, v) with K/V in cache layout [B, L, H, D]."""
-    b, l = h.shape[0], h.shape[1]
-    x = _layer_norm(h, lp["n1w"], lp["n1b"], spec.ln_epsilon)
-
-    def heads(t):                                         # [B, L, H, D]
-        return t.reshape(b, l, spec.num_heads, spec.head_dim)
-
-    q = heads(_mm(x, lp["qw"]) + lp["qb"])
-    k = heads(_mm(x, lp["kw"]) + lp["kb"])
-    v = heads(_mm(x, lp["vw"]) + lp["vb"])
-    qh = jnp.transpose(q * scale, (0, 2, 1, 3))           # [B, H, L, D]
-    kh = jnp.transpose(k, (0, 2, 1, 3))
-    vh = jnp.transpose(v, (0, 2, 1, 3))
-    prod = jnp.matmul(qh, jnp.swapaxes(kh, -1, -2))       # [B, H, L, L]
-    weights = jax.nn.softmax(prod + mask, axis=-1)
-    out = jnp.matmul(weights, vh)                         # [B, H, L, D]
-    out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, l, spec.hidden_size)
-    h = h + (_mm(out, lp["ow"]) + lp["ob"])
-    x = _layer_norm(h, lp["n2w"], lp["n2b"], spec.ln_epsilon)
-    ffn = jax.nn.gelu(_mm(x, lp["w1"]) + lp["b1"], approximate=False)
-    return h + (_mm(ffn, lp["w2"]) + lp["b2"]), k, v
+def sample_next(params, last, frozen, temperature, top_k, do_sample, eos,
+                key, max_top_k):
+    """The tail of every program: logits of the ``last`` hidden rows
+    ``[N, E]`` against the tied embedding, :func:`_sample`, and the eos
+    bookkeeping of the reference generate. ``frozen``: rows that had
+    finished before this call and keep emitting their eos (``False`` for
+    rows that were only just admitted). Returns ``(next tokens, finished)``
+    per row."""
+    lraw = (last @ params["tok"].T).astype(jnp.float32)           # [N, V]
+    nxt = _sample(lraw, temperature, top_k, do_sample, key, max_top_k)
+    nxt = jnp.where(frozen & (eos >= 0), eos, nxt)
+    return nxt, frozen | ((nxt == eos) & (eos >= 0))
 
 
 # -- the compiled programs ---------------------------------------------------
+
+def jit_program(raw, donate=()):
+    """``jax.jit`` of a raw program, donating the positional arguments
+    ``donate`` (the paged programs' arenas: with the in-place row writes
+    this is what lets XLA alias each arena to its output instead of
+    copying it). The attached ``trace_counter["traces"]`` counts
+    Python-body executions == XLA traces (the compile-counter tests assert
+    it stays flat after warmup); the compiled module keeps the raw
+    program's name (``jit__step``, ``jit__prefill``, ``jit__tail``), which
+    dumps and traces are read by."""
+    counter = {"traces": 0}
+
+    @functools.wraps(raw)
+    def _fn(*args):
+        counter["traces"] += 1
+        return raw(*args)
+
+    fn = jax.jit(_fn, donate_argnums=donate)
+    fn.trace_counter = counter
+    return fn
+
 
 def build_decode_step(spec: GPTDecodeSpec, max_top_k: int):
     """The RAW (un-jitted) decode step — the auditable program.
@@ -238,30 +156,15 @@ def build_decode_step(spec: GPTDecodeSpec, max_top_k: int):
     (tools/analyze/trace, PTA009/PTA010) can wrap the same function in its
     own counting jit without disturbing the production lru-cached wrapper.
     """
-    scale = 1.0 / np.sqrt(spec.head_dim)
-    max_pos = spec.max_position_embeddings
 
     def _step(params, kbuf, vbuf, lengths, finished, last_tokens,
               temperature, top_k, do_sample, eos, key):
-        max_seq = kv_max_seq(kbuf)
-        positions = lengths                       # write position per slot
-        posc = jnp.clip(positions, 0, max_pos - 1)
-        h = params["tok"][last_tokens] + params["pos"][posc]      # [S, E]
-        mask = valid_mask(positions, max_seq, h.dtype)
-        new_k, new_v = [], []
-        for li, lp in enumerate(params["layers"]):
-            h, kb, vb = _block_decode(spec, lp, h, kv_layer_view(kbuf, li),
-                                      kv_layer_view(vbuf, li),
-                                      positions, mask, scale)
-            new_k.append(kb)
-            new_v.append(vb)
-        kbuf = kv_stack_layers(new_k)
-        vbuf = kv_stack_layers(new_v)
-        h = _layer_norm(h, params["fnw"], params["fnb"], spec.ln_epsilon)
-        lraw = (h @ params["tok"].T).astype(jnp.float32)          # [S, V]
-        nxt = _sample(lraw, temperature, top_k, do_sample, key, max_top_k)
-        nxt = jnp.where(finished & (eos >= 0), eos, nxt)
-        finished = finished | ((nxt == eos) & (eos >= 0))
+        # a slot's write position is its length
+        view = SlotRows(kbuf, vbuf, lengths, params["tok"].dtype)
+        h = gpt_hidden(spec, params, last_tokens, lengths, view)  # [S, E]
+        nxt, finished = sample_next(params, h, finished, temperature,
+                                    top_k, do_sample, eos, key, max_top_k)
+        kbuf, vbuf = view.buffers()
         return kbuf, vbuf, lengths + 1, finished, nxt
 
     return _step
@@ -270,9 +173,7 @@ def build_decode_step(spec: GPTDecodeSpec, max_top_k: int):
 @functools.lru_cache(maxsize=64)
 def get_decode_step(spec: GPTDecodeSpec, max_top_k: int):
     """THE decode step: jitted once per (spec, max_top_k); each distinct
-    (num_slots, max_seq) shape pair traces exactly once (the attached
-    ``trace_counter["traces"]`` counts Python-body executions == XLA
-    traces — the compile-counter tests assert it stays flat after warmup).
+    (num_slots, max_seq) shape pair traces exactly once.
 
     step(params, kbuf, vbuf, lengths, finished, last_tokens,
          temperature, top_k, do_sample, eos, key)
@@ -283,47 +184,24 @@ def get_decode_step(spec: GPTDecodeSpec, max_top_k: int):
     program unique); per-slot eos semantics match the reference generate:
     finished rows keep emitting their eos token.
     """
-    counter = {"traces": 0}
-    raw = build_decode_step(spec, max_top_k)
-
-    def _step(*args):
-        counter["traces"] += 1
-        return raw(*args)
-
-    fn = jax.jit(_step)
-    fn.trace_counter = counter
-    return fn
+    return jit_program(build_decode_step(spec, max_top_k))
 
 
 def build_prefill_fn(spec: GPTDecodeSpec, max_top_k: int):
     """The RAW (un-jitted) prefill — see :func:`build_decode_step`."""
-    scale = 1.0 / np.sqrt(spec.head_dim)
 
     def _prefill(params, tokens, true_lens, kbuf, vbuf, lengths, finished,
                  slot_ids, temperature, top_k, do_sample, eos, key):
-        b, lp_len = tokens.shape
-        pos = jnp.arange(lp_len, dtype=jnp.int32)
-        h = params["tok"][tokens] + params["pos"][pos][None]   # [B, L, E]
-        # the same additive causal triu the dense path materialises
-        mask = jnp.triu(jnp.full((lp_len, lp_len), -1e9, h.dtype),
-                        1)[None, None]
-        kcs, vcs = [], []
-        for lp in params["layers"]:
-            h, k, v = _block_prefill(spec, lp, h, mask, scale)
-            kcs.append(k)
-            vcs.append(v)
-        kbuf, vbuf = write_prompt_kv(
-            kbuf, vbuf, jnp.stack(kcs, axis=1), jnp.stack(vcs, axis=1),
-            slot_ids)
+        view = FullSequence()
+        pos = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None]
+        h = gpt_hidden(spec, params, tokens, pos, view)        # [B, L, E]
+        kbuf, vbuf = write_prompt_kv(kbuf, vbuf, *stack_kv(view.kv, 1),
+                                     slot_ids)
         lengths = lengths.at[slot_ids].set(true_lens)
-        h = _layer_norm(h, params["fnw"], params["fnb"], spec.ln_epsilon)
-        last = jnp.take_along_axis(
-            h, (true_lens - 1)[:, None, None].astype(jnp.int32),
-            axis=1)[:, 0]                                      # [B, E]
-        lraw = (last @ params["tok"].T).astype(jnp.float32)
-        nxt = _sample(lraw, temperature, top_k, do_sample, key, max_top_k)
-        finished = finished.at[slot_ids].set((nxt == eos) & (eos >= 0))
-        return kbuf, vbuf, lengths, finished, nxt
+        nxt, fin = sample_next(params, last_rows(h, true_lens), False,
+                               temperature, top_k, do_sample, eos, key,
+                               max_top_k)
+        return kbuf, vbuf, lengths, finished.at[slot_ids].set(fin), nxt
 
     return _prefill
 
@@ -346,31 +224,22 @@ def get_prefill_fn(spec: GPTDecodeSpec, max_top_k: int):
     [true_len, Lp) is masked by the slot length until later tokens
     overwrite it.
     """
-    counter = {"traces": 0}
-    raw = build_prefill_fn(spec, max_top_k)
-
-    def _prefill(*args):
-        counter["traces"] += 1
-        return raw(*args)
-
-    fn = jax.jit(_prefill)
-    fn.trace_counter = counter
-    return fn
+    return jit_program(build_prefill_fn(spec, max_top_k))
 
 
 def build_tail_prefill_fn(spec: GPTDecodeSpec, max_top_k: int):
     """The RAW (un-jitted) tail prefill — prefill a prompt *suffix* into a
     slot whose first ``starts[i]`` rows were bulk-copied from the prefix
     store. Queries attend over the slot's FULL cache row (cached prefix +
-    freshly written tail) under an offset-causal mask, so the produced
-    hidden states — and therefore the first sampled token — are bitwise
-    what a full prefill of the whole prompt would produce: masked
-    positions contribute exactly-0.0 softmax weight (same -1e9 additive
-    mask as the dense path), and row-wise dot products contract in the
-    same order regardless of the extra zero-weight columns.
+    the fresh tail spliced in: :class:`~.kvcache.TailRows`) under an
+    offset-causal mask, so the produced hidden states — and therefore the
+    first sampled token — are bitwise what a full prefill of the whole
+    prompt would produce: masked positions contribute exactly-0.0 softmax
+    weight (same -1e9 additive mask as the dense path), and row-wise dot
+    products contract in the same order regardless of the extra
+    zero-weight columns. The buffers are written once, after the layer
+    loop, via ONE update per request.
     """
-    scale = 1.0 / np.sqrt(spec.head_dim)
-    max_pos = spec.max_position_embeddings
 
     def _tail(params, tokens, tail_lens, starts, kbuf, vbuf, lengths,
               finished, slot_ids, temperature, top_k, do_sample, eos, key):
@@ -381,65 +250,19 @@ def build_tail_prefill_fn(spec: GPTDecodeSpec, max_top_k: int):
                 "tail prefill (prefix reuse) over an int8 KV cache is "
                 "unsupported; LLMEngineConfig gates prefix_cache off for "
                 "kv_dtype='int8'")
-        b, lt = tokens.shape
-        max_seq = kbuf.shape[2]
-        pos = starts[:, None] + jnp.arange(lt, dtype=jnp.int32)[None]
-        posc = jnp.clip(pos, 0, max_pos - 1)
-        h = params["tok"][tokens] + params["pos"][posc]        # [B, Lt, E]
-        # offset-causal over the whole row: tail query i (absolute
-        # position starts+i) sees cache rows j <= starts+i — the reused
-        # prefix plus the tail K/V written below (its own row included)
-        j = jnp.arange(max_seq, dtype=jnp.int32)[None, None]
-        mask = jnp.where(j <= pos[:, :, None], 0.0,
-                         -1e9).astype(h.dtype)[:, None]        # [B,1,Lt,max]
-        kcs, vcs = [], []
-        for li, lp in enumerate(params["layers"]):
-            x = _layer_norm(h, lp["n1w"], lp["n1b"], spec.ln_epsilon)
-
-            def heads(t):
-                return t.reshape(b, lt, spec.num_heads, spec.head_dim)
-
-            q = heads(_mm(x, lp["qw"]) + lp["qb"])
-            kn = heads(_mm(x, lp["kw"]) + lp["kb"])
-            vn = heads(_mm(x, lp["vw"]) + lp["vb"])
-            # attention reads the gathered slot rows with the fresh tail
-            # K/V spliced in; the buffers themselves are written once,
-            # after the layer loop, via ONE update per request
-            row_k = kbuf[slot_ids, li]                         # [B,max,H,D]
-            row_v = vbuf[slot_ids, li]
-
-            def _splice(row, new, st):
-                return jax.lax.dynamic_update_slice(row, new, (st, 0, 0))
-
-            row_k = jax.vmap(_splice)(row_k, kn, starts)
-            row_v = jax.vmap(_splice)(row_v, vn, starts)
-            qh = jnp.transpose(q * scale, (0, 2, 1, 3))        # [B,H,Lt,D]
-            kt = jnp.transpose(row_k, (0, 2, 1, 3))            # [B,H,max,D]
-            vt = jnp.transpose(row_v, (0, 2, 1, 3))
-            prod = jnp.matmul(qh, jnp.swapaxes(kt, -1, -2))    # [B,H,Lt,max]
-            weights = jax.nn.softmax(prod + mask, axis=-1)
-            out = jnp.matmul(weights, vt)                      # [B,H,Lt,D]
-            out = jnp.transpose(out, (0, 2, 1, 3)).reshape(
-                b, lt, spec.hidden_size)
-            h = h + (_mm(out, lp["ow"]) + lp["ob"])
-            x = _layer_norm(h, lp["n2w"], lp["n2b"], spec.ln_epsilon)
-            ffn = jax.nn.gelu(_mm(x, lp["w1"]) + lp["b1"],
-                              approximate=False)
-            h = h + (_mm(ffn, lp["w2"]) + lp["b2"])
-            kcs.append(kn)
-            vcs.append(vn)
-        kbuf, vbuf = write_prompt_kv_at(
-            kbuf, vbuf, jnp.stack(kcs, axis=1), jnp.stack(vcs, axis=1),
-            slot_ids, starts)
+        pos = starts[:, None] + jnp.arange(tokens.shape[1],
+                                           dtype=jnp.int32)[None]
+        view = TailRows(lambda buf, li: buf[slot_ids, li], kbuf, vbuf,
+                        starts, valid_mask(pos, kbuf.shape[2],
+                                           params["tok"].dtype))
+        h = gpt_hidden(spec, params, tokens, pos, view)        # [B, Lt, E]
+        kbuf, vbuf = write_prompt_kv_at(kbuf, vbuf, *stack_kv(view.kv, 1),
+                                        slot_ids, starts)
         lengths = lengths.at[slot_ids].set(starts + tail_lens)
-        h = _layer_norm(h, params["fnw"], params["fnb"], spec.ln_epsilon)
-        last = jnp.take_along_axis(
-            h, (tail_lens - 1)[:, None, None].astype(jnp.int32),
-            axis=1)[:, 0]                                      # [B, E]
-        lraw = (last @ params["tok"].T).astype(jnp.float32)
-        nxt = _sample(lraw, temperature, top_k, do_sample, key, max_top_k)
-        finished = finished.at[slot_ids].set((nxt == eos) & (eos >= 0))
-        return kbuf, vbuf, lengths, finished, nxt
+        nxt, fin = sample_next(params, last_rows(h, tail_lens), False,
+                               temperature, top_k, do_sample, eos, key,
+                               max_top_k)
+        return kbuf, vbuf, lengths, finished.at[slot_ids].set(fin), nxt
 
     return _tail
 
@@ -455,16 +278,7 @@ def get_tail_prefill_fn(spec: GPTDecodeSpec, max_top_k: int):
                  top_k[B], do_sample[B], eos[B], key)
       -> (kbuf, vbuf, lengths, finished, next_tokens[B])
     """
-    counter = {"traces": 0}
-    raw = build_tail_prefill_fn(spec, max_top_k)
-
-    def _tail(*args):
-        counter["traces"] += 1
-        return raw(*args)
-
-    fn = jax.jit(_tail)
-    fn.trace_counter = counter
-    return fn
+    return jit_program(build_tail_prefill_fn(spec, max_top_k))
 
 
 def build_insert_prefix_fn():
@@ -485,16 +299,7 @@ def build_insert_prefix_fn():
 def get_insert_prefix_fn():
     """Jitted prefix bulk-copy; retraces only per distinct prefix-row
     count (block multiples — a small closed set)."""
-    counter = {"traces": 0}
-    raw = build_insert_prefix_fn()
-
-    def _insert(*args):
-        counter["traces"] += 1
-        return raw(*args)
-
-    fn = jax.jit(_insert)
-    fn.trace_counter = counter
-    return fn
+    return jit_program(build_insert_prefix_fn())
 
 
 def pack_sampling(params_list: Sequence[SamplingParams]):

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...models.gpt import masked_attention
+
 
 class SlotsExhausted(RuntimeError):
     """alloc() called with every slot in use (callers should gate on
@@ -264,55 +266,23 @@ class StaticKVCache:
 
 # -- functional update kernels (used inside jitted programs) ----------------
 
-def append_token_kv(kb, vb, k_new, v_new, positions):
-    """Write one new token's K/V for every slot at that slot's position
-    (one layer's buffers — decode updates layer *l*'s cache before layer
-    *l* attends, so the update is interleaved with the forward pass).
+def append_tokens_kv(kb, vb, k_new, v_new, positions):
+    """Write T new tokens' K/V for every slot starting at that slot's
+    position (one layer's buffers — a step updates layer *l*'s cache
+    before layer *l* attends, so the update is interleaved with the
+    forward pass). ``T = 1`` is the decode tick, the speculative verify
+    step lands its k+1 candidate rows with the same call.
 
-    ``kb``/``vb``: ``[S, max_seq, H, D]``; ``k_new``/``v_new``:
-    ``[S, H, D]`` (the current token's projections); ``positions``:
-    ``[S]`` int32. A vmapped ``lax.dynamic_update_slice`` over the slot
-    axis — per-slot starts are traced values, so XLA lowers this to one
-    scatter, keeping the decode step a single fused program.
+    ``kb``/``vb``: ``[S, max_seq, H, D]`` (or the int8 dict);
+    ``k_new``/``v_new``: ``[S, T, H, D]``; ``positions``: ``[S]`` int32.
+    A vmapped ``lax.dynamic_update_slice`` over the slot axis — per-slot
+    starts are traced values, so XLA lowers this to one scatter per leaf,
+    keeping the step a single fused program.
     """
     if is_quantized_kv(kb):
-        return (_append_token_kv_q(kb, k_new, positions),
-                _append_token_kv_q(vb, v_new, positions))
+        return (_append_tokens_kv_q(kb, k_new, positions),
+                _append_tokens_kv_q(vb, v_new, positions))
 
-    def _one(row_k, row_v, kn, vn, pos):
-        # row_*: [max_seq, H, D]; kn/vn: [H, D]
-        start = (pos, 0, 0)
-        return (jax.lax.dynamic_update_slice(row_k, kn[None], start),
-                jax.lax.dynamic_update_slice(row_v, vn[None], start))
-
-    return jax.vmap(_one)(kb, vb, k_new, v_new, positions)
-
-
-def _append_token_kv_q(buf, new, positions):
-    """int8 variant of the single-token writer: quantize the new rows
-    (one scale per slot) and land code + scale with the same vmapped
-    ``dynamic_update_slice`` shape — still one scatter per leaf."""
-    qs = quantize_kv_rows(new)                 # q [S, H, D], s [S]
-
-    def _one(row_q, row_s, qn, sn, pos):
-        # row_q: [max_seq, H, D] int8; row_s: [max_seq] f32
-        return (jax.lax.dynamic_update_slice(row_q, qn[None], (pos, 0, 0)),
-                jax.lax.dynamic_update_slice(row_s, sn[None], (pos,)))
-
-    q, s = jax.vmap(_one)(buf["q"], buf["s"], qs["q"], qs["s"], positions)
-    return {"q": q, "s": s}
-
-
-def append_tokens_kv(kb, vb, k_new, v_new, positions):
-    """Multi-token generalisation of :func:`append_token_kv`: write T new
-    tokens' K/V per slot starting at that slot's position (the speculative
-    verify step lands its k+1 candidate rows with this).
-
-    ``kb``/``vb``: ``[S, max_seq, H, D]``; ``k_new``/``v_new``:
-    ``[S, T, H, D]``; ``positions``: ``[S]`` int32. Same vmapped
-    ``lax.dynamic_update_slice`` shape as the single-token writer, so XLA
-    lowers it to one scatter per buffer.
-    """
     def _one(row_k, row_v, kn, vn, pos):
         # row_*: [max_seq, H, D]; kn/vn: [T, H, D]
         start = (pos, 0, 0)
@@ -320,6 +290,28 @@ def append_tokens_kv(kb, vb, k_new, v_new, positions):
                 jax.lax.dynamic_update_slice(row_v, vn, start))
 
     return jax.vmap(_one)(kb, vb, k_new, v_new, positions)
+
+
+def _append_tokens_kv_q(buf, new, positions):
+    """int8 variant: quantize the new rows (one scale per row) and land
+    code + scale with the same vmapped ``dynamic_update_slice`` shape —
+    still one scatter per leaf."""
+    qs = quantize_kv_rows(new)                 # q [S, T, H, D], s [S, T]
+
+    def _one(row_q, row_s, qn, sn, pos):
+        # row_q: [max_seq, H, D] int8; row_s: [max_seq] f32
+        return (jax.lax.dynamic_update_slice(row_q, qn, (pos, 0, 0)),
+                jax.lax.dynamic_update_slice(row_s, sn, (pos,)))
+
+    q, s = jax.vmap(_one)(buf["q"], buf["s"], qs["q"], qs["s"], positions)
+    return {"q": q, "s": s}
+
+
+def append_token_kv(kb, vb, k_new, v_new, positions):
+    """:func:`append_tokens_kv` for one token per slot: ``k_new``/``v_new``
+    ``[S, H, D]``."""
+    return append_tokens_kv(kb, vb, k_new[:, None], v_new[:, None],
+                            positions)
 
 
 def write_prompt_kv_at(k_buf, v_buf, k_new, v_new, slot_ids, starts):
@@ -376,12 +368,77 @@ def write_prompt_kv(k_buf, v_buf, k_prompt, v_prompt, slot_ids):
     return k_buf, v_buf
 
 
-def valid_mask(lengths, max_seq, dtype=jnp.float32):
-    """Additive attention mask ``[S, 1, 1, max_seq]``: 0 where the cache
-    row index is <= the slot's current position (the just-written token
+def per_slot(z, trailing: int):
+    """``[S, *rest]`` (one token per slot) or ``[S, T, *rest]`` as
+    ``[S, T, *rest]``, ``rest`` being the last ``trailing`` axes."""
+    return z.reshape((z.shape[0], -1) + z.shape[z.ndim - trailing:])
+
+
+def valid_mask(positions, max_seq, dtype=jnp.float32):
+    """Additive attention mask ``[S, 1, T, max_seq]`` for queries at
+    ``positions`` ``[S, T]`` (or ``[S]``: one query per slot): 0 where the
+    cache row index is <= the query's position (a just-written token
     attends to itself and the whole valid prefix), -1e9 beyond — the same
     finite -1e9 the dense path uses, so softmax zeros stale rows exactly
     (exp(-1e9) underflows to 0.0 in f32)."""
-    idx = jnp.arange(max_seq, dtype=jnp.int32)[None, :]        # [1, max_seq]
-    ok = idx <= lengths[:, None]                               # [S, max_seq]
-    return jnp.where(ok, 0.0, -1e9).astype(dtype)[:, None, None, :]
+    idx = jnp.arange(max_seq, dtype=jnp.int32)[None, None]     # [1,1,max]
+    ok = idx <= per_slot(positions, 0)[:, :, None]             # [S,T,max]
+    return jnp.where(ok, 0.0, -1e9).astype(dtype)[:, None]
+
+
+# -- cache views of models.gpt.gpt_block (used inside jitted programs) -------
+
+class SlotRows:
+    """The view of new tokens per slot over the slot buffers: layer ``li``
+    writes its rows at ``positions`` and attends over the slot's whole row
+    under the validity mask; int8 rows are dequantised here, the buffers
+    stay quantised. ``positions`` ``[S]`` with ``q``/``k``/``v``
+    ``[S, H, D]`` is the decode tick; ``[S, T]`` (consecutive from column
+    0) with ``[S, T, H, D]`` the speculative verify step's ``k + 1``
+    candidate rows. :meth:`buffers` hands the written buffers back when
+    the layers are done."""
+
+    def __init__(self, kbuf, vbuf, positions, dtype):
+        self.kbuf, self.vbuf = kbuf, vbuf
+        self.starts = per_slot(positions, 0)[:, 0]
+        self.mask = valid_mask(positions, kv_max_seq(kbuf), dtype)
+        self._k, self._v = [], []
+
+    def attend(self, li, q, k, v, scale):
+        kb, vb = append_tokens_kv(kv_layer_view(self.kbuf, li),
+                                  kv_layer_view(self.vbuf, li),
+                                  per_slot(k, 2), per_slot(v, 2),
+                                  self.starts)
+        self._k.append(kb)
+        self._v.append(vb)
+        return masked_attention(q, dequantize_kv(kb, q.dtype),
+                                dequantize_kv(vb, q.dtype), self.mask, scale)
+
+    def buffers(self):
+        return kv_stack_layers(self._k), kv_stack_layers(self._v)
+
+
+class TailRows:
+    """The view of prompt tails behind cached prefixes: request ``i``'s
+    queries attend over its slot's whole logical row with the fresh tail
+    K/V spliced in at ``starts[i]``, under ``mask`` (offset-causal over
+    the row). ``rows(buf, li)`` reads layer ``li``'s logical rows
+    ``[B, max_seq, H, D]`` of the requests' slots: a slice of the slot
+    buffers, or a gather through block tables. The buffers are not written
+    here: ``kv`` records each layer's tail ``(k, v)`` and the program
+    writes them once, after the layer loop. Dense rows only."""
+
+    def __init__(self, rows, kbuf, vbuf, starts, mask):
+        self.rows, self.kbuf, self.vbuf = rows, kbuf, vbuf
+        self.starts, self.mask = starts, mask
+        self.kv = []
+
+    def attend(self, li, q, k, v, scale):
+        self.kv.append((k, v))
+
+        def _splice(row, new, st):
+            return jax.lax.dynamic_update_slice(row, new, (st, 0, 0))
+
+        row_k = jax.vmap(_splice)(self.rows(self.kbuf, li), k, self.starts)
+        row_v = jax.vmap(_splice)(self.rows(self.vbuf, li), v, self.starts)
+        return masked_attention(q, row_k, row_v, self.mask, scale)

@@ -44,86 +44,24 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ....models.gpt import FullSequence, gpt_hidden, stack_kv
 from ..decode import (GPTDecodeSpec, GPTStaticDecoder, _AUDIT_SPEC,
-                      _AUDIT_TOP_K, _audit_params, _block_prefill,
-                      _layer_norm, _mm, _sample)
-from ..kvcache import dequantize_kv, is_quantized_kv, valid_mask
-from .pool import (PagedKVCache, paged_gather_rows,
-                   paged_write_prompt_rows, paged_write_rows,
-                   pages_for_tokens)
-
-
-def _write_page_index(block_tables, positions, page_size):
-    """(physical page, in-page offset) of each slot's write position.
-    Out-of-range positions (inactive slots whose lengths keep advancing)
-    clip to the last table entry, which for a freed slot is the trash
-    page — the paged analogue of the slot path's clamped
-    ``dynamic_update_slice`` on inactive rows."""
-    idx = jnp.clip(positions // page_size, 0,
-                   block_tables.shape[1] - 1)
-    pid = jnp.take_along_axis(block_tables, idx[:, None], axis=1)[:, 0]
-    return pid, positions % page_size
-
-
-def _paged_block_decode(spec, lp, h, kbuf, vbuf, li, block_tables, pid,
-                        ppos, positions, mask, scale, attn_impl):
-    """One pre-norm block for a single new token per slot — the paged
-    twin of ``decode._block_decode``. ``kbuf``/``vbuf``: the whole
-    ``[P+1, L, page, H, D]`` arenas; the token's K/V is scattered at
-    (``pid``, ``li``, ``ppos``) before layer ``li`` attends."""
-    s = h.shape[0]
-    x = _layer_norm(h, lp["n1w"], lp["n1b"], spec.ln_epsilon)
-    q = (_mm(x, lp["qw"]) + lp["qb"]).reshape(s, spec.num_heads,
-                                              spec.head_dim)
-    kn = (_mm(x, lp["kw"]) + lp["kb"]).reshape(s, spec.num_heads,
-                                               spec.head_dim)
-    vn = (_mm(x, lp["vw"]) + lp["vb"]).reshape(s, spec.num_heads,
-                                               spec.head_dim)
-    kbuf = paged_write_rows(kbuf, kn, pid, ppos, li)
-    vbuf = paged_write_rows(vbuf, vn, pid, ppos, li)
-    if attn_impl == "kernel":
-        from ....ops.paged_attention import paged_attention
-        out = paged_attention(q, kbuf, vbuf, block_tables, positions,
-                              layer=li,
-                              scale=scale).reshape(s, spec.hidden_size)
-    else:
-        kd = dequantize_kv(paged_gather_rows(kbuf, block_tables, li),
-                           h.dtype)
-        vd = dequantize_kv(paged_gather_rows(vbuf, block_tables, li),
-                           h.dtype)
-        qh = (q * scale)[:, :, None, :]                   # [S, H, 1, D]
-        kt = jnp.transpose(kd, (0, 2, 1, 3))              # [S, H, max, D]
-        vt = jnp.transpose(vd, (0, 2, 1, 3))
-        prod = jnp.matmul(qh, jnp.swapaxes(kt, -1, -2))   # [S, H, 1, max]
-        weights = jax.nn.softmax(prod + mask, axis=-1)
-        out = jnp.matmul(weights, vt)                     # [S, H, 1, D]
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(s,
-                                                       spec.hidden_size)
-    h = h + (_mm(out, lp["ow"]) + lp["ob"])
-    x = _layer_norm(h, lp["n2w"], lp["n2b"], spec.ln_epsilon)
-    ffn = jax.nn.gelu(_mm(x, lp["w1"]) + lp["b1"], approximate=False)
-    return h + (_mm(ffn, lp["w2"]) + lp["b2"]), kbuf, vbuf
+                      _AUDIT_TOP_K, _audit_params, jit_program, last_rows,
+                      sample_next)
+from ..kvcache import TailRows, is_quantized_kv, valid_mask
+from .pool import (PagedKVCache, PagedRows, paged_gather_rows,
+                   paged_write_prompts, pages_for_tokens)
 
 
 # -- the compiled programs ---------------------------------------------------
 
-def jit_donating_arenas(raw, arenas):
-    """``jax.jit`` of a raw paged program, donating the positional
-    arguments ``arenas`` (its ``kbuf`` and ``vbuf``): with the in-place
-    row writes this is what lets XLA alias each arena to its output
-    instead of copying it. Carries the getters' ``trace_counter``; the
-    compiled module keeps the raw program's name (``jit__step``,
-    ``jit__prefill``, ``jit__tail``), which dumps and traces are read by."""
-    counter = {"traces": 0}
-
-    @functools.wraps(raw)
-    def _fn(*args):
-        counter["traces"] += 1
-        return raw(*args)
-
-    fn = jax.jit(_fn, donate_argnums=arenas)
-    fn.trace_counter = counter
-    return fn
+def _token_major(kv):
+    """A view's per-layer ``(k, v)`` records as ``(K, V)``
+    ``[B, T, L, H, D]``, the rows :func:`~.pool.paged_write_prompts`
+    scatters. Stacked by layer and then swapped, not stacked on axis 2:
+    the compiled prefill then keeps each layer's rows as one block, as the
+    slot plane's ``[B, L, T, H, D]`` does."""
+    return tuple(jnp.swapaxes(z, 1, 2) for z in stack_kv(kv, 1))
 
 
 def build_paged_decode_step(spec: GPTDecodeSpec, max_top_k: int,
@@ -137,35 +75,23 @@ def build_paged_decode_step(spec: GPTDecodeSpec, max_top_k: int,
 
     The block table is read-only inside the step (page mapping is host
     policy, applied between ticks). The raw step is a pure function;
-    the arenas run through the layer loop as one value each, written by
-    scatter only, so the jitted, donating step
-    (``get_paged_decode_step``) updates them in place.
+    the arenas run through the layer loop as one value each
+    (:class:`~.pool.PagedRows`), written by scatter only, so the jitted,
+    donating step (``get_paged_decode_step``) updates them in place.
     """
     if attn_impl not in ("gather", "kernel"):
         raise ValueError(f"attn_impl must be 'gather' or 'kernel', got "
                          f"{attn_impl!r}")
-    scale = 1.0 / np.sqrt(spec.head_dim)
-    max_pos = spec.max_position_embeddings
 
     def _step(params, kbuf, vbuf, block_tables, lengths, finished,
               last_tokens, temperature, top_k, do_sample, eos, key):
-        max_seq = block_tables.shape[1] * page_size
-        positions = lengths                   # write position per slot
-        posc = jnp.clip(positions, 0, max_pos - 1)
-        h = params["tok"][last_tokens] + params["pos"][posc]      # [S, E]
-        mask = (valid_mask(positions, max_seq, h.dtype)
-                if attn_impl == "gather" else None)
-        pid, ppos = _write_page_index(block_tables, positions, page_size)
-        for li, lp in enumerate(params["layers"]):
-            h, kbuf, vbuf = _paged_block_decode(
-                spec, lp, h, kbuf, vbuf, li, block_tables, pid, ppos,
-                positions, mask, scale, attn_impl)
-        h = _layer_norm(h, params["fnw"], params["fnb"], spec.ln_epsilon)
-        lraw = (h @ params["tok"].T).astype(jnp.float32)          # [S, V]
-        nxt = _sample(lraw, temperature, top_k, do_sample, key, max_top_k)
-        nxt = jnp.where(finished & (eos >= 0), eos, nxt)
-        finished = finished | ((nxt == eos) & (eos >= 0))
-        return kbuf, vbuf, lengths + 1, finished, nxt
+        # a slot's write position is its length
+        view = PagedRows(kbuf, vbuf, block_tables, lengths, page_size,
+                         attn_impl, params["tok"].dtype)
+        h = gpt_hidden(spec, params, last_tokens, lengths, view)  # [S, E]
+        nxt, finished = sample_next(params, h, finished, temperature,
+                                    top_k, do_sample, eos, key, max_top_k)
+        return view.kbuf, view.vbuf, lengths + 1, finished, nxt
 
     return _step
 
@@ -176,9 +102,9 @@ def get_paged_decode_step(spec: GPTDecodeSpec, max_top_k: int,
     """Jitted paged decode step; ``trace_counter`` contract matches
     ``get_decode_step`` (one trace per (num_pages, num_slots) shape).
     Donates ``kbuf`` and ``vbuf``."""
-    return jit_donating_arenas(
+    return jit_program(
         build_paged_decode_step(spec, max_top_k, page_size, attn_impl),
-        arenas=(1, 2))
+        donate=(1, 2))
 
 
 def build_paged_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
@@ -188,42 +114,24 @@ def build_paged_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
     the K/V rows scatter through each request's block-table row, with
     right-padding junk routed to the trash page instead of parked past
     the slot length."""
-    scale = 1.0 / np.sqrt(spec.head_dim)
 
     def _prefill(params, tokens, true_lens, kbuf, vbuf, block_tables,
                  lengths, finished, slot_ids, temperature, top_k,
                  do_sample, eos, key):
-        b, lp_len = tokens.shape
-        trash = jax.tree_util.tree_leaves(kbuf)[0].shape[0] - 1
-        pos = jnp.arange(lp_len, dtype=jnp.int32)
-        h = params["tok"][tokens] + params["pos"][pos][None]   # [B, L, E]
-        mask = jnp.triu(jnp.full((lp_len, lp_len), -1e9, h.dtype),
-                        1)[None, None]
-        kcs, vcs = [], []
-        for lp in params["layers"]:
-            h, k, v = _block_prefill(spec, lp, h, mask, scale)
-            kcs.append(k)
-            vcs.append(v)
-        k_new = jnp.stack(kcs, axis=1)                 # [B, L, Lp, H, D]
-        v_new = jnp.stack(vcs, axis=1)
-        ppos = pos % page_size
-        page_idx = pos // page_size                    # < PP: buckets
-        for i in range(b):                             # fit in max_seq
-            bt_row = block_tables[slot_ids[i]]         # [PP]
-            pid = jnp.where(pos < true_lens[i], bt_row[page_idx], trash)
-            kbuf = paged_write_prompt_rows(
-                kbuf, jnp.transpose(k_new[i], (1, 0, 2, 3)), pid, ppos)
-            vbuf = paged_write_prompt_rows(
-                vbuf, jnp.transpose(v_new[i], (1, 0, 2, 3)), pid, ppos)
+        view = FullSequence()
+        pos = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None]
+        h = gpt_hidden(spec, params, tokens, pos, view)        # [B, L, E]
+        k_new, v_new = _token_major(view.kv)           # [B, Lp, L, H, D]
+        starts = jnp.zeros_like(true_lens)
+        kbuf = paged_write_prompts(kbuf, k_new, block_tables, slot_ids,
+                                   starts, true_lens, page_size)
+        vbuf = paged_write_prompts(vbuf, v_new, block_tables, slot_ids,
+                                   starts, true_lens, page_size)
         lengths = lengths.at[slot_ids].set(true_lens)
-        h = _layer_norm(h, params["fnw"], params["fnb"], spec.ln_epsilon)
-        last = jnp.take_along_axis(
-            h, (true_lens - 1)[:, None, None].astype(jnp.int32),
-            axis=1)[:, 0]                                      # [B, E]
-        lraw = (last @ params["tok"].T).astype(jnp.float32)
-        nxt = _sample(lraw, temperature, top_k, do_sample, key, max_top_k)
-        finished = finished.at[slot_ids].set((nxt == eos) & (eos >= 0))
-        return kbuf, vbuf, lengths, finished, nxt
+        nxt, fin = sample_next(params, last_rows(h, true_lens), False,
+                               temperature, top_k, do_sample, eos, key,
+                               max_top_k)
+        return kbuf, vbuf, lengths, finished.at[slot_ids].set(fin), nxt
 
     return _prefill
 
@@ -231,8 +139,8 @@ def build_paged_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
 @functools.lru_cache(maxsize=64)
 def get_paged_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
                          page_size: int):
-    return jit_donating_arenas(
-        build_paged_prefill_fn(spec, max_top_k, page_size), arenas=(3, 4))
+    return jit_program(
+        build_paged_prefill_fn(spec, max_top_k, page_size), donate=(3, 4))
 
 
 def build_paged_tail_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
@@ -243,9 +151,8 @@ def build_paged_tail_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
     which bulk-copied them first). Attention gathers the slot's full
     logical row (shared pages + the fresh tail spliced in) under the
     same offset-causal mask, so the first sampled token is bitwise what
-    a full prefill would produce."""
-    scale = 1.0 / np.sqrt(spec.head_dim)
-    max_pos = spec.max_position_embeddings
+    a full prefill would produce. The arenas are written once, after the
+    layer loop."""
 
     def _tail(params, tokens, tail_lens, starts, kbuf, vbuf,
               block_tables, lengths, finished, slot_ids, temperature,
@@ -255,75 +162,24 @@ def build_paged_tail_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
                 "tail prefill (prefix reuse) over int8 pages is "
                 "unsupported; LLMEngineConfig gates prefix_cache off "
                 "for kv_dtype='int8'")
-        b, lt = tokens.shape
-        pp_n = block_tables.shape[1]
-        max_seq = pp_n * page_size
-        trash = kbuf.shape[0] - 1
-        pos = starts[:, None] + jnp.arange(lt, dtype=jnp.int32)[None]
-        posc = jnp.clip(pos, 0, max_pos - 1)
-        h = params["tok"][tokens] + params["pos"][posc]    # [B, Lt, E]
-        j = jnp.arange(max_seq, dtype=jnp.int32)[None, None]
-        mask = jnp.where(j <= pos[:, :, None], 0.0,
-                         -1e9).astype(h.dtype)[:, None]    # [B,1,Lt,max]
+        max_seq = block_tables.shape[1] * page_size
+        pos = starts[:, None] + jnp.arange(tokens.shape[1],
+                                           dtype=jnp.int32)[None]
         bt_sel = block_tables[slot_ids]                    # [B, PP]
-        kcs, vcs = [], []
-        for li, lp in enumerate(params["layers"]):
-            x = _layer_norm(h, lp["n1w"], lp["n1b"], spec.ln_epsilon)
-
-            def heads(t):
-                return t.reshape(b, lt, spec.num_heads, spec.head_dim)
-
-            q = heads(_mm(x, lp["qw"]) + lp["qb"])
-            kn = heads(_mm(x, lp["kw"]) + lp["kb"])
-            vn = heads(_mm(x, lp["vw"]) + lp["vb"])
-            # attention reads the gathered logical rows with the fresh
-            # tail spliced in; the arenas are written once, after the
-            # layer loop
-            row_k = paged_gather_rows(kbuf, bt_sel, li)
-            row_v = paged_gather_rows(vbuf, bt_sel, li)
-
-            def _splice(row, new, st):
-                return jax.lax.dynamic_update_slice(row, new, (st, 0, 0))
-
-            row_k = jax.vmap(_splice)(row_k, kn, starts)
-            row_v = jax.vmap(_splice)(row_v, vn, starts)
-            qh = jnp.transpose(q * scale, (0, 2, 1, 3))    # [B,H,Lt,D]
-            kt = jnp.transpose(row_k, (0, 2, 1, 3))        # [B,H,max,D]
-            vt = jnp.transpose(row_v, (0, 2, 1, 3))
-            prod = jnp.matmul(qh, jnp.swapaxes(kt, -1, -2))
-            weights = jax.nn.softmax(prod + mask, axis=-1)
-            out = jnp.matmul(weights, vt)                  # [B,H,Lt,D]
-            out = jnp.transpose(out, (0, 2, 1, 3)).reshape(
-                b, lt, spec.hidden_size)
-            h = h + (_mm(out, lp["ow"]) + lp["ob"])
-            x = _layer_norm(h, lp["n2w"], lp["n2b"], spec.ln_epsilon)
-            ffn = jax.nn.gelu(_mm(x, lp["w1"]) + lp["b1"],
-                              approximate=False)
-            h = h + (_mm(ffn, lp["w2"]) + lp["b2"])
-            kcs.append(kn)
-            vcs.append(vn)
-        k_new = jnp.stack(kcs, axis=1)                 # [B, L, Lt, H, D]
-        v_new = jnp.stack(vcs, axis=1)
-        t = jnp.arange(lt, dtype=jnp.int32)
-        for i in range(b):
-            pos_i = starts[i] + t
-            page_idx = jnp.clip(pos_i // page_size, 0, pp_n - 1)
-            pid = jnp.where(t < tail_lens[i], bt_sel[i][page_idx], trash)
-            kbuf = paged_write_prompt_rows(
-                kbuf, jnp.transpose(k_new[i], (1, 0, 2, 3)), pid,
-                pos_i % page_size)
-            vbuf = paged_write_prompt_rows(
-                vbuf, jnp.transpose(v_new[i], (1, 0, 2, 3)), pid,
-                pos_i % page_size)
+        view = TailRows(
+            lambda buf, li: paged_gather_rows(buf, bt_sel, li), kbuf, vbuf,
+            starts, valid_mask(pos, max_seq, params["tok"].dtype))
+        h = gpt_hidden(spec, params, tokens, pos, view)    # [B, Lt, E]
+        k_new, v_new = _token_major(view.kv)           # [B, Lt, L, H, D]
+        kbuf = paged_write_prompts(kbuf, k_new, block_tables, slot_ids,
+                                   starts, tail_lens, page_size)
+        vbuf = paged_write_prompts(vbuf, v_new, block_tables, slot_ids,
+                                   starts, tail_lens, page_size)
         lengths = lengths.at[slot_ids].set(starts + tail_lens)
-        h = _layer_norm(h, params["fnw"], params["fnb"], spec.ln_epsilon)
-        last = jnp.take_along_axis(
-            h, (tail_lens - 1)[:, None, None].astype(jnp.int32),
-            axis=1)[:, 0]                                      # [B, E]
-        lraw = (last @ params["tok"].T).astype(jnp.float32)
-        nxt = _sample(lraw, temperature, top_k, do_sample, key, max_top_k)
-        finished = finished.at[slot_ids].set((nxt == eos) & (eos >= 0))
-        return kbuf, vbuf, lengths, finished, nxt
+        nxt, fin = sample_next(params, last_rows(h, tail_lens), False,
+                               temperature, top_k, do_sample, eos, key,
+                               max_top_k)
+        return kbuf, vbuf, lengths, finished.at[slot_ids].set(fin), nxt
 
     return _tail
 
@@ -331,9 +187,9 @@ def build_paged_tail_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
 @functools.lru_cache(maxsize=64)
 def get_paged_tail_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
                               page_size: int):
-    return jit_donating_arenas(
+    return jit_program(
         build_paged_tail_prefill_fn(spec, max_top_k, page_size),
-        arenas=(4, 5))
+        donate=(4, 5))
 
 
 #: (model class, decoder class) of the families served on pages beside GPT

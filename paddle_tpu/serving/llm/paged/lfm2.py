@@ -40,11 +40,10 @@ from ....ops.paged_attention import paged_attention
 from ....models.lfm2 import (FullSequence, LFM2Config, LFM2ForCausalLM,
                              lfm2_hidden)
 from ...cache import default_cache
-from ..decode import _sample
-from .decode import (_write_page_index, jit_donating_arenas,
-                     register_paged_decoder)
-from .pool import (PagedKVCache, paged_gather_rows,
-                   paged_write_prompt_rows, paged_write_rows)
+from ..decode import jit_program, last_rows, sample_next
+from .decode import register_paged_decoder
+from .pool import (PagedKVCache, paged_gather_rows, paged_row_index,
+                   paged_write_prompts, paged_write_rows)
 
 
 class PagedStep:
@@ -58,8 +57,8 @@ class PagedStep:
         self.kvbuf, self.state = kvbuf, state
         self.block_tables, self.positions = block_tables, positions
         self.attn_impl = attn_impl
-        self.pid, self.ppos = _write_page_index(block_tables, positions,
-                                                page_size)
+        self.pid, self.ppos = paged_row_index(block_tables, positions,
+                                              page_size)
         max_seq = block_tables.shape[1] * page_size
         self.mask = None if attn_impl == "kernel" else jnp.where(
             jnp.arange(max_seq)[None] <= positions[:, None], 0.0, -1e9)
@@ -109,14 +108,12 @@ def build_lfm2_paged_decode_step(cfg: LFM2Config, max_top_k: int,
                          page_size, attn_impl)
         h, counts = lfm2_hidden(cfg, params, last_tokens[:, None],
                                 lengths[:, None], view)
-        lraw = (h[:, 0] @ params["tok"].T).astype(jnp.float32)     # [S, V]
-        nxt = _sample(lraw, temperature, top_k, do_sample, key, max_top_k)
-        nxt = jnp.where(finished & (eos >= 0), eos, nxt)
+        nxt, finished = sample_next(params, h[:, 0], finished, temperature,
+                                    top_k, do_sample, eos, key, max_top_k)
         counts = jnp.stack(counts) if counts else jnp.zeros((1, 1), jnp.int32)
         fetch = jnp.concatenate([nxt, jnp.stack(
             [jnp.sum(counts > 0), jnp.max(counts)]).astype(jnp.int32)])
-        return (view.kvbuf, view.state, lengths + 1,
-                finished | ((nxt == eos) & (eos >= 0)), nxt, fetch)
+        return view.kvbuf, view.state, lengths + 1, finished, nxt, fetch
 
     return _step
 
@@ -124,9 +121,9 @@ def build_lfm2_paged_decode_step(cfg: LFM2Config, max_top_k: int,
 @functools.lru_cache(maxsize=64)
 def get_lfm2_paged_decode_step(cfg: LFM2Config, max_top_k: int,
                                page_size: int, attn_impl: str):
-    return jit_donating_arenas(
+    return jit_program(
         build_lfm2_paged_decode_step(cfg, max_top_k, page_size, attn_impl),
-        arenas=(1, 2))
+        donate=(1, 2))
 
 
 def build_lfm2_paged_prefill_fn(cfg: LFM2Config, max_top_k: int,
@@ -139,27 +136,20 @@ def build_lfm2_paged_prefill_fn(cfg: LFM2Config, max_top_k: int,
     def _prefill(params, tokens, true_lens, kvbuf, state, block_tables,
                  lengths, finished, slot_ids, temperature, top_k,
                  do_sample, eos, key):
-        b, lp_len = tokens.shape
-        trash = kvbuf.shape[0] - 1
-        pos = jnp.arange(lp_len, dtype=jnp.int32)
+        pos = jnp.arange(tokens.shape[1], dtype=jnp.int32)
         view = FullSequence(true_lens)
         h, _ = lfm2_hidden(cfg, params, tokens, pos[None], view)
         kv_new = jnp.stack([jnp.concatenate(kv, axis=-1) for kv in view.kv],
                            axis=2)                      # [B, Lp, La, H, 2D]
-        for i in range(b):
-            bt_row = block_tables[slot_ids[i]]
-            pid = jnp.where(pos < true_lens[i], bt_row[pos // page_size],
-                            trash)
-            kvbuf = paged_write_prompt_rows(kvbuf, kv_new[i], pid,
-                                            pos % page_size)
+        kvbuf = paged_write_prompts(kvbuf, kv_new, block_tables, slot_ids,
+                                    jnp.zeros_like(true_lens), true_lens,
+                                    page_size)
         state = state.at[slot_ids].set(jnp.stack(view.conv_tails, axis=1))
         lengths = lengths.at[slot_ids].set(true_lens)
-        last = jnp.take_along_axis(
-            h, (true_lens - 1)[:, None, None].astype(jnp.int32),
-            axis=1)[:, 0]                                      # [B, hidden]
-        lraw = (last @ params["tok"].T).astype(jnp.float32)
-        nxt = _sample(lraw, temperature, top_k, do_sample, key, max_top_k)
-        finished = finished.at[slot_ids].set((nxt == eos) & (eos >= 0))
+        nxt, fin = sample_next(params, last_rows(h, true_lens), False,
+                               temperature, top_k, do_sample, eos, key,
+                               max_top_k)
+        finished = finished.at[slot_ids].set(fin)
         return kvbuf, state, lengths, finished, nxt
 
     return _prefill
@@ -168,9 +158,9 @@ def build_lfm2_paged_prefill_fn(cfg: LFM2Config, max_top_k: int,
 @functools.lru_cache(maxsize=64)
 def get_lfm2_paged_prefill_fn(cfg: LFM2Config, max_top_k: int,
                               page_size: int):
-    return jit_donating_arenas(
+    return jit_program(
         build_lfm2_paged_prefill_fn(cfg, max_top_k, page_size),
-        arenas=(3, 4))
+        donate=(3, 4))
 
 
 class LFM2PagedDecoder:

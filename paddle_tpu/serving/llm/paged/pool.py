@@ -52,9 +52,10 @@ whole arena in and out. Such a cache keeps ONE arena whose rows hold a
 head's key and value side by side, ``[P+1, L, page, H, 2 * D]`` in ``k``
 (``v`` is an empty placeholder): the same bytes a page, a 128-wide minor
 axis, and one row read serves both. The decoder family asks for it, the
-cache does not choose it from ``head_dim``: the GPT programs read ``kbuf``
-and ``vbuf`` in each of their seven bodies, so a GPT with head size 64
-still keeps two arenas (and pays that copy) until those bodies are one.
+cache does not choose it from ``head_dim``: the GPT views below
+(:class:`PagedRows`, the tail prefill's gather), the page copy and the export read
+``kbuf`` and ``vbuf`` as two arenas, so a GPT with head size 64 still keeps
+two (and pays that copy) until they read fused rows.
 """
 from __future__ import annotations
 
@@ -66,8 +67,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kvcache import (SlotsExhausted, is_quantized_kv, kv_nbytes,
-                       quantize_kv_rows)
+from ....models.gpt import masked_attention
+from ....ops.paged_attention import paged_attention
+from ..kvcache import (SlotsExhausted, dequantize_kv, is_quantized_kv,
+                       kv_nbytes, per_slot, quantize_kv_rows, valid_mask)
 
 
 class PagesExhausted(RuntimeError):
@@ -262,6 +265,80 @@ def paged_gather_rows(buf, block_tables, layer):
     g = buf[block_tables, layer]
     sh = g.shape
     return g.reshape(sh[0], sh[1] * sh[2], sh[3], sh[4])
+
+
+def paged_row_index(block_tables, positions, page_size):
+    """(physical page, in-page offset) of logical rows ``positions``
+    (``[S]`` or ``[S, T]``) of each slot, both flattened to ``[S * T]``.
+    Out-of-range positions (inactive slots whose lengths keep advancing)
+    clip to the last table entry, which for a freed slot is the trash
+    page — the paged analogue of the slot path's clamped
+    ``dynamic_update_slice`` on inactive rows."""
+    positions = per_slot(positions, 0)
+    idx = jnp.clip(positions // page_size, 0, block_tables.shape[1] - 1)
+    pid = jnp.take_along_axis(block_tables, idx, axis=1)
+    return pid.reshape(-1), (positions % page_size).reshape(-1)
+
+
+def paged_write_prompts(buf, rows, block_tables, slot_ids, starts, lens,
+                        page_size):
+    """Scatter whole prompts (or prompt tails) into a whole arena, one
+    :func:`paged_write_prompt_rows` per request: request ``i``'s row ``t``
+    of ``rows`` ``[B, T, L, H, D]`` lands at logical position
+    ``starts[i] + t`` of slot ``slot_ids[i]``; right-padding (``t >=
+    lens[i]``) goes to the trash page instead of being parked past the
+    slot length."""
+    trash = jax.tree_util.tree_leaves(buf)[0].shape[0] - 1
+    last_page = block_tables.shape[1] - 1
+    t = jnp.arange(rows.shape[1], dtype=jnp.int32)
+    for i in range(rows.shape[0]):
+        pos = starts[i] + t
+        bt_row = block_tables[slot_ids[i]]                     # [PP]
+        pid = jnp.where(
+            t < lens[i],
+            bt_row[jnp.clip(pos // page_size, 0, last_page)], trash)
+        buf = paged_write_prompt_rows(buf, rows[i], pid, pos % page_size)
+    return buf
+
+
+# -- cache views of models.gpt.gpt_block (used inside jitted programs) -------
+
+class PagedRows:
+    """The paged twin of :class:`~..kvcache.SlotRows` (same ``positions``
+    and row shapes): layer ``li`` scatters its rows through the block
+    tables into the whole arenas, where they lie, then attends.
+    ``attn_impl="gather"`` gathers the slots' logical rows back (int8
+    pages dequantised here) and runs the slot path's exact attention under
+    the validity mask; ``"kernel"`` (the decode tick, dense arenas) hands
+    the arenas to the Pallas ``paged_attention``, which walks the block
+    table itself. Holds the (traced) arenas and replaces them as layers
+    write: read ``kbuf`` and ``vbuf`` back when the layers are done."""
+
+    def __init__(self, kbuf, vbuf, block_tables, positions, page_size,
+                 attn_impl, dtype):
+        self.kbuf, self.vbuf = kbuf, vbuf
+        self.block_tables, self.positions = block_tables, positions
+        self.attn_impl = attn_impl
+        self.pid, self.ppos = paged_row_index(block_tables, positions,
+                                              page_size)
+        self.mask = None if attn_impl == "kernel" else valid_mask(
+            positions, block_tables.shape[1] * page_size, dtype)
+
+    def attend(self, li, q, k, v, scale):
+        flat = (-1,) + k.shape[-2:]                            # [S*T, H, D]
+        self.kbuf = paged_write_rows(self.kbuf, k.reshape(flat), self.pid,
+                                     self.ppos, li)
+        self.vbuf = paged_write_rows(self.vbuf, v.reshape(flat), self.pid,
+                                     self.ppos, li)
+        if self.attn_impl == "kernel":
+            return paged_attention(q, self.kbuf, self.vbuf,
+                                   self.block_tables, self.positions,
+                                   layer=li, scale=scale)
+        kd = dequantize_kv(
+            paged_gather_rows(self.kbuf, self.block_tables, li), q.dtype)
+        vd = dequantize_kv(
+            paged_gather_rows(self.vbuf, self.block_tables, li), q.dtype)
+        return masked_attention(q, kd, vd, self.mask, scale)
 
 
 def pages_for_tokens(n_tokens: int, page_size: int) -> int:

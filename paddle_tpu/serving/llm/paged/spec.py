@@ -21,53 +21,12 @@ from __future__ import annotations
 
 import functools
 
-import numpy as np
-
-import jax
-import jax.numpy as jnp
-
-from ..decode import _block_decode, _layer_norm, _sample
-from ..kvcache import valid_mask
-from ..spec import GPTDecodeSpec, GPTSpecDecoder
-from .decode import GPTPagedDecoder, jit_donating_arenas
-from .pool import PagedKVCache, paged_gather_rows, paged_write_rows
-
-
-def _paged_block_verify(spec, lp, h, kbuf, vbuf, li, block_tables,
-                        pid_flat, ppos_flat, mask, scale):
-    """``spec._block_verify`` with the K/V substrate paged: all T
-    candidate rows scatter into layer ``li`` of the whole arenas
-    through (``pid_flat``, ``ppos_flat``) — the [S*T] physical
-    coordinates of ``positions..positions+T-1`` — then the full logical
-    rows gather back for the attention. Dense only (the spec engine
-    path never runs over int8 KV; the config gate predates paging)."""
-    s, t = h.shape[0], h.shape[1]
-    x = _layer_norm(h, lp["n1w"], lp["n1b"], spec.ln_epsilon)
-
-    def heads(z):                                          # [S, T, H, D]
-        return z.reshape(s, t, spec.num_heads, spec.head_dim)
-
-    q = heads(x @ lp["qw"] + lp["qb"])
-    kn = heads(x @ lp["kw"] + lp["kb"])
-    vn = heads(x @ lp["vw"] + lp["vb"])
-    flat = (s * t, spec.num_heads, spec.head_dim)
-    kbuf = paged_write_rows(kbuf, kn.reshape(flat), pid_flat, ppos_flat,
-                            li)
-    vbuf = paged_write_rows(vbuf, vn.reshape(flat), pid_flat, ppos_flat,
-                            li)
-    kg = paged_gather_rows(kbuf, block_tables, li)         # [S, max, H, D]
-    vg = paged_gather_rows(vbuf, block_tables, li)
-    qh = jnp.transpose(q * scale, (0, 2, 1, 3))            # [S, H, T, D]
-    kt = jnp.transpose(kg, (0, 2, 1, 3))                   # [S, H, max, D]
-    vt = jnp.transpose(vg, (0, 2, 1, 3))
-    prod = jnp.matmul(qh, jnp.swapaxes(kt, -1, -2))        # [S, H, T, max]
-    weights = jax.nn.softmax(prod + mask, axis=-1)
-    out = jnp.matmul(weights, vt)                          # [S, H, T, D]
-    out = jnp.transpose(out, (0, 2, 1, 3)).reshape(s, t, spec.hidden_size)
-    h = h + (out @ lp["ow"] + lp["ob"])
-    x = _layer_norm(h, lp["n2w"], lp["n2b"], spec.ln_epsilon)
-    ffn = jax.nn.gelu(x @ lp["w1"] + lp["b1"], approximate=False)
-    return h + (ffn @ lp["w2"] + lp["b2"]), kbuf, vbuf
+from ....models.gpt import gpt_hidden
+from ..decode import jit_program
+from ..spec import (GPTDecodeSpec, GPTSpecDecoder, accept_prefix,
+                    draft_proposals, verify_inputs)
+from .decode import GPTPagedDecoder
+from .pool import PagedKVCache, PagedRows
 
 
 def build_paged_spec_decode_step(tspec: GPTDecodeSpec,
@@ -88,95 +47,22 @@ def build_paged_spec_decode_step(tspec: GPTDecodeSpec,
     """
     if k < 1:
         raise ValueError(f"speculation depth k must be >= 1, got {k}")
-    t_scale = 1.0 / np.sqrt(tspec.head_dim)
-    d_scale = 1.0 / np.sqrt(dspec.head_dim)
-    t_max_pos = tspec.max_position_embeddings
-    d_max_pos = dspec.max_position_embeddings
 
     def _step(params_t, params_d, kbuf_t, vbuf_t, kbuf_d, vbuf_d,
               block_tables, lengths, finished, last_tokens, temperature,
               top_k, do_sample, eos, key):
-        s = lengths.shape[0]
-        pp_n = block_tables.shape[1]
-        max_seq = pp_n * page_size
-        d_max_seq = kbuf_d.shape[2]
-        # -- 1. draft proposes k tokens greedily (slot-layout cache) -----
-        # identical to the slot spec step, k+1 micro-steps (the last one
-        # only deposits the final proposal's K/V row)
-        d_last = last_tokens
-        drafts = []
-        for i in range(k + 1):
-            pos_i = lengths + i
-            posc = jnp.clip(pos_i, 0, d_max_pos - 1)
-            h = params_d["tok"][d_last] + params_d["pos"][posc]
-            mask = valid_mask(pos_i, d_max_seq, h.dtype)
-            new_k, new_v = [], []
-            for li, lp in enumerate(params_d["layers"]):
-                h, kb, vb = _block_decode(dspec, lp, h, kbuf_d[:, li],
-                                          vbuf_d[:, li], pos_i, mask,
-                                          d_scale)
-                new_k.append(kb)
-                new_v.append(vb)
-            kbuf_d = jnp.stack(new_k, axis=1)
-            vbuf_d = jnp.stack(new_v, axis=1)
-            if i == k:
-                break
-            h = _layer_norm(h, params_d["fnw"], params_d["fnb"],
-                            dspec.ln_epsilon)
-            lraw_d = (h @ params_d["tok"].T).astype(jnp.float32)
-            d_i = jnp.argmax(lraw_d, axis=-1).astype(jnp.int32)
-            drafts.append(d_i)
-            d_last = d_i
-        drafts_arr = jnp.stack(drafts, axis=1)                 # [S, k]
-
-        # -- 2. target verifies through the page arena -------------------
-        t_len = k + 1
-        u = jnp.concatenate([last_tokens[:, None], drafts_arr], axis=1)
-        pos_mat = lengths[:, None] + jnp.arange(t_len, dtype=jnp.int32)
-        posc = jnp.clip(pos_mat, 0, t_max_pos - 1)
-        h = params_t["tok"][u] + params_t["pos"][posc]         # [S, T, E]
-        j = jnp.arange(max_seq, dtype=jnp.int32)[None, None]
-        vmask = jnp.where(j <= pos_mat[:, :, None], 0.0,
-                          -1e9).astype(h.dtype)[:, None]       # [S,1,T,max]
-        # physical coordinates of all S*T candidate rows; out-of-range
-        # positions (inactive slots) clip to the last table entry — the
-        # trash page for freed slots
-        page_idx = jnp.clip(pos_mat // page_size, 0, pp_n - 1)
-        pid_flat = jnp.take_along_axis(block_tables, page_idx,
-                                       axis=1).reshape(-1)     # [S*T]
-        ppos_flat = (pos_mat % page_size).reshape(-1)
-        for li, lp in enumerate(params_t["layers"]):
-            h, kbuf_t, vbuf_t = _paged_block_verify(
-                tspec, lp, h, kbuf_t, vbuf_t, li, block_tables,
-                pid_flat, ppos_flat, vmask, t_scale)
-        h = _layer_norm(h, params_t["fnw"], params_t["fnb"],
-                        tspec.ln_epsilon)
-        lraw = (h @ params_t["tok"].T).astype(jnp.float32)     # [S, T, V]
-        t_greedy = jnp.argmax(lraw, axis=-1).astype(jnp.int32)
-
-        # -- 3. accept-prefix + bonus (identical to the slot step) -------
-        match = (drafts_arr == t_greedy[:, :k]).astype(jnp.int32)
-        m = jnp.sum(jnp.cumprod(match, axis=1), axis=1)        # [S], 0..k
-        m = jnp.where(do_sample | finished, 0, m)
-        bonus = jnp.take_along_axis(t_greedy, m[:, None], axis=1)[:, 0]
-        samp_tok = _sample(lraw[:, 0], temperature, top_k, do_sample,
-                           key, max_top_k)
-        step_tok = jnp.where(do_sample, samp_tok, bonus)
-        step_tok = jnp.where(finished & (eos >= 0), eos, step_tok)
-        idx = jnp.arange(t_len, dtype=jnp.int32)[None]         # [1, T]
-        ext_drafts = jnp.concatenate(
-            [drafts_arr, jnp.zeros((s, 1), jnp.int32)], axis=1)
-        emit = jnp.where(idx < m[:, None], ext_drafts,
-                         jnp.where(idx == m[:, None], step_tok[:, None],
-                                   0))
-        n_emit = m + 1
-        hit_eos = ((emit == eos[:, None]) & (eos >= 0)[:, None]
-                   & (idx < n_emit[:, None])).any(axis=1)
-        finished = finished | hit_eos
-        out = jnp.concatenate([n_emit[:, None], emit],
-                              axis=1).astype(jnp.int32)        # [S, k+2]
-        return (kbuf_t, vbuf_t, kbuf_d, vbuf_d, lengths + n_emit,
-                finished, step_tok, out)
+        # the draft and the accept-prefix are the slot spec step's; only
+        # the verify view differs: all k+1 candidate rows per slot scatter
+        # through the block table, then the full logical rows gather back
+        kbuf_d, vbuf_d, drafts = draft_proposals(
+            dspec, k, params_d, kbuf_d, vbuf_d, lengths, last_tokens)
+        u, pos = verify_inputs(lengths, last_tokens, drafts)
+        view = PagedRows(kbuf_t, vbuf_t, block_tables, pos, page_size,
+                         "gather", params_t["tok"].dtype)
+        h = gpt_hidden(tspec, params_t, u, pos, view)          # [S, T, E]
+        return (view.kbuf, view.vbuf, kbuf_d, vbuf_d) + accept_prefix(
+            params_t, h, drafts, lengths, finished, temperature, top_k,
+            do_sample, eos, key, max_top_k)
 
     return _step
 
@@ -187,9 +73,9 @@ def get_paged_spec_decode_step(tspec: GPTDecodeSpec,
                                max_top_k: int, page_size: int):
     """Jitted paged speculative step. Donates the TARGET arenas (the
     paged ones); the draft's slot-layout buffers are the slot plane's."""
-    return jit_donating_arenas(
+    return jit_program(
         build_paged_spec_decode_step(tspec, dspec, k, max_top_k,
-                                     page_size), arenas=(2, 3))
+                                     page_size), donate=(2, 3))
 
 
 class GPTPagedSpecDecoder(GPTSpecDecoder):

@@ -49,40 +49,77 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ...models.gpt import gpt_hidden
 from ..cache import ExecutableCache
-from .decode import (GPTDecodeSpec, GPTStaticDecoder, _block_decode,
-                     _layer_norm, _sample, extract_gpt_params,
-                     get_prefill_fn)
-from .kvcache import StaticKVCache, append_tokens_kv, valid_mask
+from .decode import (GPTDecodeSpec, GPTStaticDecoder, _sample,
+                     extract_gpt_params, get_prefill_fn, jit_program)
+from .kvcache import SlotRows, StaticKVCache
 
 
-def _block_verify(spec, lp, h, kb, vb, positions, mask, scale):
-    """One pre-norm block over T=k+1 candidate tokens per slot against
-    the full cache row. ``h``: [S, T, E]; ``kb``/``vb``: this layer's
-    [S, max_seq, H, D] cache; all T candidate K/V rows are written at
-    ``positions..positions+T-1`` before attending (query i's own row is
-    visible to it, mirroring the single-token step)."""
-    s, t = h.shape[0], h.shape[1]
-    x = _layer_norm(h, lp["n1w"], lp["n1b"], spec.ln_epsilon)
+def draft_proposals(dspec: GPTDecodeSpec, k: int, params_d, kbuf_d, vbuf_d,
+                    lengths, last_tokens):
+    """Part 1 of a speculative tick: the draft proposes ``k`` tokens
+    greedily over its own small slot cache. Returns ``(kbuf_d, vbuf_d,
+    drafts[S, k])``.
 
-    def heads(z):                                          # [S, T, H, D]
-        return z.reshape(s, t, spec.num_heads, spec.head_dim)
+    k+1 micro-steps, not k: when every draft is accepted the tick's valid
+    rows extend to position p+k, so the draft cache needs the LAST
+    proposal's K/V row too — without it the next tick's draft attends a
+    garbage row and acceptance collapses. The extra step only deposits
+    that row; its logits are never formed."""
+    d_last = last_tokens
+    drafts = []
+    for i in range(k + 1):
+        pos_i = lengths + i
+        view = SlotRows(kbuf_d, vbuf_d, pos_i, params_d["tok"].dtype)
+        h = gpt_hidden(dspec, params_d, d_last, pos_i, view)      # [S, E]
+        kbuf_d, vbuf_d = view.buffers()
+        if i == k:
+            break
+        lraw_d = (h @ params_d["tok"].T).astype(jnp.float32)
+        d_last = jnp.argmax(lraw_d, axis=-1).astype(jnp.int32)
+        drafts.append(d_last)
+    return kbuf_d, vbuf_d, jnp.stack(drafts, axis=1)
 
-    q = heads(x @ lp["qw"] + lp["qb"])
-    kn = heads(x @ lp["kw"] + lp["kb"])
-    vn = heads(x @ lp["vw"] + lp["vb"])
-    kb, vb = append_tokens_kv(kb, vb, kn, vn, positions)
-    qh = jnp.transpose(q * scale, (0, 2, 1, 3))            # [S, H, T, D]
-    kt = jnp.transpose(kb, (0, 2, 1, 3))                   # [S, H, max, D]
-    vt = jnp.transpose(vb, (0, 2, 1, 3))
-    prod = jnp.matmul(qh, jnp.swapaxes(kt, -1, -2))        # [S, H, T, max]
-    weights = jax.nn.softmax(prod + mask, axis=-1)
-    out = jnp.matmul(weights, vt)                          # [S, H, T, D]
-    out = jnp.transpose(out, (0, 2, 1, 3)).reshape(s, t, spec.hidden_size)
-    h = h + (out @ lp["ow"] + lp["ob"])
-    x = _layer_norm(h, lp["n2w"], lp["n2b"], spec.ln_epsilon)
-    ffn = jax.nn.gelu(x @ lp["w1"] + lp["b1"], approximate=False)
-    return h + (ffn @ lp["w2"] + lp["b2"]), kb, vb
+
+def verify_inputs(lengths, last_tokens, drafts):
+    """Part 2's queries: ``u = [x0, d0, .., d_{k-1}]`` ``[S, k+1]`` at
+    positions ``p..p+k``."""
+    u = jnp.concatenate([last_tokens[:, None], drafts], axis=1)
+    pos = lengths[:, None] + jnp.arange(u.shape[1], dtype=jnp.int32)
+    return u, pos
+
+
+def accept_prefix(params_t, h, drafts, lengths, finished, temperature,
+                  top_k, do_sample, eos, key, max_top_k):
+    """Part 3, all on device: from the target's final-norm hidden states
+    ``h`` ``[S, k+1, E]`` of the verify queries, the accepted prefix of
+    ``drafts`` plus the bonus token. Returns ``(lengths + n, finished,
+    new_last, out[S, k+2])``."""
+    s, k = drafts.shape
+    lraw = (h @ params_t["tok"].T).astype(jnp.float32)         # [S, T, V]
+    t_greedy = jnp.argmax(lraw, axis=-1).astype(jnp.int32)
+    match = (drafts == t_greedy[:, :k]).astype(jnp.int32)
+    m = jnp.sum(jnp.cumprod(match, axis=1), axis=1)            # [S], 0..k
+    # sampling slots take one verified token per tick; finished slots
+    # freeze (the host released them already — mirror the plain step)
+    m = jnp.where(do_sample | finished, 0, m)
+    bonus = jnp.take_along_axis(t_greedy, m[:, None], axis=1)[:, 0]
+    samp_tok = _sample(lraw[:, 0], temperature, top_k, do_sample, key,
+                       max_top_k)
+    step_tok = jnp.where(do_sample, samp_tok, bonus)
+    step_tok = jnp.where(finished & (eos >= 0), eos, step_tok)
+    idx = jnp.arange(k + 1, dtype=jnp.int32)[None]             # [1, T]
+    ext_drafts = jnp.concatenate(
+        [drafts, jnp.zeros((s, 1), jnp.int32)], axis=1)
+    emit = jnp.where(idx < m[:, None], ext_drafts,
+                     jnp.where(idx == m[:, None], step_tok[:, None], 0))
+    n_emit = m + 1
+    hit_eos = ((emit == eos[:, None]) & (eos >= 0)[:, None]
+               & (idx < n_emit[:, None])).any(axis=1)
+    out = jnp.concatenate([n_emit[:, None], emit],
+                          axis=1).astype(jnp.int32)            # [S, k+2]
+    return lengths + n_emit, finished | hit_eos, step_tok, out
 
 
 def build_spec_decode_step(tspec: GPTDecodeSpec, dspec: GPTDecodeSpec,
@@ -102,96 +139,22 @@ def build_spec_decode_step(tspec: GPTDecodeSpec, dspec: GPTDecodeSpec,
     """
     if k < 1:
         raise ValueError(f"speculation depth k must be >= 1, got {k}")
-    t_scale = 1.0 / np.sqrt(tspec.head_dim)
-    d_scale = 1.0 / np.sqrt(dspec.head_dim)
-    t_max_pos = tspec.max_position_embeddings
-    d_max_pos = dspec.max_position_embeddings
 
     def _step(params_t, params_d, kbuf_t, vbuf_t, kbuf_d, vbuf_d, lengths,
               finished, last_tokens, temperature, top_k, do_sample, eos,
               key):
-        s = lengths.shape[0]
-        max_seq = kbuf_t.shape[2]
-        d_max_seq = kbuf_d.shape[2]
-        # -- 1. draft proposes k tokens greedily (its own small cache) ---
-        # k+1 micro-steps, not k: when every draft is accepted the tick's
-        # valid rows extend to position p+k, so the draft cache needs the
-        # LAST proposal's K/V row too — without it the next tick's draft
-        # attends a garbage row and acceptance collapses. The extra step
-        # only deposits that row; its logits are never formed.
-        d_last = last_tokens
-        drafts = []
-        for i in range(k + 1):
-            pos_i = lengths + i
-            posc = jnp.clip(pos_i, 0, d_max_pos - 1)
-            h = params_d["tok"][d_last] + params_d["pos"][posc]
-            mask = valid_mask(pos_i, d_max_seq, h.dtype)
-            new_k, new_v = [], []
-            for li, lp in enumerate(params_d["layers"]):
-                h, kb, vb = _block_decode(dspec, lp, h, kbuf_d[:, li],
-                                          vbuf_d[:, li], pos_i, mask,
-                                          d_scale)
-                new_k.append(kb)
-                new_v.append(vb)
-            kbuf_d = jnp.stack(new_k, axis=1)
-            vbuf_d = jnp.stack(new_v, axis=1)
-            if i == k:
-                break
-            h = _layer_norm(h, params_d["fnw"], params_d["fnb"],
-                            dspec.ln_epsilon)
-            lraw_d = (h @ params_d["tok"].T).astype(jnp.float32)
-            d_i = jnp.argmax(lraw_d, axis=-1).astype(jnp.int32)
-            drafts.append(d_i)
-            d_last = d_i
-        drafts_arr = jnp.stack(drafts, axis=1)                 # [S, k]
-
-        # -- 2. target verifies all k (+ the carried last token) at once -
-        t_len = k + 1
-        u = jnp.concatenate([last_tokens[:, None], drafts_arr], axis=1)
-        pos_mat = lengths[:, None] + jnp.arange(t_len, dtype=jnp.int32)
-        posc = jnp.clip(pos_mat, 0, t_max_pos - 1)
-        h = params_t["tok"][u] + params_t["pos"][posc]         # [S, T, E]
-        j = jnp.arange(max_seq, dtype=jnp.int32)[None, None]
-        vmask = jnp.where(j <= pos_mat[:, :, None], 0.0,
-                          -1e9).astype(h.dtype)[:, None]       # [S,1,T,max]
-        new_k, new_v = [], []
-        for li, lp in enumerate(params_t["layers"]):
-            h, kb, vb = _block_verify(tspec, lp, h, kbuf_t[:, li],
-                                      vbuf_t[:, li], lengths, vmask,
-                                      t_scale)
-            new_k.append(kb)
-            new_v.append(vb)
-        kbuf_t = jnp.stack(new_k, axis=1)
-        vbuf_t = jnp.stack(new_v, axis=1)
-        h = _layer_norm(h, params_t["fnw"], params_t["fnb"],
-                        tspec.ln_epsilon)
-        lraw = (h @ params_t["tok"].T).astype(jnp.float32)     # [S, T, V]
-        t_greedy = jnp.argmax(lraw, axis=-1).astype(jnp.int32)
-
-        # -- 3. accept-prefix + bonus, all on device ---------------------
-        match = (drafts_arr == t_greedy[:, :k]).astype(jnp.int32)
-        m = jnp.sum(jnp.cumprod(match, axis=1), axis=1)        # [S], 0..k
-        # sampling slots take one verified token per tick; finished slots
-        # freeze (the host released them already — mirror the plain step)
-        m = jnp.where(do_sample | finished, 0, m)
-        bonus = jnp.take_along_axis(t_greedy, m[:, None], axis=1)[:, 0]
-        samp_tok = _sample(lraw[:, 0], temperature, top_k, do_sample, key,
-                           max_top_k)
-        step_tok = jnp.where(do_sample, samp_tok, bonus)
-        step_tok = jnp.where(finished & (eos >= 0), eos, step_tok)
-        idx = jnp.arange(t_len, dtype=jnp.int32)[None]         # [1, T]
-        ext_drafts = jnp.concatenate(
-            [drafts_arr, jnp.zeros((s, 1), jnp.int32)], axis=1)
-        emit = jnp.where(idx < m[:, None], ext_drafts,
-                         jnp.where(idx == m[:, None], step_tok[:, None], 0))
-        n_emit = m + 1
-        hit_eos = ((emit == eos[:, None]) & (eos >= 0)[:, None]
-                   & (idx < n_emit[:, None])).any(axis=1)
-        finished = finished | hit_eos
-        out = jnp.concatenate([n_emit[:, None], emit],
-                              axis=1).astype(jnp.int32)        # [S, k+2]
-        return (kbuf_t, vbuf_t, kbuf_d, vbuf_d, lengths + n_emit,
-                finished, step_tok, out)
+        kbuf_d, vbuf_d, drafts = draft_proposals(
+            dspec, k, params_d, kbuf_d, vbuf_d, lengths, last_tokens)
+        # the target verifies all k (+ the carried last token) at once:
+        # all k+1 candidate rows are written before attending, query i's
+        # own row is visible to it, mirroring the single-token step
+        u, pos = verify_inputs(lengths, last_tokens, drafts)
+        view = SlotRows(kbuf_t, vbuf_t, pos, params_t["tok"].dtype)
+        h = gpt_hidden(tspec, params_t, u, pos, view)          # [S, T, E]
+        kbuf_t, vbuf_t = view.buffers()
+        return (kbuf_t, vbuf_t, kbuf_d, vbuf_d) + accept_prefix(
+            params_t, h, drafts, lengths, finished, temperature, top_k,
+            do_sample, eos, key, max_top_k)
 
     return _step
 
@@ -202,16 +165,7 @@ def get_spec_decode_step(tspec: GPTDecodeSpec, dspec: GPTDecodeSpec,
     """THE speculative decode step: jitted once per (target spec, draft
     spec, k, max_top_k); one trace per (num_slots, max_seq) shape pair
     (``trace_counter`` pins it, same contract as ``get_decode_step``)."""
-    counter = {"traces": 0}
-    raw = build_spec_decode_step(tspec, dspec, k, max_top_k)
-
-    def _step(*args):
-        counter["traces"] += 1
-        return raw(*args)
-
-    fn = jax.jit(_step)
-    fn.trace_counter = counter
-    return fn
+    return jit_program(build_spec_decode_step(tspec, dspec, k, max_top_k))
 
 
 class GPTSpecDecoder:

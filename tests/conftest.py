@@ -24,6 +24,16 @@ def _seed_all():
 # timeout, unittests/CMakeLists.txt set_tests_properties TIMEOUT). No
 # pytest-timeout in this image, so a SIGALRM guard: default 300 s, override
 # with @pytest.mark.timeout_s(N).
+@pytest.fixture()
+def reference_paddle():
+    """Path of the reference's ``python/paddle`` tree, which the export
+    parity tests read; they skip on a machine that does not have it."""
+    path = "/root/reference/python/paddle"
+    if not os.path.isdir(path):
+        pytest.skip(f"the reference tree {path} is not on this machine")
+    return path
+
+
 import signal  # noqa: E402
 
 

@@ -289,6 +289,30 @@ class TestSpeculativeDecoding:
         assert toks == baseline
         assert reg.get("serving.llm.spec.ticks") > 0
 
+    @pytest.mark.parametrize("kv_layout", ["slot", "paged"])
+    def test_int8_weights_speculate_like_the_plain_int8_engine(
+            self, model, kv_layout):
+        """int8 weight leaves go through the verify step too (its
+        projections were a bare ``@``: the worker died at its first tick
+        with a TypeError): greedy tokens equal the same int8 engine's
+        without speculation. The target is its own draft; the draft runs
+        the float32 weights, so not every proposal verifies."""
+        def run(**kw):
+            reg = StatRegistry()
+            eng = LLMEngine(model, LLMEngineConfig(
+                num_slots=4, max_seq=64, warmup=False, weight_dtype="int8",
+                kv_layout=kv_layout, **kw), registry=reg, draft_model=model)
+            try:
+                return _generate_all(eng), reg
+            finally:
+                eng.drain()
+
+        plain, _ = run()
+        toks, reg = run(spec_k=2)
+        assert toks == plain
+        assert reg.get("serving.llm.spec.ticks") > 0
+        assert reg.get("serving.llm.spec.accepted") > 0
+
     def test_spec_with_prefix_reuse_bitwise(self, model, baseline):
         """Both features on at once: the draft cache prefills the full
         prompt even when the target reuses a cached head, and output

@@ -12,10 +12,10 @@ import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
 
 
-def test_export_parity_nn_and_functional():
+def test_export_parity_nn_and_functional(reference_paddle):
     for path, ours in [
-            ("/root/reference/python/paddle/nn/__init__.py", nn),
-            ("/root/reference/python/paddle/nn/functional/__init__.py", F)]:
+            (f"{reference_paddle}/nn/__init__.py", nn),
+            (f"{reference_paddle}/nn/functional/__init__.py", F)]:
         src = open(path).read()
         names = re.findall(r"from \.[\w.]+ import (\w+)", src)
         names += re.findall(r"^\s+'(\w+)',?\s*$", src, re.M)

@@ -381,7 +381,7 @@ def test_linalg_toplevel_and_tensor_namespace():
         paddle.tensor.rank(paddle.to_tensor(a)).numpy(), 2)
 
 
-def test_tensor_method_parity_vs_reference():
+def test_tensor_method_parity_vs_reference(reference_paddle):
     """Every method-shaped name in the reference's tensor/__init__.py
     resolves on Tensor (free creation functions excluded — they live at
     the paddle top level and are covered by the top-level parity test)."""
@@ -389,7 +389,7 @@ def test_tensor_method_parity_vs_reference():
     import numpy as np
     import paddle_tpu as paddle
 
-    src = open("/root/reference/python/paddle/tensor/__init__.py").read()
+    src = open(f"{reference_paddle}/tensor/__init__.py").read()
     names = []
     for m in re.finditer(r"from \.\w+ import ([\w,\s]+)", src):
         for n in m.group(1).split(","):
