@@ -26,6 +26,7 @@ timings (one run, compile included where it says so), not benchmark results.
 from __future__ import annotations
 
 import argparse
+import collections
 import functools
 import glob
 import json
@@ -61,16 +62,24 @@ def check(cond, what):
 
 def mosaic_kernel_calls(module_glob):
     """{kernel_name: count} over the Mosaic custom calls in the lowered
-    (StableHLO) modules jax dumped under IR_DIR matching ``module_glob``."""
+    (StableHLO) modules jax dumped under IR_DIR matching ``module_glob``.
+    A kernel inside a private function (a jitted helper that a program's
+    layers share, as ``paged_attn``'s is) counts once a call of it."""
     counts = {}
     for path in glob.glob(os.path.join(IR_DIR, module_glob)):
         with open(path) as f:
-            for line in f:
-                if "@tpu_custom_call" not in line:
-                    continue
+            text = f.read()
+        called = collections.Counter(re.findall(r"\bcall @([\w.]+)", text))
+        func = None
+        for line in text.splitlines():
+            m = re.search(r"func\.func (?:\w+ )?@([\w.]+)", line)
+            if m:
+                func = m.group(1)
+            elif "@tpu_custom_call" in line:
                 m = re.search(r'kernel_name = "([^"]+)"', line)
                 if m:
-                    counts[m.group(1)] = counts.get(m.group(1), 0) + 1
+                    counts[m.group(1)] = (counts.get(m.group(1), 0)
+                                          + (called.get(func) or 1))
     return counts
 
 

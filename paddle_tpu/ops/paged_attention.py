@@ -6,42 +6,50 @@ arena: logical row ``t`` of sequence ``s`` lives at physical page
 (see ``serving/llm/paged/pool.py``). Rather than gathering the pages
 into a contiguous ``[S, max_seq, H, D]`` tensor in HBM first (the
 reference lane, ``paged_gather_rows``), this kernel walks the block
-table *inside* the grid: the page id rides the scalar-prefetch channel
-into each K/V BlockSpec index map, so the pipeline DMAs exactly the
-pages the sequence owns, one per grid step, with the online-softmax
-running statistics (m, l, acc) carried across the page axis in VMEM
-scratch — the flash-attention recurrence over a gathered key axis.
+table itself, and only as far as the sequence reaches.
 
-The kernel reads the WHOLE ``[P+1, L, page, H, D]`` arena, all layers of
-it: the layer index rides the scalar-prefetch channel beside the page id
-and the layer axis of the block is squeezed, so a grid step fetches the
-same contiguous ``[page, block_h, D]`` chunk a single-layer arena would
-give, no layer is ever cut out of the arena for the call (the decode
-step updates the arena in place), and every layer's call is one and the
-same Mosaic kernel.
+Grid: ``(S, Hkv // block_h)`` — one sequence's head block a step. The
+arenas stay whole in HBM (``memory_space=pl.ANY``, no BlockSpec); the
+block table, the positions and the layer index ride the scalar-prefetch
+channel into SMEM. The kernel reads the WHOLE ``[P+1, L, page, H, D]``
+arena, all layers of it: no layer is ever cut out of the arena for the
+call (the decode step updates the arena in place), and every layer's
+call is one and the same Mosaic kernel.
+
+The walk: a step has ``n = positions[s] // page_size + 1`` live pages and
+loops over them ``pages_per_step`` at a time, a trip count that is the
+sequence's own. Each iteration starts one ``make_async_copy`` a live
+page and arena (``arena[page_id, layer, :, heads]`` -> a slot of the
+other half of a double buffer in VMEM), waits for the copies of its own
+half and runs the online-softmax recurrence over its pages, page by
+page in table order, with the running statistics (m, l, acc) in VMEM
+scratch. A page past the live ones is never indexed, fetched or
+computed. The last iteration of a step also starts the first copies of
+the NEXT grid step (the grid runs in order), so no step but the first
+waits for a copy nothing overlaps.
+
+Masking: query at position ``positions[s]`` attends rows ``j <=
+positions[s]`` (the just-written token sees itself and the whole valid
+prefix — same semantics as ``kvcache.valid_mask``). Only the last live
+page can hold rows past the position: it alone is computed under the
+mask, its dead rows' values zeroed too (``0 * NaN`` is NaN), so nothing
+past the end reaches the result whatever lies there.
 
 Grouped-query heads: with ``G = Hq // Hkv`` query heads to each KV head
 (query head ``j`` reads KV head ``j // G``), the block over heads walks KV
-heads, and each K/V block fetched serves its ``G`` query heads: ``q`` rides
+heads, and each page fetched serves its ``G`` query heads: ``q`` rides
 in as ``[S, G, Hkv, D]`` (group-major, so that every group is a
 ``[block_h, D]`` slab laid out like a K row) and the recurrence runs once a
-group on the one K/V block. ``G = 1`` is plain multi-head attention.
+group on the one page. ``G = 1`` is plain multi-head attention.
 
 Fused rows: with ``v_arena=None`` the one arena's rows hold a head's key
 and value side by side (``[..., Hkv, 2 * D]``, what the cache keeps for a
 head size under the lane width); ``q`` is padded with zeros over the value's
-lanes, so the same multiply and lane reduction give ``q . k``, the block is
-read once and serves as both operands, and the value's lanes of the
-accumulator are the result.
-
-Grid: ``(S, Hkv // block_h, pages_per_seq)`` — the page axis is innermost,
-so on TPU (sequential grid) the scratch accumulators persist across one
-sequence-head-block's page walk and reset via ``@pl.when(p == 0)``.
-
-Masking: query at position ``positions[s]`` attends rows ``j <=
-positions[s]`` (the just-written token sees itself and the whole valid
-prefix — same semantics as ``kvcache.valid_mask``). Pages past the
-length (including trash-page junk) zero out in the running softmax.
+lanes, so the same multiply and lane reduction give ``q . k``, the page is
+fetched once and serves as both operands, and the value's lanes of the
+accumulator are the result. Mosaic copies whole 128-lane tiles out of an
+array in HBM, so two arenas of narrower rows are laid side by side for the
+call (a copy; the cache fuses such rows itself to avoid it).
 
 Off-TPU the wrapper runs in interpret mode — the same numerics, so CPU
 tests cover the kernel's math; interpret-mode output matches the gather
@@ -50,10 +58,16 @@ a different order — the bitwise-parity contract belongs to the gather
 lane).
 
 Tuner family ``paged_attn`` (``paddle_tpu.tuner.paged_key``): the one
-knob is ``block_h``, how many heads share a grid step's DMA and compute
-block — a multiple of the sublane tile that divides the head count, or
-all the heads (``_sanitize_block_h``). ``default_winners.json`` carries
-committed entries; unknown shapes fall back to a dividing heuristic.
+knob is ``block_h``, how many heads share a grid step — a multiple of the
+sublane tile that divides the head count, or all the heads
+(``_sanitize_block_h``). ``default_winners.json`` carries committed
+entries; an unknown shape takes all the heads where a page of them fits
+the buffers (a page's rows of one layer are then one contiguous piece of
+the arena: at 16 heads of 128 the all-heads walk timed 2.07 ms against
+3.10 ms for two blocks of 8, PERF.md PR 29). ``pages_per_step`` is no
+knob: ``tuner.space.paged_pages_per_step`` derives it, the largest power
+of two whose double buffers fit ``PAGED_BUFFER_BUDGET`` and the table, and
+``tuner.space.paged_attn_vmem_bytes`` states the footprint that follows.
 """
 from __future__ import annotations
 
@@ -100,53 +114,143 @@ def _tuned_block_h(num_heads, head_dim, page_size, dtype):
     return b if b > 0 else None
 
 
-def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, *refs,
-                       scale, page_size, pages_per_seq, block_h, groups):
-    """One page of one sequence's head block per grid step. With a single
-    query row there is nothing for the MXU to amortize, so the whole
-    recurrence stays on the VPU in the arena's own ``[page, heads, D]``
+def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
+                       scale, page_size, pages_per_seq, pages_per_step,
+                       block_h, head_blocks, groups, arenas):
+    """One sequence's head block per grid step; the page walk is a loop in
+    here, over the pages the sequence has rows in and no further. Each
+    iteration starts the copies of the next ``pages_per_step`` pages
+    (HBM arena -> the other half of the double buffer, a copy a page and
+    arena, ids from the block table in SMEM), waits for its own and runs
+    the online-softmax recurrence over them, a page at a time.
+
+    With a single query row there is nothing for the MXU to amortize, so
+    the recurrence stays on the VPU in the arena's own ``[page, heads, D]``
     layout: q.k is a multiply and a lane reduction, softmax statistics
     reduce over the major (page) axis, p.v is a lane broadcast and a
-    major-axis sum — no transpose, no batched dot, no relayout. The K/V
-    block is read once and serves each of the ``groups`` query heads of its
-    KV heads in turn; without a ``v_ref`` (fused rows) it is both operands.
-    ``layer_ref`` is read by the index maps only."""
+    major-axis sum — no transpose, no batched dot, no relayout. A fetched
+    page serves each of the ``groups`` query heads of its KV heads in
+    turn; with one arena (fused rows) it is both operands. Only the page
+    that holds row ``positions[s]`` can hold rows past it, so only that
+    page pays for the mask."""
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    v_ref = refs[0] if len(refs) == 5 else k_ref
-    o_ref, acc_ref, m_ref, l_ref = refs[-4:]
-    s = pl.program_id(0)
-    p = pl.program_id(2)
+    hbm = refs[:arenas]
+    o_ref = refs[arenas]
+    bufs = refs[arenas + 1:2 * arenas + 1]
+    sem, first_half, acc_ref, m_ref, l_ref = refs[2 * arenas + 1:]
+    s, hb = pl.program_id(0), pl.program_id(1)
+    layer = layer_ref[0]
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def position(seq):
+        # a slot nobody serves keeps counting ticks: the table's last row
+        # is as far as a walk goes (the gather lane's mask ends there too)
+        return jnp.clip(len_ref[seq], 0, pages_per_seq * page_size - 1)
 
-    kblk = k_ref[0].astype(jnp.float32)               # [page, bh, D]
-    vblk = kblk if v_ref is k_ref else v_ref[0].astype(jnp.float32)
-    j = p * page_size + lax.broadcasted_iota(
-        jnp.int32, (page_size, block_h, 1), 0)
-    valid = j <= len_ref[s]
-    for g in range(groups):
-        q = q_ref[0, g].astype(jnp.float32) * scale   # [bh, D]
-        s_blk = jnp.sum(kblk * q[None], axis=-1, keepdims=True)
-        s_blk = jnp.where(valid, s_blk, _NEG_INF)     # [page, bh, 1]
-        m_prev = m_ref[g, :, :1]                      # [bh, 1]
-        l_prev = l_ref[g, :, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=0))
-        alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.where(valid, jnp.exp(s_blk - m_new[None]), 0.0)
-        l_new = l_prev * alpha + jnp.sum(pexp, axis=0)
-        acc_ref[g] = acc_ref[g] * alpha + jnp.sum(pexp * vblk, axis=0)
-        m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-        l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+    pos = position(s)
+    n_full = (pos + 1) // page_size          # pages whose every row is live
+    n_pages = pos // page_size + 1           # pages with a live row
+    n_steps = (n_pages + pages_per_step - 1) // pages_per_step
 
-    @pl.when(p == pages_per_seq - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :, :1], 1e-30)
-                    ).astype(o_ref.dtype)
+    def copies(seq, hblk, step, half, start):
+        """Start, or wait for, the copy of every live page of loop step
+        ``step`` of sequence ``seq``'s head block ``hblk`` into buffer
+        ``half``."""
+        first = step * pages_per_step
+        # all the heads: a page's rows of a layer, one contiguous piece
+        heads = () if head_blocks == 1 else (
+            slice(None), pl.ds(pl.multiple_of(hblk * block_h, block_h),
+                               block_h))
+
+        def one(i, carry):
+            rows = (bt_ref[seq * pages_per_seq + first + i], layer) + heads
+            for a in range(arenas):
+                copy = pltpu.make_async_copy(
+                    hbm[a].at[rows], bufs[a].at[half, i], sem.at[half, a])
+                # not `copy.start()`: tools/analyze resolves a call by its
+                # name and would walk every `start` and `wait` in the repo
+                (copy.start if start else copy.wait)()
+            return carry
+
+        live = position(seq) // page_size + 1
+        lax.fori_loop(0, jnp.minimum(live - first, pages_per_step), one, 0)
+
+    # the first pages of a grid step are on their way since the step
+    # before it (the grid runs in order): only the first step of all
+    # starts its own
+    @pl.when((s == 0) & (hb == 0))
+    def _():
+        first_half[0] = 0
+        copies(s, hb, 0, 0, start=True)
+
+    half0 = first_half[0]
+    last_hb = hb + 1 == head_blocks
+    s_next, hb_next = (jnp.where(last_hb, s + 1, s),
+                       jnp.where(last_hb, 0, hb + 1))
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    qs = [q_ref[0, g].astype(jnp.float32) * scale for g in range(groups)]
+
+    def page(half, i, masked):
+        """The recurrence over page slot ``i`` of buffer ``half``."""
+        kblk = bufs[0][half, i].astype(jnp.float32)    # [page, bh, D]
+        vblk = kblk if arenas == 1 else bufs[1][half, i].astype(jnp.float32)
+        if masked:    # rows past the end may hold anything: 0 * NaN is NaN
+            first = (n_pages - 1) * page_size
+            valid = first + lax.broadcasted_iota(
+                jnp.int32, (page_size, block_h, 1), 0) <= pos
+            vblk = jnp.where(valid, vblk, 0.0)
+            if arenas == 1:
+                kblk = vblk
+        for g in range(groups):
+            s_blk = jnp.sum(kblk * qs[g][None], axis=-1, keepdims=True)
+            if masked:
+                s_blk = jnp.where(valid, s_blk, _NEG_INF)  # [page, bh, 1]
+            m_prev = m_ref[g, :, :1]                      # [bh, 1]
+            l_prev = l_ref[g, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=0))
+            alpha = jnp.exp(m_prev - m_new)
+            # a masked row's exp(-1e30 - m) is 0: row 0 is always live,
+            # so m_new is a real score from the first page on
+            pexp = jnp.exp(s_blk - m_new[None])
+            l_new = l_prev * alpha + jnp.sum(pexp, axis=0)
+            acc_ref[g] = acc_ref[g] * alpha + jnp.sum(pexp * vblk, axis=0)
+            m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+    def walk(step, carry):
+        half = lax.rem(half0 + step, 2)
+
+        @pl.when(step + 1 < n_steps)
+        def _():
+            copies(s, hb, step + 1, 1 - half, start=True)
+
+        @pl.when((step + 1 == n_steps) & (s_next < pl.num_programs(0)))
+        def _():
+            first_half[0] = 1 - half
+            copies(s_next, hb_next, 0, 1 - half, start=True)
+
+        copies(s, hb, step, half, start=False)
+        first = step * pages_per_step
+
+        def whole_page(i, carry):
+            page(half, i, False)
+            return carry
+
+        lax.fori_loop(0, jnp.minimum(n_full - first, pages_per_step),
+                      whole_page, 0)
+
+        @pl.when((n_full < n_pages) & (n_full - first < pages_per_step))
+        def _():
+            page(half, n_full - first, True)
+        return carry
+
+    lax.fori_loop(0, n_steps, walk, 0)
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :, :1], 1e-30)
+                ).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_arena, v_arena, block_tables, positions,
@@ -166,58 +270,91 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
     ``[S, Hq, D]`` in ``q.dtype``. ``v_arena=None``: ``k_arena`` holds fused
     ``[K | V]`` rows of width ``2 * D``.
     """
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    from ..tuner.space import PAGED_BUFFER_BUDGET, paged_buffer_bytes
 
-    fused = v_arena is None
     if isinstance(k_arena, dict) or isinstance(v_arena, dict):
         raise ValueError(
             "paged_attention kernel reads dense arenas only — the int8 "
             "lane uses the gather implementation (dequantize in-graph)")
-    if fused:       # zeros over the value's lanes; scale by the true D
-        scale = 1.0 / np.sqrt(q.shape[-1]) if scale is None else scale
+    num_heads, head_dim = k_arena.shape[3], q.shape[-1]        # KV heads
+    if q.shape[1] % num_heads:
+        raise ValueError(f"{q.shape[1]} query heads are not a multiple of "
+                         f"the arena's {num_heads} KV heads")
+    interpret = resolve_interpret("paged_attn", interpret)
+    if v_arena is not None and head_dim % 128 and not interpret:
+        # Mosaic copies whole lane tiles out of an arena in HBM: rows
+        # under the lane width go side by side first (a copy of both
+        # arenas, where the device already relaid each out for the call)
+        k_arena = jnp.concatenate([k_arena, v_arena], axis=-1)
+        v_arena = None
+    arenas = 1 if v_arena is None else 2
+    page_size, row = k_arena.shape[2], k_arena.shape[-1]
+    itemsize = jnp.dtype(k_arena.dtype).itemsize
+    if block_h is None:
+        block_h = _tuned_block_h(num_heads, row, page_size, q.dtype)
+    if block_h is None:
+        # all the heads (a page's rows of one layer are then one contiguous
+        # piece of the arena) where a page of them fits the buffers
+        fits = paged_buffer_bytes(1, num_heads, page_size, row, itemsize,
+                                  arenas) <= PAGED_BUFFER_BUDGET
+        block_h = num_heads if fits else 8
+    return _paged_attention(
+        q, k_arena, v_arena, block_tables.astype(jnp.int32),
+        positions.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        scale=1.0 / np.sqrt(head_dim) if scale is None else scale,
+        block_h=_sanitize_block_h(block_h, num_heads, itemsize),
+        interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block_h", "interpret"))
+def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
+                     scale, block_h, interpret):
+    """The call itself, jitted on its own: a program's layers share one
+    trace and one lowered function of it (the layer is an operand)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ..tuner.space import paged_pages_per_step
+
+    fused = v_arena is None
+    arenas = 1 if fused else 2
+    if fused:       # zeros over the value's lanes
         q = jnp.pad(q, ((0, 0), (0, 0), (0, q.shape[-1])))
     s_n, q_heads, head_dim = q.shape
-    num_heads = k_arena.shape[3]                       # KV heads
-    if q_heads % num_heads:
-        raise ValueError(f"{q_heads} query heads are not a multiple of "
-                         f"the arena's {num_heads} KV heads")
+    num_heads, page_size = k_arena.shape[3], k_arena.shape[2]
+    if num_heads % block_h:
+        raise ValueError(f"block_h {block_h} does not divide the arena's "
+                         f"{num_heads} KV heads")
     groups = q_heads // num_heads
-    page_size = k_arena.shape[2]
     pages_per_seq = block_tables.shape[1]
-    if scale is None:
-        scale = 1.0 / np.sqrt(head_dim)
-    if block_h is None:
-        block_h = _tuned_block_h(num_heads, head_dim, page_size, q.dtype)
-    if block_h is None:
-        # heuristic: one full f32 sublane tile of heads per step
-        block_h = 8
-    block_h = _sanitize_block_h(block_h, num_heads,
-                                jnp.dtype(k_arena.dtype).itemsize)
+    pages_per_step = paged_pages_per_step(
+        block_h, page_size, head_dim, jnp.dtype(k_arena.dtype).itemsize,
+        arenas, pages_per_seq)
 
     kernel = functools.partial(
         _paged_attn_kernel, scale=scale, page_size=page_size,
-        pages_per_seq=pages_per_seq, block_h=block_h, groups=groups)
-    bt_flat = block_tables.reshape(-1).astype(jnp.int32)
+        pages_per_seq=pages_per_seq, pages_per_step=pages_per_step,
+        block_h=block_h, head_blocks=num_heads // block_h, groups=groups,
+        arenas=arenas)
+    bt_flat = block_tables.reshape(-1)
     # group-major: q_g[s, g, h] is query head h * groups + g
     q_g = jnp.swapaxes(q.reshape(s_n, num_heads, groups, head_dim), 1, 2)
 
-    def _q_map(s, h, p, bt_ref, len_ref, layer_ref):
+    def _q_map(s, h, bt_ref, len_ref, layer_ref):
         return (s, 0, h, 0)
 
-    def _kv_map(s, h, p, bt_ref, len_ref, layer_ref):
-        # the block-table walk: (physical page id, layer) -> arena block
-        return (bt_ref[s * pages_per_seq + p], layer_ref[0], 0, h, 0)
-
     q_block = (1, groups, block_h, head_dim)
-    kv_block = (1, None, page_size, block_h, head_dim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(s_n, num_heads // block_h, pages_per_seq),
+        grid=(s_n, num_heads // block_h),
         in_specs=[pl.BlockSpec(q_block, _q_map)]
-        + [pl.BlockSpec(kv_block, _kv_map)] * (1 if fused else 2),
+        + [pl.BlockSpec(memory_space=pl.ANY)] * arenas,   # whole, in HBM
         out_specs=pl.BlockSpec(q_block, _q_map),
-        scratch_shapes=[
+        scratch_shapes=[      # a double buffer of pages an arena
+            pltpu.VMEM((2, pages_per_step, page_size, block_h, head_dim),
+                       k_arena.dtype)] * arenas + [
+            pltpu.SemaphoreType.DMA((2, arenas)),
+            pltpu.SMEM((1,), jnp.int32),    # the half a step starts in
             pltpu.VMEM((groups, block_h, head_dim), jnp.float32),  # acc
             pltpu.VMEM((groups, block_h, 128), jnp.float32),  # running max
             pltpu.VMEM((groups, block_h, 128), jnp.float32),  # running sum
@@ -227,10 +364,12 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, groups, num_heads, head_dim),
                                        q.dtype),
-        interpret=resolve_interpret("paged_attn", interpret),
+        # in order: a step starts the next one's first copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
         name="paged_attn",
-    )(bt_flat, positions.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q_g,
+    )(bt_flat, positions, layer, q_g,
       *((k_arena,) if fused else (k_arena, v_arena)))
     out = jnp.swapaxes(out, 1, 2).reshape(s_n, q_heads, head_dim)
     return out[..., head_dim // 2:] if fused else out

@@ -268,6 +268,17 @@ class PagedBatcher(ContinuousBatcher):
         self._publish_pages()
         return n
 
+    def _tick_inner(self) -> int:
+        # what paged_attn's walk covers this tick, from the lengths held
+        # here: the pages up to each request's write position, of the
+        # table rows its slots have
+        page = self.kv.page_size
+        self._stat_add("paged_attn.pages_live", sum(
+            (req.seq_len - 1) // page + 1 for req in self._reqs.values()))
+        self._stat_add("paged_attn.pages_table",
+                       len(self._reqs) * self.kv.pages_per_seq)
+        return super()._tick_inner()
+
     def _ensure_decode_capacity(self):
         """Map the next write position for every active slot before the
         tick — ``+1`` token plain, ``+k+1`` speculative (the verify step

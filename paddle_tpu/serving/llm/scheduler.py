@@ -1313,6 +1313,7 @@ class LLMEngine(DrainableEngineBase):
         # "serving.llm.replica0" payload (and vice versa) when several
         # in-process replicas share one registry.
         pre = self._prefix + "."
+        table = self._registry.get(pre + "paged_attn.pages_table")
         return {
             "stats": self._registry.stats_with_prefix(pre),
             "histograms":
@@ -1335,6 +1336,11 @@ class LLMEngine(DrainableEngineBase):
                        "cow_splits": self._batcher.kv.cow_splits,
                        "pending": len(self._batcher._pending)}
                       if self._config.kv_layout == "paged" else None),
+            # share of the block tables' pages that hold a live row, over
+            # the ticks so far: what paged_attn's walk does not skip
+            "paged_attn_live_page_share": (
+                self._registry.get(pre + "paged_attn.pages_live") / table
+                if table else None),
         }
 
     # -- worker --------------------------------------------------------------

@@ -155,26 +155,62 @@ def paged_block_h_legal(block_h: int, num_heads: int,
                  or block_h % SUBLANE_ROWS[itemsize] == 0))
 
 
+#: VMEM the kernel gives its double-buffered page blocks; what is left of
+#: the default 16 MiB scope holds the compute's own temporaries
+PAGED_BUFFER_BUDGET = 4 * 1024 * 1024
+
+
+def paged_buffer_bytes(pages: int, block_h: int, page_size: int,
+                       head_dim: int, itemsize: int = 4,
+                       arenas: int = 2) -> int:
+    """Bytes of the kernel's double buffers at ``pages`` pages a loop step:
+    two halves an arena, each ``[pages, page_size, block_h, head_dim]``.
+    ``head_dim`` is the arena's row width (``2 * D`` for fused rows)."""
+    return 2 * arenas * pages * page_size * block_h * head_dim * itemsize
+
+
+def paged_pages_per_step(block_h: int, page_size: int, head_dim: int,
+                         itemsize: int = 4, arenas: int = 2,
+                         pages_per_seq: Optional[int] = None) -> int:
+    """Pages the kernel fetches a loop step: the largest power of two whose
+    double buffers (:func:`paged_buffer_bytes`) fit
+    :data:`PAGED_BUFFER_BUDGET`, no more than a sequence has, and 1 where
+    even one page is over the budget."""
+    cap = PAGED_BUFFER_BUDGET // paged_buffer_bytes(
+        1, block_h, page_size, head_dim, itemsize, arenas)
+    if pages_per_seq is not None:
+        cap = min(cap, pages_per_seq)
+    pages = 1
+    while 2 * pages <= cap:
+        pages *= 2
+    return pages
+
+
 def paged_attn_vmem_bytes(block_h: int, page_size: int, head_dim: int,
-                          itemsize: int = 4) -> int:
+                          itemsize: int = 4, arenas: int = 2,
+                          groups: int = 1,
+                          pages_per_seq: Optional[int] = None) -> int:
     """VMEM-resident bytes for one paged-attention program instance: the
-    q/out head block, one K and one V page block, the f32 accumulator and
-    the (block_h, 128)-padded running max/sum scratch."""
-    q_blk = block_h * head_dim * itemsize
-    kv_blk = 2 * page_size * block_h * head_dim * itemsize
-    scores = block_h * page_size * 4
-    acc = block_h * head_dim * 4
-    stats = 2 * block_h * 128 * 4
-    out = block_h * head_dim * itemsize
-    return q_blk + kv_blk + scores + acc + stats + out
+    double-buffered page blocks of each arena (what
+    :func:`paged_pages_per_step` sized), the q and out head blocks (held
+    twice each by the pipeline), the f32 accumulator and the
+    (block_h, 128)-padded running max/sum scratch."""
+    pages = paged_pages_per_step(block_h, page_size, head_dim, itemsize,
+                                 arenas, pages_per_seq)
+    buffers = paged_buffer_bytes(pages, block_h, page_size, head_dim,
+                                 itemsize, arenas)
+    q_out = 2 * 2 * groups * block_h * head_dim * itemsize
+    acc = groups * block_h * head_dim * 4
+    stats = 2 * groups * block_h * 128 * 4
+    return buffers + q_out + acc + stats
 
 
 def paged_attn_candidates(num_heads: int, head_dim: int, page_size: int,
                           itemsize: int = 4) -> List[Dict[str, int]]:
     """block_h candidates for a paged decode-attention shape: the legal
-    head blocks only (:func:`paged_block_h_legal`), VMEM pruned — though
-    at decode page sizes the footprint is tiny, so pruning only bites on
-    pathological page_size * head_dim products."""
+    head blocks only (:func:`paged_block_h_legal`), VMEM pruned (a head
+    block so wide that one double-buffered page of it is over the
+    budget)."""
     out = [{"block_h": b} for b in sorted({*PAGED_BLOCK_H, num_heads})
            if paged_block_h_legal(b, num_heads, itemsize)
            and paged_attn_vmem_bytes(b, page_size, head_dim,

@@ -245,30 +245,120 @@ class TestStepParity:
         self._run("topk")
 
 
+def _gather_lane(q, ka, va, bt, positions, layer=0):
+    """The reference: the pages gathered dense, masked, softmaxed. Query
+    head ``j`` reads KV head ``j // groups``; ``va=None``: fused rows."""
+    if va is None:
+        kg, vg = jnp.split(paged_gather_rows(ka, bt, layer), 2, axis=-1)
+    else:
+        kg = paged_gather_rows(ka, bt, layer)    # [S, pp*page, Hkv, D]
+        vg = paged_gather_rows(va, bt, layer)
+    groups = q.shape[1] // kg.shape[2]
+    kg, vg = jnp.repeat(kg, groups, axis=2), jnp.repeat(vg, groups, axis=2)
+    logits = jnp.einsum("shd,sthd->sht", q / np.sqrt(q.shape[-1]), kg)
+    mask = jnp.arange(kg.shape[1])[None, :] <= positions[:, None]
+    w = jax.nn.softmax(jnp.where(mask[:, None, :], logits, -1e30), axis=-1)
+    return jnp.einsum("sht,sthd->shd", w, vg)
+
+
 class TestPagedAttentionKernel:
-    def test_matches_gather_reference(self):
+    # 6 pages of 4 rows a sequence; tiny rows, so the buffers' budget
+    # allows the whole table and the kernel takes the largest power of
+    # two in it, 4 pages a loop step: the table is a step and a half
+    PAGE, PP, STEP = 4, 6, 4
+    EDGE = STEP * PAGE            # first row of the second loop step
+    POSITIONS = {
+        "first_rows": [0, PAGE - 1, PAGE],
+        "step_edge": [EDGE - 1, EDGE, EDGE - 2],
+        # the table's last row, a dead slot (its table all trash), a
+        # short and a long sequence side by side
+        "mixed_and_dead": [PP * PAGE - 1, 0, 5, EDGE + 1],
+        # a free slot's length keeps counting ticks, past its table: the
+        # walk ends with the table (the last slot's has nothing behind it)
+        "past_the_table": [3, PP * PAGE, PP * PAGE + 1000],
+    }
+
+    def _arenas(self, rng, S, hkv, D, fused, fill=None):
+        num_pages = S * self.PP
+        shape = (num_pages + 1, 2, self.PAGE, hkv, 2 * D if fused else D)
+        make = (lambda: jnp.full(shape, fill, jnp.float32)) if fill \
+            is not None else (lambda: jnp.asarray(
+                rng.standard_normal(shape), jnp.float32))
+        bt = rng.permutation(num_pages).reshape(S, self.PP)
+        return make(), None if fused else make(), bt, num_pages
+
+    def test_these_shapes_take_four_pages_a_step(self):
+        from paddle_tpu.tuner.space import paged_pages_per_step
+        for row, arenas in ((8, 2), (16, 1)):      # two arenas, fused rows
+            assert paged_pages_per_step(2, self.PAGE, row, 4, arenas,
+                                        self.PP) == self.STEP
+
+    @pytest.mark.parametrize("fused", [False, True],
+                             ids=["two_arenas", "fused_rows"])
+    @pytest.mark.parametrize("groups", [1, 4])
+    @pytest.mark.parametrize("case", sorted(POSITIONS))
+    def test_matches_gather_reference(self, case, groups, fused):
         rng = np.random.default_rng(3)
-        S, H, D, page, pp = 3, 4, 8, 4, 3
-        num_pages = S * pp
-        q = jnp.asarray(rng.standard_normal((S, H, D)), jnp.float32)
-        ka = jnp.asarray(rng.standard_normal(
-            (num_pages + 1, 1, page, H, D)), jnp.float32)
-        va = jnp.asarray(rng.standard_normal(ka.shape), jnp.float32)
-        bt = jnp.arange(num_pages, dtype=jnp.int32).reshape(S, pp)
-        positions = jnp.asarray([2, 7, 11], jnp.int32)
-        out = paged_attention(q, ka, va, bt, positions, interpret=True)
-        # reference: gather the pages dense, mask, softmax
-        kg = paged_gather_rows(ka, bt, 0)        # [S, pp*page, H, D]
-        vg = paged_gather_rows(va, bt, 0)
-        scale = 1.0 / np.sqrt(D)
-        mask = (jnp.arange(pp * page)[None, :]
-                <= positions[:, None])           # [S, T]
-        logits = jnp.einsum("shd,sthd->sht", q * scale, kg)
-        logits = jnp.where(mask[:, None, :], logits, -1e30)
-        w = jax.nn.softmax(logits, axis=-1)
-        ref = jnp.einsum("sht,sthd->shd", w, vg)
+        pos = self.POSITIONS[case]
+        S, hkv, D = len(pos), 2, 8
+        ka, va, bt, trash = self._arenas(rng, S, hkv, D, fused)
+        if case == "mixed_and_dead":
+            bt[1] = trash
+        q = jnp.asarray(rng.standard_normal((S, hkv * groups, D)),
+                        jnp.float32)
+        bt, pos = jnp.asarray(bt, jnp.int32), jnp.asarray(pos, jnp.int32)
+        out = paged_attention(q, ka, va, bt, pos, layer=1, interpret=True)
+        ref = _gather_lane(q, ka, va, bt, pos, layer=1)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+
+    def test_head_blocks_share_the_walk(self):
+        # two head blocks a sequence: each step starts the next one's
+        # first copies, across head blocks and across sequences
+        rng = np.random.default_rng(4)
+        pos = [self.EDGE, 2, self.PP * self.PAGE - 1]
+        ka, va, bt, _ = self._arenas(rng, len(pos), 16, 8, False)
+        q = jnp.asarray(rng.standard_normal((len(pos), 16, 8)), jnp.float32)
+        bt, pos = jnp.asarray(bt, jnp.int32), jnp.asarray(pos, jnp.int32)
+        out = paged_attention(q, ka, va, bt, pos, block_h=8,
+                              interpret=True)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(_gather_lane(q, ka, va, bt, pos)),
+            rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("fused", [False, True],
+                             ids=["two_arenas", "fused_rows"])
+    def test_nothing_past_the_end_reaches_the_result(self, fused):
+        """The walk stops at the sequence's end: every page a sequence
+        does not own (the trash page and the other layer among them) and
+        every row past its position is NaN, and the result is the
+        reference's over the live rows."""
+        rng = np.random.default_rng(5)
+        pos = [0, self.PAGE - 1, self.PAGE, self.EDGE - 1, self.EDGE,
+               self.PP * self.PAGE - 2]
+        S, hkv, D, groups = len(pos), 2, 8, 2
+        ka, va, bt, trash = self._arenas(rng, S, hkv, D, fused,
+                                         fill=np.nan)
+        live = np.zeros(ka.shape[:3], bool)           # [pages, L, row]
+        for s, p in enumerate(pos):
+            bt[s, p // self.PAGE + 1:] = trash
+            for j in range(p + 1):
+                live[bt[s, j // self.PAGE], 1, j % self.PAGE] = True
+        rows = jnp.asarray(rng.standard_normal(ka.shape), jnp.float32)
+        ka = jnp.where(live[..., None, None], rows, ka)
+        if not fused:
+            va = jnp.where(live[..., None, None], rows[::-1], va)
+        q = jnp.asarray(rng.standard_normal((S, hkv * groups, D)),
+                        jnp.float32)
+        bt, pos = jnp.asarray(bt, jnp.int32), jnp.asarray(pos, jnp.int32)
+        out = np.asarray(paged_attention(q, ka, va, bt, pos, layer=1,
+                                         interpret=True))
+        assert np.isfinite(out).all()
+        ref = _gather_lane(q, jnp.nan_to_num(ka),
+                           None if fused else jnp.nan_to_num(va), bt, pos,
+                           layer=1)
+        np.testing.assert_allclose(out, np.asarray(ref), rtol=2e-5,
+                                   atol=2e-5)
 
     def test_rejects_int8_arena(self):
         q = jnp.zeros((1, 2, 4), jnp.float32)
@@ -344,6 +434,42 @@ class TestEngineParity:
         paged8.drain(timeout=120)
         assert slot_out == paged_out
         assert kv.pool.pages_in_use == 0
+
+
+class TestLivePageCounters:
+    def test_counts_follow_the_requests_lengths_tick_by_tick(self, model):
+        """``paged_attn.pages_live`` / ``pages_table`` grow each tick by
+        what the lengths of the requests in flight give (the pages up to
+        each write position; a table row of 8 pages a request), and
+        ``stats()`` reports their ratio."""
+        eng = _engine(model, kv_layout="paged", page_size=8)
+        batcher, reg = eng._batcher, eng._registry
+        name = eng._prefix + ".paged_attn.pages_"
+        inner, seen = batcher._tick_inner, []
+
+        def counted_tick():
+            lens = [r.seq_len for r in batcher._reqs.values()]
+            before = reg.get(name + "live"), reg.get(name + "table")
+            n = inner()
+            seen.append((lens, reg.get(name + "live") - before[0],
+                         reg.get(name + "table") - before[1]))
+            return n
+
+        assert eng.stats()["paged_attn_live_page_share"] is None
+        batcher._tick_inner = counted_tick
+        rng = np.random.default_rng(11)
+        futs = [eng.submit(list(rng.integers(0, 64, n)), max_new_tokens=m)
+                for n, m in ((5, 12), (20, 7), (33, 9))]
+        for f in futs:
+            f.result(120)
+        assert len(seen) >= 11 and any(len(t[0]) == 3 for t in seen)
+        for lens, live, table in seen:
+            assert live == sum((n - 1) // 8 + 1 for n in lens)
+            assert table == len(lens) * (64 // 8)
+        share = eng.stats()["paged_attn_live_page_share"]
+        assert share == sum(t[1] for t in seen) / sum(t[2] for t in seen)
+        assert 0 < share < 1
+        eng.drain(timeout=120)
 
 
 class TestPrefixSharing:
@@ -497,6 +623,50 @@ class TestTunerFamily:
         assert key == "paged_attn|cpu|float32|h4|d8|p8"
         cfg = tuner._resolve(key)
         assert cfg and cfg["block_h"] == 4   # committed default winner
+
+
+    @pytest.mark.parametrize("shape,pages", [
+        # (block_h, page, row width, itemsize, arenas, pages a sequence)
+        ((16, 16, 128, 4, 2, 48), 8),     # serve-gpt1p3b-decode
+        ((16, 16, 128, 4, 2, 68), 8),     # serve-gpt1p3b-longprompt
+        ((8, 16, 128, 4, 1, 96), 32),     # serve-lfm2moe-decode, fused
+        ((16, 16, 128, 4, 2, 6), 4),      # no more than the table has
+        ((32, 256, 128, 4, 2, 48), 1),    # one page is over the budget
+    ])
+    def test_pages_a_step_follow_shapes_and_budget(self, shape, pages):
+        from paddle_tpu.tuner import space
+        assert space.paged_pages_per_step(*shape) == pages
+        *dims, table = shape
+        buffers = space.paged_buffer_bytes(pages, *dims)
+        # the largest power of two that fits, unless not even one does
+        assert buffers <= space.PAGED_BUFFER_BUDGET or pages == 1
+        assert 2 * buffers > space.PAGED_BUFFER_BUDGET or 2 * pages > table
+
+    @pytest.mark.parametrize("hkv,groups,fused", [(4, 1, False),
+                                                  (2, 4, True)])
+    def test_vmem_model_is_what_the_kernel_allocates(self, hkv, groups,
+                                                     fused):
+        """``paged_attn_vmem_bytes`` against the traced call: its VMEM
+        scratch, and the q and out blocks the pipeline holds twice."""
+        from paddle_tpu.tuner.space import paged_attn_vmem_bytes
+        page, pp, D = 4, 6, 8
+        row = 2 * D if fused else D
+        arena = jnp.zeros((9, 2, page, hkv, row), jnp.float32)
+        args = (jnp.zeros((2, hkv * groups, D)), arena,
+                None if fused else arena, jnp.zeros((2, pp), jnp.int32),
+                jnp.zeros((2,), jnp.int32))
+        *_, jitted = jax.make_jaxpr(
+            lambda *a: paged_attention(*a, interpret=True))(*args).eqns
+        call, = [e for e in jitted.params["jaxpr"].eqns
+                 if e.primitive.name == "pallas_call"]
+        grid = call.params["grid_mapping"]
+        scratch = call.params["jaxpr"].invars[-grid.num_scratch_operands:]
+        held = sum(v.aval.size * v.aval.dtype.itemsize for v in scratch
+                   if str(v.aval.memory_space) == "vmem")
+        held += 2 * 2 * groups * hkv * row * 4          # q, out: twice each
+        assert held == paged_attn_vmem_bytes(
+            hkv, page, row, 4, arenas=1 if fused else 2, groups=groups,
+            pages_per_seq=pp)
 
 
 class TestAuditEntrypoint:
