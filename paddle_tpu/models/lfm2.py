@@ -170,6 +170,11 @@ class FullSequence:
         return grouped_causal_attention(q, k, v, scale)
 
 
+def swiglu(f, w1, w3, w2):
+    """The gated feed-forward ``(silu(f W1) * (f W3)) W2``."""
+    return (jax.nn.silu(f @ w1) * (f @ w3)) @ w2
+
+
 def _short_conv(cfg, lp, u, view, ci):
     b, c, x = jnp.split(u @ lp["in"], 3, axis=-1)
     return (c * view.conv(ci, b * x, lp["k"])) @ lp["out"]
@@ -203,8 +208,7 @@ def lfm2_block(cfg: LFM2Config, i: int, lp, h, positions, view):
     h = h + op
     f = rms_norm(h, lp["n2"], cfg.norm_eps)
     if i < cfg.num_dense_layers:
-        return h + (jax.nn.silu(f @ lp["w1"]) * (f @ lp["w3"])) @ lp["w2"], \
-            None
+        return h + swiglu(f, lp["w1"], lp["w3"], lp["w2"]), None
     lo = cfg.experts_held[0] if cfg.experts_held else 0
     out, counts = _moe.moe_feed_forward(
         f.reshape(-1, f.shape[-1]), lp["gate"], lp["bias"], lp["w1"],

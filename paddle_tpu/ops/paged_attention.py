@@ -51,6 +51,20 @@ accumulator are the result. Mosaic copies whole 128-lane tiles out of an
 array in HBM, so two arenas of narrower rows are laid side by side for the
 call (a copy; the cache fuses such rows itself to avoid it).
 
+Selected pages: with ``selected=(tables, counts)`` a step walks a table
+of its OWN in place of the sequence's block table: ``tables[s, h, :counts[s,
+h]]``, the physical pages block-sparse attention chose for sequence ``s`` and
+KV head ``h``, in ascending logical order, so that the page of the position
+is last and alone is masked. The walk is the one above (the same double
+buffer, the same hand-over of a step's first copies to the step before it),
+as long as the selection and no longer. Every KV head walks pages of its own,
+so a head's rows of a page must lie together: such an arena is head-major
+with fused rows, ``[P+1, L * Hkv, page, 2 * D]`` (arena row ``layer * Hkv +
+h`` is layer ``layer``'s KV head ``h``), and one copy brings a page's keys
+and values of one head as a ``[page, 2 * D]`` slab. With the ``G`` query
+heads of a KV head on the sublanes, the recurrence is two small MXU products
+a page (``[G, D] x [D, page]`` and ``[G, page] x [page, D]``).
+
 Off-TPU the wrapper runs in interpret mode — the same numerics, so CPU
 tests cover the kernel's math; interpret-mode output matches the gather
 lane to float tolerance (NOT bitwise: the blocked online-softmax sums in
@@ -254,7 +268,8 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
 
 
 def paged_attention(q, k_arena, v_arena, block_tables, positions,
-                    layer=0, scale=None, block_h=None, interpret=None):
+                    layer=0, scale=None, block_h=None, interpret=None,
+                    selected=None):
     """Single-token decode attention through a paged KV arena.
 
     ``q``: ``[S, Hq, D]`` (one query per sequence, already projected);
@@ -269,7 +284,31 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
     ``s`` attends logical rows ``j <= positions[s]``. Returns
     ``[S, Hq, D]`` in ``q.dtype``. ``v_arena=None``: ``k_arena`` holds fused
     ``[K | V]`` rows of width ``2 * D``.
+
+    ``selected=(tables [S, Hkv, K], counts [S, Hkv])``: each sequence and KV
+    head walks ``tables[s, h, :counts[s, h]]`` (physical pages in ascending
+    logical order, the position's page last) in place of its block-table
+    row; ``k_arena`` is then head-major with fused rows, ``[num_pages + 1,
+    num_layers * Hkv, page_size, 2 * D]``, and ``v_arena`` None.
     """
+    if selected is not None:
+        tables, counts = selected
+        if v_arena is not None or k_arena.ndim != 4 \
+                or k_arena.shape[-1] != 2 * q.shape[-1]:
+            raise ValueError(
+                "a selected walk reads a head-major arena of fused rows, "
+                "[P+1, L * Hkv, page, 2 * D], and no second arena")
+        if q.shape[1] % tables.shape[1] or k_arena.shape[1] % tables.shape[1]:
+            raise ValueError(
+                f"{q.shape[1]} query heads or {k_arena.shape[1]} arena rows "
+                f"are not a multiple of the tables' {tables.shape[1]} KV "
+                f"heads")
+        return _selected_attention(
+            q, k_arena, tables.astype(jnp.int32), counts.astype(jnp.int32),
+            positions.astype(jnp.int32),
+            jnp.asarray(layer, jnp.int32).reshape(1),
+            scale=1.0 / np.sqrt(q.shape[-1]) if scale is None else scale,
+            interpret=resolve_interpret("paged_attn", interpret))
     from ..tuner.space import PAGED_BUFFER_BUDGET, paged_buffer_bytes
 
     if isinstance(k_arena, dict) or isinstance(v_arena, dict):
@@ -373,3 +412,169 @@ def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
       *((k_arena,) if fused else (k_arena, v_arena)))
     out = jnp.swapaxes(out, 1, 2).reshape(s_n, q_heads, head_dim)
     return out[..., head_dim // 2:] if fused else out
+
+
+# -- the walk over selected pages ----------------------------------------------
+
+def _selected_attn_kernel(tab_ref, cnt_ref, len_ref, layer_ref, q_ref, hbm,
+                          o_ref, buf, sem, first_half, acc_ref, m_ref, l_ref,
+                          *, page_size, width, pages_per_step, kv_heads,
+                          head_dim):
+    """One sequence's KV head per grid step: the walk of
+    ``_paged_attn_kernel`` over ``tables[s, h, :counts[s, h]]``. A page is a
+    ``[page, 2 * D]`` slab of one head's fused rows; the head's ``G`` query
+    heads ride the sublanes, so the scores of a page are one ``[G, D] x [D,
+    page]`` product and its part of the result one ``[G, page] x [page,
+    D]``."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, h = pl.program_id(0), pl.program_id(1)
+    layer = layer_ref[0]
+    pos = jnp.maximum(len_ref[s], 0)
+    in_last = lax.rem(pos, page_size)        # the position's row in its page
+    n_pages = jnp.clip(cnt_ref[s * kv_heads + h], 0, width)
+    n_full = jnp.where(in_last + 1 == page_size, n_pages, n_pages - 1)
+    n_steps = (n_pages + pages_per_step - 1) // pages_per_step
+
+    def copies(seq, head, step, half, start):
+        first = step * pages_per_step
+        live = jnp.clip(cnt_ref[seq * kv_heads + head], 0, width)
+
+        def one(i, carry):
+            pid = tab_ref[(seq * kv_heads + head) * width + first + i]
+            copy = pltpu.make_async_copy(
+                hbm.at[pid, layer * kv_heads + head], buf.at[half, i],
+                sem.at[half])
+            (copy.start if start else copy.wait)()
+            return carry
+
+        lax.fori_loop(0, jnp.minimum(live - first, pages_per_step), one, 0)
+
+    @pl.when((s == 0) & (h == 0))
+    def _():
+        first_half[0] = 0
+        copies(s, h, 0, 0, start=True)
+
+    half0 = first_half[0]
+    last_h = h + 1 == kv_heads
+    s_next, h_next = jnp.where(last_h, s + 1, s), jnp.where(last_h, 0, h + 1)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    q = q_ref[0, 0].astype(jnp.float32)                 # [G, D], scaled
+
+    def page(half, i, masked):
+        kv = buf[half, i].astype(jnp.float32)           # [page, 2 D]
+        k, v = kv[:, :head_dim], kv[:, head_dim:]
+        scores = lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), precision=lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)         # [G, page]
+        if masked:    # rows past the end may hold anything: 0 * NaN is NaN
+            scores = jnp.where(lax.broadcasted_iota(
+                jnp.int32, scores.shape, 1) <= in_last, scores, _NEG_INF)
+            v = jnp.where(lax.broadcasted_iota(
+                jnp.int32, v.shape, 0) <= in_last, v, 0.0)
+        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        pexp = jnp.exp(scores - m_new)
+        l_new = l_prev * alpha + jnp.sum(pexp, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
+            pexp, v, (((1,), (0,)), ((), ())),
+            precision=lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    def walk(step, carry):
+        half = lax.rem(half0 + step, 2)
+
+        @pl.when(step + 1 < n_steps)
+        def _():
+            copies(s, h, step + 1, 1 - half, start=True)
+
+        @pl.when((step + 1 == n_steps) & (s_next < pl.num_programs(0)))
+        def _():
+            first_half[0] = 1 - half
+            copies(s_next, h_next, 0, 1 - half, start=True)
+
+        copies(s, h, step, half, start=False)
+        first = step * pages_per_step
+
+        def whole_page(i, carry):
+            page(half, i, False)
+            return carry
+
+        lax.fori_loop(0, jnp.minimum(n_full - first, pages_per_step),
+                      whole_page, 0)
+
+        @pl.when((n_full < n_pages) & (n_full - first >= 0)
+                 & (n_full - first < pages_per_step))
+        def _():
+            page(half, n_full - first, True)
+        return carry
+
+    lax.fori_loop(0, n_steps, walk, 0)
+
+    # a step with nothing to walk (an empty slot) still hands the next
+    # step its first copies
+    @pl.when((n_steps == 0) & (s_next < pl.num_programs(0)))
+    def _():
+        copies(s_next, h_next, 0, half0, start=True)
+
+    o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+                   ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _selected_attention(q, arena, tables, counts, positions, layer, *, scale,
+                        interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ..tuner.space import paged_pages_per_step
+
+    s_n, q_heads, head_dim = q.shape
+    kv_heads, width = tables.shape[1], tables.shape[2]
+    groups = q_heads // kv_heads
+    page_size = arena.shape[2]
+    pages_per_step = paged_pages_per_step(
+        1, page_size, 2 * head_dim, jnp.dtype(arena.dtype).itemsize, 1,
+        width)
+    kernel = functools.partial(
+        _selected_attn_kernel, page_size=page_size, width=width,
+        pages_per_step=pages_per_step, kv_heads=kv_heads, head_dim=head_dim)
+    # query head h * groups + g reads KV head h: already head-major
+    q_g = (q * scale).reshape(s_n, kv_heads, groups, head_dim)
+
+    def _q_map(s, h, *_):
+        return (s, h, 0, 0)
+
+    q_block = (1, 1, groups, head_dim)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(s_n, kv_heads),
+        in_specs=[pl.BlockSpec(q_block, _q_map),
+                  pl.BlockSpec(memory_space=pl.ANY)],   # whole, in HBM
+        out_specs=pl.BlockSpec(q_block, _q_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages_per_step, page_size, 2 * head_dim),
+                       arena.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),    # the half a step starts in
+            pltpu.VMEM((groups, head_dim), jnp.float32),      # acc
+            pltpu.VMEM((groups, 128), jnp.float32),           # running max
+            pltpu.VMEM((groups, 128), jnp.float32),           # running sum
+        ])
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s_n, kv_heads, groups, head_dim),
+                                       q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="paged_attn",
+    )(tables.reshape(-1), counts.reshape(-1), positions, layer, q_g, arena)
+    return out.reshape(s_n, q_heads, head_dim)

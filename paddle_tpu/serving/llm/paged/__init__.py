@@ -9,7 +9,9 @@ mirror the slot decode programs with the block table threaded through,
 ``LLMEngineConfig(kv_layout="paged")``. The engine picks the decoder family
 from the model it is given (``paged_decoder_class``): GPT by default,
 ``lfm2`` for the LFM2-MoE family, whose cache also holds a per-slot
-convolution state beside the pages.
+convolution state beside the pages, ``sala`` for MiniCPM-SALA, whose cache
+holds pages for its sparse layers only, their compressed keys by page and a
+linear-attention state a slot.
 """
 from .batcher import PagedBatcher
 from .decode import (GPTPagedDecoder, paged_decoder_class,
@@ -22,6 +24,7 @@ from .pool import (PagedKVCache, PagePool, PagesExhausted,
                    paged_write_rows, pages_for_tokens)
 from .prefix import PagedPrefixEntry, PagedPrefixStore
 from .lfm2 import LFM2PagedDecoder
+from .sala import SALAPagedDecoder
 from .spec import (GPTPagedSpecDecoder, build_paged_spec_decode_step,
                    get_paged_spec_decode_step)
 
@@ -41,6 +44,7 @@ __all__ = [
     "get_paged_tail_prefill_fn",
     "GPTPagedDecoder",
     "LFM2PagedDecoder",
+    "SALAPagedDecoder",
     "paged_decoder_class",
     "register_paged_decoder",
     "build_paged_spec_decode_step",

@@ -31,6 +31,19 @@ deadline logic are inherited unchanged) and replaces the memory policy:
   On a miss, the freshly prefilled sequence's own page-aligned head is
   claimed by the store by refcount, again copying nothing.
 
+- **chunked prefill** (``LLMEngineConfig.prefill_chunk``, a decoder that
+  offers ``chunk_prefill``): an admission maps the whole prompt's pages (one
+  dispatch) and parks the request in ``_prefilling``; every tick then runs
+  at most ONE chunk of the oldest such request before the decode step, so
+  the slots that decode wait a chunk and not a prompt. The slot joins the
+  decode batch after its last chunk, which samples its first token. While
+  it is prefilled the slot's ``finished`` flag is set, which keeps the
+  decode step's writes off it. One compiled chunk program serves every
+  offset. Span ``serving.llm/prefill_chunk``, counters ``prefill_chunks``
+  and ``worker.prefill_chunk_s``, histogram ``prefill_chunk_ms`` (from a
+  chunk's dispatch to the end of the fetch that follows it: the tick's,
+  the first token's, or the chunk's own where nothing decodes).
+
 Gauges: ``<stat_prefix>.pages_free`` and ``.pages_cow_splits`` publish
 the pool state at every admission and tick (the /metricsz view of the
 admission math in docs/serving.md).
@@ -38,7 +51,7 @@ admission math in docs/serving.md).
 from __future__ import annotations
 
 import collections
-from typing import Optional
+from typing import Dict, Optional
 
 import numpy as np
 
@@ -73,6 +86,10 @@ class PagedBatcher(ContinuousBatcher):
                          spec_decoder=spec_decoder, **kw)
         self.kv: PagedKVCache
         self._pending = collections.deque()
+        #: slot -> [request, tokens prefilled, admission time]: requests
+        #: whose prompts are still entering a chunk at a time, oldest first
+        self._prefilling: Dict[int, list] = {}
+        self._chunk_dispatched: Optional[float] = None
         if config.prefix_cache:
             self.prefix_store = PagedPrefixStore(
                 self.kv, registry=registry,
@@ -87,7 +104,7 @@ class PagedBatcher(ContinuousBatcher):
     def active(self) -> int:
         # pending requests count: the worker must keep ticking (ticks
         # free pages) and drain must not exit while any wait for pages
-        return len(self._reqs) + len(self._pending)
+        return len(self._reqs) + len(self._pending) + len(self._prefilling)
 
     @property
     def free_slots(self) -> int:
@@ -116,7 +133,7 @@ class PagedBatcher(ContinuousBatcher):
                 head.fail_expired()
                 continue
             if not self._try_admit(head):
-                if not self._reqs:
+                if not self._reqs and not self._prefilling:
                     # nothing running -> no pages will ever free up;
                     # _try_admit already drained the prefix store, so
                     # this request simply does not fit the pool
@@ -127,7 +144,7 @@ class PagedBatcher(ContinuousBatcher):
             self._pending.popleft()
 
     def _park_or_fail(self, req: GenerationRequest):
-        if not self._reqs:
+        if not self._reqs and not self._prefilling:
             self._fail_oversize(req)
             return
         self._pending.append(req)
@@ -183,7 +200,7 @@ class PagedBatcher(ContinuousBatcher):
         # headroom: one lookahead page per running sequence, so an
         # admission cannot immediately force a mid-stream eviction at
         # the next tick's capacity pass
-        reserve = len(self._reqs)
+        reserve = len(self._reqs) + len(self._prefilling)
         shortfall = need_alloc + reserve - self.kv.pool.free_pages
         if shortfall > 0 and self.prefix_store is not None:
             shortfall -= self.prefix_store.evict_unpinned(shortfall)
@@ -220,6 +237,13 @@ class PagedBatcher(ContinuousBatcher):
                 self.prefix_store.note_copied(self.kv.page_nbytes())
                 self._stat_add("prefix.cow_splits", 1)
             self.kv.ensure_pages(slot, req.prompt_len)
+            if self.config.prefill_chunk is not None:
+                # the prompt enters a chunk a tick; until its last chunk the
+                # decode step must leave the slot alone
+                del self._reqs[slot]
+                self._finished = self._finished.at[slot].set(True)
+                self._prefilling[slot] = [req, 0, t0]
+                return
         with self.phase("prefill", {"req": req.req_id}):
             if entry is not None:
                 req._prefix_entry = entry   # stays pinned until release
@@ -259,6 +283,10 @@ class PagedBatcher(ContinuousBatcher):
 
     # -- per-tick capacity ---------------------------------------------------
     def tick(self) -> int:
+        # a chunk first: a prompt whose last chunk this is decodes in this
+        # very tick, so the capacity pass below must see it
+        if self._prefilling:
+            self._advance_prefill()
         with self.phase("tick_capacity"):
             self._drain_pending()
             self._stat_set("pages_pending_requests", len(self._pending))
@@ -268,16 +296,72 @@ class PagedBatcher(ContinuousBatcher):
         self._publish_pages()
         return n
 
+    def _advance_prefill(self):
+        """One chunk of the oldest request still being prefilled; after its
+        last chunk the request gets its first token and joins the decode
+        batch."""
+        slot = next(iter(self._prefilling))
+        req, start, t0 = self._prefilling[slot]
+        if req.expired:
+            del self._prefilling[slot]
+            self.kv.free(slot)
+            req.fail_expired()
+            self._stat_add("evicted_midstream", 1)
+            return
+        chunk = self.config.prefill_chunk
+        n = min(chunk, req.prompt_len - start)
+        last = start + n == req.prompt_len
+        with self.phase("prefill_chunk", {"req": req.req_id, "start": start,
+                                          "n": n}):
+            padded = np.zeros((1, chunk), np.int32)
+            padded[0, :n] = req.prompt[start:start + n]
+            self._chunk_dispatched = self._clock()
+            nxt, self._finished = self.decoder.chunk_prefill(
+                self.kv, self._params, jnp.asarray(padded), start, n, last,
+                slot, self._finished, pack_sampling([req.sampling]),
+                self._next_key())
+            self._stat_add("prefill_chunks", 1)
+            self.decoder.note_chunk(start, n, self.kv.pages_per_seq,
+                                    self._stat_add)
+            if not last:
+                self._prefilling[slot][1] = start + n
+                if not self._reqs:      # no tick's fetch follows: its own
+                    nxt.block_until_ready()  # noqa: PTA002 -- nothing decodes, so the worker has only this chunk to wait for; the wait bounds the chunk's histogram sample
+                    self._chunk_fetched()
+                return
+            del self._prefilling[slot]
+            self._reqs[slot] = req
+            self._last = self._last.at[jnp.asarray([slot])].set(nxt)
+        self._deliver_first_token(req, slot, nxt, t0)
+
+    def _chunk_fetched(self):
+        """A fetch has ended: the chunk dispatched before it, if any, is
+        done."""
+        if self._chunk_dispatched is not None:
+            self._stat_observe(
+                "prefill_chunk_ms",
+                (self._clock() - self._chunk_dispatched) * 1000.0)
+            self._chunk_dispatched = None
+
+    def _deliver_first_token(self, req, slot, nxt, t0):
+        super()._deliver_first_token(req, slot, nxt, t0)
+        self._chunk_fetched()
+
     def _tick_inner(self) -> int:
         # what paged_attn's walk covers this tick, from the lengths held
         # here: the pages up to each request's write position, of the
         # table rows its slots have
         page = self.kv.page_size
+        if hasattr(self.decoder, "note_lengths"):   # a family's own walks
+            self.decoder.note_lengths(
+                [req.seq_len for req in self._reqs.values()], self._stat_add)
         self._stat_add("paged_attn.pages_live", sum(
             (req.seq_len - 1) // page + 1 for req in self._reqs.values()))
         self._stat_add("paged_attn.pages_table",
                        len(self._reqs) * self.kv.pages_per_seq)
-        return super()._tick_inner()
+        n = super()._tick_inner()
+        self._chunk_fetched()
+        return n
 
     def _ensure_decode_capacity(self):
         """Map the next write position for every active slot before the
@@ -317,12 +401,14 @@ class PagedBatcher(ContinuousBatcher):
 
     def _youngest_other(self, slot: int) -> Optional[int]:
         others = [(s, r) for s, r in self._reqs.items() if s != slot]
+        others += [(s, st[0]) for s, st in self._prefilling.items()]
         if not others:
             return None
         return max(others, key=lambda sr: sr[1].t_enqueue)[0]
 
     def _evict_for_pages(self, slot: int):
-        req = self._reqs.pop(slot)
+        req = (self._prefilling.pop(slot)[0] if slot in self._prefilling
+               else self._reqs.pop(slot))
         self.kv.free(slot)
         self._unpin_prefix(req)
         req.fail(PagesExhausted(
@@ -442,8 +528,18 @@ class PagedBatcher(ContinuousBatcher):
         return True
 
     # -- exits ---------------------------------------------------------------
+    def _drop_prefilling(self):
+        """Detach the requests still being prefilled: slots, pages and
+        states go back, the requests are the caller's."""
+        out = [st[0] for st in self._prefilling.values()]
+        for slot in list(self._prefilling):
+            del self._prefilling[slot]
+            self.kv.free(slot)
+        return out
+
     def evacuate(self):
         out = super().evacuate()
+        out.extend(self._drop_prefilling())   # nothing streamed yet
         while self._pending:
             out.append(self._pending.popleft())
         self._stat_set("pages_pending_requests", 0)
@@ -452,6 +548,8 @@ class PagedBatcher(ContinuousBatcher):
 
     def abort_all(self, exc_factory):
         super().abort_all(exc_factory)
+        for req in self._drop_prefilling():
+            req.fail(exc_factory(req))
         while self._pending:
             req = self._pending.popleft()
             req.fail(exc_factory(req))

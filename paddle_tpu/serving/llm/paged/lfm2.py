@@ -12,7 +12,8 @@ state side by side in one ``PagedKVCache``:
   ``paged/pool.py``: with D = 64 a K-only row would be half a lane row and
   the device would lay the arena out in another order than the kernel
   reads, a whole-arena copy in and out of every program);
-- the convolution state ``[slots, conv layers, L-1, hidden]``: the last
+- the convolution state ``state["conv"]`` ``[slots, conv layers, L-1,
+  hidden]`` (a named row per slot of the cache's ``state``): the last
   ``L-1`` inputs of each short convolution (hidden on the lane axis). The
   prefill writes a slot's whole row from its own prompt (zeros where the
   prompt is shorter than ``L-1``), the decode step rolls it in place, and
@@ -234,8 +235,9 @@ class LFM2PagedDecoder:
             num_slots, len(c.attn_layers), max_seq, c.num_key_value_heads,
             c.head_dim, dtype=self.params()["tok"].dtype,
             page_size=self.page_size, num_pages=self.num_pages,
-            state_shape=(len(c.conv_layers), c.conv_L_cache - 1,
-                         c.hidden_size), fused_kv=True)
+            state_rows={"conv": ("slot", (len(c.conv_layers),
+                                          c.conv_L_cache - 1,
+                                          c.hidden_size))}, fused_kv=True)
 
     def publish_gauges(self, kv: PagedKVCache, stat_set):
         stat_set("conv_state_bytes", kv.state_bytes())
@@ -269,9 +271,9 @@ class LFM2PagedDecoder:
                 slot_ids, finished, samp_vecs, key):
         fn = self.prefill_fn(tokens.shape[0], tokens.shape[1])
         k, state, lengths, finished, nxt = fn(
-            params, tokens, true_lens, kv.k, kv.state, kv.block_tables,
-            kv.lengths, finished, slot_ids, *samp_vecs, key)
-        kv.swap(k, kv.v, lengths, state)
+            params, tokens, true_lens, kv.k, kv.state["conv"],
+            kv.block_tables, kv.lengths, finished, slot_ids, *samp_vecs, key)
+        kv.swap(k, kv.v, lengths, {"conv": state})
         return nxt, finished
 
     def decode_step(self, kv: PagedKVCache, params, finished, last_tokens,
@@ -281,9 +283,9 @@ class LFM2PagedDecoder:
         them (what the host fetches)."""
         fn = self.decode_fn(kv.num_slots, kv.max_seq)
         k, state, lengths, finished, nxt, fetch = fn(
-            params, kv.k, kv.state, kv.block_tables, kv.lengths, finished,
-            last_tokens, *samp_vecs, key)
-        kv.swap(k, kv.v, lengths, state)
+            params, kv.k, kv.state["conv"], kv.block_tables, kv.lengths,
+            finished, last_tokens, *samp_vecs, key)
+        kv.swap(k, kv.v, lengths, {"conv": state})
         return nxt, finished, fetch
 
 

@@ -37,13 +37,18 @@ are deleted by it: :class:`PagedKVCache` installs the outputs at once
 (``swap``), and nothing else may keep a reference to ``kv.k``/``kv.v``
 across a call.
 
-Beside the pages a cache may hold a second kind of per-sequence state: a
-fixed-size ``state`` row per SLOT (``[num_slots, *state_shape]``; a short
-convolution's last inputs, say), for models whose layers do not all keep
-keys and values. It is addressed by slot, not by page: the slot's prefill
-overwrites the whole row (so a reused slot starts from its own prompt,
-never from the last tenant's), every program that takes the arenas takes,
-donates and returns it with them, and freeing the slot frees it.
+Beside the pages a cache may hold further kinds of per-sequence state, for
+models whose layers do not all keep keys and values: ``state`` is a dict of
+named arrays (``state_rows``), each either a fixed-size row per SLOT
+(``("slot", shape)`` -> ``[num_slots, *shape]``: a short convolution's last
+inputs, a linear-attention layer's state) or a row per PAGE (``("page",
+shape)`` -> ``[num_pages + 1, *shape]``: an index over a page's rows, such
+as its compressed keys, which is then allocated, shared, freed and evicted
+with the page, trash page included). A slot's row is addressed by slot: the
+slot's prefill starts it afresh (so a reused slot starts from its own
+prompt, never from the last tenant's) and freeing the slot frees it. Every
+program that takes the arenas takes, donates and returns the whole dict with
+them.
 
 ``fused_kv``: a head size under the 128-lane width (64, say) would give the
 arena a minor axis the device lays out compactly, in another order than the
@@ -61,7 +66,7 @@ from __future__ import annotations
 
 import functools
 import heapq
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -178,6 +183,17 @@ class PagePool:
 @jax.jit
 def _bt_set_entry(bt, slot, idx, pid):
     return bt.at[slot, idx].set(pid)
+
+
+@jax.jit
+def _bt_set_entries(bt, slot, start, pids, n):
+    """Entries ``[start, start + n)`` of a slot's row from the first ``n``
+    of ``pids`` (padded to the row's length): a whole prompt's pages in one
+    dispatch."""
+    idx = jnp.arange(bt.shape[1])
+    fresh = jnp.take(pids, jnp.clip(idx - start, 0, pids.shape[0] - 1))
+    return bt.at[slot].set(jnp.where((idx >= start) & (idx < start + n),
+                                     fresh, bt[slot]))
 
 
 @jax.jit
@@ -362,8 +378,9 @@ class PagedKVCache:
                  num_heads: int, head_dim: int, dtype="float32",
                  kv_dtype: Optional[str] = None, page_size: int = 16,
                  num_pages: Optional[int] = None,
-                 state_shape: Optional[Tuple[int, ...]] = None,
-                 fused_kv: bool = False):
+                 state_rows: Optional[Dict[str, Tuple]] = None,
+                 fused_kv: bool = False,
+                 row_shape: Optional[Tuple[int, ...]] = None):
         if num_slots < 1 or max_seq < 2:
             raise ValueError(
                 f"need num_slots >= 1 and max_seq >= 2, got "
@@ -400,8 +417,12 @@ class PagedKVCache:
         self.fused_kv = bool(fused_kv)
         if self.fused_kv and self.quantized:
             raise ValueError("fused K|V rows are dense only")
-        shape = (self.num_pages + 1, self.num_layers, self.page_size,
-                 self.num_heads, self.head_dim * (2 if fused_kv else 1))
+        # ``row_shape``: a family's own layout of one row of one layer (a
+        # head-major arena counts its heads among the layers and keeps
+        # ``(2 * D,)`` rows); the default is ``[heads, D]``
+        shape = (self.num_pages + 1, self.num_layers, self.page_size) + (
+            tuple(row_shape) if row_shape is not None else (
+                self.num_heads, self.head_dim * (2 if fused_kv else 1)))
         if self.quantized:
             def _zero_buf():
                 return {"q": jnp.zeros(shape, jnp.int8),
@@ -411,12 +432,21 @@ class PagedKVCache:
                 return jnp.zeros(shape, self.dtype)
         self.k = _zero_buf()
         self.v = jnp.zeros((0,), self.dtype) if fused_kv else _zero_buf()
-        #: per-slot fixed-size state beside the pages (None: K and V only)
-        self.state = None if state_shape is None else jnp.zeros(
-            (self.num_slots,) + tuple(state_shape), self.dtype)
+        #: named state beside the pages (None: K and V only)
+        leading = {"slot": self.num_slots, "page": self.num_pages + 1}
+        self.state = None if not state_rows else {
+            name: jnp.zeros((leading[per],) + tuple(shape), self.dtype)
+            for name, (per, shape) in state_rows.items()}
         self.block_tables = jnp.full(
             (self.num_slots, self.pages_per_seq), self.trash, jnp.int32)
         self.lengths = jnp.zeros((self.num_slots,), jnp.int32)
+        # both page-mapping programs compile here, in set-up, whichever of
+        # them a cell's first admissions happen to need (they write the
+        # trash page's id where it already stands)
+        self.block_tables = _bt_set_entry(self.block_tables, 0, 0, self.trash)
+        self.block_tables = _bt_set_entries(
+            self.block_tables, 0, 0,
+            jnp.full((self.pages_per_seq,), self.trash, jnp.int32), 0)
         self.pool = PagePool(self.num_pages)
         self._slot_pages: List[List[int]] = [[] for _ in
                                              range(self.num_slots)]
@@ -500,9 +530,19 @@ class PagedKVCache:
         have = len(self._slot_pages[slot])
         if need <= have:
             return 0
+        if need > self.pages_per_seq:
+            raise ValueError(
+                f"slot {slot} would map {need} pages (max_seq reached)")
         fresh = self.pool.alloc_many(need - have)
-        for pid in fresh:
-            self._map_page(slot, pid)
+        if len(fresh) == 1:     # a decode step's next page: scalars only
+            self._map_page(slot, fresh[0])
+        else:       # a prompt's pages: one dispatch, not one a page
+            self._slot_pages[slot].extend(fresh)
+            padded = np.full(self.pages_per_seq, self.trash, np.int32)
+            padded[:len(fresh)] = fresh
+            self.block_tables = _bt_set_entries(
+                self.block_tables, slot, have, jnp.asarray(padded),
+                len(fresh))
         return len(fresh)
 
     def adopt_shared_page(self, slot: int, pid: int):
@@ -577,9 +617,12 @@ class PagedKVCache:
             (_shapes(k), _shapes(self.k), _shapes(state))
         self.k, self.v, self.lengths, self.state = k, v, lengths, state
 
-    def state_bytes(self) -> int:
-        """Device bytes of the per-slot state (0 without one)."""
-        return 0 if self.state is None else int(self.state.nbytes)
+    def state_bytes(self, name: Optional[str] = None) -> int:
+        """Device bytes of the state beside the pages, or of the one named
+        (0 without it)."""
+        held = self.state or {}
+        return sum(int(v.nbytes) for k, v in held.items()
+                   if name is None or k == name)
 
     def kv_bytes(self) -> int:
         """Device bytes held by the K+V arenas (trash page included).

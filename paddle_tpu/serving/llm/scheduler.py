@@ -252,6 +252,7 @@ class LLMEngineConfig:
                  page_size: int = 16,
                  num_pages: Optional[int] = None,
                  paged_attn_impl: str = "auto",
+                 prefill_chunk: Optional[int] = None,
                  stat_prefix: str = "serving.llm"):
         self.num_slots = int(num_slots)
         self.max_seq = int(max_seq)
@@ -323,6 +324,20 @@ class LLMEngineConfig:
         self.page_size = int(page_size)
         self.num_pages = None if num_pages is None else int(num_pages)
         self.paged_attn_impl = paged_attn_impl
+        # chunked prefill (paged layout, a decoder family that offers
+        # ``chunk_prefill``): a prompt enters in chunks of this many tokens,
+        # at most one chunk between two decode ticks; None admits every
+        # prompt in one bucketed program
+        if prefill_chunk is not None:
+            if kv_layout != "paged":
+                raise NotImplementedError(
+                    "prefill_chunk needs kv_layout='paged'")
+            if not 1 <= int(prefill_chunk) <= self.max_seq:
+                raise ValueError(
+                    f"prefill_chunk must lie in [1, max_seq={self.max_seq}],"
+                    f" got {prefill_chunk}")
+            prefill_chunk = int(prefill_chunk)
+        self.prefill_chunk = prefill_chunk
         if kv_layout == "paged":
             if self.page_size < 1 or self.max_seq % self.page_size:
                 raise ValueError(
@@ -339,8 +354,11 @@ class LLMEngineConfig:
 
     @property
     def max_prompt_len(self) -> int:
-        """Longest admissible prompt: must fit a bucket AND leave room for
-        at least one generated token in the slot."""
+        """Longest admissible prompt: must fit a bucket (chunked prefill
+        has none) AND leave room for at least one generated token in the
+        slot."""
+        if self.prefill_chunk is not None:
+            return self.max_seq - 1
         return min(self.prefill_buckets[-1], self.max_seq - 1)
 
     def bucket_for(self, prompt_len: int) -> int:
@@ -361,6 +379,7 @@ _PHASE_COUNTERS = {
     "admit": ("serving.llm/admit", "worker.admit_s"),
     "admit_pages": ("serving.llm/admit_pages", "worker.admit_pages_s"),
     "prefill": ("serving.llm/prefill", "worker.prefill_dispatch_s"),
+    "prefill_chunk": ("serving.llm/prefill_chunk", "worker.prefill_chunk_s"),
     "first_token_fetch": ("serving.llm/first_token_fetch",
                           "worker.first_token_fetch_s"),
     "tick_capacity": ("serving.llm/tick_capacity", "worker.tick_capacity_s"),
@@ -824,7 +843,12 @@ class ContinuousBatcher:
         t0 = self._clock()
         samp = pack_sampling([SamplingParams()])
         slot0 = jnp.asarray([0], jnp.int32)
-        for lp in self.config.prefill_buckets:
+        chunk = self.config.prefill_chunk
+        if chunk is not None:       # every admission is the chunk program
+            self.decoder.chunk_prefill(
+                self.kv, self._params, jnp.zeros((1, chunk), jnp.int32), 0,
+                chunk, True, 0, self._finished, samp, self._next_key())
+        for lp in (() if chunk is not None else self.config.prefill_buckets):
             self.decoder.prefill(
                 self.kv, self._params, jnp.zeros((1, lp), jnp.int32),
                 jnp.asarray([lp], jnp.int32), slot0,
@@ -914,6 +938,11 @@ class LLMEngine(DrainableEngineBase):
                 num_pages=self._config.num_pages,
                 attn_impl=self._config.paged_attn_impl)
             self._decoder.check_config(self._config)
+            if self._config.prefill_chunk is not None \
+                    and not hasattr(self._decoder, "chunk_prefill"):
+                raise NotImplementedError(
+                    f"{type(self._decoder).__name__} has no chunked "
+                    f"prefill yet: leave prefill_chunk unset")
             spec_decoder = None
             if self._config.spec_k > 0:
                 if draft_model is None:
@@ -1314,6 +1343,7 @@ class LLMEngine(DrainableEngineBase):
         # in-process replicas share one registry.
         pre = self._prefix + "."
         table = self._registry.get(pre + "paged_attn.pages_table")
+        sparse_live = self._registry.get(pre + "sparse_attn.pages_live")
         return {
             "stats": self._registry.stats_with_prefix(pre),
             "histograms":
@@ -1341,6 +1371,11 @@ class LLMEngine(DrainableEngineBase):
             "paged_attn_live_page_share": (
                 self._registry.get(pre + "paged_attn.pages_live") / table
                 if table else None),
+            # share of the live pages that the sparse layers' selections
+            # read, over the ticks so far (1.0: the walk ignores them)
+            "sparse_attn_selected_share": (
+                self._registry.get(pre + "sparse_attn.pages_selected")
+                / sparse_live if sparse_live else None),
         }
 
     # -- worker --------------------------------------------------------------

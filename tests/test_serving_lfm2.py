@@ -223,7 +223,7 @@ def test_prefill_then_decode_logits_match_the_full_forward(seeded, impl,
         return (h[:, 0] @ params["tok"].T, view.kvbuf, view.state,
                 lengths + 1)
 
-    k, state, lengths = kv.k, kv.state, kv.lengths
+    k, state, lengths = kv.k, kv.state["conv"], kv.lengths
     for t in range(plen, 24):
         logits, k, state, lengths = step(
             k, state, lengths, jnp.asarray([row[t], 0], jnp.int32))
@@ -244,8 +244,9 @@ def test_prefill_writes_kv_heads_only_and_zero_pads_the_state(seeded):
     assert kv.v.size == 0
     assert kv.page_nbytes() == 2 * PAGE * n_attn * cfg[
         "num_key_value_heads"] * d * 4
-    assert kv.state.shape == (2, cfg["layer_types"].count("conv"),
-                              cfg["conv_L_cache"] - 1, cfg["hidden_size"])
+    assert kv.state["conv"].shape == (2, cfg["layer_types"].count("conv"),
+                                      cfg["conv_L_cache"] - 1,
+                                      cfg["hidden_size"])
     slot = kv.alloc()
     kv.ensure_pages(slot, 1)
     padded = np.zeros((1, 16), np.int32)
@@ -254,7 +255,7 @@ def test_prefill_writes_kv_heads_only_and_zero_pads_the_state(seeded):
                 jnp.asarray([1], jnp.int32), jnp.asarray([slot], jnp.int32),
                 jnp.zeros((2,), bool), pack_sampling([SamplingParams()]),
                 jax.random.PRNGKey(0))
-    state = np.asarray(kv.state)
+    state = np.asarray(kv.state["conv"])
     assert np.all(state[0, :, 0] == 0)          # z_{-1}: before the prompt
     assert np.all(np.abs(state[0, :, 1]).max(-1) > 0)   # z_0
     assert np.all(state[1] == 0)                # the other slot untouched
