@@ -317,6 +317,12 @@ def phase_serve(net, sizes, counter, rehearsal):
     check(stats1["executable_cache"]["misses"] == misses0,
           f"engine compile counter unmoved after warm-up "
           f"({misses0} -> {stats1['executable_cache']['misses']})")
+    # the decode tick runs one step ahead: all but a batch's first tick
+    share = stats1["tick_overlap_share"]
+    print(f"  smoke: tick_overlap_share {share:.3f}", flush=True)
+    check(share > 0.5,
+          f"most decode ticks were dispatched ahead of the fetch before "
+          f"them (tick_overlap_share {share:.3f} > 0.5)")
 
     # greedy lanes against model.generate on the same prompt
     for p, s in zip(prompts[:-1], streams[:-1]):

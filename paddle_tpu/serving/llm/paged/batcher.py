@@ -17,11 +17,17 @@ deadline logic are inherited unchanged) and replaces the memory policy:
   pool EMPTY of other users fails outright instead of deadlocking.
 
 - **per-tick capacity**: before each tick, one page-table pass maps the
-  next write position (``+k+1`` under speculation) for every active
-  slot. When the pool runs dry mid-stream, unpinned prefix entries are
-  dropped first, then the YOUNGEST request is evicted (least progress
-  lost) — pages reclaimed mid-stream, the slot-path analogue being
-  deadline eviction.
+  write position of the step about to be dispatched (``+k+1`` under
+  speculation) for every active slot. The plain tick runs one step ahead
+  (``ContinuousBatcher.tick``), so with a step unfetched that position is
+  ``req.seq_len``, not ``req.seq_len - 1``. When the pool runs dry
+  mid-stream, unpinned prefix entries are dropped first, then the step in
+  flight is settled (a row run ahead takes no page from anyone: its tokens
+  may end a request and free what is needed), then the YOUNGEST request is
+  evicted (least progress lost) — pages reclaimed mid-stream, the
+  slot-path analogue being deadline eviction. A request whose last row is
+  in flight (it ends by length) gets no page for the row after it: that row
+  is dropped, and writes the trash page or a page the slot holds.
 
 - **prefix sharing is zero-copy**: a :class:`PagedPrefixStore` hit
   adopts full shared pages by table splice (``bytes_shared``), and when
@@ -42,7 +48,11 @@ deadline logic are inherited unchanged) and replaces the memory policy:
   offset. Span ``serving.llm/prefill_chunk``, counters ``prefill_chunks``
   and ``worker.prefill_chunk_s``, histogram ``prefill_chunk_ms`` (from a
   chunk's dispatch to the end of the fetch that follows it: the tick's,
-  the first token's, or the chunk's own where nothing decodes).
+  the first token's, or the chunk's own where nothing decodes). An engine
+  with chunked prefill keeps the tick SERIAL (``_room_ahead``): the chunk
+  between two ticks is the contract, its histogram is defined against a
+  loop that fetches what it dispatched, and the one cell that measures it
+  cannot tell a faster engine from a slower one (``PERF.md`` section 7).
 
 Gauges: ``<stat_prefix>.pages_free`` and ``.pages_cow_splits`` publish
 the pool state at every admission and tick (the /metricsz view of the
@@ -347,35 +357,71 @@ class PagedBatcher(ContinuousBatcher):
         super()._deliver_first_token(req, slot, nxt, t0)
         self._chunk_fetched()
 
-    def _tick_inner(self) -> int:
-        # what paged_attn's walk covers this tick, from the lengths held
+    def _note_step(self, ahead):
+        # what paged_attn's walk covers in this step, from the lengths held
         # here: the pages up to each request's write position, of the
         # table rows its slots have
         page = self.kv.page_size
+        # each request's length as this step sees it, its new row included
+        lens = [req.seq_len + self._behind(ahead, slot, req)
+                for slot, req in self._reqs.items()]
         if hasattr(self.decoder, "note_lengths"):   # a family's own walks
-            self.decoder.note_lengths(
-                [req.seq_len for req in self._reqs.values()], self._stat_add)
-        self._stat_add("paged_attn.pages_live", sum(
-            (req.seq_len - 1) // page + 1 for req in self._reqs.values()))
+            self.decoder.note_lengths(lens, self._stat_add)
+        self._stat_add("paged_attn.pages_live",
+                       sum((n - 1) // page + 1 for n in lens))
         self._stat_add("paged_attn.pages_table",
-                       len(self._reqs) * self.kv.pages_per_seq)
-        n = super()._tick_inner()
+                       len(lens) * self.kv.pages_per_seq)
+
+    def _finish_step(self, step) -> int:
+        n = super()._finish_step(step)
         self._chunk_fetched()
         return n
 
+    def _room_ahead(self) -> bool:
+        """Nothing was in flight and the first step is dispatched: map the
+        row after it for every request, if the pool has those pages free
+        or held by unpinned prefix entries alone. Nobody is evicted for a
+        row run ahead: short of pages the tick stays serial (and evicts as
+        it always did). So does the tick of an engine that prefills in
+        chunks (the module's docstring says why)."""
+        if self.config.prefill_chunk is not None:
+            return False
+        need = {}
+        for slot, req in self._reqs.items():
+            if self._ends_by_length(req):
+                continue
+            tok = min(req.seq_len + 1, self.config.max_seq)
+            if pages_for_tokens(tok, self.kv.page_size) \
+                    > self.kv.mapped_pages(slot):
+                need[slot] = tok
+        short = len(need) - self.kv.pool.free_pages     # a page a request
+        if short > 0 and self.prefix_store is not None:
+            self.prefix_store.evict_unpinned(short)
+        if len(need) > self.kv.pool.free_pages:
+            return False
+        for slot, tok in need.items():
+            self.kv.ensure_pages(slot, tok)
+        return True
+
     def _ensure_decode_capacity(self):
-        """Map the next write position for every active slot before the
-        tick — ``+1`` token plain, ``+k+1`` speculative (the verify step
-        lands k+1 candidate rows). Pool dry: drop unpinned prefix
-        entries, then evict the youngest request; a lone un-mappable
-        sequence finishes with reason 'length' (nothing left to
-        reclaim)."""
+        """Map, for every active slot, the write position of the step about
+        to be dispatched — ``+1`` token plain, ``+k+1`` speculative (the
+        verify step lands k+1 candidate rows); a request with a row in the
+        step in flight is one token further than the host has counted.
+        Pool dry: drop unpinned prefix entries, then settle the step in
+        flight and start over (nobody loses a page to a row run ahead),
+        then evict the youngest request; a lone un-mappable sequence
+        finishes with reason 'length' (nothing left to reclaim)."""
         horizon = (self.spec.k + 1) if self.spec is not None else 1
+        ahead = self._inflight
         for slot in sorted(self._reqs):
             req = self._reqs.get(slot)
             if req is None:
                 continue
-            pos = req.seq_len - 1
+            behind = self._behind(ahead, slot, req)
+            if behind and self._ends_by_length(req):
+                continue    # the row after its last is dropped: no page
+            pos = req.seq_len - 1 + behind
             need_tok = min(pos + horizon, self.config.max_seq)
             while True:
                 try:
@@ -389,6 +435,11 @@ class PagedBatcher(ContinuousBatcher):
                             self.prefix_store.evict_unpinned(
                                 max(1, short)) > 0:
                         continue
+                    if self._inflight is not None:
+                        # what is in flight may end a request and free the
+                        # pages; whoever is evicted has had its tokens
+                        self.settle()
+                        return self._ensure_decode_capacity()
                     victim = self._youngest_other(slot)
                     if victim is None:
                         # this is the only sequence and the pool cannot
@@ -427,12 +478,14 @@ class PagedBatcher(ContinuousBatcher):
 
     def export_all(self):
         """Snapshot-and-detach every live sequence into host-side
-        manifests (worker thread, between ticks). A request still
+        manifests (worker thread, the tick in flight settled first). A
+        request still
         mid-replay from an earlier resume ships payload-free — its
         cache is not yet a faithful transcript, so the target replays
         it instead of splicing. Pending (page-starved) requests ship
         cold. On return the batcher holds none of them."""
         from ...fleet.migrate import SequenceManifest
+        self.settle()       # the cache must hold what the clients have
         sig = self.decoder.prefix_sig(self.kv)
         out = []
         for slot in sorted(self._reqs):
@@ -462,7 +515,8 @@ class PagedBatcher(ContinuousBatcher):
 
     def import_manifest(self, man) -> bool:
         """Splice a migrated sequence into a free slot and arm it for
-        the next tick (worker thread, between ticks). Page-aligned
+        the next tick (worker thread, a closure of the control plane: the
+        tick in flight is settled before it runs). Page-aligned
         prompt-prefix pages this engine already holds are adopted
         zero-copy through the prefix store's chain hash; the rest are
         allocated and filled from the shipped payload. Returns False

@@ -13,7 +13,11 @@ Host<->device traffic per tick is exactly one fetch: the ``[num_slots]``
 next-token vector, which streaming delivery needs on host anyway. Slot
 bookkeeping, finish detection, and deadline eviction are all host-side
 reads of that vector plus counters the scheduler already tracks, so the
-device never round-trips for control flow.
+device never round-trips for control flow. That is also why the tick can
+run one step ahead (:meth:`ContinuousBatcher.tick`): step t+1 is
+dispatched before step t's tokens are fetched, and delivery, finishing and
+eviction follow one tick behind, beside the device's work instead of
+between two pieces of it.
 
 Drain semantics match the classifier engine: ``begin_drain`` (or SIGTERM
 through the chained handler) stops admission, and the worker keeps
@@ -410,6 +414,22 @@ class _Phase:
         return self._span.__exit__(exc_type, exc, tb)
 
 
+class _Step:
+    """One decode step that has been dispatched and not fetched yet: who held
+    each slot when it was dispatched (its tokens go to them, and to no one
+    admitted since), the device value its fetch reads, when it was
+    dispatched, and whether the step before it was still unfetched then."""
+
+    __slots__ = ("reqs", "out", "packed", "t0", "overlapped")
+
+    def __init__(self, reqs, out, packed: bool, t0: float, overlapped: bool):
+        self.reqs = reqs
+        self.out = out
+        self.packed = packed
+        self.t0 = t0
+        self.overlapped = overlapped
+
+
 class ContinuousBatcher:
     """Slot-level scheduling state + the per-tick device interaction.
 
@@ -454,6 +474,10 @@ class ContinuousBatcher:
         self._finished = jnp.zeros((config.num_slots,), jnp.bool_)
         self._last = jnp.zeros((config.num_slots,), jnp.int32)
         self._rng = jax.random.PRNGKey(config.seed)
+        #: the decode step dispatched and not fetched yet: the tick runs one
+        #: ahead (see :meth:`tick`)
+        self._inflight: Optional[_Step] = None
+        self._fetched_at = 0.0      # when the newest fetch of a step ended
         # decode-step FLOPs (measure_mfu): measured lazily at first tick
         self._decode_flops: Optional[float] = None
         self._peak_flops: Optional[float] = None
@@ -605,10 +629,40 @@ class ContinuousBatcher:
         self._maybe_finish(slot, req, tok)
 
     def tick(self) -> int:
-        """One decode tick: advance every slot through THE compiled step
-        (1 token plain, 1..k+1 speculative), deliver tokens, retire
-        finished slots. Returns the number of active sequences advanced."""
+        """One decode tick: every active sequence gets the tokens of one
+        compiled step (1 token plain, 1..k+1 speculative), and finished
+        slots retire. Returns the number of sequences advanced.
+
+        The plain tick runs ONE STEP AHEAD. A step needs nothing from the
+        host but to be dispatched: lengths, finished flags and last tokens
+        are device arrays that each step hands to the next. So a call
+        dispatches step t+1 first and only then fetches, delivers and
+        finishes step t: the device has its next step queued while the
+        worker's Python, the copy to the host and the wake-up run. A call
+        that finds nothing in flight dispatches both steps. What follows
+        from it:
+
+        - a step's tokens go to the requests that held the slots when it
+          was dispatched (``_Step.reqs``); one that has ended, was evicted
+          or forgotten since gets nothing, and a request admitted into the
+          slot since never sees the old occupant's token;
+        - a request that ends at step t has one more row computed in t+1,
+          which is dropped. That row writes the slot's own cache row (a page
+          the slot alone holds, or the trash page), and ``kv.free`` and the
+          next admission's prefill are dispatched after it: the device runs
+          in order, so none of them waits;
+        - whatever touches the slots from outside the tick calls
+          :meth:`settle` first (the control plane, an export or import, an
+          evacuation), and when the batch empties the step in flight is
+          dropped without a wait. An admission leaves it in flight: its own
+          first-token fetch waits for everything queued;
+        - the speculative tick stays serial: how many tokens a verify step
+          accepts, and so where the next one writes, is known only from
+          its fetch. So does every tick of a paged engine that prefills in
+          chunks, and any tick for whose row ahead the pool has no page
+          (``PagedBatcher._room_ahead``)."""
         if not self._reqs:
+            self._drop_inflight()
             return 0
         if self.spec is not None:
             if self._spec_room_ok():
@@ -705,35 +759,82 @@ class ContinuousBatcher:
     def _tick_inner(self) -> int:
         if self.config.measure_mfu and self._decode_flops is None:
             self._measure_decode_flops()
+        step = self._inflight
+        if step is None:
+            # nothing is ahead: this call's own step, then the one after it
+            step = self._dispatch_step(None)
+            run_ahead = self.spec is None and self._room_ahead()
+        else:
+            run_ahead = True
+        self._inflight = self._dispatch_step(step) if run_ahead else None
+        return self._finish_step(step)
+
+    @staticmethod
+    def _behind(ahead: Optional[_Step], slot: int,
+                req: GenerationRequest) -> int:
+        """1 where ``ahead``, a step not fetched yet, holds a row of
+        ``req``: the host's ``req.seq_len`` is then one token behind the
+        device's length of the slot."""
+        return int(ahead is not None and ahead.reqs.get(slot) is req)
+
+    def _room_ahead(self) -> bool:
+        """Whether a second step may be dispatched behind the one just
+        dispatched (the paged lane maps its rows first, if the pool has
+        them to spare)."""
+        return True
+
+    def _note_step(self, ahead: Optional[_Step]):
+        """A step is about to be dispatched behind ``ahead`` (the paged
+        lane counts the pages its attention will walk)."""
+
+    def _dispatch_step(self, ahead: Optional[_Step]) -> _Step:
+        """Enqueue THE compiled step over every slot, behind ``ahead`` (the
+        step still unfetched, or None)."""
         t0 = self._clock()
         with self.phase("tick_dispatch"):
+            self._note_step(ahead)
             nxt, self._finished, *packed = self.decoder.decode_step(
                 self.kv, self._params, self._finished, self._last,
                 self._samp_vecs, self._next_key())
             self._last = nxt
+        # a decoder family may pack its tick counters behind the tokens, so
+        # that they ride the same fetch
+        return _Step(dict(self._reqs), packed[0] if packed else nxt,
+                     bool(packed), t0, ahead is not None)
+
+    def _finish_step(self, step: _Step) -> int:
+        """Fetch ``step``'s tokens, deliver them to the requests that held
+        the slots when it was dispatched and still do, and retire what
+        ended."""
         # THE one host fetch of the tick: the [num_slots] next-token
-        # vector (a decoder family may pack its tick counters behind the
-        # tokens, so that they ride the same fetch). Streaming delivery
-        # and host-side finish detection both consume it, so this sync is
-        # the feature, not an accident.
+        # vector. Streaming delivery and host-side finish detection both
+        # consume it, so this sync is the feature, not an accident.
         with self.phase("tick_fetch"):
-            toks = np.asarray(jax.device_get(packed[0] if packed else nxt))  # noqa: PTA002 -- the single per-tick [num_slots] fetch; token streaming requires host delivery
+            toks = np.asarray(jax.device_get(step.out))  # noqa: PTA002 -- the single per-tick [num_slots] fetch; token streaming requires host delivery
         with self.phase("tick_emit"):
-            n = len(self._reqs)
-            if packed:
-                self.decoder.note_tick(toks[self.config.num_slots:], n,
-                                       self._stat_add)
-            dt = max(self._clock() - t0, 1e-9)
+            live = [(slot, req) for slot, req in step.reqs.items()
+                    if self._reqs.get(slot) is req]
+            n = len(live)
+            if step.packed:
+                self.decoder.note_tick(toks[self.config.num_slots:],
+                                       len(step.reqs), self._stat_add)
+            # the tick's period: since the fetch before it where the step
+            # was dispatched ahead of that fetch, else since its dispatch
+            now = self._clock()
+            dt = max(now - (self._fetched_at if step.overlapped
+                            else step.t0), 1e-9)
+            self._fetched_at = now
             self._stat_observe("decode_tick_ms", dt * 1000.0)
             self._stat_observe("tpot_ms", dt * 1000.0)
             self._stat_add("tokens_generated", n)
+            if step.overlapped:
+                self._stat_add("ticks_overlapped", 1)
             if self._decode_flops and self._peak_flops:
-                # tick wall time includes the sanctioned token fetch, so
-                # this is delivered MFU, not device-only MFU
+                # the period includes the sanctioned token fetch, so this
+                # is delivered MFU, not device-only MFU
                 self._stat_set("mfu",
                                self._decode_flops / dt / self._peak_flops)
-            now = self._clock()
-            for slot, req in list(self._reqs.items()):
+            for slot, req in live:
                 if req.expired:
                     self._evict(slot, req)
                     continue
@@ -746,7 +847,31 @@ class ContinuousBatcher:
                                        (now - req._t_last) * 1000.0)
                 req._t_last = now
                 self._maybe_finish(slot, req, tok)
+        if not self._reqs:
+            self._drop_inflight()
         return n
+
+    def settle(self):
+        """Fetch, deliver and finish the step in flight, if there is one.
+        Whoever touches the slots from outside the tick calls this first
+        and then acts on a batcher whose host state and device state
+        agree: ``_loop_once`` before the control plane's closures (an
+        import among them), ``export_all``, ``evacuate``. A tick settled
+        so is a ``decode_tick`` span (fetch and emit) like any other."""
+        step, self._inflight = self._inflight, None
+        if step is None:
+            return
+        self._stat_add("ticks_settled_early", 1)
+        with _otrace.span("serving.llm/decode_tick"):
+            self._finish_step(step)
+
+    def _drop_inflight(self) -> Optional[_Step]:
+        """Forget the step in flight without waiting for it: no request is
+        left to take its tokens (the batch emptied, or is being failed)."""
+        step, self._inflight = self._inflight, None
+        if step is not None:
+            self._stat_add("ticks_settled_early", 1)
+        return step
 
     def _measure_decode_flops(self):
         """XLA cost analysis of THE decode step (once, at first tick when
@@ -773,6 +898,14 @@ class ContinuousBatcher:
             self._release(slot, req, "length")
         elif req.seq_len >= self.config.max_seq:
             self._release(slot, req, "length")
+
+    def _ends_by_length(self, req: GenerationRequest) -> bool:
+        """True where ``req`` has a row in the step in flight and that row
+        is known to be its last: :meth:`_maybe_finish`'s two length rules,
+        one token on (a replayed request's count is not known from here)."""
+        return req._replay_pos is None and (
+            len(req.tokens) + 1 >= req.sampling.max_new_tokens
+            or req.seq_len + 1 >= self.config.max_seq)
 
     def _unpin_prefix(self, req: GenerationRequest):
         """Drop the request's prefix-store pin (if any) the moment the
@@ -817,7 +950,10 @@ class ContinuousBatcher:
         """Detach every in-flight request WITHOUT failing it — the
         zero-loss half of a hard kill. Slots and prefix pins are
         reclaimed; the futures stay pending for the router's recovery
-        replay (docs/fault_tolerance.md "Zero-loss serving")."""
+        replay (docs/fault_tolerance.md "Zero-loss serving"). The step in
+        flight is settled first: its tokens were computed, so they are
+        delivered rather than computed again by the replay."""
+        self.settle()
         out: List[GenerationRequest] = []
         for slot, req in list(self._reqs.items()):
             del self._reqs[slot]
@@ -827,7 +963,11 @@ class ContinuousBatcher:
         return out
 
     def abort_all(self, exc_factory):
-        """Fail every in-flight sequence (forced shutdown, not drain)."""
+        """Fail every in-flight sequence (forced shutdown, not drain). The
+        step in flight is dropped, not settled: its tokens have no one to
+        go to, and the worker's death handler calls this, where a fetch
+        could raise again."""
+        self._drop_inflight()
         for slot, req in list(self._reqs.items()):
             del self._reqs[slot]
             self.kv.free(slot)
@@ -1001,7 +1141,7 @@ class LLMEngine(DrainableEngineBase):
         self._queue = BatchQueue(max_size=self._config.max_queue)
         # between-tick control plane (docs/fault_tolerance.md "Zero-loss
         # serving"): closures queued here run ON the worker thread at the
-        # top of its loop — never concurrent with a decode tick. The
+        # top of its loop, after the tick in flight is settled. The
         # sequence export/import paths ride this so migration can touch
         # batcher state without a lock on the hot path.
         self._ctl: "collections.deque" = collections.deque()
@@ -1149,8 +1289,10 @@ class LLMEngine(DrainableEngineBase):
     # (docs/fault_tolerance.md "Zero-loss serving")
     def _run_on_worker(self, fn, timeout: float = 30.0):
         """Run ``fn`` on the engine worker at the top of its next loop
-        iteration — i.e. BETWEEN decode ticks, never concurrent with
-        one. Blocks the caller until serviced; re-raises whatever ``fn``
+        iteration, after the tick in flight is settled (the worker calls
+        ``batcher.settle()`` before any closure, so ``fn`` never sees a
+        step dispatched and not delivered). Blocks the caller until
+        serviced; re-raises whatever ``fn``
         raised. A worker that exits first fails the call with
         :class:`EngineKilled` instead of hanging it."""
         if self._stopped.is_set():
@@ -1342,12 +1484,13 @@ class LLMEngine(DrainableEngineBase):
         # "serving.llm.replica0" payload (and vice versa) when several
         # in-process replicas share one registry.
         pre = self._prefix + "."
+        hists = self._registry.histograms_with_prefix(pre)
+        ticks = hists.get(pre + "decode_tick_ms", {}).get("count", 0)
         table = self._registry.get(pre + "paged_attn.pages_table")
         sparse_live = self._registry.get(pre + "sparse_attn.pages_live")
         return {
             "stats": self._registry.stats_with_prefix(pre),
-            "histograms":
-                self._registry.histograms_with_prefix(pre),
+            "histograms": hists,
             "executable_cache": self._cache.stats(),
             "draining": self.draining,
             "queue_depth": len(self._queue),
@@ -1366,6 +1509,11 @@ class LLMEngine(DrainableEngineBase):
                        "cow_splits": self._batcher.kv.cow_splits,
                        "pending": len(self._batcher._pending)}
                       if self._config.kv_layout == "paged" else None),
+            # share of the ticks so far whose step was dispatched while the
+            # step before it was still unfetched (0 on the speculative lane)
+            "tick_overlap_share": (
+                self._registry.get(pre + "ticks_overlapped") / ticks
+                if ticks else None),
             # share of the block tables' pages that hold a live row, over
             # the ticks so far: what paged_attn's walk does not skip
             "paged_attn_live_page_share": (
@@ -1417,7 +1565,11 @@ class LLMEngine(DrainableEngineBase):
         decode tick. True when the worker is to exit (killed, or drained
         dry)."""
         # between-tick control plane: migration export/import closures run
-        # here, on the worker, never mid-tick
+        # here, on the worker, after the tick in flight is settled: a
+        # closure finds every token delivered that the device has computed,
+        # and the host's lengths equal to the device's
+        if self._ctl:
+            self._batcher.settle()
         while self._ctl:
             fn, box, ev = self._ctl.popleft()
             try:

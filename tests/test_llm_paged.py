@@ -438,25 +438,29 @@ class TestEngineParity:
 
 class TestLivePageCounters:
     def test_counts_follow_the_requests_lengths_tick_by_tick(self, model):
-        """``paged_attn.pages_live`` / ``pages_table`` grow each tick by
-        what the lengths of the requests in flight give (the pages up to
-        each write position; a table row of 8 pages a request), and
-        ``stats()`` reports their ratio."""
+        """``paged_attn.pages_live`` / ``pages_table`` grow with each step
+        dispatched by what the lengths of the requests in flight give (the
+        pages up to each write position; a table row of 8 pages a
+        request), and ``stats()`` reports their ratio. The tick runs one
+        step ahead: a request with a row in the step not yet fetched is one
+        token further than the host has counted."""
         eng = _engine(model, kv_layout="paged", page_size=8)
         batcher, reg = eng._batcher, eng._registry
         name = eng._prefix + ".paged_attn.pages_"
-        inner, seen = batcher._tick_inner, []
+        inner, seen = batcher._dispatch_step, []
 
-        def counted_tick():
-            lens = [r.seq_len for r in batcher._reqs.values()]
+        def counted_step(ahead):
+            lens = [r.seq_len + (ahead is not None
+                                 and ahead.reqs.get(slot) is r)
+                    for slot, r in batcher._reqs.items()]
             before = reg.get(name + "live"), reg.get(name + "table")
-            n = inner()
+            step = inner(ahead)
             seen.append((lens, reg.get(name + "live") - before[0],
                          reg.get(name + "table") - before[1]))
-            return n
+            return step
 
         assert eng.stats()["paged_attn_live_page_share"] is None
-        batcher._tick_inner = counted_tick
+        batcher._dispatch_step = counted_step
         rng = np.random.default_rng(11)
         futs = [eng.submit(list(rng.integers(0, 64, n)), max_new_tokens=m)
                 for n, m in ((5, 12), (20, 7), (33, 9))]
