@@ -6,12 +6,14 @@ families natively so BASELINE configs 3 and 5 (BERT finetune, GPT hybrid
 parallel) are expressible inside the framework. ``lfm2`` is the LFM2-MoE
 family (short convolutions, grouped-query rotary attention, sparse
 experts) and ``sala`` is MiniCPM-SALA (block-sparse attention in a few
-layers, decayed linear attention in the rest), both served on the paged
-engine.
+layers, decayed linear attention in the rest) and ``trinity`` is the Trinity
+(``afmoe``) family (sliding-window and full attention layers mixed, sparse
+experts beside a shared expert), all three served on the paged engine.
 """
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM, GPTPretrainingCriterion  # noqa: F401
 from .lfm2 import LFM2Config, LFM2ForCausalLM  # noqa: F401
 from .sala import SALAConfig, MiniCPMSALAForCausalLM  # noqa: F401
+from .trinity import TrinityConfig, TrinityForCausalLM  # noqa: F401
 from .bert import (BertConfig, BertModel,  # noqa: F401
                    BertForSequenceClassification,
                    ErnieConfig, ErnieModel,

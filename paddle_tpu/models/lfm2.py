@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from ..nn import Embedding, LayerList, Linear, MoEFeedForward, RMSNorm
 from ..nn import initializer as I
 from ..nn.layer_base import Layer, ParamAttr
-from ..nn.moe import rms_norm
+from ..nn.moe import rms_norm, swiglu  # noqa: F401 -- re-exported
 from ..ops import moe as _moe
 from ..ops.dispatch import apply
 
@@ -130,15 +130,18 @@ def rope(x, positions, theta: float):
     return x * cos + rot * sin
 
 
-def grouped_causal_attention(q, k, v, scale: float):
+def grouped_causal_attention(q, k, v, scale: float, window=None):
     """Causal attention within whole sequences: ``q`` ``[B, T, Hq, D]``,
     ``k``/``v`` ``[B, T, Hkv, D]``, query head ``j`` reading KV head
-    ``j // (Hq // Hkv)``."""
+    ``j // (Hq // Hkv)``. With ``window`` a query at row ``t`` reads the
+    rows ``t - window < j <= t`` only."""
     b, t, hq, d = q.shape
     hkv = k.shape[2]
     qg = q.reshape(b, t, hkv, hq // hkv, d)
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k) * scale
     causal = jnp.tril(jnp.ones((t, t), bool))
+    if window is not None:
+        causal &= ~jnp.tril(jnp.ones((t, t), bool), -window)
     probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
     return jnp.einsum("bkgqs,bskd->bqkgd", probs, v).reshape(b, t, hq, d)
 
@@ -168,11 +171,6 @@ class FullSequence:
     def attend(self, ai, q, k, v, scale):
         self.kv.append((k, v))
         return grouped_causal_attention(q, k, v, scale)
-
-
-def swiglu(f, w1, w3, w2):
-    """The gated feed-forward ``(silu(f W1) * (f W3)) W2``."""
-    return (jax.nn.silu(f @ w1) * (f @ w3)) @ w2
 
 
 def _short_conv(cfg, lp, u, view, ci):

@@ -21,6 +21,11 @@ def rms_norm(x, w, eps: float):
     return (y * w).astype(x.dtype)
 
 
+def swiglu(f, w1, w3, w2):
+    """The gated feed-forward ``(silu(f W1) * (f W3)) W2`` (raw arrays)."""
+    return (jax.nn.silu(f @ w1) * (f @ w3)) @ w2
+
+
 class RMSNorm(Layer):
     """Root-mean-square norm over the last axis, no bias."""
 
@@ -59,13 +64,19 @@ class MoEFeedForward(Layer):
     score + ``expert_bias``; the weights are the scores alone, normalised
     over the chosen (``norm_topk``) and scaled. ``held = (lo, n)`` says
     which of the ``num_experts`` live here (default: all): routing is over
-    all of them and the result is the held experts' part of the sum."""
+    all of them and the result is the held experts' part of the sum.
+    ``shared`` experts (a count; one SwiGLU of ``shared * width``) are
+    computed for every token, unweighted, by EVERY holder: the result is then
+    ``Shared(x)`` + the held experts' part, and whoever adds the holders'
+    parts up counts the shared one once. ``eps`` is the renormalisation's."""
 
     def __init__(self, hidden: int, width: int, num_experts: int,
                  top_k: int, norm_topk: bool = True, scale: float = 1.0,
-                 held: Optional[Tuple[int, int]] = None):
+                 held: Optional[Tuple[int, int]] = None, shared: int = 0,
+                 eps: float = 1e-6, scope: str = "lfm2"):
         super().__init__()
         self.top_k, self.norm_topk, self.scale = top_k, norm_topk, scale
+        self.eps, self.scope = eps, scope
         self.expert_lo, num_held = held or (0, num_experts)
         if not (0 <= self.expert_lo
                 and self.expert_lo + num_held <= num_experts):
@@ -76,15 +87,21 @@ class MoEFeedForward(Layer):
         self.expert_bias = self.create_parameter(
             [num_experts], default_initializer=I.Constant(0.0))
         self.experts = ExpertStack(num_held, hidden, width)
+        if shared:      # one stack row: the same leaves as an expert's
+            self.shared_experts = ExpertStack(1, hidden, shared * width)
 
     def forward(self, x):
         kw = dict(top_k=self.top_k, norm_topk=self.norm_topk,
-                  scale=self.scale, expert_lo=self.expert_lo)
+                  scale=self.scale, expert_lo=self.expert_lo, eps=self.eps,
+                  scope=self.scope)
 
-        def _ffn(a, gate, bias, w1, w3, w2):
+        def _ffn(a, gate, bias, w1, w3, w2, *shared):
             out, _counts = _moe.moe_feed_forward(
                 a.reshape(-1, a.shape[-1]), gate, bias, w1, w3, w2, **kw)
-            return out.reshape(a.shape)
+            out = out.reshape(a.shape)
+            return out + swiglu(a, *(w[0] for w in shared)) if shared else out
         e = self.experts
+        sh = getattr(self, "shared_experts", None)
         return apply("moe_feed_forward", _ffn, x, self.gate.weight,
-                     self.expert_bias, e.w1, e.w3, e.w2)
+                     self.expert_bias, e.w1, e.w3, e.w2,
+                     *((sh.w1, sh.w3, sh.w2) if sh is not None else ()))

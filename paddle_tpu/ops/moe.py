@@ -51,19 +51,21 @@ K_TILE = 256
 
 
 def route_sigmoid_topk(f, gate_w, expert_bias, top_k: int,
-                       norm_topk: bool = True, scale: float = 1.0):
+                       norm_topk: bool = True, scale: float = 1.0,
+                       eps: float = 1e-6):
     """Sigmoid scores, chosen by score plus bias, weighted by score alone.
 
     ``f``: ``[T, h]``; ``gate_w``: ``[h, E]``; ``expert_bias``: ``[E]``.
     Returns ``(idx [T, k] int32, weights [T, k])``: the ``top_k`` experts of
     ``sigmoid(f @ gate_w) + expert_bias`` and their scores (without the
-    bias), divided by their sum + 1e-6 under ``norm_topk``, times ``scale``.
+    bias), divided by their sum + ``eps`` under ``norm_topk``, times
+    ``scale``.
     """
     s = jax.nn.sigmoid(f @ gate_w)
     _, idx = lax.top_k(s + expert_bias, top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if norm_topk:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return idx.astype(jnp.int32), w * scale
 
 
@@ -210,14 +212,16 @@ def moe_experts(rows, w1, w3, w2, layout, interpret=None):
 
 def moe_feed_forward(f, gate_w, expert_bias, w1, w3, w2, *, top_k: int,
                      norm_topk: bool = True, scale: float = 1.0,
-                     expert_lo: int = 0, interpret=None):
+                     expert_lo: int = 0, interpret=None, eps: float = 1e-6,
+                     scope: str = "lfm2"):
     """The expert layer on ``f`` ``[T, h]``: ``(out [T, h], counts [n])``,
-    ``counts`` the pairs each held expert received."""
-    with jax.named_scope("lfm2/moe_route"):
+    ``counts`` the pairs each held expert received. ``scope`` prefixes the
+    two ``jax.named_scope``s (the calling family's name)."""
+    with jax.named_scope(f"{scope}/moe_route"):
         idx, wts = route_sigmoid_topk(f, gate_w, expert_bias, top_k,
-                                      norm_topk, scale)
+                                      norm_topk, scale, eps)
         layout = group_layout(idx, w1.shape[0], expert_lo)
-    with jax.named_scope("lfm2/moe_experts"):
+    with jax.named_scope(f"{scope}/moe_experts"):
         y = moe_experts(f[layout["src"]], w1, w3, w2, layout, interpret)
         pairs = y[layout["dest"]]                             # [T, k, h]
         out = jnp.sum(jnp.where(layout["valid"][..., None],

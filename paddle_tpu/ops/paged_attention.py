@@ -65,6 +65,14 @@ and values of one head as a ``[page, 2 * D]`` slab. With the ``G`` query
 heads of a KV head on the sublanes, the recurrence is two small MXU products
 a page (``[G, D] x [D, page]`` and ``[G, page] x [page, D]``).
 
+A window: with ``window=W`` query ``s`` attends the ``W`` rows ``positions[s]
+- W < j <= positions[s]`` only, and its walk BEGINS at the page of row
+``max(0, positions[s] - W + 1)``: the pages wholly behind the window cost no
+grid step, no copy and no table read (a cache may have released them and
+pointed their entries elsewhere). The first page walked is computed under
+the window's mask where the window starts inside it, as the last is under
+the length's. ``window=None`` is the walk above, unchanged.
+
 Off-TPU the wrapper runs in interpret mode — the same numerics, so CPU
 tests cover the kernel's math; interpret-mode output matches the gather
 lane to float tolerance (NOT bitwise: the blocked online-softmax sums in
@@ -130,7 +138,7 @@ def _tuned_block_h(num_heads, head_dim, page_size, dtype):
 
 def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
                        scale, page_size, pages_per_seq, pages_per_step,
-                       block_h, head_blocks, groups, arenas):
+                       block_h, head_blocks, groups, arenas, window=None):
     """One sequence's head block per grid step; the page walk is a loop in
     here, over the pages the sequence has rows in and no further. Each
     iteration starts the copies of the next ``pages_per_step`` pages
@@ -165,6 +173,19 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
     pos = position(s)
     n_full = (pos + 1) // page_size          # pages whose every row is live
     n_pages = pos // page_size + 1           # pages with a live row
+
+    def first_page(seq):
+        """Where the walk of ``seq`` begins: the page of the first row its
+        window holds."""
+        return jnp.maximum(position(seq) - (window - 1), 0) // page_size
+
+    if window is not None:
+        # the walk's pages are counted from its first on; a window that
+        # starts inside that page puts it under the mask too
+        lo = jnp.maximum(pos - (window - 1), 0)
+        p0 = lo // page_size
+        n_full, n_pages = n_full - p0, n_pages - p0
+        head = (lax.rem(lo, page_size) != 0).astype(jnp.int32)
     n_steps = (n_pages + pages_per_step - 1) // pages_per_step
 
     def copies(seq, hblk, step, half, start):
@@ -177,8 +198,11 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
             slice(None), pl.ds(pl.multiple_of(hblk * block_h, block_h),
                                block_h))
 
+        begin = 0 if window is None else first_page(seq)
+
         def one(i, carry):
-            rows = (bt_ref[seq * pages_per_seq + first + i], layer) + heads
+            rows = (bt_ref[seq * pages_per_seq + begin + first + i],
+                    layer) + heads
             for a in range(arenas):
                 copy = pltpu.make_async_copy(
                     hbm[a].at[rows], bufs[a].at[half, i], sem.at[half, a])
@@ -188,6 +212,8 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
             return carry
 
         live = position(seq) // page_size + 1
+        if window is not None:
+            live = live - begin
         lax.fori_loop(0, jnp.minimum(live - first, pages_per_step), one, 0)
 
     # the first pages of a grid step are on their way since the step
@@ -208,14 +234,20 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
     l_ref[...] = jnp.zeros_like(l_ref)
     qs = [q_ref[0, g].astype(jnp.float32) * scale for g in range(groups)]
 
-    def page(half, i, masked):
-        """The recurrence over page slot ``i`` of buffer ``half``."""
+    def page(half, i, masked, at=None):
+        """The recurrence over page slot ``i`` of buffer ``half``, the
+        walk's page ``at`` (a window's mask needs to know)."""
         kblk = bufs[0][half, i].astype(jnp.float32)    # [page, bh, D]
         vblk = kblk if arenas == 1 else bufs[1][half, i].astype(jnp.float32)
         if masked:    # rows past the end may hold anything: 0 * NaN is NaN
-            first = (n_pages - 1) * page_size
-            valid = first + lax.broadcasted_iota(
-                jnp.int32, (page_size, block_h, 1), 0) <= pos
+            if window is None:      # only the last page is ever masked
+                first = (n_pages - 1) * page_size
+                valid = first + lax.broadcasted_iota(
+                    jnp.int32, (page_size, block_h, 1), 0) <= pos
+            else:       # the first or the last, or one page that is both
+                row = (p0 + at) * page_size + lax.broadcasted_iota(
+                    jnp.int32, (page_size, block_h, 1), 0)
+                valid = (row >= lo) & (row <= pos)
             vblk = jnp.where(valid, vblk, 0.0)
             if arenas == 1:
                 kblk = vblk
@@ -254,12 +286,30 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
             page(half, i, False)
             return carry
 
-        lax.fori_loop(0, jnp.minimum(n_full - first, pages_per_step),
+        if window is None:
+            lax.fori_loop(0, jnp.minimum(n_full - first, pages_per_step),
+                          whole_page, 0)
+
+            @pl.when((n_full < n_pages) & (n_full - first < pages_per_step))
+            def _():
+                page(half, n_full - first, True)
+            return carry
+
+        # the walk's first page, where the window starts inside it
+        @pl.when((step == 0) & (head == 1))
+        def _():
+            page(half, 0, True, 0)
+
+        lax.fori_loop(jnp.clip(head - first, 0, pages_per_step),
+                      jnp.minimum(n_full - first, pages_per_step),
                       whole_page, 0)
 
-        @pl.when((n_full < n_pages) & (n_full - first < pages_per_step))
+        # the position's page, unless it was the first and is done
+        @pl.when((n_full < n_pages) & (n_full - first >= 0)
+                 & (n_full - first < pages_per_step)
+                 & ((n_full > 0) | (head == 0)))
         def _():
-            page(half, n_full - first, True)
+            page(half, n_full - first, True, n_full)
         return carry
 
     lax.fori_loop(0, n_steps, walk, 0)
@@ -269,7 +319,7 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
 
 def paged_attention(q, k_arena, v_arena, block_tables, positions,
                     layer=0, scale=None, block_h=None, interpret=None,
-                    selected=None):
+                    selected=None, window=None):
     """Single-token decode attention through a paged KV arena.
 
     ``q``: ``[S, Hq, D]`` (one query per sequence, already projected);
@@ -290,7 +340,16 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
     logical order, the position's page last) in place of its block-table
     row; ``k_arena`` is then head-major with fused rows, ``[num_pages + 1,
     num_layers * Hkv, page_size, 2 * D]``, and ``v_arena`` None.
+
+    ``window=W`` (a positive int, the plain walk only): query ``s`` attends
+    rows ``positions[s] - W < j <= positions[s]``, and walks from the page of
+    the first of them.
     """
+    if window is not None and (selected is not None or int(window) < 1):  # noqa: PTA001 -- a python int of the configuration, never a traced value
+        raise ValueError(
+            f"window must be a positive int on the plain walk, got "
+            f"{window!r}" + (" with a selection" if selected is not None
+                             else ""))
     if selected is not None:
         tables, counts = selected
         if v_arena is not None or k_arena.ndim != 4 \
@@ -343,12 +402,13 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
         jnp.asarray(layer, jnp.int32).reshape(1),
         scale=1.0 / np.sqrt(head_dim) if scale is None else scale,
         block_h=_sanitize_block_h(block_h, num_heads, itemsize),
-        interpret=interpret)
+        interpret=interpret, window=None if window is None else int(window))  # noqa: PTA001 -- a python int of the configuration
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block_h", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "block_h", "interpret",
+                                             "window"))
 def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
-                     scale, block_h, interpret):
+                     scale, block_h, interpret, window=None):
     """The call itself, jitted on its own: a program's layers share one
     trace and one lowered function of it (the layer is an operand)."""
     import jax.experimental.pallas as pl
@@ -374,7 +434,7 @@ def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
         _paged_attn_kernel, scale=scale, page_size=page_size,
         pages_per_seq=pages_per_seq, pages_per_step=pages_per_step,
         block_h=block_h, head_blocks=num_heads // block_h, groups=groups,
-        arenas=arenas)
+        arenas=arenas, **({} if window is None else {"window": window}))
     bt_flat = block_tables.reshape(-1)
     # group-major: q_g[s, g, h] is query head h * groups + g
     q_g = jnp.swapaxes(q.reshape(s_n, num_heads, groups, head_dim), 1, 2)

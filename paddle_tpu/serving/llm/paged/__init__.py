@@ -11,7 +11,9 @@ from the model it is given (``paged_decoder_class``): GPT by default,
 ``lfm2`` for the LFM2-MoE family, whose cache also holds a per-slot
 convolution state beside the pages, ``sala`` for MiniCPM-SALA, whose cache
 holds pages for its sparse layers only, their compressed keys by page and a
-linear-attention state a slot.
+linear-attention state a slot, ``trinity`` for the Trinity family, whose
+window and full attention layers keep different pages of one sequence (two
+page groups in one cache).
 """
 from .batcher import PagedBatcher
 from .decode import (GPTPagedDecoder, paged_decoder_class,
@@ -19,16 +21,18 @@ from .decode import (GPTPagedDecoder, paged_decoder_class,
                      build_paged_prefill_fn, build_paged_tail_prefill_fn,
                      get_paged_decode_step, get_paged_prefill_fn,
                      get_paged_tail_prefill_fn)
-from .pool import (PagedKVCache, PagePool, PagesExhausted,
+from .pool import (PagedKVCache, PageGroup, PagePool, PagesExhausted,
                    paged_gather_rows, paged_write_prompt_rows,
                    paged_write_rows, pages_for_tokens)
 from .prefix import PagedPrefixEntry, PagedPrefixStore
 from .lfm2 import LFM2PagedDecoder
 from .sala import SALAPagedDecoder
+from .trinity import TrinityPagedDecoder
 from .spec import (GPTPagedSpecDecoder, build_paged_spec_decode_step,
                    get_paged_spec_decode_step)
 
 __all__ = [
+    "PageGroup",
     "PagePool",
     "PagedKVCache",
     "PagesExhausted",
@@ -45,6 +49,7 @@ __all__ = [
     "GPTPagedDecoder",
     "LFM2PagedDecoder",
     "SALAPagedDecoder",
+    "TrinityPagedDecoder",
     "paged_decoder_class",
     "register_paged_decoder",
     "build_paged_spec_decode_step",

@@ -37,6 +37,16 @@ deadline logic are inherited unchanged) and replaces the memory policy:
   On a miss, the freshly prefilled sequence's own page-aligned head is
   claimed by the store by refcount, again copying nothing.
 
+- **page groups** (``paged/pool.py``): where a cache has a group of window
+  layers beside the group that keeps every page, the admission math above is
+  the FIRST group's (the whole prompt, so that no prompt stalls half way)
+  and every further group is asked for its own need (a window group's is
+  bounded: ``groups_short``). A chunked admission maps a window group's
+  pages as the chunks advance (``ensure_pages`` before a chunk) and gives
+  back what a chunk left behind the window after it (``release_behind``);
+  the decode capacity pass does the same a tick. With one group both do
+  what ``ensure_pages`` always did.
+
 - **chunked prefill** (``LLMEngineConfig.prefill_chunk``, a decoder that
   offers ``chunk_prefill``): an admission maps the whole prompt's pages (one
   dispatch) and parks the request in ``_prefilling``; every tick then runs
@@ -104,6 +114,8 @@ class PagedBatcher(ContinuousBatcher):
             self.prefix_store = PagedPrefixStore(
                 self.kv, registry=registry,
                 stat_prefix=f"{config.stat_prefix}.prefix")
+        #: a group of the cache gives pages back behind a window
+        self._windowed = self.kv.has_window
         self._stat_set("pages_free", self.kv.pool.free_pages)
         self._stat_set("pages_cow_splits", 0)
         if hasattr(decoder, "publish_gauges"):   # a family's own state
@@ -214,12 +226,18 @@ class PagedBatcher(ContinuousBatcher):
         shortfall = need_alloc + reserve - self.kv.pool.free_pages
         if shortfall > 0 and self.prefix_store is not None:
             shortfall -= self.prefix_store.evict_unpinned(shortfall)
-        if shortfall > 0:
+        if shortfall > 0 or self.kv.groups_short(
+                req.prompt_len, self._span(req.prompt_len), reserve):
             if entry is not None:
                 self.prefix_store.unpin(entry)
             return False
         self._admit_paged(req, entry, reuse_n, ext_n, cow_src)
         return True
+
+    def _span(self, prompt_len: int) -> int:
+        """Rows one prefill program writes at most: a chunk, or the
+        prompt."""
+        return min(self.config.prefill_chunk or prompt_len, prompt_len)
 
     def _admit_paged(self, req: GenerationRequest, entry, reuse_n: int,
                      ext_n: int, cow_src: Optional[int]):
@@ -246,7 +264,11 @@ class PagedBatcher(ContinuousBatcher):
                 self.kv.adopt_copied_page(slot, cow_src)
                 self.prefix_store.note_copied(self.kv.page_nbytes())
                 self._stat_add("prefix.cow_splits", 1)
-            self.kv.ensure_pages(slot, req.prompt_len)
+            # a chunked admission reserves the prompt where every page is
+            # kept; a window group's pages come a chunk at a time
+            self.kv.ensure_pages(
+                slot, req.prompt_len,
+                windowed=self.config.prefill_chunk is None)
             if self.config.prefill_chunk is not None:
                 # the prompt enters a chunk a tick; until its last chunk the
                 # decode step must leave the slot alone
@@ -321,6 +343,18 @@ class PagedBatcher(ContinuousBatcher):
         chunk = self.config.prefill_chunk
         n = min(chunk, req.prompt_len - start)
         last = start + n == req.prompt_len
+        try:    # a window group's pages of this chunk (elsewhere: mapped)
+            self.kv.ensure_pages(slot, start + n)
+        except PagesExhausted:
+            # the requests that decode hold the pages and end; the others
+            # that wait here hold none of a window group's
+            if self._reqs:
+                self._stat_add("prefill_chunk_stalls", 1)
+                return
+            del self._prefilling[slot]
+            self.kv.free(slot)
+            self._fail_oversize(req)
+            return
         with self.phase("prefill_chunk", {"req": req.req_id, "start": start,
                                           "n": n}):
             padded = np.zeros((1, chunk), np.int32)
@@ -333,6 +367,8 @@ class PagedBatcher(ContinuousBatcher):
             self._stat_add("prefill_chunks", 1)
             self.decoder.note_chunk(start, n, self.kv.pages_per_seq,
                                     self._stat_add)
+            # the next query is at row start + n: what lies behind its window
+            self.kv.release_behind(slot, start + n)
             if not last:
                 self._prefilling[slot][1] = start + n
                 if not self._reqs:      # no tick's fetch follows: its own
@@ -371,6 +407,12 @@ class PagedBatcher(ContinuousBatcher):
                        sum((n - 1) // page + 1 for n in lens))
         self._stat_add("paged_attn.pages_table",
                        len(lens) * self.kv.pages_per_seq)
+        if self._windowed:      # what the window groups hold, sampled
+            held, unbounded = self.kv.window_pages()
+            self._stat_add("kv_pages.window_held", held)
+            self._stat_add("kv_pages.window_unbounded", unbounded)
+            self._stat_set("kv_pages.window_released",
+                           self.kv.window_released)
 
     def _finish_step(self, step) -> int:
         n = super()._finish_step(step)
@@ -399,8 +441,11 @@ class PagedBatcher(ContinuousBatcher):
             self.prefix_store.evict_unpinned(short)
         if len(need) > self.kv.pool.free_pages:
             return False
-        for slot, tok in need.items():
-            self.kv.ensure_pages(slot, tok)
+        try:
+            for slot, tok in need.items():
+                self.kv.ensure_pages(slot, tok)
+        except PagesExhausted:      # a further group's pool: stay serial
+            return False
         return True
 
     def _ensure_decode_capacity(self):
@@ -423,6 +468,10 @@ class PagedBatcher(ContinuousBatcher):
                 continue    # the row after its last is dropped: no page
             pos = req.seq_len - 1 + behind
             need_tok = min(pos + horizon, self.config.max_seq)
+            # a window group gives back what the step's query, at row pos,
+            # no longer reads, before it is asked for the step's page
+            if self._windowed:
+                self.kv.release_behind(slot, pos)
             while True:
                 try:
                     self.kv.ensure_pages(slot, need_tok)

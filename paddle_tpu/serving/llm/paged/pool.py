@@ -50,6 +50,19 @@ prompt, never from the last tenant's) and freeing the slot frees it. Every
 program that takes the arenas takes, donates and returns the whole dict with
 them.
 
+**Page groups.** Layers of one model need not keep the same pages of a
+sequence: a sliding-window layer reads the last ``W`` rows only. A cache is
+therefore a list of page groups (:class:`PageGroup`), each with its layers,
+its own arena ``[pages + 1, len(layers), page, H, D]``, its own
+:class:`PagePool`, block tables and trash page, and an optional window. A
+group without a window keeps every page; a group with one maps only the
+pages that hold rows a later query still reads (``ensure_pages``) and gives
+back those that fall wholly behind the window (``release_behind``), during
+decode and between the chunks of a prefill. The default, ONE group of all
+the layers and no window, is the cache described above: ``kv.k``, ``kv.v``,
+``kv.block_tables``, ``kv.pool`` are the first group's, which is all the GPT,
+LFM2 and SALA programs read.
+
 ``fused_kv``: a head size under the 128-lane width (64, say) would give the
 arena a minor axis the device lays out compactly, in another order than the
 row-major one the paged kernel reads, and every program would copy the
@@ -66,7 +79,8 @@ from __future__ import annotations
 
 import functools
 import heapq
-from typing import Dict, List, Optional, Tuple
+from dataclasses import dataclass
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -362,6 +376,48 @@ def pages_for_tokens(n_tokens: int, page_size: int) -> int:
     return -(-int(n_tokens) // int(page_size))
 
 
+@dataclass(frozen=True)
+class PageGroup:
+    """Layers that keep the same pages of a sequence: ``layers`` (the
+    model's indices, in arena order), ``window`` (None: every page; ``W``:
+    the pages that hold the last ``W`` rows) and ``num_pages`` (None: the
+    worst case, every slot at its most)."""
+    layers: Tuple[int, ...]
+    window: Optional[int] = None
+    num_pages: Optional[int] = None
+
+
+class _GroupState:
+    """One group's device arrays and host bookkeeping. ``slot_pages[s]``
+    are the physical pages of slot ``s``'s logical pages ``first[s] ..``
+    (``first`` stays 0 in a group without a window)."""
+
+    def __init__(self, group: PageGroup, num_slots: int, pages_per_seq: int,
+                 num_pages: int, zero_buf, v_buf):
+        self.layers, self.window = tuple(group.layers), group.window
+        self.num_pages = int(num_pages)
+        self.trash = self.num_pages            # physical junk-sink page
+        self.k = zero_buf(self.num_pages + 1, len(self.layers))
+        self.v = v_buf(self.num_pages + 1, len(self.layers))
+        self.block_tables = jnp.full((num_slots, pages_per_seq), self.trash,
+                                     jnp.int32)
+        self.pool = PagePool(self.num_pages)
+        self.slot_pages: List[List[int]] = [[] for _ in range(num_slots)]
+        self.first: List[int] = [0] * num_slots
+
+    def reset_tables(self):
+        self.block_tables = jnp.full(self.block_tables.shape, self.trash,
+                                     jnp.int32)
+
+
+def window_page_bound(window: int, span: int, page_size: int) -> int:
+    """The most pages a window group maps for one slot at any moment, when
+    at most ``span`` rows are written between two ``release_behind``: the
+    window's rows, the rows being written, and a page's slack at either
+    end."""
+    return -(-(window + span) // page_size) + 2
+
+
 class PagedKVCache:
     """Paged per-slot KV storage: one shared page arena + per-slot block
     tables + the same device ``lengths`` vector StaticKVCache threads.
@@ -380,7 +436,8 @@ class PagedKVCache:
                  num_pages: Optional[int] = None,
                  state_rows: Optional[Dict[str, Tuple]] = None,
                  fused_kv: bool = False,
-                 row_shape: Optional[Tuple[int, ...]] = None):
+                 row_shape: Optional[Tuple[int, ...]] = None,
+                 groups: Optional[Sequence[PageGroup]] = None):
         if num_slots < 1 or max_seq < 2:
             raise ValueError(
                 f"need num_slots >= 1 and max_seq >= 2, got "
@@ -401,16 +458,16 @@ class PagedKVCache:
         self.head_dim = int(head_dim)
         self.page_size = int(page_size)
         self.pages_per_seq = self.max_seq // self.page_size
-        if num_pages is None:
-            # worst case: every slot fully grown — byte parity with the
-            # static cache; real deployments size this far smaller
-            num_pages = self.num_slots * self.pages_per_seq
-        if num_pages < self.pages_per_seq:
+        if groups is None:      # today's cache: every layer, every page
+            groups = [PageGroup(tuple(range(self.num_layers)), None,
+                                num_pages)]
+        elif num_pages is not None:
+            raise ValueError("give each group its own num_pages")
+        if sorted(i for g in groups for i in g.layers) != list(
+                range(self.num_layers)):
             raise ValueError(
-                f"num_pages {num_pages} cannot hold even one full "
-                f"sequence ({self.pages_per_seq} pages)")
-        self.num_pages = int(num_pages)
-        self.trash = self.num_pages            # physical junk-sink page
+                f"the groups' layers {[g.layers for g in groups]} are not "
+                f"the {self.num_layers} layers, each once")
         self.dtype = jnp.dtype(dtype)
         self.kv_dtype = kv_dtype
         self.quantized = kv_dtype == "int8"
@@ -420,25 +477,47 @@ class PagedKVCache:
         # ``row_shape``: a family's own layout of one row of one layer (a
         # head-major arena counts its heads among the layers and keeps
         # ``(2 * D,)`` rows); the default is ``[heads, D]``
-        shape = (self.num_pages + 1, self.num_layers, self.page_size) + (
-            tuple(row_shape) if row_shape is not None else (
-                self.num_heads, self.head_dim * (2 if fused_kv else 1)))
-        if self.quantized:
-            def _zero_buf():
+        row = tuple(row_shape) if row_shape is not None else (
+            self.num_heads, self.head_dim * (2 if fused_kv else 1))
+
+        def _zero_buf(pages, layers):
+            shape = (pages, layers, self.page_size) + row
+            if self.quantized:
                 return {"q": jnp.zeros(shape, jnp.int8),
                         "s": jnp.zeros(shape[:3], jnp.float32)}
-        else:
-            def _zero_buf():
-                return jnp.zeros(shape, self.dtype)
-        self.k = _zero_buf()
-        self.v = jnp.zeros((0,), self.dtype) if fused_kv else _zero_buf()
+            return jnp.zeros(shape, self.dtype)
+
+        def _v_buf(pages, layers):
+            return (jnp.zeros((0,), self.dtype) if fused_kv
+                    else _zero_buf(pages, layers))
+
+        #: the page groups, the first of them behind ``k``/``v``/
+        #: ``block_tables``/``pool``
+        self.groups: List[_GroupState] = []
+        for g in groups:
+            if g.window is not None and g.window < 1:
+                raise ValueError(f"window {g.window} < 1")
+            n = g.num_pages
+            if n is None:
+                # worst case: every slot fully grown — byte parity with the
+                # static cache; real deployments size this far smaller (a
+                # family sizes its window groups by their bound)
+                n = self.num_slots * self.pages_per_seq
+            least = self.pages_per_seq if g.window is None else min(
+                self.pages_per_seq, pages_for_tokens(g.window,
+                                                     self.page_size) + 1)
+            if n < least:
+                raise ValueError(
+                    f"num_pages {n} cannot hold even one full "
+                    f"sequence ({least} pages)")
+            self.groups.append(_GroupState(g, self.num_slots,
+                                           self.pages_per_seq, n, _zero_buf,
+                                           _v_buf))
         #: named state beside the pages (None: K and V only)
         leading = {"slot": self.num_slots, "page": self.num_pages + 1}
         self.state = None if not state_rows else {
             name: jnp.zeros((leading[per],) + tuple(shape), self.dtype)
             for name, (per, shape) in state_rows.items()}
-        self.block_tables = jnp.full(
-            (self.num_slots, self.pages_per_seq), self.trash, jnp.int32)
         self.lengths = jnp.zeros((self.num_slots,), jnp.int32)
         # both page-mapping programs compile here, in set-up, whichever of
         # them a cell's first admissions happen to need (they write the
@@ -447,13 +526,25 @@ class PagedKVCache:
         self.block_tables = _bt_set_entries(
             self.block_tables, 0, 0,
             jnp.full((self.pages_per_seq,), self.trash, jnp.int32), 0)
-        self.pool = PagePool(self.num_pages)
-        self._slot_pages: List[List[int]] = [[] for _ in
-                                             range(self.num_slots)]
         self._free: List[int] = list(range(self.num_slots))
         self._active: set = set()
         #: copy-on-write splits performed (admission divergence)
         self.cow_splits = 0
+        #: pages window groups gave back behind their windows
+        self.window_released = 0
+
+    # -- the first group, under the names a cache of one group has ------------
+    k = property(lambda self: self.groups[0].k,
+                 lambda self, x: setattr(self.groups[0], "k", x))
+    v = property(lambda self: self.groups[0].v,
+                 lambda self, x: setattr(self.groups[0], "v", x))
+    block_tables = property(
+        lambda self: self.groups[0].block_tables,
+        lambda self, x: setattr(self.groups[0], "block_tables", x))
+    pool = property(lambda self: self.groups[0].pool)
+    num_pages = property(lambda self: self.groups[0].num_pages)
+    trash = property(lambda self: self.groups[0].trash)
+    _slot_pages = property(lambda self: self.groups[0].slot_pages)
 
     # -- slot lifecycle (host side) ------------------------------------------
     @property
@@ -480,11 +571,11 @@ class PagedKVCache:
             raise ValueError(
                 f"slot {slot} is not active (double free?)")
         self._active.discard(slot)
-        for pid in self._slot_pages[slot]:
-            self.pool.release(pid)
-        self._slot_pages[slot] = []
-        self.block_tables = _bt_reset_row(self.block_tables, slot,
-                                          self.trash)
+        for g in self.groups:
+            for pid in g.slot_pages[slot]:
+                g.pool.release(pid)
+            g.slot_pages[slot], g.first[slot] = [], 0
+            g.block_tables = _bt_reset_row(g.block_tables, slot, g.trash)
         self._free.append(slot)
         self._free.sort()
 
@@ -496,10 +587,11 @@ class PagedKVCache:
             self.free(slot)
         self._free = list(range(self.num_slots))
         self._active.clear()
-        self._slot_pages = [[] for _ in range(self.num_slots)]
-        self.pool.reset()
-        self.block_tables = jnp.full(
-            (self.num_slots, self.pages_per_seq), self.trash, jnp.int32)
+        for g in self.groups:
+            g.slot_pages = [[] for _ in range(self.num_slots)]
+            g.first = [0] * self.num_slots
+            g.pool.reset()
+            g.reset_tables()
         self.lengths = jnp.zeros((self.num_slots,), jnp.int32)
 
     # -- page mapping (host decides, device block table records) -------------
@@ -512,6 +604,10 @@ class PagedKVCache:
     def slot_page_ids(self, slot: int) -> Tuple[int, ...]:
         return tuple(self._slot_pages[slot])
 
+    @property
+    def has_window(self) -> bool:
+        return any(g.window is not None for g in self.groups)
+
     def _map_page(self, slot: int, pid: int):
         idx = len(self._slot_pages[slot])
         if idx >= self.pages_per_seq:
@@ -521,29 +617,109 @@ class PagedKVCache:
         self.block_tables = _bt_set_entry(self.block_tables, slot, idx,
                                           pid)
 
-    def ensure_pages(self, slot: int, n_tokens: int) -> int:
-        """Map fresh pages so logical rows ``[0, n_tokens)`` are backed;
-        returns how many pages were newly allocated. Atomic: raises
-        :class:`PagesExhausted` without mapping anything when the pool
-        cannot cover the need (callers evict and retry)."""
+    def ensure_pages(self, slot: int, n_tokens: int,
+                     windowed: bool = True) -> int:
+        """Map fresh pages so logical rows ``[0, n_tokens)`` are backed, in
+        every group: from the slot's first page in a group that keeps them
+        all, and behind what the group has mapped so far in a group with a
+        window (what :meth:`release_behind` gave back stays given back).
+        ``windowed=False`` leaves the window groups alone: a chunked
+        admission reserves the whole prompt where every page is kept and
+        maps a window group's pages as the chunks advance. Returns how many
+        pages were newly allocated. Atomic: raises :class:`PagesExhausted`
+        without mapping anything when a pool cannot cover its group's need
+        (callers evict and retry)."""
         need = pages_for_tokens(n_tokens, self.page_size)
-        have = len(self._slot_pages[slot])
-        if need <= have:
+        plan = []
+        for g in self.groups:
+            start = g.first[slot] + len(g.slot_pages[slot])
+            if need > start and (windowed or g.window is None):
+                plan.append((g, start, need - start))
+        if not plan:
             return 0
         if need > self.pages_per_seq:
             raise ValueError(
                 f"slot {slot} would map {need} pages (max_seq reached)")
-        fresh = self.pool.alloc_many(need - have)
-        if len(fresh) == 1:     # a decode step's next page: scalars only
-            self._map_page(slot, fresh[0])
-        else:       # a prompt's pages: one dispatch, not one a page
-            self._slot_pages[slot].extend(fresh)
-            padded = np.full(self.pages_per_seq, self.trash, np.int32)
-            padded[:len(fresh)] = fresh
-            self.block_tables = _bt_set_entries(
-                self.block_tables, slot, have, jnp.asarray(padded),
-                len(fresh))
-        return len(fresh)
+        for g, _, n in plan:
+            if n > g.pool.free_pages:
+                raise PagesExhausted(
+                    f"need {n} pages, only {g.pool.free_pages} of "
+                    f"{g.pool.num_pages} free")
+        for g, start, n in plan:
+            fresh = g.pool.alloc_many(n)
+            g.slot_pages[slot].extend(fresh)
+            if n == 1:      # a decode step's next page: scalars only
+                g.block_tables = _bt_set_entry(g.block_tables, slot, start,
+                                               fresh[0])
+            else:       # a prompt's pages: one dispatch, not one a page
+                padded = np.full(self.pages_per_seq, g.trash, np.int32)
+                padded[:n] = fresh
+                g.block_tables = _bt_set_entries(
+                    g.block_tables, slot, start, jnp.asarray(padded), n)
+        return sum(n for _, _, n in plan)
+
+    def _release_pages(self, g: _GroupState, slot: int, n: int):
+        """Give the first ``n`` mapped pages of ``slot`` in group ``g`` back
+        to its pool and point their table entries at the trash page, in one
+        dispatch."""
+        if n <= 0:
+            return
+        for pid in g.slot_pages[slot][:n]:
+            g.pool.release(pid)
+        del g.slot_pages[slot][:n]
+        g.block_tables = _bt_set_entries(
+            g.block_tables, slot, g.first[slot],
+            jnp.full((self.pages_per_seq,), g.trash, jnp.int32), n)
+        g.first[slot] += n
+        self.window_released += n
+
+    def release_behind(self, slot: int, next_row: int) -> int:
+        """The next query of ``slot`` is at row ``next_row`` or later: every
+        window group returns the pages that lie wholly before the first row
+        that query reads, ``next_row - W + 1``. Returns the pages released
+        (0 in a cache whose groups keep every page)."""
+        released = 0
+        for g in self.groups:
+            if g.window is None:
+                continue
+            keep_from = max(0, next_row - g.window + 1) // self.page_size
+            n = min(keep_from - g.first[slot], len(g.slot_pages[slot]))
+            self._release_pages(g, slot, n)
+            released += max(n, 0)
+        return released
+
+    def window_pages(self) -> Tuple[int, int]:
+        """``(held, unbounded)``: the pages the window groups hold mapped,
+        and what they would hold if they kept every page of the same
+        sequences."""
+        held = unbounded = 0
+        for g in self.groups:
+            if g.window is not None:
+                for first, pids in zip(g.first, g.slot_pages):
+                    held += len(pids)
+                    unbounded += (first + len(pids)) if pids else 0
+        return held, unbounded
+
+    def groups_short(self, n_tokens: int, span: int, reserve: int) -> bool:
+        """Whether a group beyond the first lacks what admitting a prompt of
+        ``n_tokens`` (``span`` rows written at a time) needs, ``reserve``
+        pages kept back: the prompt's pages in a group that keeps them all;
+        in a window group its bound, out of what the pool has free less
+        what the slots already admitted may still claim up to theirs (a
+        window group's pages are mapped as a prompt advances, not at its
+        admission). The first group is the admission's own page math."""
+        pages = pages_for_tokens(n_tokens, self.page_size)
+        for g in self.groups[1:]:
+            need, free = pages, g.pool.free_pages
+            if g.window is not None:
+                bound = min(self.pages_per_seq, window_page_bound(
+                    g.window, span, self.page_size))
+                need = min(pages, bound)
+                free -= sum(max(0, bound - len(g.slot_pages[s]))
+                            for s in self._active)
+            if need + reserve > free:
+                return True
+        return False
 
     def adopt_shared_page(self, slot: int, pid: int):
         """Splice an already-live page (a prefix-store page) into the
@@ -617,6 +793,15 @@ class PagedKVCache:
             (_shapes(k), _shapes(self.k), _shapes(state))
         self.k, self.v, self.lengths, self.state = k, v, lengths, state
 
+    def swap_groups(self, ks, vs, lengths):
+        """:meth:`swap` for a cache of several groups: ``ks[i]``, ``vs[i]``
+        are group ``i``'s arenas as a program returned them."""
+        assert [a.shape for a in ks] == [g.k.shape for g in self.groups] \
+            and [a.shape for a in vs] == [g.v.shape for g in self.groups]
+        for g, k, v in zip(self.groups, ks, vs):
+            g.k, g.v = k, v
+        self.lengths = lengths
+
     def state_bytes(self, name: Optional[str] = None) -> int:
         """Device bytes of the state beside the pages, or of the one named
         (0 without it)."""
@@ -628,12 +813,17 @@ class PagedKVCache:
         """Device bytes held by the K+V arenas (trash page included).
         Shape arithmetic only, so it is safe from any thread: an array
         a program has just taken by donation still knows its size."""
-        return kv_nbytes(self.k) + kv_nbytes(self.v)
+        return sum(kv_nbytes(g.k) + kv_nbytes(g.v) for g in self.groups)
+
+    def group_bytes(self, windowed: bool) -> int:
+        """Device bytes of the groups with a window, or of those without."""
+        return sum(kv_nbytes(g.k) + kv_nbytes(g.v) for g in self.groups
+                   if (g.window is not None) == windowed)
 
     def page_nbytes(self) -> int:
         """Device bytes of ONE physical page across both arenas and all
         layers — the unit the bytes_shared/bytes_copied counters count."""
-        return self.kv_bytes() // (self.num_pages + 1)
+        return (kv_nbytes(self.k) + kv_nbytes(self.v)) // (self.num_pages + 1)
 
     def host_lengths(self) -> np.ndarray:
         """One deliberate device->host fetch of the per-slot lengths

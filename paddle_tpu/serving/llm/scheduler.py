@@ -1488,6 +1488,9 @@ class LLMEngine(DrainableEngineBase):
         ticks = hists.get(pre + "decode_tick_ms", {}).get("count", 0)
         table = self._registry.get(pre + "paged_attn.pages_table")
         sparse_live = self._registry.get(pre + "sparse_attn.pages_live")
+        window_live = self._registry.get(pre + "window_attn.pages_live")
+        window_unbounded = self._registry.get(
+            pre + "kv_pages.window_unbounded")
         return {
             "stats": self._registry.stats_with_prefix(pre),
             "histograms": hists,
@@ -1524,6 +1527,15 @@ class LLMEngine(DrainableEngineBase):
             "sparse_attn_selected_share": (
                 self._registry.get(pre + "sparse_attn.pages_selected")
                 / sparse_live if sparse_live else None),
+            # share of the live pages that the window layers' walks read,
+            # and of the pages one group would hold that the window group
+            # holds mapped, over the ticks so far (1.0: the window ignored)
+            "window_attn_walked_share": (
+                self._registry.get(pre + "window_attn.pages_walked")
+                / window_live if window_live else None),
+            "window_held_page_share": (
+                self._registry.get(pre + "kv_pages.window_held")
+                / window_unbounded if window_unbounded else None),
         }
 
     # -- worker --------------------------------------------------------------

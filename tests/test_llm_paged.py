@@ -16,6 +16,7 @@ from paddle_tpu.serving.llm.decode import (_AUDIT_SPEC, _audit_params,
                                            build_decode_step,
                                            build_prefill_fn)
 from paddle_tpu.serving.llm.paged import (GPTPagedDecoder, PagedKVCache,
+                                          PageGroup,
                                           PagePool, PagesExhausted,
                                           build_paged_decode_step,
                                           build_paged_prefill_fn,
@@ -159,6 +160,73 @@ class TestPagedKVCache:
         assert kv.pool.pages_in_use == 0
 
 
+class TestPageGroups:
+    """Two kinds of layer keep different pages of one sequence."""
+    PAGE, WINDOW = 4, 8
+
+    def _kv(self, window_pages=10, slots=2, max_seq=176):
+        return PagedKVCache(
+            num_slots=slots, num_layers=3, max_seq=max_seq, num_heads=2,
+            head_dim=4, page_size=self.PAGE, groups=[
+                PageGroup((1,), None, slots * max_seq // self.PAGE),
+                PageGroup((0, 2), self.WINDOW, window_pages)])
+
+    def test_each_group_has_its_own_arena_pool_and_tables(self):
+        kv = self._kv()
+        full, window = kv.groups
+        assert full.k.shape == (89, 1, 4, 2, 4)
+        assert window.k.shape == (11, 2, 4, 2, 4) == window.v.shape
+        assert (full.trash, window.trash) == (88, 10)
+        assert kv.k is full.k and kv.block_tables is full.block_tables
+        assert (np.asarray(window.block_tables) == 10).all()
+        assert kv.has_window and kv.kv_bytes() == 2 * (89 + 2 * 11) * 128
+        assert kv.group_bytes(windowed=True) == 2 * 2 * 11 * 128
+
+    @pytest.mark.parametrize("span", [1, 4, 8, 16])
+    def test_a_window_group_never_holds_more_than_its_bound(self, span):
+        """A sequence grows to 20 windows and 160 rows, ``span`` rows at a
+        time (decode steps, chunks up to two windows long): mapped before
+        the rows are written, released behind the window after."""
+        from paddle_tpu.serving.llm.paged.pool import window_page_bound
+        kv = self._kv()
+        full, window = kv.groups
+        bound = window_page_bound(self.WINDOW, span, self.PAGE)
+        assert bound == -(-(self.WINDOW + span) // self.PAGE) + 2
+        slot = kv.alloc()
+        for n in range(span, 20 * self.WINDOW + 1, span):
+            kv.ensure_pages(slot, n)
+            assert len(window.slot_pages[slot]) <= bound
+            assert len(full.slot_pages[slot]) == -(-n // self.PAGE)
+            kv.release_behind(slot, n)
+            first = max(0, n - self.WINDOW + 1) // self.PAGE
+            assert window.first[slot] == first
+            table = np.asarray(window.block_tables[slot])
+            assert (table[:first] == window.trash).all()
+            assert (table[first:-(-n // self.PAGE)] != window.trash).all()
+        assert kv.window_released == window.first[slot] >= 37
+        assert kv.window_pages() == (len(window.slot_pages[slot]), 40)
+
+    def test_released_pages_serve_another_slot_and_free_walks_the_groups(
+            self):
+        kv = self._kv(window_pages=4)
+        full, window = kv.groups
+        a, b = kv.alloc(), kv.alloc()
+        kv.ensure_pages(a, 16)               # all 4 of the window pool
+        with pytest.raises(PagesExhausted):
+            kv.ensure_pages(b, 4)
+        assert not full.slot_pages[b]        # atomic across the groups
+        assert kv.release_behind(a, 16) == 2     # rows 0-7 are behind
+        kv.ensure_pages(b, 8)
+        assert sorted(window.slot_pages[b]) == [0, 1]    # a's first two
+        kv.free(a)
+        assert window.pool.pages_in_use == 2 and full.pool.pages_in_use == 2
+        assert window.first[a] == 0
+        assert (np.asarray(window.block_tables[a]) == window.trash).all()
+        kv.reset()
+        assert not window.pool.pages_in_use and not full.pool.pages_in_use
+        assert kv.free_slots == 2
+
+
 class TestStaticKVCacheDoubleFree:
     """Satellite regression: free() must reject a stale slot id instead
     of corrupting the free list (a double-freed slot handed to two
@@ -245,9 +313,10 @@ class TestStepParity:
         self._run("topk")
 
 
-def _gather_lane(q, ka, va, bt, positions, layer=0):
+def _gather_lane(q, ka, va, bt, positions, layer=0, window=None):
     """The reference: the pages gathered dense, masked, softmaxed. Query
-    head ``j`` reads KV head ``j // groups``; ``va=None``: fused rows."""
+    head ``j`` reads KV head ``j // groups``; ``va=None``: fused rows;
+    ``window``: rows behind it are masked too."""
     if va is None:
         kg, vg = jnp.split(paged_gather_rows(ka, bt, layer), 2, axis=-1)
     else:
@@ -256,7 +325,12 @@ def _gather_lane(q, ka, va, bt, positions, layer=0):
     groups = q.shape[1] // kg.shape[2]
     kg, vg = jnp.repeat(kg, groups, axis=2), jnp.repeat(vg, groups, axis=2)
     logits = jnp.einsum("shd,sthd->sht", q / np.sqrt(q.shape[-1]), kg)
-    mask = jnp.arange(kg.shape[1])[None, :] <= positions[:, None]
+    at = jnp.arange(kg.shape[1])[None, :]
+    # a walk ends with its table, whatever the position says
+    ends = jnp.minimum(positions, kg.shape[1] - 1)[:, None]
+    mask = at <= positions[:, None]
+    if window is not None:
+        mask &= at > ends - window
     w = jax.nn.softmax(jnp.where(mask[:, None, :], logits, -1e30), axis=-1)
     return jnp.einsum("sht,sthd->shd", w, vg)
 
@@ -311,6 +385,66 @@ class TestPagedAttentionKernel:
         ref = _gather_lane(q, ka, va, bt, pos, layer=1)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("fused", [False, True],
+                             ids=["two_arenas", "fused_rows"])
+    @pytest.mark.parametrize("window", [1, 3, 4, 9, 24])
+    @pytest.mark.parametrize("case", sorted(POSITIONS))
+    def test_windowed_walk_matches_gather_reference(self, case, window,
+                                                    fused):
+        """Windows of a row, under a page, of a page, over two and of the
+        whole table, at the same positions: the walk begins at the
+        window's first page and masks the rows before it there."""
+        rng = np.random.default_rng(6)
+        pos = self.POSITIONS[case]
+        S, hkv, D, groups = len(pos), 2, 8, 2
+        ka, va, bt, trash = self._arenas(rng, S, hkv, D, fused)
+        if case == "mixed_and_dead":
+            bt[1] = trash
+        q = jnp.asarray(rng.standard_normal((S, hkv * groups, D)),
+                        jnp.float32)
+        bt, pos = jnp.asarray(bt, jnp.int32), jnp.asarray(pos, jnp.int32)
+        out = paged_attention(q, ka, va, bt, pos, layer=1, window=window,
+                              interpret=True)
+        ref = _gather_lane(q, ka, va, bt, pos, layer=1, window=window)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("window", [2, 4, 7])
+    def test_nothing_behind_the_window_reaches_the_result(self, window):
+        """The walk begins at the window: every page wholly behind it is
+        given back (its table entry is the trash page's) and holds NaN, as
+        do the rows before the window in its first page and past the
+        position in its last, and the result is the reference's over the
+        window's rows. A step for such a page, or a copy of it, would put
+        NaN in the result."""
+        rng = np.random.default_rng(7)
+        pos = [0, self.PAGE - 1, self.PAGE, self.EDGE - 1, self.EDGE,
+               self.PP * self.PAGE - 2]
+        S, hkv, D, groups = len(pos), 2, 8, 2
+        ka, va, bt, trash = self._arenas(rng, S, hkv, D, False, fill=np.nan)
+        live = np.zeros(ka.shape[:3], bool)           # [pages, L, row]
+        clean = bt.copy()
+        for s, p in enumerate(pos):
+            first = max(0, p - window + 1)
+            for j in range(first, p + 1):
+                live[bt[s, j // self.PAGE], 1, j % self.PAGE] = True
+            bt[s, :first // self.PAGE] = trash
+            bt[s, p // self.PAGE + 1:] = trash
+        rows = jnp.asarray(rng.standard_normal(ka.shape), jnp.float32)
+        ka = jnp.where(live[..., None, None], rows, ka)
+        va = jnp.where(live[..., None, None], rows[::-1], va)
+        q = jnp.asarray(rng.standard_normal((S, hkv * groups, D)),
+                        jnp.float32)
+        pos = jnp.asarray(pos, jnp.int32)
+        out = np.asarray(paged_attention(q, ka, va, jnp.asarray(bt), pos,
+                                         layer=1, window=window,
+                                         interpret=True))
+        assert np.isfinite(out).all()
+        ref = _gather_lane(q, jnp.nan_to_num(ka), jnp.nan_to_num(va),
+                           jnp.asarray(clean), pos, layer=1, window=window)
+        np.testing.assert_allclose(out, np.asarray(ref), rtol=2e-5,
+                                   atol=2e-5)
 
     def test_head_blocks_share_the_walk(self):
         # two head blocks a sequence: each step starts the next one's

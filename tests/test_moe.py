@@ -107,3 +107,61 @@ class TestMoE:
                          paddle.to_tensor(w2, stop_gradient=False))
         (out * out).sum().backward()
         assert x.grad is not None and np.abs(x.grad.numpy()).sum() > 0
+
+
+class TestSharedExpertBesideAHeldShare:
+    """``nn.MoEFeedForward``: the held experts' part of the routed sum, a
+    shared expert every holder computes, the renormalisation's epsilon."""
+
+    def _layer(self, held, shared, eps=1e-6, seed=3):
+        from paddle_tpu.nn import MoEFeedForward
+        paddle.seed(seed)
+        layer = MoEFeedForward(16, 8, 8, 2, True, 1.5, held=held,
+                               shared=shared, eps=eps, scope="trinity")
+        rng = np.random.RandomState(seed)
+        whole = {m: rng.randn(8, *shape).astype(np.float32) * 0.3
+                 for m, shape in (("w1", (16, 8)), ("w3", (16, 8)),
+                                  ("w2", (8, 16)))}
+        lo, n = held or (0, 8)
+        layer.gate.weight.set_value(rng.randn(16, 8).astype(np.float32))
+        layer.expert_bias.set_value(
+            rng.randn(8).astype(np.float32) * 0.02)
+        for m, w in whole.items():
+            getattr(layer.experts, m).set_value(w[lo:lo + n])
+        if shared:
+            for m, shape in (("w1", (1, 16, 8 * shared)),
+                             ("w3", (1, 16, 8 * shared)),
+                             ("w2", (1, 8 * shared, 16))):
+                getattr(layer.shared_experts, m).set_value(
+                    rng.randn(*shape).astype(np.float32) * 0.3)
+        return layer
+
+    @pytest.mark.parametrize("shared", [0, 1, 2])
+    @pytest.mark.parametrize("cuts", [((0, 8),), ((0, 4), (4, 4)),
+                                      ((0, 1), (1, 5), (6, 2))])
+    def test_shares_add_up_with_the_shared_expert_counted_once(self, cuts,
+                                                               shared):
+        x = np.random.RandomState(0).randn(12, 16).astype(np.float32)
+        with paddle.no_grad():
+            whole = self._layer(None, shared)(paddle.to_tensor(x)).numpy()
+            routed = self._layer(None, 0)(paddle.to_tensor(x)).numpy()
+            parts = [self._layer(cut, shared)(paddle.to_tensor(x)).numpy()
+                     for cut in cuts]
+        once = whole - routed                   # the shared expert's part
+        total = sum(p - once for p in parts) + once
+        np.testing.assert_allclose(total, whole, atol=2e-5, rtol=0)
+        if shared:
+            assert np.abs(once).max() > 1e-2
+            layer = self._layer(cuts[0], shared)
+            assert layer.shared_experts.w1.shape == [1, 16, 8 * shared]
+
+    def test_eps_is_a_keyword_and_its_default_is_lfm2s(self):
+        from paddle_tpu.nn import MoEFeedForward
+        assert MoEFeedForward(16, 8, 8, 2).eps == 1e-6
+        assert MoEFeedForward(16, 8, 8, 2).scope == "lfm2"
+        assert not hasattr(MoEFeedForward(16, 8, 8, 2), "shared_experts")
+        x = np.full((3, 16), 1e-9, np.float32)
+        with paddle.no_grad():
+            a = self._layer(None, 0, eps=1e-6)(paddle.to_tensor(x)).numpy()
+            b = self._layer(None, 0, eps=1e-20)(paddle.to_tensor(x)).numpy()
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-9)
