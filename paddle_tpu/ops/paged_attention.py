@@ -21,10 +21,11 @@ loops over them ``pages_per_step`` at a time, a trip count that is the
 sequence's own. Each iteration starts one ``make_async_copy`` a live
 page and arena (``arena[page_id, layer, :, heads]`` -> a slot of the
 other half of a double buffer in VMEM), waits for the copies of its own
-half and runs the online-softmax recurrence over its pages, page by
-page in table order, with the running statistics (m, l, acc) in VMEM
-scratch. A page past the live ones is never indexed, fetched or
-computed. The last iteration of a step also starts the first copies of
+half and runs the online-softmax recurrence over its pages in table
+order (a page at a time, or several stacked: "Grouped-query heads"
+below), with the running statistics (m, l, acc) in VMEM scratch. A page
+past the live ones is never indexed, fetched or computed. The last
+iteration of a step also starts the first copies of
 the NEXT grid step (the grid runs in order), so no step but the first
 waits for a copy nothing overlaps.
 
@@ -37,19 +38,47 @@ past the end reaches the result whatever lies there.
 
 Grouped-query heads: with ``G = Hq // Hkv`` query heads to each KV head
 (query head ``j`` reads KV head ``j // G``), the block over heads walks KV
-heads, and each page fetched serves its ``G`` query heads: ``q`` rides
-in as ``[S, G, Hkv, D]`` (group-major, so that every group is a
-``[block_h, D]`` slab laid out like a K row) and the recurrence runs once a
-group on the one page. ``G = 1`` is plain multi-head attention.
+heads, and each page fetched serves its ``G`` query heads. ``G = 1`` is
+plain multi-head attention.
+
+The recurrence over a fetched page is one of two, chosen from the call's
+shapes by ``tuner.space.paged_recurrence`` (no option, no tuner key):
+
+- ``"vpu"``: ``G = 1``, and a grouped shape whose page is not whole sublane
+  tiles or does not fit the buffers. With one query row a KV head there is
+  nothing for the MXU to amortize: the recurrence stays in the arena's
+  ``[page, heads, D]`` layout, a multiply and a lane reduction for ``q . k``,
+  a lane broadcast and a major-axis sum for ``p . v``, a page at a time.
+  ``q`` rides in as ``[S, G, Hkv, D]`` (group-major, so that every group is a
+  ``[block_h, D]`` slab laid out like a K row) and a grouped shape runs the
+  recurrence once a group on the one page.
+- ``"mxu"``: ``G > 1``. The arena reaches the call as ``[P+1, L, page * Hkv,
+  D]``, a page as its flat rows (row ``r`` is token ``r // Hkv``, KV head ``r
+  % Hkv``: the arena's own memory order, so the reshape is a bitcast and a
+  page's copy one contiguous piece), all the KV heads a grid step. The scores
+  of a page for ALL ``Hq`` query heads are one product ``[Hq, D] x [D, page *
+  Hkv]`` at ``Precision.HIGHEST``; the entries of another KV head's rows are
+  pushed to ``-1e30`` by a bias built once a grid step (the MXU's columns are
+  idle at ``Hq`` rows anyway, so the ``Hkv``-fold redundancy costs no pass)
+  and weigh exactly 0 in ``p . v``, ``[Hq, page * Hkv] x [page * Hkv, D]``;
+  running max and sum are a column a query head. The whole pages of a loop
+  step are STACKED into one product (``tuner.space.paged_stack_pages``: the
+  step's pages while the score block stays under 512 KiB, then the powers of
+  two under that for what is left), because a page's chain of product,
+  reductions, ``exp`` and product is latency the next page's cannot hide: a
+  product a page ran SLOWER than the VPU form at LFM2's shape and the stacked
+  one 2.1x faster, 5.4x at Trinity's (PERF.md, PR 35). The other way round
+  (the page streamed against standing queries, ``[page * Hkv, D] x [D, Hq]``)
+  measured 1.5x slower than this: its score block is quarter-filled vregs.
 
 Fused rows: with ``v_arena=None`` the one arena's rows hold a head's key
 and value side by side (``[..., Hkv, 2 * D]``, what the cache keeps for a
 head size under the lane width); ``q`` is padded with zeros over the value's
-lanes, so the same multiply and lane reduction give ``q . k``, the page is
-fetched once and serves as both operands, and the value's lanes of the
-accumulator are the result. Mosaic copies whole 128-lane tiles out of an
-array in HBM, so two arenas of narrower rows are laid side by side for the
-call (a copy; the cache fuses such rows itself to avoid it).
+lanes, so the same contraction over a row (either recurrence's) gives ``q .
+k``, the page is fetched once and serves as both operands, and the value's
+lanes of the accumulator are the result. Mosaic copies whole 128-lane tiles
+out of an array in HBM, so two arenas of narrower rows are laid side by side
+for the call (a copy; the cache fuses such rows itself to avoid it).
 
 Selected pages: with ``selected=(tables, counts)`` a step walks a table
 of its OWN in place of the sequence's block table: ``tables[s, h, :counts[s,
@@ -86,7 +115,8 @@ sublane tile that divides the head count, or all the heads
 entries; an unknown shape takes all the heads where a page of them fits
 the buffers (a page's rows of one layer are then one contiguous piece of
 the arena: at 16 heads of 128 the all-heads walk timed 2.07 ms against
-3.10 ms for two blocks of 8, PERF.md PR 29). ``pages_per_step`` is no
+3.10 ms for two blocks of 8, PERF.md PR 29); a shape on the MXU recurrence
+takes all its KV heads whatever block was asked for. ``pages_per_step`` is no
 knob: ``tuner.space.paged_pages_per_step`` derives it, the largest power
 of two whose double buffers fit ``PAGED_BUFFER_BUDGET`` and the table, and
 ``tuner.space.paged_attn_vmem_bytes`` states the footprint that follows.
@@ -138,23 +168,32 @@ def _tuned_block_h(num_heads, head_dim, page_size, dtype):
 
 def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
                        scale, page_size, pages_per_seq, pages_per_step,
-                       block_h, head_blocks, groups, arenas, window=None):
+                       block_h, head_blocks, groups, arenas, window=None,
+                       stack=None):
     """One sequence's head block per grid step; the page walk is a loop in
     here, over the pages the sequence has rows in and no further. Each
     iteration starts the copies of the next ``pages_per_step`` pages
     (HBM arena -> the other half of the double buffer, a copy a page and
     arena, ids from the block table in SMEM), waits for its own and runs
-    the online-softmax recurrence over them, a page at a time.
+    the online-softmax recurrence over them.
 
-    With a single query row there is nothing for the MXU to amortize, so
-    the recurrence stays on the VPU in the arena's own ``[page, heads, D]``
-    layout: q.k is a multiply and a lane reduction, softmax statistics
-    reduce over the major (page) axis, p.v is a lane broadcast and a
-    major-axis sum — no transpose, no batched dot, no relayout. A fetched
-    page serves each of the ``groups`` query heads of its KV heads in
-    turn; with one arena (fused rows) it is both operands. Only the page
-    that holds row ``positions[s]`` can hold rows past it, so only that
-    page pays for the mask."""
+    ``stack=None``, the VPU recurrence, a page at a time: with a single
+    query row a KV head there is nothing for the MXU to amortize, so the
+    recurrence stays in the arena's own ``[page, heads, D]`` layout: q.k is
+    a multiply and a lane reduction, softmax statistics reduce over the
+    major (page) axis, p.v is a lane broadcast and a major-axis sum — no
+    transpose, no batched dot, no relayout. A fetched page serves each of
+    the ``groups`` query heads of its KV heads in turn; with one arena
+    (fused rows) it is both operands.
+
+    ``stack=n``, the MXU recurrence (the module docstring's ``"mxu"``): the
+    buffers hold a page as its flat rows ``[page * Hkv, D]``, ``q`` is all
+    the query heads ``[Hq, D]``, and up to ``n`` whole pages go through one
+    pair of products for all of them, the rows of other KV heads biased out.
+
+    Only the page that holds row ``positions[s]`` can hold rows past it
+    (and a window's first page rows before it), so only those pages pay
+    for the mask, one page a product."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -232,11 +271,76 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
-    qs = [q_ref[0, g].astype(jnp.float32) * scale for g in range(groups)]
+    if stack is None:
+        qs = [q_ref[0, g].astype(jnp.float32) * scale for g in range(groups)]
+    else:
+        q_all = q_ref[0].astype(jnp.float32) * scale         # [Hq, row]
+        rows = page_size * block_h                  # a page's flat rows
+
+        def other_heads(n):
+            """``_NEG_INF`` where a flat row of ``n`` stacked pages is not
+            of the query head's KV head, 0 where it is: ``[Hq, n * rows]``."""
+            shape = (q_all.shape[0], n * rows)
+            own = lax.rem(lax.broadcasted_iota(jnp.int32, shape, 1), block_h) \
+                == lax.div(lax.broadcasted_iota(jnp.int32, shape, 0), groups)
+            return jnp.where(own, 0.0, _NEG_INF)
+
+        # a product takes `stack` whole pages, or a power of two under it
+        bias = {2 ** k: other_heads(2 ** k) for k in range(stack.bit_length())}
+
+    def flat_pages(half, i, n, masked=False, at=None):
+        """The MXU recurrence over the ``n`` page slots from ``i`` of buffer
+        ``half``; under the mask ``n`` is 1 and the page the walk's ``at``."""
+
+        def flat(buf):
+            if n == 1:
+                return buf[half, i].astype(jnp.float32)      # [rows, row]
+            return buf[half, pl.ds(i, n)].astype(jnp.float32).reshape(
+                n * rows, buf.shape[-1])
+
+        kblk = flat(bufs[0])
+        vblk = kblk if arenas == 1 else flat(bufs[1])
+        if masked:    # rows past the end may hold anything: 0 * NaN is NaN
+            # flat row r is live where its token r // Hkv is
+            if window is None:      # only the last page is ever masked
+                start, first = (n_pages - 1) * page_size, 0
+            else:       # the first or the last, or one page that is both
+                start = (p0 + at) * page_size
+                first = (lo - start) * block_h
+            past = (pos - start + 1) * block_h
+
+            def live(shape, axis):
+                r = lax.broadcasted_iota(jnp.int32, shape, axis)
+                return (r >= first) & (r < past)
+
+            vblk = jnp.where(live((rows, 1), 0), vblk, 0.0)
+            if arenas == 1:
+                kblk = vblk
+        s_blk = lax.dot_general(
+            q_all, kblk, (((1,), (1,)), ((), ())),
+            precision=lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32) + bias[n]    # [Hq, n * rows]
+        if masked:
+            s_blk = jnp.where(live((1, rows), 1), s_blk, _NEG_INF)
+        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]          # [Hq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # a masked entry's exp(-1e30 - m) is 0: every page computed holds
+        # a live row of every KV head, so m_new is a real score
+        pexp = jnp.exp(s_blk - m_new)
+        l_new = l_prev * alpha + jnp.sum(pexp, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
+            pexp, vblk, (((1,), (0,)), ((), ())),
+            precision=lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     def page(half, i, masked, at=None):
         """The recurrence over page slot ``i`` of buffer ``half``, the
         walk's page ``at`` (a window's mask needs to know)."""
+        if stack is not None:
+            return flat_pages(half, i, 1, masked, at)
         kblk = bufs[0][half, i].astype(jnp.float32)    # [page, bh, D]
         vblk = kblk if arenas == 1 else bufs[1][half, i].astype(jnp.float32)
         if masked:    # rows past the end may hold anything: 0 * NaN is NaN
@@ -286,9 +390,32 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
             page(half, i, False)
             return carry
 
+        def whole_pages(begin, end):
+            """The whole pages ``[begin, end)`` of this half: a page at a
+            time, or ``stack`` a product while that many are left and then
+            the powers of two under it, each at most once."""
+            if stack is None:
+                lax.fori_loop(begin, end, whole_page, 0)
+                return
+            full = jnp.maximum(end - begin, 0) // stack
+
+            def stacked(j, carry):
+                flat_pages(half, begin + j * stack, stack)
+                return carry
+
+            lax.fori_loop(0, full, stacked, 0)
+            begin, n = begin + full * stack, stack // 2
+            while n:
+                fits = end - begin >= n
+
+                @pl.when(fits)
+                def _(begin=begin, n=n):
+                    flat_pages(half, begin, n)
+
+                begin, n = begin + jnp.where(fits, n, 0), n // 2
+
         if window is None:
-            lax.fori_loop(0, jnp.minimum(n_full - first, pages_per_step),
-                          whole_page, 0)
+            whole_pages(0, jnp.minimum(n_full - first, pages_per_step))
 
             @pl.when((n_full < n_pages) & (n_full - first < pages_per_step))
             def _():
@@ -300,9 +427,8 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
         def _():
             page(half, 0, True, 0)
 
-        lax.fori_loop(jnp.clip(head - first, 0, pages_per_step),
-                      jnp.minimum(n_full - first, pages_per_step),
-                      whole_page, 0)
+        whole_pages(jnp.clip(head - first, 0, pages_per_step),
+                    jnp.minimum(n_full - first, pages_per_step))
 
         # the position's page, unless it was the first and is done
         @pl.when((n_full < n_pages) & (n_full - first >= 0)
@@ -313,7 +439,7 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
         return carry
 
     lax.fori_loop(0, n_steps, walk, 0)
-    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :, :1], 1e-30)
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[..., :1], 1e-30)
                 ).astype(o_ref.dtype)
 
 
@@ -368,7 +494,8 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
             jnp.asarray(layer, jnp.int32).reshape(1),
             scale=1.0 / np.sqrt(q.shape[-1]) if scale is None else scale,
             interpret=resolve_interpret("paged_attn", interpret))
-    from ..tuner.space import PAGED_BUFFER_BUDGET, paged_buffer_bytes
+    from ..tuner.space import (PAGED_BUFFER_BUDGET, paged_buffer_bytes,
+                               paged_recurrence)
 
     if isinstance(k_arena, dict) or isinstance(v_arena, dict):
         raise ValueError(
@@ -388,6 +515,9 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
     arenas = 1 if v_arena is None else 2
     page_size, row = k_arena.shape[2], k_arena.shape[-1]
     itemsize = jnp.dtype(k_arena.dtype).itemsize
+    if paged_recurrence(q.shape[1] // num_heads, num_heads, page_size, row,
+                        itemsize, arenas) == "mxu":
+        block_h = num_heads     # a page's flat rows hold all the KV heads
     if block_h is None:
         block_h = _tuned_block_h(num_heads, row, page_size, q.dtype)
     if block_h is None:
@@ -413,7 +543,8 @@ def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
     trace and one lowered function of it (the layer is an operand)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from ..tuner.space import paged_pages_per_step
+    from ..tuner.space import (paged_pages_per_step, paged_recurrence,
+                               paged_stack_pages)
 
     fused = v_arena is None
     arenas = 1 if fused else 2
@@ -426,23 +557,42 @@ def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
                          f"{num_heads} KV heads")
     groups = q_heads // num_heads
     pages_per_seq = block_tables.shape[1]
+    itemsize = jnp.dtype(k_arena.dtype).itemsize
     pages_per_step = paged_pages_per_step(
-        block_h, page_size, head_dim, jnp.dtype(k_arena.dtype).itemsize,
-        arenas, pages_per_seq)
+        block_h, page_size, head_dim, itemsize, arenas, pages_per_seq)
+    mxu = block_h == num_heads and paged_recurrence(
+        groups, num_heads, page_size, head_dim, itemsize, arenas) == "mxu"
 
     kernel = functools.partial(
         _paged_attn_kernel, scale=scale, page_size=page_size,
         pages_per_seq=pages_per_seq, pages_per_step=pages_per_step,
         block_h=block_h, head_blocks=num_heads // block_h, groups=groups,
-        arenas=arenas, **({} if window is None else {"window": window}))
+        arenas=arenas, **({} if window is None else {"window": window}),
+        **({"stack": paged_stack_pages(pages_per_step, q_heads,
+                                       page_size * num_heads)}
+           if mxu else {}))
     bt_flat = block_tables.reshape(-1)
-    # group-major: q_g[s, g, h] is query head h * groups + g
-    q_g = jnp.swapaxes(q.reshape(s_n, num_heads, groups, head_dim), 1, 2)
+    if mxu:
+        # a page as its flat rows, the arena's own memory order (a bitcast),
+        # under all the query heads in their own order
+        page_block = (page_size * num_heads, head_dim)
+        k_arena, v_arena = (a if a is None else a.reshape(
+            a.shape[:2] + page_block) for a in (k_arena, v_arena))
+        q_g, q_block = q, (1, q_heads, head_dim)
+        heads = (q_heads,)
 
-    def _q_map(s, h, bt_ref, len_ref, layer_ref):
-        return (s, 0, h, 0)
+        def _q_map(s, h, bt_ref, len_ref, layer_ref):
+            return (s, 0, 0)
+    else:
+        page_block = (page_size, block_h, head_dim)
+        # group-major: q_g[s, g, h] is query head h * groups + g
+        q_g = jnp.swapaxes(q.reshape(s_n, num_heads, groups, head_dim), 1, 2)
+        q_block = (1, groups, block_h, head_dim)
+        heads = (groups, block_h)
 
-    q_block = (1, groups, block_h, head_dim)
+        def _q_map(s, h, bt_ref, len_ref, layer_ref):
+            return (s, 0, h, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(s_n, num_heads // block_h),
@@ -450,19 +600,18 @@ def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
         + [pl.BlockSpec(memory_space=pl.ANY)] * arenas,   # whole, in HBM
         out_specs=pl.BlockSpec(q_block, _q_map),
         scratch_shapes=[      # a double buffer of pages an arena
-            pltpu.VMEM((2, pages_per_step, page_size, block_h, head_dim),
-                       k_arena.dtype)] * arenas + [
+            pltpu.VMEM((2, pages_per_step) + page_block, k_arena.dtype)
+        ] * arenas + [
             pltpu.SemaphoreType.DMA((2, arenas)),
             pltpu.SMEM((1,), jnp.int32),    # the half a step starts in
-            pltpu.VMEM((groups, block_h, head_dim), jnp.float32),  # acc
-            pltpu.VMEM((groups, block_h, 128), jnp.float32),  # running max
-            pltpu.VMEM((groups, block_h, 128), jnp.float32),  # running sum
+            pltpu.VMEM(heads + (head_dim,), jnp.float32),     # acc
+            pltpu.VMEM(heads + (128,), jnp.float32),          # running max
+            pltpu.VMEM(heads + (128,), jnp.float32),          # running sum
         ])
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_n, groups, num_heads, head_dim),
-                                       q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_g.shape, q.dtype),
         # in order: a step starts the next one's first copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
@@ -470,7 +619,8 @@ def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
         name="paged_attn",
     )(bt_flat, positions, layer, q_g,
       *((k_arena,) if fused else (k_arena, v_arena)))
-    out = jnp.swapaxes(out, 1, 2).reshape(s_n, q_heads, head_dim)
+    if not mxu:
+        out = jnp.swapaxes(out, 1, 2).reshape(s_n, q_heads, head_dim)
     return out[..., head_dim // 2:] if fused else out
 
 

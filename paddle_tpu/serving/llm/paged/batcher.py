@@ -80,7 +80,7 @@ import jax.numpy as jnp
 from ...request import RequestTooLarge
 from ..decode import pack_sampling
 from ..scheduler import ContinuousBatcher, GenerationRequest
-from .decode import GPTPagedDecoder
+from .decode import GPTPagedDecoder, plain_walk_recurrence
 from .pool import PagedKVCache, PagesExhausted, pages_for_tokens
 from .prefix import PagedPrefixStore
 
@@ -118,6 +118,11 @@ class PagedBatcher(ContinuousBatcher):
         self._windowed = self.kv.has_window
         self._stat_set("pages_free", self.kv.pool.free_pages)
         self._stat_set("pages_cow_splits", 0)
+        #: the recurrence of paged_attn's plain walk, static an engine
+        self.attn_recurrence = plain_walk_recurrence(decoder, self.kv)
+        if self.attn_recurrence is not None:
+            self._stat_set("paged_attn.recurrence_mxu",
+                           int(self.attn_recurrence == "mxu"))
         if hasattr(decoder, "publish_gauges"):   # a family's own state
             decoder.publish_gauges(self.kv, self._stat_set)
 

@@ -205,6 +205,23 @@ def register_paged_decoder(model_cls, decoder_cls):
     _PAGED_DECODERS.append((model_cls, decoder_cls))
 
 
+def plain_walk_recurrence(decoder, kv) -> Optional[str]:
+    """``"mxu"`` or ``"vpu"``: the recurrence ``paged_attn``'s plain walk
+    runs over this cache's pages (``tuner.space.paged_recurrence``, from
+    the call's shapes alone, so one answer an engine); None where no plain
+    walk runs: on the gather lane, and for a family whose every walk is
+    over selected pages of a head-major arena."""
+    from ....tuner.space import paged_recurrence
+    if getattr(decoder, "attn_impl", None) != "kernel" \
+            or getattr(kv.k, "ndim", 0) != 5:
+        return None
+    spec = decoder.spec
+    q_heads = getattr(spec, "num_attention_heads", None) or spec.num_heads
+    _, _, page, kv_heads, row = kv.k.shape
+    return paged_recurrence(q_heads // kv_heads, kv_heads, page, row,
+                            kv.k.dtype.itemsize, 1 if kv.fused_kv else 2)
+
+
 def paged_decoder_class(model):
     """The decoder family of ``model``: a registered one, GPT by default."""
     for model_cls, decoder_cls in _PAGED_DECODERS:

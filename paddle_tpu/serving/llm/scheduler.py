@@ -1507,6 +1507,10 @@ class LLMEngine(DrainableEngineBase):
             "kv_layout": self._config.kv_layout,
             # the lane "auto" resolved to (None on the slot plane)
             "paged_attn_impl": getattr(self._decoder, "attn_impl", None),
+            # "mxu" / "vpu": what the kernel lane's plain walk runs over a
+            # fetched page, chosen from the cache's shapes (None elsewhere)
+            "paged_attn_recurrence": getattr(self._batcher,
+                                             "attn_recurrence", None),
             "pages": ({"total": self._batcher.kv.pool.num_pages,
                        "free": self._batcher.kv.pool.free_pages,
                        "cow_splits": self._batcher.kv.cow_splits,

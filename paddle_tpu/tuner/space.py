@@ -186,6 +186,43 @@ def paged_pages_per_step(block_h: int, page_size: int, head_dim: int,
     return pages
 
 
+def paged_recurrence(groups: int, kv_heads: int, page_size: int,
+                     head_dim: int, itemsize: int = 4,
+                     arenas: int = 2) -> str:
+    """Which online-softmax recurrence the plain walk of ``paged_attn``
+    runs over a fetched page, from the call's shapes alone: ``"mxu"``
+    where ``groups`` query heads share each KV head and a page of all the
+    KV heads is whole sublane tiles that fit the buffers (the page's flat
+    rows ``[page_size * kv_heads, head_dim]`` then go through the MXU once
+    for all the query heads), ``"vpu"`` otherwise (a multiply and a
+    reduction a query group in the arena's own layout: with one query head
+    a KV head there is nothing for the MXU to amortize). ``head_dim`` is
+    the arena's row width (``2 * D`` for fused rows)."""
+    flat = page_size * kv_heads
+    fits = paged_buffer_bytes(1, kv_heads, page_size, head_dim, itemsize,
+                              arenas) <= PAGED_BUFFER_BUDGET
+    if groups > 1 and fits and flat % SUBLANE_ROWS[itemsize] == 0:
+        return "mxu"
+    return "vpu"
+
+
+#: the MXU recurrence's score block ``[Hq, stacked flat rows]`` in float32:
+#: what the largest product measured held (LFM2: 32 heads x 32 pages of 128
+#: flat rows), so that more heads stack fewer pages
+PAGED_SCORE_BYTES = 512 * 1024
+
+
+def paged_stack_pages(pages_per_step: int, q_heads: int,
+                      flat_rows: int) -> int:
+    """Whole pages the MXU recurrence puts through one product: the loop
+    step's (a power of two), halved while the score block ``[q_heads,
+    pages * flat_rows]`` is over :data:`PAGED_SCORE_BYTES`."""
+    pages = pages_per_step
+    while pages > 1 and q_heads * pages * flat_rows * 4 > PAGED_SCORE_BYTES:
+        pages //= 2
+    return pages
+
+
 def paged_attn_vmem_bytes(block_h: int, page_size: int, head_dim: int,
                           itemsize: int = 4, arenas: int = 2,
                           groups: int = 1,
@@ -194,7 +231,12 @@ def paged_attn_vmem_bytes(block_h: int, page_size: int, head_dim: int,
     double-buffered page blocks of each arena (what
     :func:`paged_pages_per_step` sized), the q and out head blocks (held
     twice each by the pipeline), the f32 accumulator and the
-    (block_h, 128)-padded running max/sum scratch."""
+    (block_h, 128)-padded running max/sum scratch. The MXU recurrence
+    (:func:`paged_recurrence`; ``block_h`` is then all the KV heads) keeps
+    the same scratch under other shapes (flat rows, ``[Hq, head_dim]``, a
+    column a query head) and holds values besides: the scores of the pages
+    stacked into a product, their ``exp``, and the other-heads bias of
+    every product size (``stack``, ``stack / 2`` ... 1 pages)."""
     pages = paged_pages_per_step(block_h, page_size, head_dim, itemsize,
                                  arenas, pages_per_seq)
     buffers = paged_buffer_bytes(pages, block_h, page_size, head_dim,
@@ -202,7 +244,13 @@ def paged_attn_vmem_bytes(block_h: int, page_size: int, head_dim: int,
     q_out = 2 * 2 * groups * block_h * head_dim * itemsize
     acc = groups * block_h * head_dim * 4
     stats = 2 * groups * block_h * 128 * 4
-    return buffers + q_out + acc + stats
+    scores = 0
+    if paged_recurrence(groups, block_h, page_size, head_dim, itemsize,
+                        arenas) == "mxu":
+        q_heads, flat = groups * block_h, page_size * block_h
+        stack = paged_stack_pages(pages, q_heads, flat)
+        scores = (4 * stack - 1) * q_heads * flat * 4
+    return buffers + q_out + acc + stats + scores
 
 
 def paged_attn_candidates(num_heads: int, head_dim: int, page_size: int,

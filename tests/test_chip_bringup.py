@@ -258,6 +258,31 @@ def test_committed_tpu_winner_lowers(key, cfg):
     assert text.count('kernel_name = "flash_bwd_dkv"') == 1
 
 
+@pytest.mark.parametrize("window", [None, 2048],
+                         ids=["to_the_position", "window"])
+@pytest.mark.parametrize("cell,hq,hkv,d,page,fused", [
+    ("serve-trinity-mixedctx", 32, 4, 128, 64, False),
+    ("serve-lfm2moe-decode", 32, 8, 64, 16, True)])
+def test_the_mxu_recurrence_lowers_at_the_cells_shapes(cell, hq, hkv, d, page,
+                                                       fused, window):
+    """The grouped-query cells' calls of ``paged_attn`` (the rule sends both
+    to the MXU recurrence) through the JAX-side Mosaic lowering: flat rows,
+    stacked pages, both products, with and without a window."""
+    from paddle_tpu.ops.paged_attention import paged_attention
+    from paddle_tpu.tuner.space import paged_recurrence
+    row, arenas = (2 * d, 1) if fused else (d, 2)
+    assert paged_recurrence(hq // hkv, hkv, page, row, 4, arenas) == "mxu"
+    arena = jnp.zeros((9, 2, page, hkv, row), jnp.float32)
+    text = _lower_for_tpu(
+        lambda a, k, v, t, p: paged_attention(
+            a, k, v, t, p, layer=1, window=window, interpret=False),
+        jnp.zeros((2, hq, d), jnp.float32), arena, None if fused else arena,
+        jnp.zeros((2, 64), jnp.int32), jnp.zeros((2,), jnp.int32))
+    assert text.count('kernel_name = "paged_attn"') == 1
+    # the arena reaches the call as its flat rows
+    assert f"tensor<9x2x{page * hkv}x{row}xf32>" in text
+
+
 def test_nms_kernel_lowers_for_tpu_at_its_default_unroll():
     from paddle_tpu.ops.custom import pallas_greedy_nms
     k = 128

@@ -504,6 +504,188 @@ class TestPagedAttentionKernel:
             paged_attention(q, arena, arena, bt, pos)
 
 
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+class TestMXURecurrence:
+    """Where query heads share a KV head the plain walk puts a page's flat
+    rows through the MXU once for all of them, whole pages stacked a
+    product (``tuner.space.paged_recurrence``); each case here is such a
+    shape, held to the gather lane."""
+    PP = 6
+
+    def _positions(self, page):
+        # a frozen slot at row 0 (its table all trash), a row of the first
+        # page, a page's last row and the next page's first, the second
+        # loop step, the table's last row, and a slot past its table
+        return [0, 5, page - 1, page, 4 * page + 3, self.PP * page - 1,
+                self.PP * page + 1000]
+
+    def _case(self, rng, groups, hkv, fused, page, fill=None):
+        from paddle_tpu.tuner.space import (paged_pages_per_step,
+                                            paged_recurrence)
+        D = 8
+        row, arenas = (2 * D, 1) if fused else (D, 2)
+        assert paged_recurrence(groups, hkv, page, row, 4, arenas) == "mxu"
+        # a loop step of four pages: the table is a step and a half, so a
+        # product takes 4, 2 and 1 whole pages in turn
+        assert paged_pages_per_step(hkv, page, row, 4, arenas, self.PP) == 4
+        pos = self._positions(page)
+        S = len(pos)
+        num_pages = S * self.PP
+        shape = (num_pages + 1, 2, page, hkv, row)
+        make = (lambda: jnp.full(shape, fill, jnp.float32)) if fill \
+            is not None else (lambda: jnp.asarray(
+                rng.standard_normal(shape), jnp.float32))
+        bt = rng.permutation(num_pages).reshape(S, self.PP)
+        bt[0] = num_pages                       # the trash page
+        q = jnp.asarray(rng.standard_normal((S, hkv * groups, D)),
+                        jnp.float32)
+        return q, make(), None if fused else make(), bt, pos, num_pages
+
+    @pytest.mark.parametrize("window", [None, 21],
+                             ids=["to_the_position", "window_21"])
+    @pytest.mark.parametrize("page", [16, 64])
+    @pytest.mark.parametrize("fused", [False, True],
+                             ids=["two_arenas", "fused_rows"])
+    @pytest.mark.parametrize("hkv", [2, 4, 8])
+    @pytest.mark.parametrize("groups", [2, 4, 8])
+    def test_matches_gather_reference(self, groups, hkv, fused, page,
+                                      window):
+        """A window of 21 rows starts inside a page at every position but
+        the frozen slot's and is under a page and over one (pages of 16)."""
+        rng = np.random.default_rng(35)
+        q, ka, va, bt, pos, _ = self._case(rng, groups, hkv, fused, page)
+        bt, pos = jnp.asarray(bt, jnp.int32), jnp.asarray(pos, jnp.int32)
+        out = paged_attention(q, ka, va, bt, pos, layer=1, window=window,
+                              interpret=True)
+        ref = _gather_lane(q, ka, va, bt, pos, layer=1, window=window)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("window", [None, 21],
+                             ids=["to_the_position", "window_21"])
+    @pytest.mark.parametrize("fused", [False, True],
+                             ids=["two_arenas", "fused_rows"])
+    @pytest.mark.parametrize("groups,hkv,page", [(8, 4, 64), (4, 8, 16),
+                                                 (2, 2, 16)])
+    def test_nothing_outside_the_attended_rows_reaches_the_result(
+            self, groups, hkv, page, fused, window):
+        """Every row past a position or behind a window, every page a walk
+        does not read and the other layer hold NaN: a masked entry that kept
+        a weight, or a row of another page stacked into a product, would
+        put NaN in the result."""
+        rng = np.random.default_rng(36)
+        q, ka, va, bt, pos, trash = self._case(rng, groups, hkv, fused,
+                                               page, fill=np.nan)
+        pos = pos[:-1]              # past its table a walk reads it whole
+        q, bt = q[:len(pos)], bt[:len(pos)]
+        bt[0] = rng.permutation(trash)[:self.PP]
+        live = np.zeros(ka.shape[:3], bool)            # [pages, L, row]
+        clean = bt.copy()
+        for s, p in enumerate(pos):
+            first = 0 if window is None else max(0, p - window + 1)
+            for j in range(first, p + 1):
+                live[bt[s, j // page], 1, j % page] = True
+            bt[s, :first // page] = trash
+            bt[s, p // page + 1:] = trash
+        rows = jnp.asarray(rng.standard_normal(ka.shape), jnp.float32)
+        ka = jnp.where(live[..., None, None], rows, ka)
+        if not fused:
+            va = jnp.where(live[..., None, None], rows[::-1], va)
+        pos = jnp.asarray(pos, jnp.int32)
+        out = np.asarray(paged_attention(q, ka, va, jnp.asarray(bt), pos,
+                                         layer=1, window=window,
+                                         interpret=True))
+        assert np.isfinite(out).all()
+        ref = _gather_lane(q, jnp.nan_to_num(ka),
+                           None if fused else jnp.nan_to_num(va),
+                           jnp.asarray(clean), pos, layer=1, window=window)
+        np.testing.assert_allclose(out, np.asarray(ref), rtol=2e-5,
+                                   atol=2e-5)
+
+    @pytest.mark.parametrize("shape,found", [
+        # (groups, KV heads, page, row width, itemsize, arenas)
+        ((1, 16, 16, 128, 4, 2), "vpu"),    # both 1.3B cells
+        ((1, 12, 16, 128, 4, 1), "vpu"),    # chip_smoke.py, side by side
+        ((4, 8, 16, 128, 4, 1), "mxu"),     # serve-lfm2moe-decode, fused
+        ((8, 4, 64, 128, 4, 2), "mxu"),     # serve-trinity-mixedctx
+        ((2, 3, 2, 8, 4, 2), "vpu"),        # 6 flat rows: no sublane tile
+        ((2, 4, 4, 128, 2, 2), "mxu"),      # bfloat16: a tile is 16 rows
+        ((2, 2, 4, 128, 2, 2), "vpu"),
+        ((8, 32, 256, 128, 4, 2), "vpu"),   # a page is over the buffers
+    ])
+    def test_the_rule_reads_shapes_alone(self, shape, found):
+        from paddle_tpu.tuner import space
+        assert space.paged_recurrence(*shape) == found
+        # nothing but its arguments: no tuner entry, no device, no option
+        assert space.paged_recurrence.__code__.co_names == (
+            "paged_buffer_bytes", "PAGED_BUFFER_BUDGET", "SUBLANE_ROWS")
+
+    def test_a_head_block_the_flat_rows_cannot_take_is_sanitized(self):
+        """The tuner's block_h candidates reach the call: where the rule
+        sends the shape to the MXU a page's flat rows hold all the KV
+        heads, whatever block was asked for."""
+        rng = np.random.default_rng(37)
+        q, ka, va, bt, pos, _ = self._case(rng, 2, 16, False, 16)
+        bt, pos = jnp.asarray(bt, jnp.int32), jnp.asarray(pos, jnp.int32)
+        out = paged_attention(q, ka, va, bt, pos, block_h=8, interpret=True)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(_gather_lane(q, ka, va, bt, pos)),
+            rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("steps,q_heads,flat,pages", [
+        (8, 32, 256, 8),        # serve-trinity-mixedctx: the whole step
+        (32, 32, 128, 32),      # serve-lfm2moe-decode: the whole step
+        (32, 128, 128, 8),      # more heads stack fewer pages
+        (4, 4, 8, 4), (1, 512, 1024, 1)])
+    def test_pages_a_product_follow_the_step_and_the_score_block(
+            self, steps, q_heads, flat, pages):
+        from paddle_tpu.tuner import space
+        assert space.paged_stack_pages(steps, q_heads, flat) == pages
+
+    def test_one_query_head_a_kv_head_is_the_vpu_program(self, monkeypatch):
+        """G = 1 never takes the new body: its lowered call is the same
+        text whether the rule is consulted or stands fixed at "vpu", and
+        that text holds no product."""
+        from paddle_tpu.ops import paged_attention as pa
+        from paddle_tpu.tuner import space
+        arena = jnp.zeros((9, 2, 16, 16, 128), jnp.float32)
+        args = (jnp.zeros((2, 16, 128), jnp.float32), arena, arena,
+                jnp.zeros((2, 4), jnp.int32), jnp.zeros((2,), jnp.int32))
+
+        def lowered():
+            pa._paged_attention.clear_cache()
+            return jax.jit(lambda *a: paged_attention(
+                *a, layer=1, interpret=False)).trace(*args).lower(
+                    lowering_platforms=("tpu",)).as_text()
+
+        asked = []
+        rule = space.paged_recurrence
+
+        def watched(*shape):
+            asked.append(shape)
+            return rule(*shape)
+
+        texts = []
+        # one call site: the lowered text carries its callers' lines
+        for stand_in in (watched, lambda *shape: "vpu"):
+            monkeypatch.setattr(space, "paged_recurrence", stand_in)
+            texts.append(lowered())
+        assert asked and all(shape[0] == 1 for shape in asked)
+        assert texts[0] == texts[1]
+        pa._paged_attention.clear_cache()
+        kernel, = [e for e in jax.make_jaxpr(lambda *a: paged_attention(
+            *a, layer=1, interpret=False))(*args).eqns[-1].params[
+                "jaxpr"].eqns if e.primitive.name == "pallas_call"]
+        assert "dot_general" not in str(kernel.params["jaxpr"])
+
+
 class TestEngineParity:
     """End-to-end greedy decode through the engine: the paged layout
     must be invisible in the tokens."""
@@ -740,6 +922,22 @@ class TestSchedulerConfig:
         with pytest.raises(ValueError, match="paged_attn_impl"):
             LLMEngineConfig(kv_layout="paged", paged_attn_impl="magic")
 
+    @pytest.mark.parametrize("impl,found", [("kernel", "vpu"),
+                                            ("gather", None)])
+    def test_stats_name_the_plain_walks_recurrence(self, model, impl, found):
+        """A GPT engine has one query head a KV head: the VPU recurrence,
+        named where the kernel lane runs and nowhere else."""
+        eng = _engine(model, kv_layout="paged", page_size=8,
+                      paged_attn_impl=impl, warmup=False)
+        try:
+            st = eng.stats()
+            assert st["paged_attn_recurrence"] == found
+            gauge = st["stats"].get(
+                eng.config.stat_prefix + ".paged_attn.recurrence_mxu")
+            assert gauge == (None if found is None else 0)
+        finally:
+            eng.drain(timeout=30)
+
     def test_decoder_requires_paged_types(self, model):
         dec = GPTPagedDecoder(model, page_size=8)
         assert dec.kv_layout == "paged"
@@ -780,14 +978,18 @@ class TestTunerFamily:
         assert buffers <= space.PAGED_BUFFER_BUDGET or pages == 1
         assert 2 * buffers > space.PAGED_BUFFER_BUDGET or 2 * pages > table
 
-    @pytest.mark.parametrize("hkv,groups,fused", [(4, 1, False),
-                                                  (2, 4, True)])
+    @pytest.mark.parametrize("hkv,groups,fused,page", [
+        (4, 1, False, 4), (2, 4, True, 4),      # the second: flat rows
+        (3, 2, False, 2),                       # grouped, on the VPU
+        (4, 8, False, 64), (8, 4, True, 16)])   # Trinity's and LFM2's heads
     def test_vmem_model_is_what_the_kernel_allocates(self, hkv, groups,
-                                                     fused):
+                                                     fused, page):
         """``paged_attn_vmem_bytes`` against the traced call: its VMEM
-        scratch, and the q and out blocks the pipeline holds twice."""
+        scratch, and the q and out blocks the pipeline holds twice. The MXU
+        recurrence keeps its buffers as flat rows, its accumulator as ``[Hq,
+        row]`` and its statistics a column a query head: the same bytes."""
         from paddle_tpu.tuner.space import paged_attn_vmem_bytes
-        page, pp, D = 4, 6, 8
+        pp, D = 6, 8
         row = 2 * D if fused else D
         arena = jnp.zeros((9, 2, page, hkv, row), jnp.float32)
         args = (jnp.zeros((2, hkv * groups, D)), arena,
@@ -802,6 +1004,14 @@ class TestTunerFamily:
         held = sum(v.aval.size * v.aval.dtype.itemsize for v in scratch
                    if str(v.aval.memory_space) == "vmem")
         held += 2 * 2 * groups * hkv * row * 4          # q, out: twice each
+        # the MXU recurrence's values: the largest product's scores, their
+        # exp, and a bias a product size (that many pages, half, ... one)
+        scores = max([v.aval.size * 4 for e in _eqns(call.params["jaxpr"])
+                      if e.primitive.name == "dot_general"
+                      for v in e.outvars], default=0)
+        if scores:
+            one_page = groups * hkv * page * hkv * 4
+            held += 4 * scores - one_page
         assert held == paged_attn_vmem_bytes(
             hkv, page, row, 4, arenas=1 if fused else 2, groups=groups,
             pages_per_seq=pp)

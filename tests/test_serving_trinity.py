@@ -78,6 +78,14 @@ def test_engine_serves_what_the_reference_puts_first(seeded, chunk, impl):
         assert isinstance(eng.decoder, TrinityPagedDecoder)
         assert paged_decoder_class(net) is TrinityPagedDecoder
         assert eng.stats()["paged_attn_impl"] == impl
+        # 2 KV heads under 4 query heads, pages of 8 rows: on the kernel
+        # lane the plain walk's recurrence is the MXU's; the gather lane
+        # has no walk to name
+        counters, st = _stats(eng)
+        assert st["paged_attn_recurrence"] == (
+            "mxu" if impl == "kernel" else None)
+        assert counters.get("paged_attn.recurrence_mxu") == (
+            1 if impl == "kernel" else None)
         for plen in (1, 5, 13, 16, 27, 40, 61):   # 16: a window; 40: a page
             prompt = rng.integers(0, cfg["vocab_size"], plen).astype(np.int32)
             got = eng.generate(prompt, max_new_tokens=12)
