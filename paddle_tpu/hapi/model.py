@@ -9,6 +9,7 @@ compiled Program served, with the dygraph API surface.
 """
 from __future__ import annotations
 
+import functools
 import time as _time
 from typing import List, Optional
 
@@ -23,10 +24,32 @@ from ..nn.layer_base import Layer
 from ..metric import Metric
 from ..io import DataLoader, Dataset
 from ..jit.functionalize import trace_context, swap_params
-from ..observability import tracer as _otrace
+from ..observability import opscope as _opscope, tracer as _otrace
+from .. import amp as _amp
 from .callbacks import config_callbacks
 from .. import framework_io
 
+
+
+def _noted_jit(fn, **jit_kwargs):
+    """``jax.jit`` of ``fn`` whose traces are noted for
+    ``observability.opscope`` under the compiled module's name
+    (``jit_step``). The note is made inside the traced body: once a trace,
+    never on a call; ``fn`` itself (the auditor's ``raw_step``, the scan's
+    body) stays as it is."""
+    @functools.wraps(fn)
+    def body(*args):
+        # lowered again later, outside the caller's ``auto_cast``: the
+        # autocast state is Python's, not JAX's, so the note carries it
+        st = _amp._STATE
+        _opscope.note("jit_" + fn.__name__, jitted, args, functools.partial(
+            _amp.auto_cast, st["enabled"], set(st["custom_white"]),
+            set(st["custom_black"]), st["level"],
+            st["dtype"] or "bfloat16"))
+        return fn(*args)
+
+    jitted = jax.jit(body, **jit_kwargs)
+    return jitted
 
 
 def _mark_first_compile(tag, jitted):
@@ -165,7 +188,8 @@ class Model:
                 full[pos] = r
             for pos, r in zip(t_pos, train_raws):
                 full[pos] = r
-            with trace_context(key) as ctx:
+            with jax.named_scope("train/forward"), \
+                    trace_context(key) as ctx:
                 with swap_params(state, full):
                     with _ag.no_grad():
                         xs = [Tensor(r) for r in x_raws]
@@ -186,18 +210,20 @@ class Model:
                 fwd_loss, has_aux=True)(train_raws, fixed_raws, x_raws,
                                         y_raws, key)
             grads = list(grads)
-            # clip first, then regularize — same order as Optimizer.step
-            if clip is not None:
-                grads = clip._clip_raw(trainable, grads)
-            for i, rc in enumerate(reg_coeffs):
-                if rc is not None:
-                    grads[i] = grads[i] + rc * train_raws[i]
-            new_p, new_s = [], []
-            for pr, g, st, ctx in zip(train_raws, grads, opt_states, ctxs):
-                p2, s2 = opt._update(pr, g.astype(pr.dtype), st, lr, step_no,
-                                     ctx)
-                new_p.append(p2)
-                new_s.append(s2)
+            with jax.named_scope("train/optimizer"):
+                # clip first, then regularize — same order as Optimizer.step
+                if clip is not None:
+                    grads = clip._clip_raw(trainable, grads)
+                for i, rc in enumerate(reg_coeffs):
+                    if rc is not None:
+                        grads[i] = grads[i] + rc * train_raws[i]
+                new_p, new_s = [], []
+                for pr, g, st, ctx in zip(train_raws, grads, opt_states,
+                                          ctxs):
+                    p2, s2 = opt._update(pr, g.astype(pr.dtype), st, lr,
+                                         step_no, ctx)
+                    new_p.append(p2)
+                    new_s.append(s2)
             return loss, preds, new_p, new_s, effects
 
         def grads_only(train_raws, fixed_raws, x_raws, y_raws, key):
@@ -208,7 +234,7 @@ class Model:
                                         y_raws, key)
             return loss, preds, list(grads), effects
 
-        jitted = jax.jit(step, donate_argnums=(0, 2))
+        jitted = _noted_jit(step, donate_argnums=(0, 2))
         return {"fn": _mark_first_compile("train_step", jitted),
                 "grads_fn": _mark_first_compile("train_grads",
                                                 jax.jit(grads_only)),
@@ -506,27 +532,29 @@ class Model:
                     flat_grads.append(jnp.concatenate(
                         [grads[i].reshape(-1) for i in idxs]).astype(
                             pbuf.dtype))
-                if clip is not None:
-                    gn = jnp.sqrt(sum(
-                        jnp.sum((g.astype(jnp.float32) * m) ** 2)
-                        for g, m in zip(flat_grads, clip_masks)))
-                    scale = clip.clip_norm / jnp.maximum(gn, clip.clip_norm)
-                    flat_grads = [
-                        jnp.where(m > 0, g * scale.astype(g.dtype), g)
-                        for g, m in zip(flat_grads, clip_masks)]
-                new_ps, new_sts = [], []
-                for gi, (pbuf, g, st) in enumerate(
-                        zip(flat_ps, flat_grads, flat_sts)):
-                    if reg_vecs[gi] is not None:
-                        g = g + reg_vecs[gi].astype(pbuf.dtype) * pbuf
-                    ctx = ctx_vecs[gi]
-                    p2, s2 = opt._update(pbuf, g, dict(st), lr, step_no,
-                                         ctx)
-                    new_ps.append(p2)
-                    new_sts.append(s2)
+                with jax.named_scope("train/optimizer"):
+                    if clip is not None:
+                        gn = jnp.sqrt(sum(
+                            jnp.sum((g.astype(jnp.float32) * m) ** 2)
+                            for g, m in zip(flat_grads, clip_masks)))
+                        scale = clip.clip_norm / jnp.maximum(
+                            gn, clip.clip_norm)
+                        flat_grads = [
+                            jnp.where(m > 0, g * scale.astype(g.dtype), g)
+                            for g, m in zip(flat_grads, clip_masks)]
+                    new_ps, new_sts = [], []
+                    for gi, (pbuf, g, st) in enumerate(
+                            zip(flat_ps, flat_grads, flat_sts)):
+                        if reg_vecs[gi] is not None:
+                            g = g + reg_vecs[gi].astype(pbuf.dtype) * pbuf
+                        ctx = ctx_vecs[gi]
+                        p2, s2 = opt._update(pbuf, g, dict(st), lr, step_no,
+                                             ctx)
+                        new_ps.append(p2)
+                        new_sts.append(s2)
                 return loss, new_ps, new_sts, effects
 
-            fused_jit = jax.jit(fused_step, donate_argnums=(0, 2))
+            fused_jit = _noted_jit(fused_step, donate_argnums=(0, 2))
 
             def pack(train_raws, states):
                 flat_ps, flat_sts = [], []

@@ -136,9 +136,10 @@ class GPTModel(Layer):
                                       dtype="int32")
             position_ids = ops.expand(ops.unsqueeze(position_ids, 0),
                                       [input_ids.shape[0], seq_len])
-        h = (self.word_embeddings(input_ids)
-             + self.position_embeddings(position_ids))
-        h = self.embedding_dropout(h)
+        with jax.named_scope("gpt/embed"):
+            h = (self.word_embeddings(input_ids)
+                 + self.position_embeddings(position_ids))
+            h = self.embedding_dropout(h)
         # causal mask as the CAUSAL_MASK sentinel: the flash path applies
         # causality inside the kernel, the dense path materialises the
         # additive triu lazily with the cached-prefix offset
@@ -160,8 +161,9 @@ class GPTForCausalLM(Layer):
         out = self.gpt(input_ids, position_ids, cache=cache)
         h, new_cache = out if cache is not None else (out, None)
         # logits = h @ E^T with the tied embedding matrix
-        logits = ops.matmul(h, self.gpt.word_embeddings.weight,
-                            transpose_y=True)
+        with jax.named_scope("gpt/loss_head"):
+            logits = ops.matmul(h, self.gpt.word_embeddings.weight,
+                                transpose_y=True)
         return logits if cache is None else (logits, new_cache)
 
 
@@ -178,10 +180,11 @@ class GPTPretrainingCriterion(Layer):
 
     def forward(self, logits, labels):
         v = logits.shape[-1]
-        tgt = ops.concat([labels[:, 1:],
-                          ops.full_like(labels[:, :1], -100)], axis=1)
-        return F.cross_entropy(ops.reshape(logits, [-1, v]),
-                               ops.reshape(tgt, [-1]), ignore_index=-100)
+        with jax.named_scope("gpt/loss_head"):
+            tgt = ops.concat([labels[:, 1:],
+                              ops.full_like(labels[:, :1], -100)], axis=1)
+            return F.cross_entropy(ops.reshape(logits, [-1, v]),
+                                   ops.reshape(tgt, [-1]), ignore_index=-100)
 
 
 # -- the block as a function of a cache view (what serving runs) --------------
@@ -315,15 +318,21 @@ def gpt_block(spec: GPTDecodeSpec, lp, h, view, li: int):
     def heads(z):                              # [B, T, H, D] or [S, H, D]
         return z.reshape(h.shape[:-1] + (spec.num_heads, spec.head_dim))
 
-    x = _layer_norm(h, lp["n1w"], lp["n1b"], spec.ln_epsilon)
-    q = heads(_mm(x, lp["qw"]) + lp["qb"])
-    k = heads(_mm(x, lp["kw"]) + lp["kb"])
-    v = heads(_mm(x, lp["vw"]) + lp["vb"])
-    out = view.attend(li, q, k, v, 1.0 / np.sqrt(spec.head_dim))
-    h = h + (_mm(out.reshape(h.shape), lp["ow"]) + lp["ob"])
-    x = _layer_norm(h, lp["n2w"], lp["n2b"], spec.ln_epsilon)
-    ffn = jax.nn.gelu(_mm(x, lp["w1"]) + lp["b1"], approximate=False)
-    return h + (_mm(ffn, lp["w2"]) + lp["b2"])
+    with jax.named_scope("gpt/norm"):
+        x = _layer_norm(h, lp["n1w"], lp["n1b"], spec.ln_epsilon)
+    with jax.named_scope("gpt/qkv"):
+        q = heads(_mm(x, lp["qw"]) + lp["qb"])
+        k = heads(_mm(x, lp["kw"]) + lp["kb"])
+        v = heads(_mm(x, lp["vw"]) + lp["vb"])
+    with jax.named_scope("gpt/attn"):
+        out = view.attend(li, q, k, v, 1.0 / np.sqrt(spec.head_dim))
+    with jax.named_scope("gpt/proj"):
+        h = h + (_mm(out.reshape(h.shape), lp["ow"]) + lp["ob"])
+    with jax.named_scope("gpt/norm"):
+        x = _layer_norm(h, lp["n2w"], lp["n2b"], spec.ln_epsilon)
+    with jax.named_scope("gpt/mlp"):
+        ffn = jax.nn.gelu(_mm(x, lp["w1"]) + lp["b1"], approximate=False)
+        return h + (_mm(ffn, lp["w2"]) + lp["b2"])
 
 
 def gpt_hidden(spec: GPTDecodeSpec, params, tokens, positions, view):
@@ -331,11 +340,13 @@ def gpt_hidden(spec: GPTDecodeSpec, params, tokens, positions, view):
     ``positions`` (``[B, T]`` or ``[1, T]``; clipped into the learned
     position table), the past read and written through ``view``; ``[S, E]``
     of ``[S]`` tokens at ``[S]`` positions for the decode tick."""
-    posc = jnp.clip(positions, 0, spec.max_position_embeddings - 1)
-    h = params["tok"][tokens] + params["pos"][posc]
+    with jax.named_scope("gpt/embed"):
+        posc = jnp.clip(positions, 0, spec.max_position_embeddings - 1)
+        h = params["tok"][tokens] + params["pos"][posc]
     for li, lp in enumerate(params["layers"]):
         h = gpt_block(spec, lp, h, view, li)
-    return _layer_norm(h, params["fnw"], params["fnb"], spec.ln_epsilon)
+    with jax.named_scope("gpt/norm"):
+        return _layer_norm(h, params["fnw"], params["fnb"], spec.ln_epsilon)
 
 
 # -- tensor-parallel plan -----------------------------------------------------

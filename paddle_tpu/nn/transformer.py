@@ -12,6 +12,8 @@ from __future__ import annotations
 import collections
 import numpy as np
 
+import jax
+
 from .layer_base import Layer
 from .container import LayerList
 from .layers_common import Linear, Dropout, LayerNorm
@@ -131,26 +133,44 @@ class MultiHeadAttention(Layer):
             from ..ops.pallas_attention import flash_attention
             b, lq = query.shape[0], query.shape[1]
             shape = [b, -1, self.num_heads, self.head_dim]
-            qf = manipulation.reshape(self.q_proj(query), shape)
-            kf = manipulation.reshape(self.k_proj(key), shape)
-            vf = manipulation.reshape(self.v_proj(value), shape)
+            with jax.named_scope("gpt/qkv"):
+                qf = manipulation.reshape(self.q_proj(query), shape)
+                kf = manipulation.reshape(self.k_proj(key), shape)
+                vf = manipulation.reshape(self.v_proj(value), shape)
             blocks = self.attn_blocks or (None, None)
-            out, _ = flash_attention(
-                qf, kf, vf, causal=isinstance(attn_mask, _CausalMask),
-                block_q=blocks[0], block_k=blocks[1])
-            out = manipulation.reshape(out, [b, lq, self.embed_dim])
-            return self.out_proj(out)
-        q = self._split_heads(self.q_proj(query))
-        if isinstance(cache, self.StaticCache):
-            k, v = cache.k, cache.v
-        else:
-            k = self._split_heads(self.k_proj(key))
-            v = self._split_heads(self.v_proj(value))
-            if isinstance(cache, self.Cache):
-                k = manipulation.concat([cache.k, k], axis=2)
-                v = manipulation.concat([cache.v, v], axis=2)
-                cache = self.Cache(k, v)
+            with jax.named_scope("gpt/attn"):
+                out, _ = flash_attention(
+                    qf, kf, vf, causal=isinstance(attn_mask, _CausalMask),
+                    block_q=blocks[0], block_k=blocks[1])
+                out = manipulation.reshape(out, [b, lq, self.embed_dim])
+            with jax.named_scope("gpt/proj"):
+                return self.out_proj(out)
+        with jax.named_scope("gpt/qkv"):
+            q = self._split_heads(self.q_proj(query))
+            if isinstance(cache, self.StaticCache):
+                k, v = cache.k, cache.v
+            else:
+                k = self._split_heads(self.k_proj(key))
+                v = self._split_heads(self.v_proj(value))
+                if isinstance(cache, self.Cache):
+                    k = manipulation.concat([cache.k, k], axis=2)
+                    v = manipulation.concat([cache.v, v], axis=2)
+                    cache = self.Cache(k, v)
+        with jax.named_scope("gpt/attn"):
+            out, weights = self._dense_attention(q, k, v, attn_mask)
+        with jax.named_scope("gpt/proj"):
+            out = self.out_proj(out)
 
+        outs = [out]
+        if self.need_weights:
+            outs.append(weights)
+        if cache is not None and isinstance(cache, self.Cache):
+            outs.append(cache)
+        return out if len(outs) == 1 else tuple(outs)
+
+    def _dense_attention(self, q, k, v, attn_mask):
+        """Softmax attention of ``q`` over ``k``/``v`` ``[B, H, L, D]``
+        under ``attn_mask``; returns ``([B, Lq, E], weights)``."""
         if isinstance(attn_mask, _CausalMask):
             # dense fallback for the sentinel: materialise the additive
             # causal mask. With an incremental-decode cache lq < lk and
@@ -171,15 +191,8 @@ class MultiHeadAttention(Layer):
                                 mode="upscale_in_train")
         out = _math.matmul(weights, v)                       # [B,H,L,D]
         out = manipulation.transpose(out, [0, 2, 1, 3])
-        out = manipulation.reshape(out, [out.shape[0], out.shape[1], self.embed_dim])
-        out = self.out_proj(out)
-
-        outs = [out]
-        if self.need_weights:
-            outs.append(weights)
-        if cache is not None and isinstance(cache, self.Cache):
-            outs.append(cache)
-        return out if len(outs) == 1 else tuple(outs)
+        return manipulation.reshape(
+            out, [out.shape[0], out.shape[1], self.embed_dim]), weights
 
 
 class TransformerEncoderLayer(Layer):
@@ -208,23 +221,31 @@ class TransformerEncoderLayer(Layer):
         self.activation = getattr(F, activation)
 
     def forward(self, src, src_mask=None, cache=None):
+        # the scopes are the serving block's (models/gpt.py:gpt_block):
+        # observability.opscope reads device time by them
         residual = src
         if self.normalize_before:
-            src = self.norm1(src)
+            with jax.named_scope("gpt/norm"):
+                src = self.norm1(src)
         if cache is None:
             src = self.self_attn(src, src, src, src_mask)
         else:
             src, incremental_cache = self.self_attn(src, src, src, src_mask, cache)
-        src = residual + self.dropout1(src)
+        with jax.named_scope("gpt/proj"):
+            src = residual + self.dropout1(src)
         if not self.normalize_before:
-            src = self.norm1(src)
+            with jax.named_scope("gpt/norm"):
+                src = self.norm1(src)
         residual = src
         if self.normalize_before:
-            src = self.norm2(src)
-        src = self.linear2(self.dropout(self.activation(self.linear1(src))))
-        src = residual + self.dropout2(src)
+            with jax.named_scope("gpt/norm"):
+                src = self.norm2(src)
+        with jax.named_scope("gpt/mlp"):
+            src = self.linear2(self.dropout(self.activation(self.linear1(src))))
+            src = residual + self.dropout2(src)
         if not self.normalize_before:
-            src = self.norm2(src)
+            with jax.named_scope("gpt/norm"):
+                src = self.norm2(src)
         return src if cache is None else (src, incremental_cache)
 
     def gen_cache(self, src):
@@ -253,7 +274,8 @@ class TransformerEncoder(Layer):
                 output, new_cache = mod(output, src_mask, cache[i])
                 new_caches.append(new_cache)
         if self.norm is not None:
-            output = self.norm(output)
+            with jax.named_scope("gpt/norm"):
+                output = self.norm(output)
         return output if cache is None else (output, new_caches)
 
     def gen_cache(self, src):

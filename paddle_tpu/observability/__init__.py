@@ -10,6 +10,9 @@ One measurement substrate spanning training, serving and LLM decode
   (served at ``/metricsz`` by ``paddle_tpu.serving.http``).
 * :mod:`.stepmeter` — per-step MFU/FLOPs accounting from XLA cost
   analysis + measured wall time (``train.mfu``, ``serving.llm.mfu``).
+* :mod:`.opscope` — device time by the program's own ``jax.named_scope``s:
+  every hot program is noted when it is traced, and a device event is
+  mapped back to the scope its instruction came from.
 * :mod:`.flight` — crash flight recorder (last-N events/spans/stats as
   JSONL on sentinel halt, unhandled loop exceptions, SIGTERM drain).
 
@@ -20,7 +23,8 @@ from __future__ import annotations
 
 from typing import Optional
 
-from . import export, flight, metrics, stepmeter, tracer  # noqa: F401
+from . import (export, flight, metrics, opscope, stepmeter,  # noqa: F401
+               tracer)
 from .export import export_chrome_trace, load_chrome_trace  # noqa: F401
 from .flight import (FlightRecorder, default_recorder,  # noqa: F401
                      record_event)

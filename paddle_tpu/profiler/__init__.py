@@ -22,6 +22,7 @@ Usage::
 from __future__ import annotations
 
 import contextlib
+import glob
 import os
 import time
 from typing import Optional
@@ -64,6 +65,32 @@ class RecordEvent:
     def __exit__(self, *exc):
         self.end()
         return False
+
+
+#: what the XLA CPU client's threads hold beside operations: its own frames
+_NOT_AN_OPERATION = ("Threadpool", "end:", "ThunkExecutor")
+
+
+def _device_lines(log_dir: str) -> list:
+    """The device lines of the newest capture under ``log_dir``, each
+    ``[[name, start_ns, dur_ns], ...]``: a chip's ``XLA Ops`` line, or off
+    the chip the XLA CPU client's threads, which stand in for it."""
+    paths = sorted(glob.glob(os.path.join(
+        log_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not paths:
+        return []
+    from jax.profiler import ProfileData
+    lines = []
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        chip = plane.name.startswith("/device:TPU:")
+        for line in plane.lines:
+            if line.name == "XLA Ops" if chip \
+                    else line.name.startswith("tf_XLAPjRtCpuClient"):
+                lines.append([
+                    [ev.name, int(ev.start_ns), int(ev.duration_ns)]
+                    for ev in line.events if ev.duration_ns > 0
+                    and not ev.name.startswith(_NOT_AN_OPERATION)])
+    return lines
 
 
 class Profiler:
@@ -138,9 +165,29 @@ class Profiler:
 
     def summary(self, sorted_by=None, op_detail=True, thread_sep=False,
                 time_unit="ms"):
-        """The device-op table lives in the captured trace (TensorBoard /
-        xprof); here we print the host-side step timing summary."""
+        """The host-side step timing, then the captured device time by the
+        program's own scopes (``observability.opscope``): exclusive time of
+        every ``(program, scope, phase)``, largest first. The per-operation
+        table stays in the captured trace (TensorBoard / xprof)."""
         print(self.step_info())
+        seconds = self.device_time_by_scope()
+        if seconds:
+            from ..observability import opscope
+            print(opscope.format_table(seconds, unit=time_unit))
+
+    def device_time_by_scope(self) -> dict:
+        """``{(program, scope, phase): exclusive seconds}`` of this
+        profiler's newest capture, summed over the device lines; empty
+        while it runs, for ``timer_only`` and where nothing was captured.
+        Lowers every program noted so far once more (``opscope.table``)."""
+        if self._running or self.timer_only:
+            return {}
+        from ..observability import opscope
+        total = {}
+        for line in _device_lines(self.log_dir):
+            for key, s in opscope.by_scope(line).items():
+                total[key] = total.get(key, 0.0) + s
+        return total
 
     def export(self, path=None, format=None):
         return self.log_dir

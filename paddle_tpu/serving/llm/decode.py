@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from ...models.gpt import (FullSequence, GPTDecodeSpec, extract_gpt_params,
                            gpt_hidden, stack_kv)
+from ...observability import opscope
 from ..cache import ExecutableCache, default_cache
 from .kvcache import SlotRows, StaticKVCache, TailRows, is_quantized_kv, \
     valid_mask, write_prompt_kv, write_prompt_kv_at
@@ -120,10 +121,12 @@ def sample_next(params, last, frozen, temperature, top_k, do_sample, eos,
     finished before this call and keep emitting their eos (``False`` for
     rows that were only just admitted). Returns ``(next tokens, finished)``
     per row."""
-    lraw = (last @ params["tok"].T).astype(jnp.float32)           # [N, V]
-    nxt = _sample(lraw, temperature, top_k, do_sample, key, max_top_k)
-    nxt = jnp.where(frozen & (eos >= 0), eos, nxt)
-    return nxt, frozen | ((nxt == eos) & (eos >= 0))
+    with jax.named_scope("decode/head"):
+        lraw = (last @ params["tok"].T).astype(jnp.float32)       # [N, V]
+    with jax.named_scope("decode/sample"):
+        nxt = _sample(lraw, temperature, top_k, do_sample, key, max_top_k)
+        nxt = jnp.where(frozen & (eos >= 0), eos, nxt)
+        return nxt, frozen | ((nxt == eos) & (eos >= 0))
 
 
 # -- the compiled programs ---------------------------------------------------
@@ -136,12 +139,16 @@ def jit_program(raw, donate=()):
     Python-body executions == XLA traces (the compile-counter tests assert
     it stays flat after warmup); the compiled module keeps the raw
     program's name (``jit__step``, ``jit__prefill``, ``jit__tail``), which
-    dumps and traces are read by."""
+    dumps and traces are read by; under that name the trace is noted for
+    ``observability.opscope``, which maps a device event back to the scope
+    it came from (the body runs only while JAX traces, so a call pays
+    nothing for it)."""
     counter = {"traces": 0}
 
     @functools.wraps(raw)
     def _fn(*args):
         counter["traces"] += 1
+        opscope.note("jit_" + raw.__name__, fn, args)
         return raw(*args)
 
     fn = jax.jit(_fn, donate_argnums=donate)

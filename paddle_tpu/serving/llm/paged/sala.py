@@ -276,10 +276,11 @@ class PagedChunk:
                                                  kv[..., d:]))
 
             shape = qb.shape[:3]
-            m, l, acc = jax.lax.fori_loop(0, n_tiles, walk, (
-                jnp.full(shape + (1,), _NEG, jnp.float32),
-                jnp.zeros(shape + (1,), jnp.float32),
-                jnp.zeros(shape + (d,), jnp.float32)))
+            with jax.named_scope("sala/chunk_walk"):
+                m, l, acc = jax.lax.fori_loop(0, n_tiles, walk, (
+                    jnp.full(shape + (1,), _NEG, jnp.float32),
+                    jnp.zeros(shape + (1,), jnp.float32),
+                    jnp.zeros(shape + (d,), jnp.float32)))
             return acc / jnp.maximum(l, 1e-30)
 
         qg = q[0].reshape(t, hkv, hq // hkv, d)
@@ -292,8 +293,9 @@ class PagedChunk:
 
     def linear(self, li, q, k, v, decay):
         carried = jnp.where(self.start > 0, self.lin[self.slot, li], 0.0)
-        y, new = decayed_linear_attention(
-            q, k, v, decay, carried[None], self.n_valid[None])
+        with jax.named_scope("sala/linear_scan"):
+            y, new = decayed_linear_attention(
+                q, k, v, decay, carried[None], self.n_valid[None])
         self.lin_new[li] = new[0]
         return y
 

@@ -177,10 +177,11 @@ class PagedChunk(_Groups):
                         acc * alpha + jnp.einsum("qkgr,rkd->qkgd", p, vt))
 
             shape = qb.shape[:3]
-            m, l, acc = jax.lax.fori_loop(first, last, walk, (
-                jnp.full(shape + (1,), _NEG, jnp.float32),
-                jnp.zeros(shape + (1,), jnp.float32),
-                jnp.zeros(shape + (d,), jnp.float32)))
+            with jax.named_scope("trinity/chunk_walk"):
+                m, l, acc = jax.lax.fori_loop(first, last, walk, (
+                    jnp.full(shape + (1,), _NEG, jnp.float32),
+                    jnp.zeros(shape + (1,), jnp.float32),
+                    jnp.zeros(shape + (d,), jnp.float32)))
             return acc / jnp.maximum(l, 1e-30)
 
         qg = q[0].reshape(t, hkv, hq // hkv, d)
