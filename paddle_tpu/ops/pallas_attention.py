@@ -9,9 +9,12 @@ O(S²) HBM traffic, which is what makes long-sequence training fit at all
 shards S *across* chips; this kernel is the per-chip inner loop story).
 
 Kernel shape: grid (B*H, S_q/block_q); each program holds one q block and
-its running (acc, m, l) statistics in VMEM/registers while scanning k/v
-blocks with ``lax.fori_loop``. Causal masking and tail padding are mask
-arithmetic inside the score block — shapes stay static.
+scans k/v blocks with ``lax.fori_loop``; its running (acc, m, l) live in
+VMEM scratch, not in the loop's carry (a carried value too large for the
+register file is copied between spill slots at both ends of every trip),
+a row's statistic in every lane of the sublane its score row lies on.
+Causal masking and tail padding are mask arithmetic inside the score
+block — shapes stay static.
 
 Runs in interpret mode off-TPU so tests are hardware-independent; every
 ``interpret`` argument below is an optional override that
@@ -32,6 +35,7 @@ from ..core.pallas_mode import resolve_interpret
 __all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
+_FAR = np.iinfo(np.int32).max     # a padded key's position: after every query
 
 #: historical hand-picked block edge — the fallback when the autotuner
 #: has no winner for a shape (paddle_tpu.tuner consults disk winners and
@@ -89,8 +93,35 @@ def _mxu_dot(mxu_dtype):
                    else lax.Precision.DEFAULT))
 
 
-def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, scale, causal,
-               block_q, block_k, seq_len, kv_len, mxu_dtype):
+#: width a row statistic is kept at: [rows, 128] float32, every lane
+#: holding the row's value (one vreg a sublane group, as a [rows, 1] column
+#: would take, but read back from VMEM already broadcast)
+_STAT_LANES = 128
+
+
+def _across(stat, n):
+    """A lane-replicated ``[rows, w]`` statistic as ``[rows, n]``."""
+    w = stat.shape[1]
+    if n <= w:
+        return stat[:, :n]
+    if n % w == 0:
+        return jnp.tile(stat, (1, n // w))
+    return jnp.broadcast_to(stat[:, :1], (stat.shape[0], n))
+
+
+def _visible(q_pos, k_pos, seq_len, causal):
+    """Which scores of a tile count. ``q_pos`` and ``k_pos`` are the
+    tile's query and key positions, a column and a row (a row and a column
+    in the transposed tile of dK/dV): they meet in one compare a score and
+    nothing as large as the tile is kept between trips. A padded key
+    (``k_pos >= seq_len``) counts for no query."""
+    if not causal:
+        return k_pos < seq_len
+    return q_pos >= jnp.where(k_pos < seq_len, k_pos, _FAR)
+
+
+def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
+               scale, causal, block_q, block_k, seq_len, kv_len, mxu_dtype):
     import jax.experimental.pallas as pl
 
     # float32 operands: q is scaled before QK^T. Native (bf16) operands:
@@ -99,53 +130,55 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, scale, causal,
     native = mxu_dtype != jnp.float32
     dot = _mxu_dot(mxu_dtype)
     qi = pl.program_id(1)
-    q = q_ref[0].astype(mxu_dtype)                           # [bq, D]
-    if not native:
-        q = q * scale
-    d = q.shape[-1]
-    q_idx = qi * block_q + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-
+    d = q_ref.shape[-1]
     n_k = kv_len // block_k
 
     def body(j, carry):
-        acc, m, l = carry
+        # q and the positions are read inside the trip, here and in the
+        # backward loops: a value made before the loop is kept through it
+        q = q_ref[0].astype(mxu_dtype)                       # [bq, D]
+        if not native:
+            q = q * scale
         kblk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(mxu_dtype)
         vblk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(mxu_dtype)
         s = dot(q, kblk, (((1,), (1,)), ((), ())))
         if native:
             s = s * scale
-        k_idx = j * block_k + lax.broadcasted_iota(jnp.int32,
-                                                   (block_q, block_k), 1)
-        mask = k_idx < seq_len                               # tail padding
-        if causal:
-            mask = mask & (q_idx >= k_idx)
-        s = jnp.where(mask, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32,
+                                                    (block_q, 1), 0)
+        k_pos = j * block_k + lax.broadcasted_iota(jnp.int32,
+                                                   (1, block_k), 1)
+        s = jnp.where(_visible(q_pos, k_pos, seq_len, causal), s, _NEG_INF)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc_new = acc * alpha[:, None] + dot(
+        p = jnp.exp(s - _across(m_new, block_k))
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * _across(alpha, d) + dot(
             p.astype(mxu_dtype), vblk, (((1,), (0,)), ((), ())))
-        return acc_new, m_new, l_new
+        return carry
 
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
+    # acc, m, l live in VMEM scratch between trips (module docstring);
+    # m, l lane-replicated (_STAT_LANES): read back already broadcast
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
     if causal:
         # early exit: k blocks entirely above the diagonal contribute
         # nothing — trip count becomes data-independent-per-program
         # ceil(((qi+1)*block_q) / block_k), halving work on average
         n_k = jnp.minimum(n_k, (qi * block_q + block_q + block_k - 1)
                           // block_k)
-    acc, m, l = lax.fori_loop(0, n_k, body, (acc0, m0, l0))
+    lax.fori_loop(0, n_k, body, 0)
     # fully-masked rows (padding queries) have l == 0
-    out = acc / jnp.maximum(l, 1e-30)[:, None]
-    o_ref[0] = out.astype(o_ref.dtype)
-    if lse_ref is not None:
-        # logsumexp of the score rows: backward recomputes P from it
-        # (shape [1, 1, bq]: TPU block rule needs the last two dims
-        # (sublane, lane)-aligned, so the row stats ride a lane axis)
-        lse_ref[0, 0] = (m + jnp.log(jnp.maximum(l, 1e-30))).astype(jnp.float32)
+    l = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = (acc_ref[...] / _across(l, d)).astype(o_ref.dtype)
+    # logsumexp of the score rows: backward recomputes P from it
+    # (shape [1, 1, bq]: TPU block rule needs the last two dims
+    # (sublane, lane)-aligned, so the row stats ride a lane axis;
+    # the column is turned into that lane row here, once a program)
+    lse_ref[0] = jnp.transpose(m_ref[...] + jnp.log(l))[:1]
 
 
 def flash_attention(query, key, value, dropout=0.0, causal=False,
@@ -218,95 +251,104 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
 # -- backward kernels (FlashAttention-style recomputation) --------------------
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, *, scale, causal, block_q, block_k, seq_len,
-                      kv_len, mxu_dtype):
+                      dq_ref, acc_ref, *, scale, causal, block_q, block_k,
+                      seq_len, kv_len, mxu_dtype):
     import jax.experimental.pallas as pl
 
     native = mxu_dtype != jnp.float32      # see _fa_kernel
     dot = _mxu_dot(mxu_dtype)
     qi = pl.program_id(1)
-    q = q_ref[0].astype(mxu_dtype)
-    if not native:
-        q = q * scale
-    do = do_ref[0].astype(mxu_dtype)                          # [bq, D]
-    lse = lse_ref[0, 0].astype(jnp.float32)                   # [bq]
-    delta = delta_ref[0, 0].astype(jnp.float32)               # [bq]
-    q_idx = qi * block_q + lax.broadcasted_iota(jnp.int32,
-                                                (block_q, block_k), 0)
+    # The lane rows become columns here, once a program: a row copied
+    # down the sublanes and turned is [bq, 128] with a query's value in every
+    # lane of its sublane; the loop only repeats that along lanes.
+    lse, delta = (jnp.transpose(jnp.broadcast_to(
+        r[0].astype(jnp.float32), (_STAT_LANES, block_q)))
+        for r in (lse_ref, delta_ref))
     n_k = kv_len // block_k
     if causal:
         n_k = jnp.minimum(n_k, (qi * block_q + block_q + block_k - 1)
                           // block_k)
 
-    def body(j, dq):
+    def body(j, carry):
+        q = q_ref[0].astype(mxu_dtype)                        # [bq, D]
+        if not native:
+            q = q * scale
+        do = do_ref[0].astype(mxu_dtype)
         kblk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(mxu_dtype)
         vblk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(mxu_dtype)
         s = dot(q, kblk, (((1,), (1,)), ((), ())))
         if native:
             s = s * scale
-        k_idx = j * block_k + lax.broadcasted_iota(jnp.int32,
-                                                   (block_q, block_k), 1)
-        mask = k_idx < seq_len
-        if causal:
-            mask = mask & (q_idx >= k_idx)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)   # [bq, bk]
+        q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32,
+                                                    (block_q, 1), 0)
+        k_pos = j * block_k + lax.broadcasted_iota(jnp.int32,
+                                                   (1, block_k), 1)
+        p = jnp.where(_visible(q_pos, k_pos, seq_len, causal),
+                      jnp.exp(s - _across(lse, block_k)), 0.0)
         dp = dot(do, vblk, (((1,), (1,)), ((), ())))
-        ds = p * (dp - delta[:, None])
-        return dq + dot(ds.astype(mxu_dtype), kblk,
-                        (((1,), (0,)), ((), ())))
-    dq = lax.fori_loop(0, n_k,
-                       body, jnp.zeros((block_q, q.shape[-1]), jnp.float32))
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+        ds = p * (dp - _across(delta, block_k))               # [bq, bk]
+        acc_ref[...] += dot(ds.astype(mxu_dtype), kblk,
+                            (((1,), (0,)), ((), ())))
+        return carry
+    # accumulated in VMEM scratch, not carried: see _fa_kernel
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    lax.fori_loop(0, n_k, body, 0)
+    dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, *, scale, causal, block_q, block_k,
-                       seq_len, q_len, mxu_dtype):
+                       dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
+                       block_q, block_k, seq_len, q_len, mxu_dtype):
     import jax.experimental.pallas as pl
 
     native = mxu_dtype != jnp.float32      # see _fa_kernel
     dot = _mxu_dot(mxu_dtype)
     ki = pl.program_id(1)
-    kblk = k_ref[0].astype(mxu_dtype)                         # [bk, D]
-    vblk = v_ref[0].astype(mxu_dtype)
-    k_idx = ki * block_k + lax.broadcasted_iota(jnp.int32,
-                                                (block_q, block_k), 1)
+    # The score tile is computed transposed, [bk, bq]: a query row's lse
+    # and delta are then read as the lane rows they are stored as and
+    # broadcast along sublanes, and dV = P^T dO and dK = dS^T Q contract
+    # the tile's last dimension: nothing is turned inside the loop.
     n_q = q_len // block_q
 
     def body(i, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :].astype(mxu_dtype)
+        kblk = k_ref[0].astype(mxu_dtype)                     # [bk, D]
+        vblk = v_ref[0].astype(mxu_dtype)
+        rows = pl.ds(i * block_q, block_q)
+        q = q_ref[0, rows, :].astype(mxu_dtype)
         if not native:
             q = q * scale
-        do = do_ref[0, pl.ds(i * block_q, block_q), :].astype(mxu_dtype)
-        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)].astype(jnp.float32)
-        delta = delta_ref[0, 0,
-                          pl.ds(i * block_q, block_q)].astype(jnp.float32)
-        s = dot(q, kblk, (((1,), (1,)), ((), ())))
+        do = do_ref[0, rows, :].astype(mxu_dtype)
+        lse = lse_ref[0, :, rows].astype(jnp.float32)         # [1, bq]
+        delta = delta_ref[0, :, rows].astype(jnp.float32)
+        st = dot(kblk, q, (((1,), (1,)), ((), ())))           # [bk, bq]
         if native:
-            s = s * scale
-        q_idx = i * block_q + lax.broadcasted_iota(jnp.int32,
-                                                   (block_q, block_k), 0)
-        mask = k_idx < seq_len
-        if causal:
-            mask = mask & (q_idx >= k_idx)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-        dv2 = dv + dot(p.astype(mxu_dtype), do, (((0,), (0,)), ((), ())))
-        dp = dot(do, vblk, (((1,), (1,)), ((), ())))
-        ds = p * (dp - delta[:, None])
-        dk2 = dk + dot(ds.astype(mxu_dtype), q, (((0,), (0,)), ((), ())))
-        return dk2, dv2
+            st = st * scale
+        k_pos = ki * block_k + lax.broadcasted_iota(jnp.int32,
+                                                    (block_k, 1), 0)
+        q_pos = i * block_q + lax.broadcasted_iota(jnp.int32,
+                                                   (1, block_q), 1)
+        pt = jnp.where(_visible(q_pos, k_pos, seq_len, causal),
+                       jnp.exp(st - lse), 0.0)
+        dv_acc[...] += dot(pt.astype(mxu_dtype), do,
+                           (((1,), (0,)), ((), ())))
+        dpt = dot(vblk, do, (((1,), (1,)), ((), ())))
+        dst = pt * (dpt - delta)
+        dk_acc[...] += dot(dst.astype(mxu_dtype), q,
+                           (((1,), (0,)), ((), ())))
+        return carry
     if causal:
         # q blocks entirely above this k block see it masked; start there
         i0 = (ki * block_k) // block_q
     else:
         i0 = 0
-    zero = jnp.zeros((block_k, kblk.shape[-1]), jnp.float32)
-    dk, dv = lax.fori_loop(i0, n_q, body, (zero, zero))
+    dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+    dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+    lax.fori_loop(i0, n_q, body, 0)
+    dk = dk_acc[...]
     if native:
         dk = dk * scale           # the f32 lane folds it into q
     dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _fa_fwd_with_lse(qb, kb, vb, causal, sc, bq, bk, interpret, true_kv):
@@ -314,6 +356,7 @@ def _fa_fwd_with_lse(qb, kb, vb, causal, sc, bq, bk, interpret, true_kv):
     (1/(2·D) of the output bytes — cheap enough to pay on inference
     too, so there is a single forward kernel to maintain)."""
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     bh, s_pad, d = qb.shape
     kv_pad = kb.shape[1]
@@ -341,6 +384,9 @@ def _fa_fwd_with_lse(qb, kb, vb, causal, sc, bq, bk, interpret, true_kv):
                    pl.BlockSpec((1, 1, bq), lambda b, i: (b, 0, i))],
         out_shape=[jax.ShapeDtypeStruct((bh, s_pad, d), qb.dtype),
                    jax.ShapeDtypeStruct((bh, 1, s_pad), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),    # acc
+                        pltpu.VMEM((bq, _STAT_LANES), jnp.float32),  # m
+                        pltpu.VMEM((bq, _STAT_LANES), jnp.float32)],  # l
         interpret=resolve_interpret("flash_fwd", interpret, mxu_dt),
         name="flash_fwd",
     )(qb, kb, vb)
@@ -379,6 +425,7 @@ def _fa_bwd_with_lse(qb, kb, vb, do, out, lse, causal, sc, bq, bk,
     dtypes (default: the operand dtypes) — the ring backward requests f32
     so per-chunk grads accumulate without intermediate rounding."""
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     bh, s_pad, d = qb.shape
     kv_pad = kb.shape[1]
@@ -409,6 +456,7 @@ def _fa_bwd_with_lse(qb, kb, vb, do, out, lse, causal, sc, bq, bk,
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s_pad, d), dq_dt),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=resolve_interpret("flash_bwd_dq", interpret, mxu_dt),
         name="flash_bwd_dq",
     )(qb, kb, vb, do, lse, delta)
@@ -431,6 +479,8 @@ def _fa_bwd_with_lse(qb, kb, vb, do, out, lse, causal, sc, bq, bk,
                    pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0))],
         out_shape=[jax.ShapeDtypeStruct((bh, kv_pad, d), dk_dt),
                    jax.ShapeDtypeStruct((bh, kv_pad, d), dv_dt)],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
         interpret=resolve_interpret("flash_bwd_dkv", interpret, mxu_dt),
         name="flash_bwd_dkv",
     )(qb, kb, vb, do, lse, delta)

@@ -44,15 +44,21 @@ def flash_vmem_bytes(block_q: int, block_k: int, kv_len: int,
     the backward a second one (dP, then dS), and the copy of P (and dS)
     that is the MXU's operand — bfloat16 when the inputs are (the dots
     take their operands in the input's dtype; 2-byte inputs are taken as
-    bfloat16), float32 otherwise, where it is the block Mosaic transposes
-    or keeps beside the next one — plus the float32 accumulators and row
-    statistics, which no input dtype narrows.
+    bfloat16); float32 inputs cost the forward and dQ a block beside the
+    next one, and dK/dV nothing: it computes its tile transposed, so P
+    and dS are their dots' operands as they stand — plus what each
+    program keeps in VMEM scratch between its loop's trips and no input
+    dtype narrows: the float32 accumulators, and every row statistic as
+    ``[rows, lanes]`` with the row's value in each lane (the forward's
+    running max and sum, dQ's lse and delta turned into columns once a
+    program; dK/dV reads its two as the lane rows they arrive as).
 
     Checked against the compiler for a described v5e (16 MiB scoped; the
-    budget is 80% of it): every shape it refuses is over the budget here
-    (K/V of 2 x 4 MiB, float32 1024x1024 backward blocks, a 2048x2048
-    score block); 1024x1024 bfloat16 backward blocks, which it builds
-    with nothing to spare, are over it too.
+    budget is 80% of it), PR 27 and again PR 37: every shape it refuses
+    is over the budget here (K/V of 2 x 4 MiB, float32 1024x1024 backward
+    blocks at head size 128, a 2048x2048 score block); 1024x1024 backward
+    blocks at head size 64, which it builds (bfloat16 with nothing to
+    spare; float32 only since dK/dV turns no tile), are over it too.
     """
     q_len = kv_len if q_len is None else q_len
     kv_pad = _ceil_to(kv_len, block_k)
@@ -61,25 +67,27 @@ def flash_vmem_bytes(block_q: int, block_k: int, kv_len: int,
     k_blk = block_k * head_dim * itemsize
     score = block_q * block_k * 4             # one f32 [bq, bk] block
     operand = block_q * block_k * itemsize    # P or dS as the dot takes it
+    stat = block_q * 128 * 4                  # a lane-replicated column
     if not bwd:
         pipeline = 2 * (q_blk                       # q
                         + 2 * kv_pad * head_dim * itemsize   # K, V whole
                         + q_blk + block_q * 4)      # out, lse
         values = (score + operand
                   + block_q * head_dim * 4          # acc
-                  + 2 * block_q * 4)                # m, l
+                  + 2 * stat)                       # m, l
         return pipeline + values
     dq_prog = (2 * (2 * q_blk                       # q, dO
                     + 2 * kv_pad * head_dim * itemsize       # K, V whole
                     + 2 * block_q * 4               # lse, delta
                     + q_blk)                        # dQ
                + 2 * score + operand
-               + block_q * head_dim * 4)            # dQ accumulator
+               + block_q * head_dim * 4             # dQ accumulator
+               + 2 * stat)                          # lse, delta as columns
     dkv_prog = (2 * (2 * q_pad * head_dim * itemsize         # Q, dO whole
                      + 2 * k_blk                    # k, v
                      + 2 * q_pad * 4                # lse, delta whole
                      + 2 * k_blk)                   # dK, dV
-                + 2 * score + 2 * operand
+                + 2 * score + (2 * operand if itemsize < 4 else 0)
                 + 2 * block_k * head_dim * 4)       # dK, dV accumulators
     return max(dq_prog, dkv_prog)
 

@@ -240,3 +240,113 @@ def test_float32_kernels_are_what_they_were(dtypes):
         assert set(prec) == {"HIGHEST"}, prec
     if set(dtypes) == {"float32"}:
         assert "bf16" not in text and "f32" in text
+
+
+# -- where a tile's statistics lie (PR 37) ------------------------------------
+
+@pytest.mark.parametrize("lengths", [(200, 200), (128, 320)],
+                         ids=["S200-padded", "q128-kv320"])
+@pytest.mark.parametrize("blocks", [(64, 128), (128, 64)],
+                         ids=["b64x128", "b128x64"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_tiles_orientation_against_dense_float32(dtype, causal, blocks,
+                                                     lengths):
+    """Forward and dQ/dK/dV against dense float32 of the same inputs where
+    a tile is not square and the lengths differ or are padded: a swapped
+    iota, a mask on the wrong axis of dK/dV's transposed tile, or a
+    statistic of the other side's length shows here and nowhere else."""
+    rng = np.random.RandomState(6)
+    B, H, D = 1, 2, 64
+    sq, skv = lengths
+    q, do = (jnp.asarray(rng.randn(B, sq, H, D).astype(np.float32) * s,
+                         dtype) for s in (0.5, 1.0))
+    k, v = (jnp.asarray(rng.randn(B, skv, H, D).astype(np.float32) * s,
+                        dtype) for s in (0.5, 1.0))
+    scale = 1 / np.sqrt(D)
+
+    def flash(a, b, c):
+        out, _ = F.flash_attention(paddle.Tensor(a), paddle.Tensor(b),
+                                   paddle.Tensor(c), causal=causal,
+                                   block_q=blocks[0], block_k=blocks[1])
+        return out._data
+
+    def dense(a, b, c):
+        return _dense(*(x.astype(jnp.float32) for x in (a, b, c)),
+                      causal, scale)
+
+    def with_grads(fn):
+        def loss(a, b, c):
+            o = fn(a, b, c)
+            return jnp.sum(o.astype(jnp.float32)
+                           * do.astype(jnp.float32)), o
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+
+    (_, o_k), g_k = with_grads(flash)(q, k, v)
+    (_, o_r), g_r = with_grads(dense)(q, k, v)
+    np.testing.assert_allclose(np.asarray(o_k.astype(jnp.float32)),
+                               np.asarray(o_r), **FWD_TOL[dtype])
+    for name, gk, gr in zip(("dQ", "dK", "dV"), g_k, g_r):
+        assert gk.shape == gr.shape and gk.dtype == jnp.dtype(dtype), name
+        gk, gr = (np.asarray(x.astype(jnp.float32)) for x in (gk, gr))
+        err = np.abs(gk - gr).max()
+        assert err <= GRAD_RTOL[dtype] * np.abs(gr).max(), (name, err)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            sub = getattr(val, "jaxpr", val)
+            if hasattr(sub, "eqns"):
+                yield from _eqns(sub)
+
+
+def _kernels(fn, *args):
+    """{kernel name: its jaxpr} of the Pallas calls ``fn`` traces."""
+    return {str(e.params["name"]): e.params["jaxpr"]
+            for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if e.primitive.name == "pallas_call"}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_no_loop_carries_a_statistic_and_dkv_turns_no_tile(dtype):
+    """What PR 37 changed, as the jaxpr shows it. (1) No loop inside a
+    flash kernel carries a float value: the accumulators and the running
+    max and sum live in VMEM scratch (a carried value is copied between
+    spill slots at both ends of every trip), and so no rank-1 float32
+    statistic is carried either. (2) dK/dV computes its score tile
+    transposed: none of its dots contracts dimension 0 of a tile-shaped
+    operand, which is what put a transpose of P and of dS before a dot."""
+    from paddle_tpu.ops import pallas_attention as fa
+    bq, bk = 64, 128
+
+    def fwd_and_bwd(q, k, v, do):
+        out, lse = fa._fa_fwd_with_lse(q, k, v, True, 0.125, bq, bk, True,
+                                       250)
+        return fa._fa_bwd_with_lse(q, k, v, do, out, lse, True, 0.125, bq,
+                                   bk, True, 250)
+    x = jnp.zeros((2, 256, 32), dtype)        # head size unlike a block
+    kernels = _kernels(fwd_and_bwd, x, x, x, x)
+    assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    for name, jaxpr in kernels.items():
+        loops = [e for e in _eqns(jaxpr) if e.primitive.name == "while"]
+        assert loops, name
+        for loop in loops:
+            carried = [(v.aval.shape, v.aval.dtype.name)
+                       for v in loop.outvars]
+            assert not [c for c in carried if "float" in c[1]], (name,
+                                                                 carried)
+    tiles = {(bq, bk), (bk, bq)}
+    dots = [e for e in _eqns(kernels["flash_bwd_dkv"])
+            if e.primitive.name == "dot_general"]
+    assert len(dots) == 4
+    for e in dots:
+        (lhs_c, rhs_c), _ = e.params["dimension_numbers"]
+        for operand, contract in zip(e.invars, (lhs_c, rhs_c)):
+            if tuple(operand.aval.shape) in tiles:
+                assert tuple(contract) == (1,), (operand.aval, contract)
+    # two of the four take a tile, as their left operand, [bk, bq]
+    assert sum(tuple(e.invars[0].aval.shape) == (bk, bq)
+               for e in dots) == 2
