@@ -8,12 +8,15 @@ family (short convolutions, grouped-query rotary attention, sparse
 experts) and ``sala`` is MiniCPM-SALA (block-sparse attention in a few
 layers, decayed linear attention in the rest) and ``trinity`` is the Trinity
 (``afmoe``) family (sliding-window and full attention layers mixed, sparse
-experts beside a shared expert), all three served on the paged engine.
+experts beside a shared expert) and ``moonlight`` is the Moonlight
+(``deepseek_v3``) family (multi-head latent attention, sparse experts beside
+shared experts), all four served on the paged engine.
 """
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM, GPTPretrainingCriterion  # noqa: F401
 from .lfm2 import LFM2Config, LFM2ForCausalLM  # noqa: F401
 from .sala import SALAConfig, MiniCPMSALAForCausalLM  # noqa: F401
 from .trinity import TrinityConfig, TrinityForCausalLM  # noqa: F401
+from .moonlight import MoonlightConfig, MoonlightForCausalLM  # noqa: F401
 from .bert import (BertConfig, BertModel,  # noqa: F401
                    BertForSequenceClassification,
                    ErnieConfig, ErnieModel,

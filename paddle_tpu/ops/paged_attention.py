@@ -94,6 +94,20 @@ and values of one head as a ``[page, 2 * D]`` slab. With the ``G`` query
 heads of a KV head on the sublanes, the recurrence is two small MXU products
 a page (``[G, D] x [D, page]`` and ``[G, page] x [page, D]``).
 
+Latent rows: with ``latent=(value, rotary)`` the cache keeps ONE row a token
+and layer for ALL the query heads (multi-head latent attention, absorbed:
+``models/moonlight.py``): the arena is ``[P+1, L, page, row]`` and a row is a
+key whole (the latent beside the shared rotary part, ``value + rotary``
+columns and zeros up to ``row``) and a value in its first ``value`` columns.
+It is the MXU recurrence with one KV head and every query head in its group
+(``paged_recurrence`` says ``"mxu"`` from the shape): ``[Hq, row] x [row, n *
+page]`` for the scores of a loop step's stacked pages, no bias (there is no
+other KV head), and ``[Hq, n * page] x [n * page, value]`` over the first
+columns of the SAME fetched rows for the result ``[S, Hq, value]``. The row is
+whole 128-lane tiles as the cache holds it: the device pads a narrower row to
+them in HBM anyway, and Mosaic refuses to copy a 576-wide slice of it
+(PERF.md section 4, PR 38).
+
 A window: with ``window=W`` query ``s`` attends the ``W`` rows ``positions[s]
 - W < j <= positions[s]`` only, and its walk BEGINS at the page of row
 ``max(0, positions[s] - W + 1)``: the pages wholly behind the window cost no
@@ -169,7 +183,7 @@ def _tuned_block_h(num_heads, head_dim, page_size, dtype):
 def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
                        scale, page_size, pages_per_seq, pages_per_step,
                        block_h, head_blocks, groups, arenas, window=None,
-                       stack=None):
+                       stack=None, value_width=None):
     """One sequence's head block per grid step; the page walk is a loop in
     here, over the pages the sequence has rows in and no further. Each
     iteration starts the copies of the next ``pages_per_step`` pages
@@ -190,6 +204,11 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
     buffers hold a page as its flat rows ``[page * Hkv, D]``, ``q`` is all
     the query heads ``[Hq, D]``, and up to ``n`` whole pages go through one
     pair of products for all of them, the rows of other KV heads biased out.
+
+    ``value_width=n`` (latent rows, on the MXU recurrence with one KV head):
+    a row is a key whole and a value in its first ``n`` columns, so the
+    second product takes those columns of the page the first one took, and
+    the accumulator is ``[Hq, n]``.
 
     Only the page that holds row ``positions[s]`` can hold rows past it
     (and a window's first page rows before it), so only those pages pay
@@ -285,8 +304,10 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
                 == lax.div(lax.broadcasted_iota(jnp.int32, shape, 0), groups)
             return jnp.where(own, 0.0, _NEG_INF)
 
-        # a product takes `stack` whole pages, or a power of two under it
-        bias = {2 ** k: other_heads(2 ** k) for k in range(stack.bit_length())}
+        # a product takes `stack` whole pages, or a power of two under it;
+        # latent rows are of the one KV head there is: nothing to push out
+        bias = {2 ** k: 0.0 if value_width is not None else other_heads(2 ** k)
+                for k in range(stack.bit_length())}
 
     def flat_pages(half, i, n, masked=False, at=None):
         """The MXU recurrence over the ``n`` page slots from ``i`` of buffer
@@ -316,6 +337,8 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
             vblk = jnp.where(live((rows, 1), 0), vblk, 0.0)
             if arenas == 1:
                 kblk = vblk
+        if value_width is not None:     # whole lane tiles of the same rows
+            vblk = kblk[:, :value_width]
         s_blk = lax.dot_general(
             q_all, kblk, (((1,), (1,)), ((), ())),
             precision=lax.Precision.HIGHEST,
@@ -445,7 +468,7 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
 
 def paged_attention(q, k_arena, v_arena, block_tables, positions,
                     layer=0, scale=None, block_h=None, interpret=None,
-                    selected=None, window=None):
+                    selected=None, window=None, latent=None):
     """Single-token decode attention through a paged KV arena.
 
     ``q``: ``[S, Hq, D]`` (one query per sequence, already projected);
@@ -470,7 +493,34 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
     ``window=W`` (a positive int, the plain walk only): query ``s`` attends
     rows ``positions[s] - W < j <= positions[s]``, and walks from the page of
     the first of them.
+
+    ``latent=(value_width, rotary_width)`` (the plain walk only, no window):
+    the cache keeps ONE row a token and layer for all the heads, ``k_arena``
+    is ``[num_pages + 1, num_layers, page_size, row]`` with ``row >=
+    value_width + rotary_width`` (what lies past is padding) and ``v_arena``
+    None. A row is a key whole and a value in its first ``value_width``
+    columns: ``q`` is ``[S, Hq, value_width + rotary_width]`` (the absorbed
+    query beside its rotary part), the scores are one dot with the row, and
+    the result is ``[S, Hq, value_width]``, the weighted sum of the rows'
+    value columns. A page is fetched once and serves both products.
     """
+    if latent is not None:
+        value_width, rotary_width = (int(n) for n in latent)  # noqa: PTA001 -- python ints of the configuration, never traced values
+        if selected is not None or window is not None or v_arena is not None \
+                or getattr(k_arena, "ndim", 0) != 4 \
+                or q.shape[-1] != value_width + rotary_width \
+                or k_arena.shape[-1] < q.shape[-1]:
+            raise ValueError(
+                "latent rows take the plain walk over ONE arena [P+1, L, "
+                "page, row >= value + rotary], no second arena, window or "
+                "selection, and queries of value + rotary columns")
+        return _paged_attention(
+            q, k_arena[:, :, :, None, :], None,
+            block_tables.astype(jnp.int32), positions.astype(jnp.int32),
+            jnp.asarray(layer, jnp.int32).reshape(1),
+            scale=1.0 / np.sqrt(q.shape[-1]) if scale is None else scale,
+            block_h=1, interpret=resolve_interpret("paged_attn", interpret),
+            value_width=value_width)
     if window is not None and (selected is not None or int(window) < 1):  # noqa: PTA001 -- a python int of the configuration, never a traced value
         raise ValueError(
             f"window must be a positive int on the plain walk, got "
@@ -536,9 +586,10 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block_h", "interpret",
-                                             "window"))
+                                             "window", "value_width"))
 def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
-                     scale, block_h, interpret, window=None):
+                     scale, block_h, interpret, window=None,
+                     value_width=None):
     """The call itself, jitted on its own: a program's layers share one
     trace and one lowered function of it (the layer is an operand)."""
     import jax.experimental.pallas as pl
@@ -548,7 +599,9 @@ def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
 
     fused = v_arena is None
     arenas = 1 if fused else 2
-    if fused:       # zeros over the value's lanes
+    if value_width is not None:     # zeros over the row's padding
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, k_arena.shape[-1] - q.shape[-1])))
+    elif fused:     # zeros over the value's lanes
         q = jnp.pad(q, ((0, 0), (0, 0), (0, q.shape[-1])))
     s_n, q_heads, head_dim = q.shape
     num_heads, page_size = k_arena.shape[3], k_arena.shape[2]
@@ -570,7 +623,9 @@ def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
         arenas=arenas, **({} if window is None else {"window": window}),
         **({"stack": paged_stack_pages(pages_per_step, q_heads,
                                        page_size * num_heads)}
-           if mxu else {}))
+           if mxu else {}),
+        **({} if value_width is None else {"value_width": value_width}))
+    out_dim = head_dim if value_width is None else value_width
     bt_flat = block_tables.reshape(-1)
     if mxu:
         # a page as its flat rows, the arena's own memory order (a bitcast),
@@ -579,6 +634,7 @@ def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
         k_arena, v_arena = (a if a is None else a.reshape(
             a.shape[:2] + page_block) for a in (k_arena, v_arena))
         q_g, q_block = q, (1, q_heads, head_dim)
+        o_shape, o_block = (s_n, q_heads, out_dim), (1, q_heads, out_dim)
         heads = (q_heads,)
 
         def _q_map(s, h, bt_ref, len_ref, layer_ref):
@@ -588,6 +644,7 @@ def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
         # group-major: q_g[s, g, h] is query head h * groups + g
         q_g = jnp.swapaxes(q.reshape(s_n, num_heads, groups, head_dim), 1, 2)
         q_block = (1, groups, block_h, head_dim)
+        o_shape, o_block = q_g.shape, q_block
         heads = (groups, block_h)
 
         def _q_map(s, h, bt_ref, len_ref, layer_ref):
@@ -598,20 +655,20 @@ def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
         grid=(s_n, num_heads // block_h),
         in_specs=[pl.BlockSpec(q_block, _q_map)]
         + [pl.BlockSpec(memory_space=pl.ANY)] * arenas,   # whole, in HBM
-        out_specs=pl.BlockSpec(q_block, _q_map),
+        out_specs=pl.BlockSpec(o_block, _q_map),
         scratch_shapes=[      # a double buffer of pages an arena
             pltpu.VMEM((2, pages_per_step) + page_block, k_arena.dtype)
         ] * arenas + [
             pltpu.SemaphoreType.DMA((2, arenas)),
             pltpu.SMEM((1,), jnp.int32),    # the half a step starts in
-            pltpu.VMEM(heads + (head_dim,), jnp.float32),     # acc
+            pltpu.VMEM(heads + (out_dim,), jnp.float32),      # acc
             pltpu.VMEM(heads + (128,), jnp.float32),          # running max
             pltpu.VMEM(heads + (128,), jnp.float32),          # running sum
         ])
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q_g.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(o_shape, q.dtype),
         # in order: a step starts the next one's first copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
@@ -621,6 +678,8 @@ def _paged_attention(q, k_arena, v_arena, block_tables, positions, layer, *,
       *((k_arena,) if fused else (k_arena, v_arena)))
     if not mxu:
         out = jnp.swapaxes(out, 1, 2).reshape(s_n, q_heads, head_dim)
+    if value_width is not None:
+        return out
     return out[..., head_dim // 2:] if fused else out
 
 

@@ -13,7 +13,9 @@ convolution state beside the pages, ``sala`` for MiniCPM-SALA, whose cache
 holds pages for its sparse layers only, their compressed keys by page and a
 linear-attention state a slot, ``trinity`` for the Trinity family, whose
 window and full attention layers keep different pages of one sequence (two
-page groups in one cache).
+page groups in one cache), ``moonlight`` for the Moonlight family, whose
+latent attention keeps ONE row a token and layer for all its heads (one arena,
+an absorbed decode step and an expanded chunk program over it).
 """
 from .batcher import PagedBatcher
 from .decode import (GPTPagedDecoder, paged_decoder_class,
@@ -28,6 +30,7 @@ from .prefix import PagedPrefixEntry, PagedPrefixStore
 from .lfm2 import LFM2PagedDecoder
 from .sala import SALAPagedDecoder
 from .trinity import TrinityPagedDecoder
+from .moonlight import MoonlightPagedDecoder
 from .spec import (GPTPagedSpecDecoder, build_paged_spec_decode_step,
                    get_paged_spec_decode_step)
 
@@ -50,6 +53,7 @@ __all__ = [
     "LFM2PagedDecoder",
     "SALAPagedDecoder",
     "TrinityPagedDecoder",
+    "MoonlightPagedDecoder",
     "paged_decoder_class",
     "register_paged_decoder",
     "build_paged_spec_decode_step",

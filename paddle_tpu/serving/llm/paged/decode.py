@@ -212,10 +212,16 @@ def plain_walk_recurrence(decoder, kv) -> Optional[str]:
     walk runs: on the gather lane, and for a family whose every walk is
     over selected pages of a head-major arena."""
     from ....tuner.space import paged_recurrence
-    if getattr(decoder, "attn_impl", None) != "kernel" \
-            or getattr(kv.k, "ndim", 0) != 5:
+    if getattr(decoder, "attn_impl", None) != "kernel":
         return None
     spec = decoder.spec
+    if getattr(decoder, "latent", None) is not None:
+        # latent rows: every query head on the one row a token keeps
+        _, _, page, row = kv.k.shape
+        return paged_recurrence(spec.num_attention_heads, 1, page, row,
+                                kv.k.dtype.itemsize, 1)
+    if getattr(kv.k, "ndim", 0) != 5:
+        return None
     q_heads = getattr(spec, "num_attention_heads", None) or spec.num_heads
     _, _, page, kv_heads, row = kv.k.shape
     return paged_recurrence(q_heads // kv_heads, kv_heads, page, row,

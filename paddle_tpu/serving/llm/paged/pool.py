@@ -74,6 +74,15 @@ cache does not choose it from ``head_dim``: the GPT views below
 (:class:`PagedRows`, the tail prefill's gather), the page copy and the export read
 ``kbuf`` and ``vbuf`` as two arenas, so a GPT with head size 64 still keeps
 two (and pays that copy) until they read fused rows.
+
+**Latent rows.** A family with latent attention keeps ONE row a token and
+layer for ALL its heads, a compressed latent beside a shared rotary key part
+(``models/moonlight.py``): a value is the row's first columns, so there is no
+second arena to allocate, copy or export. Such a cache is ``fused_kv=True``
+with the family's ``row_shape=(row,)``: one arena ``[P+1, L, page, row]``,
+``row`` the latent and rotary widths and whatever padding the family lays
+behind them; ``v`` is the empty placeholder. :meth:`PagedKVCache.row_nbytes`
+is what a token and layer hold, padding included.
 """
 from __future__ import annotations
 
@@ -755,7 +764,8 @@ class PagedKVCache:
             return jax.tree_util.tree_map(
                 lambda x: np.asarray(jax.device_get(jnp.take(x, idx, axis=0))),  # noqa: PTA002 -- sequence-export page fetch: a deliberate once-per-migration transfer on the between-tick control path
                 buf)
-        return _take(self.k), _take(self.v)
+        # a cache of one arena (fused or latent rows) has no second one
+        return _take(self.k), None if self.fused_kv else _take(self.v)
 
     def write_page(self, pid: int, k_page, v_page):
         """Install one host-shipped page (a ``read_pages`` row) at
@@ -769,7 +779,8 @@ class PagedKVCache:
                 f"shared or free page would corrupt sharers")
         dst = jnp.asarray(pid, jnp.int32)
         self.k = _arena_write_page(self.k, dst, k_page)
-        self.v = _arena_write_page(self.v, dst, v_page)
+        if not self.fused_kv:
+            self.v = _arena_write_page(self.v, dst, v_page)
 
     def set_length(self, slot: int, n_tokens: int):
         """Install a migrated sequence's resume position in the device
@@ -824,6 +835,12 @@ class PagedKVCache:
         """Device bytes of ONE physical page across both arenas and all
         layers — the unit the bytes_shared/bytes_copied counters count."""
         return (kv_nbytes(self.k) + kv_nbytes(self.v)) // (self.num_pages + 1)
+
+    def row_nbytes(self) -> int:
+        """Device bytes ONE token holds in one layer of the first group, as
+        the arenas keep it (padding and every head included)."""
+        g = self.groups[0]
+        return self.page_nbytes() // (len(g.layers) * self.page_size)
 
     def host_lengths(self) -> np.ndarray:
         """One deliberate device->host fetch of the per-slot lengths

@@ -1491,6 +1491,7 @@ class LLMEngine(DrainableEngineBase):
         window_live = self._registry.get(pre + "window_attn.pages_live")
         window_unbounded = self._registry.get(
             pre + "kv_pages.window_unbounded")
+        row_expanded = self._registry.get(pre + "kv_row_bytes_expanded")
         return {
             "stats": self._registry.stats_with_prefix(pre),
             "histograms": hists,
@@ -1540,6 +1541,12 @@ class LLMEngine(DrainableEngineBase):
             "window_held_page_share": (
                 self._registry.get(pre + "kv_pages.window_held")
                 / window_unbounded if window_unbounded else None),
+            # what a token and layer hold in a latent cache, of what its
+            # heads' keys and values would take (1.0: expanded rows; None:
+            # no latent cache)
+            "latent_cache_row_share": (
+                self._registry.get(pre + "kv_row_bytes") / row_expanded
+                if row_expanded else None),
         }
 
     # -- worker --------------------------------------------------------------

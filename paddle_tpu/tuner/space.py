@@ -205,7 +205,10 @@ def paged_recurrence(groups: int, kv_heads: int, page_size: int,
     for all the query heads), ``"vpu"`` otherwise (a multiply and a
     reduction a query group in the arena's own layout: with one query head
     a KV head there is nothing for the MXU to amortize). ``head_dim`` is
-    the arena's row width (``2 * D`` for fused rows)."""
+    the arena's row width (``2 * D`` for fused rows). Latent rows are the
+    shape ``(Hq, 1, page, row, itemsize, 1)``: every query head on the one
+    row a token keeps, so ``"mxu"`` wherever a page is whole sublane
+    tiles."""
     flat = page_size * kv_heads
     fits = paged_buffer_bytes(1, kv_heads, page_size, head_dim, itemsize,
                               arenas) <= PAGED_BUFFER_BUDGET

@@ -615,6 +615,8 @@ class TestMXURecurrence:
         ((1, 12, 16, 128, 4, 1), "vpu"),    # chip_smoke.py, side by side
         ((4, 8, 16, 128, 4, 1), "mxu"),     # serve-lfm2moe-decode, fused
         ((8, 4, 64, 128, 4, 2), "mxu"),     # serve-trinity-mixedctx
+        ((16, 1, 64, 640, 4, 1), "mxu"),    # serve-moonlight-longgen, latent
+        ((16, 1, 64, 576, 4, 1), "mxu"),    # its rows without the padding
         ((2, 3, 2, 8, 4, 2), "vpu"),        # 6 flat rows: no sublane tile
         ((2, 4, 4, 128, 2, 2), "mxu"),      # bfloat16: a tile is 16 rows
         ((2, 2, 4, 128, 2, 2), "vpu"),
@@ -641,6 +643,7 @@ class TestMXURecurrence:
 
     @pytest.mark.parametrize("steps,q_heads,flat,pages", [
         (8, 32, 256, 8),        # serve-trinity-mixedctx: the whole step
+        (8, 16, 64, 8),         # serve-moonlight-longgen: the whole step
         (32, 32, 128, 32),      # serve-lfm2moe-decode: the whole step
         (32, 128, 128, 8),      # more heads stack fewer pages
         (4, 4, 8, 4), (1, 512, 1024, 1)])
@@ -684,6 +687,67 @@ class TestMXURecurrence:
             *a, layer=1, interpret=False))(*args).eqns[-1].params[
                 "jaxpr"].eqns if e.primitive.name == "pallas_call"]
         assert "dot_general" not in str(kernel.params["jaxpr"])
+
+
+class TestLatentRows:
+    """``paged_attention(latent=...)``: one row a token for all the query
+    heads, a key whole and a value in its first columns; the walk is the
+    plain one (``tests/test_moonlight.py`` holds it to an expanded walk)."""
+
+    @pytest.mark.parametrize("row", [24, 128])
+    @pytest.mark.parametrize("heads,page,pp", [(4, 8, 5), (16, 16, 3),
+                                               (2, 8, 17)])
+    def test_what_lies_past_the_end_never_reaches_the_result(self, row,
+                                                             heads, page, pp):
+        """NaN in every row past a sequence's position, in the trash page
+        and behind the row's own columns' padding of other pages: the result
+        is finite and the gather lane's."""
+        from paddle_tpu.serving.llm.paged.moonlight import \
+            latent_gather_attention
+        rng = np.random.default_rng(heads + page + row)
+        value, rotary, seqs = 16, 8, 4
+        pages = seqs * pp
+        arena = rng.standard_normal((pages + 1, 2, page, row)).astype(
+            np.float32)
+        arena[..., value + rotary:] = 0.0
+        bt = rng.permutation(pages).reshape(seqs, pp).astype(np.int32)
+        pos = np.array([0, page - 1, page, pp * page - 2])[:seqs]
+        clean = arena.copy()
+        arena[-1] = np.nan
+        for s_, p_ in enumerate(pos):       # the rows past each position
+            at = np.arange(pp * page)
+            dead = at[at > p_]
+            arena[bt[s_, dead // page], :, dead % page] = np.nan
+        q = jnp.asarray(rng.standard_normal((seqs, heads, value + rotary)),
+                        jnp.float32)
+        out = np.asarray(paged_attention(
+            q, jnp.asarray(arena), None, jnp.asarray(bt),
+            jnp.asarray(pos, jnp.int32), layer=1, scale=0.3,
+            latent=(value, rotary), interpret=True))
+        assert out.shape == (seqs, heads, value) and np.isfinite(out).all()
+        want = latent_gather_attention(
+            q, jnp.asarray(clean), jnp.asarray(bt),
+            jnp.asarray(pos, jnp.int32), 1, 0.3, (value, rotary))
+        np.testing.assert_allclose(out, np.asarray(want), rtol=2e-5,
+                                   atol=2e-5)
+
+    def test_the_other_walks_calls_are_what_they_were(self):
+        """``latent=None``: the kernel's parameters of a fused and of a
+        two-arena call hold no ``value_width`` (the compiled kernels of the
+        accepted cells are keyed by them)."""
+        for fused in (False, True):
+            arena = jnp.zeros((9, 2, 16, 4, 256 if fused else 128))
+            args = (jnp.zeros((2, 16, 128)), arena,
+                    None if fused else arena, jnp.zeros((2, 4), jnp.int32),
+                    jnp.zeros((2,), jnp.int32))
+            *_, jitted = jax.make_jaxpr(
+                lambda *a: paged_attention(*a, interpret=True))(*args).eqns
+            assert "value_width" not in jitted.params or \
+                jitted.params.get("value_width") is None
+            call, = [e for e in jitted.params["jaxpr"].eqns
+                     if e.primitive.name == "pallas_call"]
+            assert call.params["out_avals"][0].shape[-1] == (
+                256 if fused else 128)
 
 
 class TestEngineParity:
@@ -968,6 +1032,7 @@ class TestTunerFamily:
         ((8, 16, 128, 4, 1, 96), 32),     # serve-lfm2moe-decode, fused
         ((16, 16, 128, 4, 2, 6), 4),      # no more than the table has
         ((32, 256, 128, 4, 2, 48), 1),    # one page is over the budget
+        ((1, 64, 640, 4, 1, 112), 8),     # serve-moonlight-longgen's rows
     ])
     def test_pages_a_step_follow_shapes_and_budget(self, shape, pages):
         from paddle_tpu.tuner import space

@@ -165,3 +165,56 @@ class TestSharedExpertBesideAHeldShare:
             a = self._layer(None, 0, eps=1e-6)(paddle.to_tensor(x)).numpy()
             b = self._layer(None, 0, eps=1e-20)(paddle.to_tensor(x)).numpy()
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-9)
+
+
+class TestASecondConfigurationOnTheHeldPath:
+    """``MoEFeedForward(2048, 1408, 64, 6, scale=2.446, held=(0, 8),
+    shared=2, eps=1e-20, scope="moonlight")`` at toy widths: 6 of 64 chosen
+    with 8 held and TWO shared experts (one SwiGLU of twice the width), the
+    shares adding up as Trinity's 8 of 128 with 16 held do."""
+
+    def _layer(self, experts, top_k, held, shared, seed=5):
+        from paddle_tpu.nn import MoEFeedForward
+        paddle.seed(seed)
+        layer = MoEFeedForward(16, 8, experts, top_k, True, 2.446, held=held,
+                               shared=shared, eps=1e-20, scope="moonlight")
+        rng = np.random.RandomState(seed)
+        whole = {m: rng.randn(experts, *shape).astype(np.float32) * 0.3
+                 for m, shape in (("w1", (16, 8)), ("w3", (16, 8)),
+                                  ("w2", (8, 16)))}
+        lo, n = held or (0, experts)
+        layer.gate.weight.set_value(
+            rng.randn(16, experts).astype(np.float32))
+        layer.expert_bias.set_value(
+            rng.randn(experts).astype(np.float32) * 0.02)
+        for m, w in whole.items():
+            getattr(layer.experts, m).set_value(w[lo:lo + n])
+        for m, shape in (("w1", (1, 16, 8 * shared)),
+                         ("w3", (1, 16, 8 * shared)),
+                         ("w2", (1, 8 * shared, 16))):
+            getattr(layer.shared_experts, m).set_value(
+                rng.randn(*shape).astype(np.float32) * 0.3)
+        return layer
+
+    @pytest.mark.parametrize("experts,top_k,n_held", [
+        (64, 6, 8), (16, 6, 8), (128, 8, 16)])
+    def test_every_share_of_the_layer_adds_up(self, experts, top_k, n_held):
+        x = np.random.RandomState(1).randn(20, 16).astype(np.float32)
+        with paddle.no_grad():
+            whole = self._layer(experts, top_k, None, 2)(
+                paddle.to_tensor(x)).numpy()
+            parts = [self._layer(experts, top_k, (lo, n_held), 2)(
+                paddle.to_tensor(x)).numpy()
+                for lo in range(0, experts, n_held)]
+            lone = self._layer(experts, top_k, (0, n_held), 2)
+            shared = lone.shared_experts
+            from paddle_tpu.models.lfm2 import swiglu
+            once = np.asarray(swiglu(x, shared.w1._data[0],
+                                     shared.w3._data[0], shared.w2._data[0]))
+        assert lone.scope == "moonlight" and lone.eps == 1e-20
+        assert lone.experts.w1.shape == [n_held, 16, 8]
+        assert shared.w1.shape == [1, 16, 16]       # two shared: one SwiGLU
+        total = sum(p - once for p in parts) + once
+        np.testing.assert_allclose(total, whole, atol=3e-5, rtol=0)
+        # a holder far from the chosen experts still computes the shared ones
+        assert all(np.abs(p).max() > 1e-2 for p in parts)
