@@ -6,8 +6,9 @@ cover the kernel's math). Every ``pallas_call`` in the package asks
 :func:`resolve_interpret` under a stable kernel name; the answer is
 recorded so a caller — ``chip_smoke.py``, a test — can assert that no
 kernel on its path was interpreted. A kernel whose dots follow its input's
-dtype (the flash family) records that operand dtype beside it, so the
-same callers can assert which width the MXU was fed.
+dtype (the flash family), or take another than its input's (the latent walk
+of ``paged_attn``: bfloat16 parts of float32 operands), records that operand
+dtype beside it, so the same callers can assert which width the MXU was fed.
 """
 from __future__ import annotations
 
@@ -33,10 +34,17 @@ def resolve_interpret(kernel: str, requested: Optional[bool] = None,
                  else requested)
     with _LOCK:
         _CHOSEN[kernel] = _CHOSEN.get(kernel, False) or interpret
-        if operand_dtype is not None:
-            _OPERANDS.setdefault(kernel, set()).add(
-                np.dtype(operand_dtype).name)
+    if operand_dtype is not None:
+        record_operand_dtype(kernel, operand_dtype)
     return interpret
+
+
+def record_operand_dtype(kernel: str, operand_dtype) -> None:
+    """Record (trace time) that a trace of ``kernel`` feeds its dots
+    ``operand_dtype``: for a call site that knows the dtype only in one of
+    its branches (the latent walk of ``paged_attn``)."""
+    with _LOCK:
+        _OPERANDS.setdefault(kernel, set()).add(np.dtype(operand_dtype).name)
 
 
 def chosen_modes() -> Dict[str, bool]:

@@ -106,7 +106,20 @@ other KV head), and ``[Hq, n * page] x [n * page, value]`` over the first
 columns of the SAME fetched rows for the result ``[S, Hq, value]``. The row is
 whole 128-lane tiles as the cache holds it: the device pads a narrower row to
 them in HBM anyway, and Mosaic refuses to copy a 576-wide slice of it
-(PERF.md section 4, PR 38).
+(PERF.md section 4, PR 38). Its two products are float32 products at the
+precision of ``HIGHEST`` but not left to it: with 16 query rows streamed past
+each standing ``[128, 128]`` tile of the fetched rows, the walk was bound by
+the MXU's tile LOADS and not by its copies (36 tiles a loop step of 8 pages,
+each loaded once a bfloat16 pass, six passes: 1.04 ms a layer at 48 x 4,064
+rows, 480 GB/s). ``HIGHEST`` is six bfloat16 products of the operands' three
+bfloat16 parts; grouped by the part of the STANDING operand they are three
+loads of a tile and not six, with the streamed operand's parts stacked along
+the rows, ``[48, row]``, ``[32, row]`` and ``[16, row]`` (``_stacked_dot``).
+The rows are split once and the split serves both products (the value's parts
+are the first columns of the key's). The same six partial products, another
+order of a float32 sum: 0.80 ms, 624 GB/s, the copies' time (PERF.md section 6,
+PR 39). The grouped-query walks keep float32 operands at ``HIGHEST``: Trinity's
+is at its copies' time as it is (32 tiles a step for three times the bytes).
 
 A window: with ``window=W`` query ``s`` attends the ``W`` rows ``positions[s]
 - W < j <= positions[s]`` only, and its walk BEGINS at the page of row
@@ -145,7 +158,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..core.pallas_mode import resolve_interpret
+from ..core.pallas_mode import record_operand_dtype, resolve_interpret
+from .pallas_attention import _mxu_dot
 
 __all__ = ["paged_attention"]
 
@@ -180,6 +194,38 @@ def _tuned_block_h(num_heads, head_dim, page_size, dtype):
     return b if b > 0 else None
 
 
+def _bf16_parts(x):
+    """Float32 ``x`` as three bfloat16 arrays ``h, m, l``, each the rounding
+    of what the parts before it leave: ``x = h + m + l`` to ``2**-24`` of
+    ``x``. They are the operands of the six bfloat16 products that a float32
+    product at ``Precision.HIGHEST`` is (``_stacked_dot``)."""
+    h = x.astype(jnp.bfloat16)
+    x = x - h.astype(jnp.float32)
+    m = x.astype(jnp.bfloat16)
+    return h, m, (x - m.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _stacked_dot(a_stack, b_parts, dims):
+    """``a . b`` of two float32 matrices as ``Precision.HIGHEST`` computes
+    it, six bfloat16 products accumulated in float32 (``a_h b_h``, ``a_h
+    b_m``, ``a_m b_h``, ``a_h b_l``, ``a_l b_h``, ``a_m b_m``; the three left
+    out are ``2**-23`` of the terms), grouped by the part of ``b`` they share:
+    ``(a_h, a_m, a_l) . b_h``, ``(a_h, a_m) . b_m``, ``a_h . b_l``. ``b`` is
+    the operand that stands on the MXU, so each of its tiles is loaded three
+    times and not six, and the parts of ``a`` stream past it stacked along
+    the rows. ``a_stack`` is ``concatenate(_bf16_parts(a))``, ``[3 r, k]``;
+    ``b_parts`` is ``_bf16_parts(b)``; ``dims`` contracts ``a``'s columns.
+    Returns ``[r, n]`` float32, the smallest products summed first."""
+    r = a_stack.shape[0] // 3
+    b_h, b_m, b_l = b_parts
+    dot = functools.partial(_mxu_dot(a_stack.dtype), dimension_numbers=dims)
+    high = dot(a_stack, b_h)                     # a_h b_h | a_m b_h | a_l b_h
+    mid = dot(a_stack[:2 * r], b_m)              # a_h b_m | a_m b_m
+    low = dot(a_stack[:r], b_l)                  # a_h b_l
+    return ((low + high[2 * r:] + mid[r:]) + (mid[:r] + high[r:2 * r])
+            + high[:r])
+
+
 def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
                        scale, page_size, pages_per_seq, pages_per_step,
                        block_h, head_blocks, groups, arenas, window=None,
@@ -208,7 +254,9 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
     ``value_width=n`` (latent rows, on the MXU recurrence with one KV head):
     a row is a key whole and a value in its first ``n`` columns, so the
     second product takes those columns of the page the first one took, and
-    the accumulator is ``[Hq, n]``.
+    the accumulator is ``[Hq, n]``. Both products take the bfloat16 parts of
+    their operands, the fetched rows split once for the two
+    (``_stacked_dot``; the module docstring's "Latent rows").
 
     Only the page that holds row ``positions[s]`` can hold rows past it
     (and a window's first page rows before it), so only those pages pay
@@ -304,10 +352,14 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
                 == lax.div(lax.broadcasted_iota(jnp.int32, shape, 0), groups)
             return jnp.where(own, 0.0, _NEG_INF)
 
-        # a product takes `stack` whole pages, or a power of two under it;
-        # latent rows are of the one KV head there is: nothing to push out
-        bias = {2 ** k: 0.0 if value_width is not None else other_heads(2 ** k)
-                for k in range(stack.bit_length())}
+        # a product takes `stack` whole pages, or a power of two under it
+        if value_width is None:
+            bias = {2 ** k: other_heads(2 ** k)
+                    for k in range(stack.bit_length())}
+        else:
+            # latent rows are of the one KV head there is, nothing to push
+            # out; the queries are split once a grid step
+            q_stack = jnp.concatenate(_bf16_parts(q_all), axis=0)
 
     def flat_pages(half, i, n, masked=False, at=None):
         """The MXU recurrence over the ``n`` page slots from ``i`` of buffer
@@ -337,12 +389,16 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
             vblk = jnp.where(live((rows, 1), 0), vblk, 0.0)
             if arenas == 1:
                 kblk = vblk
-        if value_width is not None:     # whole lane tiles of the same rows
-            vblk = kblk[:, :value_width]
-        s_blk = lax.dot_general(
-            q_all, kblk, (((1,), (1,)), ((), ())),
-            precision=lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32) + bias[n]    # [Hq, n * rows]
+        if value_width is not None:
+            # one split of the fetched rows serves both products: the value
+            # is whole lane tiles of the same rows
+            k_parts = _bf16_parts(kblk)
+            s_blk = _stacked_dot(q_stack, k_parts, (((1,), (1,)), ((), ())))
+        else:
+            s_blk = lax.dot_general(
+                q_all, kblk, (((1,), (1,)), ((), ())),
+                precision=lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32) + bias[n]  # [Hq, n * rows]
         if masked:
             s_blk = jnp.where(live((1, rows), 1), s_blk, _NEG_INF)
         m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]          # [Hq, 1]
@@ -352,10 +408,17 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
         # a live row of every KV head, so m_new is a real score
         pexp = jnp.exp(s_blk - m_new)
         l_new = l_prev * alpha + jnp.sum(pexp, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
-            pexp, vblk, (((1,), (0,)), ((), ())),
-            precision=lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
+        acc = acc_ref[...] * alpha
+        if value_width is not None:
+            acc_ref[...] = acc + _stacked_dot(
+                jnp.concatenate(_bf16_parts(pexp), axis=0),
+                [part[:, :value_width] for part in k_parts],
+                (((1,), (0,)), ((), ())))
+        else:
+            acc_ref[...] = acc + lax.dot_general(
+                pexp, vblk, (((1,), (0,)), ((), ())),
+                precision=lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
@@ -514,6 +577,7 @@ def paged_attention(q, k_arena, v_arena, block_tables, positions,
                 "latent rows take the plain walk over ONE arena [P+1, L, "
                 "page, row >= value + rotary], no second arena, window or "
                 "selection, and queries of value + rotary columns")
+        record_operand_dtype("paged_attn", jnp.bfloat16)  # _stacked_dot's
         return _paged_attention(
             q, k_arena[:, :, :, None, :], None,
             block_tables.astype(jnp.int32), positions.astype(jnp.int32),

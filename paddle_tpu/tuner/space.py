@@ -247,7 +247,21 @@ def paged_attn_vmem_bytes(block_h: int, page_size: int, head_dim: int,
     the same scratch under other shapes (flat rows, ``[Hq, head_dim]``, a
     column a query head) and holds values besides: the scores of the pages
     stacked into a product, their ``exp``, and the other-heads bias of
-    every product size (``stack``, ``stack / 2`` ... 1 pages)."""
+    every product size (``stack``, ``stack / 2`` ... 1 pages).
+
+    Latent rows (ONE KV head in ONE arena on the MXU recurrence; a fused-row
+    call of one KV head holds less than is stated for it) build no bias and
+    feed the MXU bfloat16 parts (``ops/paged_attention.py:_stacked_dot``):
+    beside the scores and their ``exp`` a program holds the three parts of a
+    product's stacked rows, each as converted and as re-tiled for the MXU,
+    the float32 rows a part leaves, the stacked parts of the queries and of
+    ``exp``, and the three partial results of either product (six blocks
+    ``[Hq, rows]`` and six ``[Hq, row]``) before their sum. Checked against
+    the compiler for a described v5e at ``(1, 64, 640, 4, 1, 16)``, PR 39:
+    this states 8,704,000 bytes where the compiler's scratch, windows and
+    1,448 spilled vector registers come to 8,704,000 (the parent's: 2,969,600
+    stated, 11,694,080 built, the compiler's own split of ``HIGHEST``
+    unstated), both under the 16 MiB scope."""
     pages = paged_pages_per_step(block_h, page_size, head_dim, itemsize,
                                  arenas, pages_per_seq)
     buffers = paged_buffer_bytes(pages, block_h, page_size, head_dim,
@@ -260,7 +274,15 @@ def paged_attn_vmem_bytes(block_h: int, page_size: int, head_dim: int,
                         arenas) == "mxu":
         q_heads, flat = groups * block_h, page_size * block_h
         stack = paged_stack_pages(pages, q_heads, flat)
-        scores = (4 * stack - 1) * q_heads * flat * 4
+        block = q_heads * stack * flat * 4
+        if block_h == 1 and arenas == 1:
+            rows = stack * flat * head_dim      # a product's stacked rows
+            parts = 3 * rows * 2                # their bfloat16 parts
+            stacked = 3 * q_heads * (head_dim + stack * flat) * 2   # q, exp
+            partial = 6 * (block + q_heads * head_dim * 4)
+            scores = 2 * block + 2 * parts + rows * 4 + stacked + partial
+        else:
+            scores = (4 * stack - 1) * q_heads * flat * 4
     return buffers + q_out + acc + stats + scores
 
 

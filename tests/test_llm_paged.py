@@ -750,6 +750,146 @@ class TestLatentRows:
                 256 if fused else 128)
 
 
+    #: the accepted cells' calls: query heads and width, the arena (a second
+    #: one of the same shape or fused rows), pages a sequence, options, and
+    #: the products the traced kernel holds (none on the VPU recurrence; two
+    #: a product size and masked page on the MXU one)
+    SERVED = {
+        "gpt1p3b": ((16, 128), (49, 2, 16, 16, 128), True, 48, {}, 0),
+        "lfm2": ((32, 64), (97, 2, 16, 8, 128), False, 96, {}, 14),
+        "trinity_window": ((32, 128), (40, 2, 64, 4, 128), True, 34,
+                           {"window": 2048}, 12),
+        "trinity_full": ((32, 128), (40, 2, 64, 4, 128), True, 34, {}, 10),
+    }
+
+    @staticmethod
+    def _kernel_dots(q, arena, second, pp, **options):
+        """The ``dot_general`` equations of the traced kernel of one call
+        over four sequences, as (operand dtypes, precision, result dtype)."""
+        shape = jax.ShapeDtypeStruct
+        args = [shape((4,) + q, jnp.float32), shape(arena, jnp.float32),
+                shape(arena, jnp.float32) if second else None,
+                shape((4, pp), jnp.int32), shape((4,), jnp.int32)]
+        traced = jax.make_jaxpr(lambda *a: paged_attention(
+            *a, layer=1, interpret=True, **options))(*args)
+        return [(tuple(v.aval.dtype.name for v in e.invars),
+                 e.params["precision"], e.outvars[0].aval.dtype.name)
+                for e in _eqns(traced.jaxpr)
+                if e.primitive.name == "dot_general"]
+
+    @pytest.mark.parametrize("cell", list(SERVED))
+    def test_the_split_is_not_in_the_other_cells_kernels(self, cell,
+                                                         monkeypatch):
+        """At the accepted cells' shapes the kernel's products are float32
+        operands at ``highest``, as many as before the latent walk split its
+        own, and the call records no operand dtype."""
+        from jax import lax
+        from paddle_tpu.core import pallas_mode
+        monkeypatch.setattr(pallas_mode, "_OPERANDS", {})
+        *call, options, products = self.SERVED[cell]
+        dots = self._kernel_dots(*call, **options)
+        assert len(dots) == products
+        assert all(dot == (("float32", "float32"), (lax.Precision.HIGHEST,) * 2,
+                           "float32") for dot in dots)
+        assert "paged_attn" not in pallas_mode.chosen_operand_dtypes()
+
+    def test_the_latent_walk_feeds_the_mxu_bfloat16_parts(self, monkeypatch):
+        """Every product of the latent kernel takes bfloat16 operands at
+        ``DEFAULT`` into float32, three (a part of the fetched rows each)
+        where there was one, and the call says so at trace time."""
+        from jax import lax
+        from paddle_tpu.core import pallas_mode
+        monkeypatch.setattr(pallas_mode, "_OPERANDS", {})
+        dots = self._kernel_dots((16, 576), (120, 2, 64, 640), False, 112,
+                                 latent=(512, 64))
+        # two products a size (8, 4, 2, 1 pages) and the masked page
+        assert len(dots) == 3 * 10
+        assert all(dot == (("bfloat16", "bfloat16"), (lax.Precision.DEFAULT,) * 2,
+                           "float32") for dot in dots)
+        assert pallas_mode.chosen_operand_dtypes()["paged_attn"] == (
+            "bfloat16",)
+
+
+_SCORES, _VALUES = (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ()))
+#: the six bfloat16 products of a float32 product at ``highest``, by the
+#: parts (0 high, 1 middle, 2 low) of the streamed and the standing operand
+_SIX = ((0, 0), (0, 1), (1, 0), (0, 2), (2, 0), (1, 1))
+
+
+class TestStackedDot:
+    """The latent walk's products (``ops/paged_attention.py:_stacked_dot``):
+    a float32 product as its six bfloat16 partial products, three loads of
+    the standing operand, each case held to a float64 product."""
+
+    @staticmethod
+    def _operands(product, pages, exact):
+        """The score product's ``[16, 640] x [640, pages * 64]`` or the value
+        product's ``[16, pages * 64] x [pages * 64, 512]``, over eight
+        decades of magnitude or (``exact``) small whole numbers."""
+        rng = np.random.default_rng(39 + pages)
+        shapes = ((16, 640), (pages * 64, 640)) if product == "scores" \
+            else ((16, pages * 64), (pages * 64, 512))
+        if exact:       # exact in bfloat16, and every sum of products in f32
+            return [rng.integers(-8, 9, n).astype(np.float32) for n in shapes]
+        return [(rng.choice([-1.0, 1.0], n) * 10.0 ** rng.uniform(-4, 4, n)
+                 ).astype(np.float32) for n in shapes]
+
+    @staticmethod
+    def _judged(product, a, b, dropped=None):
+        """The stacked product of ``a`` and ``b`` (less the partial product
+        ``dropped``), what three passes (``high``) leave of the same parts,
+        the float64 product and the sum of its terms' magnitudes."""
+        from paddle_tpu.ops.paged_attention import _bf16_parts, _stacked_dot
+        sub = "rk,nk->rn" if product == "scores" else "rk,kn->rn"
+        dims = _SCORES if product == "scores" else _VALUES
+        a_parts, b_parts = _bf16_parts(jnp.asarray(a)), _bf16_parts(
+            jnp.asarray(b))
+        got = np.asarray(_stacked_dot(jnp.concatenate(a_parts), b_parts,
+                                      dims), np.float64)
+        a64, b64 = ([np.asarray(part, np.float64) for part in parts]
+                    for parts in (a_parts, b_parts))
+        if dropped is not None:
+            got = got - np.einsum(sub, a64[dropped[0]], b64[dropped[1]])
+        three = sum(np.einsum(sub, a64[i], b64[j]) for i, j in _SIX[:3])
+        want = np.einsum(sub, a.astype(np.float64), b.astype(np.float64))
+        terms = np.einsum(sub, np.abs(a).astype(np.float64),
+                          np.abs(b).astype(np.float64))
+        return got, three, want, terms
+
+    @pytest.mark.parametrize("pages", [1, 2, 4, 8])
+    @pytest.mark.parametrize("product", ["scores", "values"])
+    def test_all_six_partial_products_over_eight_decades(self, product,
+                                                         pages):
+        """An entry lies within ``2**-21`` of the sum of its terms'
+        magnitudes (the three products ``highest`` itself leaves out are
+        ``2**-23`` of it), and 20 times closer than three passes come."""
+        got, three, want, terms = self._judged(
+            product, *self._operands(product, pages, exact=False))
+        err, err_high = np.abs(got - want) / terms, np.abs(three - want) / terms
+        assert err.max() <= 2.0 ** -21
+        assert 20 * err.max() <= err_high.max()
+
+    @pytest.mark.parametrize("pages", [1, 2, 4, 8])
+    @pytest.mark.parametrize("product", ["scores", "values"])
+    def test_operands_exact_in_bfloat16_give_the_exact_product(self, product,
+                                                               pages):
+        got, _, want, _ = self._judged(
+            product, *self._operands(product, pages, exact=True))
+        np.testing.assert_array_equal(got, want)
+
+    @pytest.mark.parametrize("dropped", _SIX, ids=lambda d: "hml"[d[0]]
+                             + "hml"[d[1]])
+    @pytest.mark.parametrize("product", ["scores", "values"])
+    def test_a_dropped_partial_product_is_seen(self, product, dropped):
+        """Without any one of the six the result is no longer 20 times
+        closer than three passes: the bound above is a bound on all six."""
+        got, three, want, terms = self._judged(
+            product, *self._operands(product, 8, exact=False),
+            dropped=dropped)
+        err, err_high = np.abs(got - want) / terms, np.abs(three - want) / terms
+        assert 20 * err.max() > err_high.max()
+
+
 class TestEngineParity:
     """End-to-end greedy decode through the engine: the paged layout
     must be invisible in the tokens."""
@@ -1080,6 +1220,48 @@ class TestTunerFamily:
         assert held == paged_attn_vmem_bytes(
             hkv, page, row, 4, arenas=1 if fused else 2, groups=groups,
             pages_per_seq=pp)
+
+
+    @pytest.mark.parametrize("shape,held", [
+        # (block_h, page, row width, itemsize, arenas, groups, pages a seq)
+        ((16, 16, 128, 4, 2, 1, 48), 4251648),    # serve-gpt1p3b-decode
+        ((16, 16, 128, 4, 2, 1, 68), 4251648),    # serve-gpt1p3b-longprompt
+        ((8, 16, 128, 4, 1, 4, 96), 6389760),     # serve-lfm2moe-decode
+        ((4, 64, 128, 4, 2, 8, 272), 5324800),    # serve-trinity-mixedctx
+    ])
+    def test_vmem_model_at_the_served_shapes_is_what_it_was(self, shape,
+                                                            held):
+        """The latent walk's account (below) is the latent shape's alone."""
+        from paddle_tpu.tuner.space import paged_attn_vmem_bytes
+        assert paged_attn_vmem_bytes(*shape) == held
+
+    def test_vmem_model_counts_the_latent_walks_parts(self):
+        """ONE KV head in ONE arena on the MXU recurrence is the latent
+        walk: no bias, and beside the buffers, the blocks, the scratch, the
+        scores and their ``exp`` it holds the three bfloat16 parts of a loop
+        step's rows (as converted and as the MXU takes them) and the float32
+        rows a part leaves, the stacked parts of the queries and of ``exp``,
+        and the partial results of both products. Under the 16 MiB scope
+        with its 2.6 MB of page buffers; what the compiler builds for a
+        described v5e is in the model's docstring."""
+        from paddle_tpu.tuner import space
+        heads, page, row, stack = 16, 64, 640, 8
+        shape = (1, page, row, 4, 1, heads, 112)
+        assert space.paged_recurrence(heads, 1, page, row, 4, 1) == "mxu"
+        assert space.paged_pages_per_step(1, page, row, 4, 1, 112) == stack
+        assert space.paged_stack_pages(stack, heads, page) == stack
+        buffers = space.paged_buffer_bytes(stack, 1, page, row, 4, 1)
+        assert buffers == 2621440
+        rows, block = stack * page * row, heads * stack * page * 4
+        parts = 3 * rows * 2
+        assert parts == 1966080                     # the issue's 1.97 MB
+        stacked = 3 * heads * row * 2 + 3 * heads * stack * page * 2
+        partial = 6 * block + 6 * heads * row * 4
+        held = (buffers + 2 * 2 * heads * row * 4   # q and out, twice each
+                + heads * row * 4 + 2 * heads * 128 * 4     # acc, max, sum
+                + 2 * block + 2 * parts + rows * 4 + stacked + partial)
+        assert space.paged_attn_vmem_bytes(*shape) == held == 8704000
+        assert held < space.VMEM_BUDGET < space.VMEM_BYTES == 16 * 2 ** 20
 
 
 class TestAuditEntrypoint:
