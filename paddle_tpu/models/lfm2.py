@@ -118,9 +118,15 @@ class LFM2Config:
 
 # -- the arithmetic (raw arrays; shared by forward, prefill and decode) -------
 
-def rope(x, positions, theta: float):
-    """Rotate-half RoPE over the whole head. ``x``: ``[B, T, heads, D]``;
-    ``positions``: ``[B, T]`` (or ``[T]``) int32."""
+def rope(x, positions, theta: float, rotary_dim: Optional[int] = None):
+    """Rotate-half RoPE over the first ``rotary_dim`` columns of a head (the
+    whole head by default; the other columns pass unrotated: partial
+    rotary). ``x``: ``[B, T, heads, D]``; ``positions``: ``[B, T]`` (or
+    ``[T]``) int32."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        return jnp.concatenate(
+            [rope(x[..., :rotary_dim], positions, theta),
+             x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = positions.astype(jnp.float32)[..., None] * inv          # [.., T, D/2]

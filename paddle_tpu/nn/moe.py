@@ -1,6 +1,7 @@
-"""RMSNorm and the sparse-expert feed-forward layer (top-k, sigmoid scores,
-no dropped tokens). The arithmetic is in ``ops/moe.py``; this is the
-``Layer`` that owns the parameters and is told which experts it holds."""
+"""RMSNorm and the sparse-expert feed-forward layer (top-k, sigmoid or
+softmax scores, no dropped tokens). The arithmetic is in ``ops/moe.py``; this
+is the ``Layer`` that owns the parameters and is told which experts it
+holds."""
 from __future__ import annotations
 
 from typing import Optional, Tuple
@@ -60,48 +61,76 @@ class ExpertStack(Layer):
 class MoEFeedForward(Layer):
     """``sum over the top_k chosen e of w_e * SwiGLU_e(x)``.
 
-    Scores are ``sigmoid(x @ gate)``; the chosen set is the ``top_k`` of
-    score + ``expert_bias``; the weights are the scores alone, normalised
-    over the chosen (``norm_topk``) and scaled. ``held = (lo, n)`` says
-    which of the ``num_experts`` live here (default: all): routing is over
-    all of them and the result is the held experts' part of the sum.
-    ``shared`` experts (a count; one SwiGLU of ``shared * width``) are
-    computed for every token, unweighted, by EVERY holder: the result is then
-    ``Shared(x)`` + the held experts' part, and whoever adds the holders'
-    parts up counts the shared one once. ``eps`` is the renormalisation's."""
+    ``route="sigmoid"``: scores are ``sigmoid(x @ gate)``; the chosen set is
+    the ``top_k`` of score + ``expert_bias``; the weights are the scores
+    alone, normalised over the chosen (``norm_topk``, with ``eps``) and
+    scaled. ``route="softmax"``: the ``top_k`` of ``softmax(x @ gate)`` over
+    ALL the experts, normalised over the chosen and scaled; there is no
+    ``expert_bias`` and no epsilon. ``held = (lo, n)`` says which of the
+    ``num_experts`` live here (default: all): either routing is over all of
+    them and the result is the held experts' part of the sum. ``shared``
+    experts (a count; one SwiGLU of ``shared * width``) are computed for
+    every token by EVERY holder, unweighted or, with ``shared_gate``, times
+    ``sigmoid(x @ shared_expert_gate)`` (one number a token): the result is
+    then ``Shared(x)`` + the held experts' part, and whoever adds the holders'
+    parts up counts the shared one once."""
 
     def __init__(self, hidden: int, width: int, num_experts: int,
                  top_k: int, norm_topk: bool = True, scale: float = 1.0,
                  held: Optional[Tuple[int, int]] = None, shared: int = 0,
-                 eps: float = 1e-6, scope: str = "lfm2"):
+                 eps: float = 1e-6, scope: str = "lfm2",
+                 route: str = "sigmoid", shared_gate: bool = False):
         super().__init__()
+        if route not in _moe.ROUTES:
+            raise ValueError(
+                f"route must be one of {_moe.ROUTES}, got {route!r}")
+        if shared_gate and not shared:
+            raise ValueError("shared_gate gates a shared expert: shared is 0")
         self.top_k, self.norm_topk, self.scale = top_k, norm_topk, scale
-        self.eps, self.scope = eps, scope
+        self.eps, self.scope, self.route = eps, scope, route
         self.expert_lo, num_held = held or (0, num_experts)
-        if not (0 <= self.expert_lo
+        if not (0 <= self.expert_lo and num_held >= 1
                 and self.expert_lo + num_held <= num_experts):
             raise ValueError(f"held experts {held} outside 0..{num_experts}")
+        if top_k > num_experts:
+            raise ValueError(f"top_k {top_k} of {num_experts} experts")
         self.gate = Layer()
         self.gate.weight = self.create_parameter(
             [hidden, num_experts], default_initializer=I.Normal(0.0, 0.02))
-        self.expert_bias = self.create_parameter(
-            [num_experts], default_initializer=I.Constant(0.0))
+        if route == "sigmoid":      # the selection bias is that routing's
+            self.expert_bias = self.create_parameter(
+                [num_experts], default_initializer=I.Constant(0.0))
         self.experts = ExpertStack(num_held, hidden, width)
         if shared:      # one stack row: the same leaves as an expert's
             self.shared_experts = ExpertStack(1, hidden, shared * width)
+        if shared_gate:
+            self.shared_expert_gate = Layer()
+            self.shared_expert_gate.weight = self.create_parameter(
+                [hidden, 1], default_initializer=I.Normal(0.0, 0.02))
 
     def forward(self, x):
         kw = dict(top_k=self.top_k, norm_topk=self.norm_topk,
                   scale=self.scale, expert_lo=self.expert_lo, eps=self.eps,
-                  scope=self.scope)
+                  scope=self.scope, route=self.route)
+        biased = self.route == "sigmoid"
 
-        def _ffn(a, gate, bias, w1, w3, w2, *shared):
+        def _ffn(a, gate, *rest):
+            bias, rest = (rest[0], rest[1:]) if biased else (None, rest)
+            (w1, w3, w2), shared = rest[:3], rest[3:]
             out, _counts = _moe.moe_feed_forward(
                 a.reshape(-1, a.shape[-1]), gate, bias, w1, w3, w2, **kw)
             out = out.reshape(a.shape)
-            return out + swiglu(a, *(w[0] for w in shared)) if shared else out
+            if not shared:
+                return out
+            both = swiglu(a, *(w[0] for w in shared[:3]))
+            if len(shared) == 4:        # the gated shared expert
+                both = jax.nn.sigmoid(a @ shared[3]) * both
+            return out + both
         e = self.experts
         sh = getattr(self, "shared_experts", None)
+        sg = getattr(self, "shared_expert_gate", None)
         return apply("moe_feed_forward", _ffn, x, self.gate.weight,
-                     self.expert_bias, e.w1, e.w3, e.w2,
-                     *((sh.w1, sh.w3, sh.w2) if sh is not None else ()))
+                     *((self.expert_bias,) if biased else ()),
+                     e.w1, e.w3, e.w2,
+                     *((sh.w1, sh.w3, sh.w2) if sh is not None else ()),
+                     *((sg.weight,) if sg is not None else ()))

@@ -1,7 +1,13 @@
 """Sparse experts with no dropped tokens: routing, the grouped layout and
 the grouped matrix product (Pallas kernel ``moe_experts``).
 
-Every token gets all ``top_k`` of its experts. The token-expert pairs are
+Routing is the caller's choice of two (``moe_feed_forward(route=...)``):
+``"sigmoid"`` (:func:`route_sigmoid_topk`: independent sigmoid scores, chosen
+by score plus a stored selection bias, weighted by score alone; the LFM2,
+Trinity and Moonlight families) and ``"softmax"``
+(:func:`route_softmax_topk`: the ``top_k`` of a softmax over ALL the experts,
+renormalised over the chosen; the Qwen3-Next family). Either way every token
+gets all ``top_k`` of its experts. The token-expert pairs are
 laid out by expert in a row buffer in which each expert's rows start at a
 multiple of the row tile ``tm``, so a tile of rows belongs to exactly one
 expert and the product needs no mask:
@@ -41,8 +47,10 @@ from jax import lax
 
 from ..core.pallas_mode import resolve_interpret
 
-__all__ = ["route_sigmoid_topk", "group_layout", "moe_experts",
-           "moe_feed_forward"]
+__all__ = ["route_sigmoid_topk", "route_softmax_topk", "group_layout",
+           "moe_experts", "moe_feed_forward"]
+
+ROUTES = ("sigmoid", "softmax")
 
 #: rows of a tile at most (one MXU pass of rows); fewer when fewer tokens
 MAX_ROW_TILE = 128
@@ -67,6 +75,22 @@ def route_sigmoid_topk(f, gate_w, expert_bias, top_k: int,
     if norm_topk:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return idx.astype(jnp.int32), w * scale
+
+
+def route_softmax_topk(f, gate_w, top_k: int, norm_topk: bool = True,
+                       scale: float = 1.0):
+    """The ``top_k`` of a softmax over ALL the experts, in float32.
+
+    ``f``: ``[T, h]``; ``gate_w``: ``[h, E]``. Returns ``(idx [T, k] int32,
+    weights [T, k])``: the ``top_k`` experts of ``softmax(f @ gate_w)`` and
+    their probabilities, divided by their sum under ``norm_topk`` (no
+    epsilon: a chosen probability is never zero), times ``scale``.
+    """
+    p = jax.nn.softmax((f @ gate_w).astype(jnp.float32), axis=-1)
+    w, idx = lax.top_k(p, top_k)
+    if norm_topk:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), (w * scale).astype(f.dtype)
 
 
 def row_tile(num_tokens: int) -> int:
@@ -213,13 +237,22 @@ def moe_experts(rows, w1, w3, w2, layout, interpret=None):
 def moe_feed_forward(f, gate_w, expert_bias, w1, w3, w2, *, top_k: int,
                      norm_topk: bool = True, scale: float = 1.0,
                      expert_lo: int = 0, interpret=None, eps: float = 1e-6,
-                     scope: str = "lfm2"):
+                     scope: str = "lfm2", route: str = "sigmoid"):
     """The expert layer on ``f`` ``[T, h]``: ``(out [T, h], counts [n])``,
-    ``counts`` the pairs each held expert received. ``scope`` prefixes the
-    two ``jax.named_scope``s (the calling family's name)."""
+    ``counts`` the pairs each held expert received. ``route`` names the
+    routing (:data:`ROUTES`; ``"softmax"`` has no selection bias and no
+    epsilon: ``expert_bias`` is None). ``scope`` prefixes the two
+    ``jax.named_scope``s (the calling family's name)."""
+    if route not in ROUTES:
+        raise ValueError(f"route must be one of {ROUTES}, got {route!r}")
     with jax.named_scope(f"{scope}/moe_route"):
-        idx, wts = route_sigmoid_topk(f, gate_w, expert_bias, top_k,
-                                      norm_topk, scale, eps)
+        if route == "sigmoid":
+            idx, wts = route_sigmoid_topk(f, gate_w, expert_bias, top_k,
+                                          norm_topk, scale, eps)
+        else:
+            if expert_bias is not None:
+                raise ValueError("softmax routing has no selection bias")
+            idx, wts = route_softmax_topk(f, gate_w, top_k, norm_topk, scale)
         layout = group_layout(idx, w1.shape[0], expert_lo)
     with jax.named_scope(f"{scope}/moe_experts"):
         y = moe_experts(f[layout["src"]], w1, w3, w2, layout, interpret)

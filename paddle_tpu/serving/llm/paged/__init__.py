@@ -15,7 +15,10 @@ linear-attention state a slot, ``trinity`` for the Trinity family, whose
 window and full attention layers keep different pages of one sequence (two
 page groups in one cache), ``moonlight`` for the Moonlight family, whose
 latent attention keeps ONE row a token and layer for all its heads (one arena,
-an absorbed decode step and an expanded chunk program over it).
+an absorbed decode step and an expanded chunk program over it), ``qwen3next``
+for the Qwen3-Next family, whose cache holds pages for its full-attention
+layers only (one layer in four) and, a slot and linear layer, a gated
+delta-rule state and a short convolution's last inputs.
 """
 from .batcher import PagedBatcher
 from .decode import (GPTPagedDecoder, paged_decoder_class,
@@ -31,6 +34,7 @@ from .lfm2 import LFM2PagedDecoder
 from .sala import SALAPagedDecoder
 from .trinity import TrinityPagedDecoder
 from .moonlight import MoonlightPagedDecoder
+from .qwen3next import Qwen3NextPagedDecoder
 from .spec import (GPTPagedSpecDecoder, build_paged_spec_decode_step,
                    get_paged_spec_decode_step)
 
@@ -54,6 +58,7 @@ __all__ = [
     "SALAPagedDecoder",
     "TrinityPagedDecoder",
     "MoonlightPagedDecoder",
+    "Qwen3NextPagedDecoder",
     "paged_decoder_class",
     "register_paged_decoder",
     "build_paged_spec_decode_step",

@@ -220,6 +220,13 @@ def plain_walk_recurrence(decoder, kv) -> Optional[str]:
         _, _, page, row = kv.k.shape
         return paged_recurrence(spec.num_attention_heads, 1, page, row,
                                 kv.k.dtype.itemsize, 1)
+    if getattr(decoder, "head_major_walk", False):
+        # a head-major arena walked plainly: a KV head a call, its query
+        # heads on the head's own fused rows
+        _, _, page, row = kv.k.shape
+        return paged_recurrence(
+            spec.num_attention_heads // spec.num_key_value_heads, 1, page,
+            row, kv.k.dtype.itemsize, 1)
     if getattr(kv.k, "ndim", 0) != 5:
         return None
     q_heads = getattr(spec, "num_attention_heads", None) or spec.num_heads

@@ -218,3 +218,166 @@ class TestASecondConfigurationOnTheHeldPath:
         np.testing.assert_allclose(total, whole, atol=3e-5, rtol=0)
         # a holder far from the chosen experts still computes the shared ones
         assert all(np.abs(p).max() > 1e-2 for p in parts)
+
+
+class TestSoftmaxRoutingAndAGatedSharedExpert:
+    """``MoEFeedForward(2048, 512, 512, 10, held=(0, 64), shared=1,
+    scope="qwen3next", route="softmax", shared_gate=True)`` at toy widths:
+    the top of a softmax over ALL the experts, renormalised, no selection
+    bias and no epsilon; a shared expert weighted by one sigmoid a token; the
+    sigmoid families' code as it was."""
+
+    def _layer(self, experts, top_k, held, seed=7, **kw):
+        from paddle_tpu.nn import MoEFeedForward
+        paddle.seed(seed)
+        kw = dict(dict(shared=1, scope="qwen3next", route="softmax",
+                       shared_gate=True), **kw)
+        layer = MoEFeedForward(16, 8, experts, top_k, True, held=held, **kw)
+        rng = np.random.RandomState(seed)
+        whole = {m: rng.randn(experts, *shape).astype(np.float32) * 0.3
+                 for m, shape in (("w1", (16, 8)), ("w3", (16, 8)),
+                                  ("w2", (8, 16)))}
+        lo, n = held or (0, experts)
+        layer.gate.weight.set_value(
+            rng.randn(16, experts).astype(np.float32))
+        for m, w in whole.items():
+            getattr(layer.experts, m).set_value(w[lo:lo + n])
+        for m, shape in (("w1", (1, 16, 8)), ("w3", (1, 16, 8)),
+                         ("w2", (1, 8, 16))):
+            value = rng.randn(*shape).astype(np.float32) * 0.3
+            if hasattr(layer, "shared_experts"):
+                getattr(layer.shared_experts, m).set_value(value)
+        if hasattr(layer, "shared_expert_gate"):
+            layer.shared_expert_gate.weight.set_value(
+                rng.randn(16, 1).astype(np.float32))
+        return layer, whole
+
+    def test_softmax_route_is_the_top_of_a_softmax_renormalised(self):
+        from paddle_tpu.ops.moe import route_softmax_topk
+        rng = np.random.RandomState(0)
+        f = rng.randn(12, 16).astype(np.float32)
+        gate = rng.randn(16, 32).astype(np.float32)
+        idx, w = route_softmax_topk(f, gate, 5)
+        logits = f @ gate
+        p = np.exp(logits - logits.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        order = np.argsort(-p, axis=-1)[:, :5]
+        np.testing.assert_array_equal(np.sort(np.asarray(idx), -1),
+                                      np.sort(order, -1))
+        want = np.take_along_axis(p, np.asarray(idx), -1)
+        np.testing.assert_allclose(w, want / want.sum(-1, keepdims=True),
+                                   rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(w).sum(-1), 1.0, rtol=1e-6)
+        # unnormalised: the probabilities themselves, times the scale
+        _, raw = route_softmax_topk(f, gate, 5, norm_topk=False, scale=2.0)
+        np.testing.assert_allclose(raw, 2.0 * want, rtol=1e-5)
+
+    @pytest.mark.parametrize("experts,top_k,n_held", [
+        (64, 10, 8), (16, 4, 2), (32, 10, 32)])
+    def test_every_share_adds_up_with_the_gated_shared_expert_once(
+            self, experts, top_k, n_held):
+        from paddle_tpu.models.lfm2 import swiglu
+        x = np.random.RandomState(1).randn(20, 16).astype(np.float32)
+        with paddle.no_grad():
+            whole_layer, w = self._layer(experts, top_k, None)
+            whole = whole_layer(paddle.to_tensor(x)).numpy()
+            parts = [self._layer(experts, top_k, (lo, n_held))[0](
+                paddle.to_tensor(x)).numpy()
+                for lo in range(0, experts, n_held)]
+        sh, sg = whole_layer.shared_experts, whole_layer.shared_expert_gate
+        gate = 1.0 / (1.0 + np.exp(-(x @ np.asarray(sg.weight._data))))
+        once = gate * np.asarray(swiglu(x, sh.w1._data[0], sh.w3._data[0],
+                                        sh.w2._data[0]))
+        # one number a token, and not the same number for every token
+        assert gate.shape == (20, 1) and gate.min() < 0.4 < 0.6 < gate.max()
+        total = sum(p - once for p in parts) + once
+        np.testing.assert_allclose(total, whole, atol=3e-5, rtol=0)
+        # against the layer written out: every expert on every row
+        logits = x @ np.asarray(whole_layer.gate.weight._data)
+        p = np.exp(logits - logits.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        kth = np.sort(p, -1)[:, -top_k][:, None]
+        wts = np.where(p >= kth, p, 0.0)
+        wts /= wts.sum(-1, keepdims=True)
+        dense = once + sum(
+            wts[:, e:e + 1] * np.asarray(swiglu(x, w["w1"][e], w["w3"][e],
+                                                w["w2"][e]))
+            for e in range(experts))
+        np.testing.assert_allclose(whole, dense, atol=3e-5, rtol=0)
+
+    def test_the_gate_weighs_the_shared_expert_and_nothing_else(self):
+        x = np.random.RandomState(2).randn(9, 16).astype(np.float32)
+        with paddle.no_grad():
+            gated, _ = self._layer(16, 4, (0, 4))
+            plain, _ = self._layer(16, 4, (0, 4), shared_gate=False)
+            bare, _ = self._layer(16, 4, (0, 4), shared=0, shared_gate=False)
+            g, p, b = (layer(paddle.to_tensor(x)).numpy()
+                       for layer in (gated, plain, bare))
+        gate = 1.0 / (1.0 + np.exp(-(x @ np.asarray(
+            gated.shared_expert_gate.weight._data))))
+        np.testing.assert_allclose(g - b, gate * (p - b), atol=1e-5)
+        assert not hasattr(plain, "shared_expert_gate")
+        assert not hasattr(gated, "expert_bias")    # softmax: no bias
+
+    def test_what_does_not_fit_is_refused(self):
+        from paddle_tpu.nn import MoEFeedForward
+        from paddle_tpu.ops.moe import moe_feed_forward
+        with pytest.raises(ValueError, match="route"):
+            MoEFeedForward(16, 8, 8, 2, route="top1")
+        with pytest.raises(ValueError, match="shared_gate"):
+            MoEFeedForward(16, 8, 8, 2, shared_gate=True)
+        for held in ((6, 4), (0, 0), (-1, 2)):      # both routings alike
+            for route in ("sigmoid", "softmax"):
+                with pytest.raises(ValueError, match="held"):
+                    MoEFeedForward(16, 8, 8, 2, held=held, route=route)
+        with pytest.raises(ValueError, match="top_k"):
+            MoEFeedForward(16, 8, 4, 6, route="softmax")
+        f = np.zeros((4, 16), np.float32)
+        w = np.zeros((2, 16, 8), np.float32)
+        with pytest.raises(ValueError, match="selection bias"):
+            moe_feed_forward(f, np.zeros((16, 4), np.float32),
+                             np.zeros((4,), np.float32), w, w,
+                             np.zeros((2, 8, 16), np.float32), top_k=2,
+                             route="softmax")
+        with pytest.raises(ValueError, match="route"):
+            moe_feed_forward(f, np.zeros((16, 4), np.float32), None, w, w,
+                             np.zeros((2, 8, 16), np.float32), top_k=2,
+                             route="argmax")
+
+    def test_the_sigmoid_families_run_the_code_they_ran(self):
+        """The default is the sigmoid routing with its bias and epsilon: a
+        layer built as LFM2, Trinity and Moonlight build theirs compiles to
+        the same program whether ``route`` is named or left out ('route'
+        reaches the trace as a Python branch and leaves nothing behind; PR 40
+        read the text equal to the parent tree's for all three families),
+        and to the layer written out."""
+        import jax
+        from paddle_tpu.ops.moe import moe_feed_forward, route_sigmoid_topk
+        rng = np.random.RandomState(3)
+        f = rng.randn(6, 16).astype(np.float32)
+        gate = rng.randn(16, 8).astype(np.float32)
+        bias = (rng.randn(8) * 0.02).astype(np.float32)
+        w1, w3 = (0.3 * rng.randn(8, 16, 8).astype(np.float32)
+                  for _ in range(2))
+        w2 = 0.3 * rng.randn(8, 8, 16).astype(np.float32)
+        kw = dict(top_k=2, norm_topk=True, scale=2.446, eps=1e-20,
+                  scope="moonlight")
+
+        def default(*a):
+            return moe_feed_forward(*a, **kw)
+
+        def named(*a):
+            return moe_feed_forward(*a, route="sigmoid", **kw)
+
+        args = (f, gate, bias, w1, w3, w2)
+        a, b = (jax.jit(fn).lower(*args).as_text(debug_info=False)
+                for fn in (default, named))
+        assert a.replace("named", "default") == b.replace("named", "default")
+        out, counts = default(*args)
+        idx, wts = route_sigmoid_topk(f, gate, bias, 2, True, 2.446, 1e-20)
+        assert int(np.asarray(counts).sum()) == 12
+        from paddle_tpu.models.lfm2 import swiglu
+        dense = sum(np.asarray(wts)[:, j:j + 1] * np.stack([
+            np.asarray(swiglu(f[t], w1[e], w3[e], w2[e]))
+            for t, e in enumerate(np.asarray(idx)[:, j])]) for j in range(2))
+        np.testing.assert_allclose(out, dense, atol=2e-5, rtol=0)
