@@ -68,7 +68,9 @@ class MoEFeedForward(Layer):
     ALL the experts, normalised over the chosen and scaled; there is no
     ``expert_bias`` and no epsilon. ``held = (lo, n)`` says which of the
     ``num_experts`` live here (default: all): either routing is over all of
-    them and the result is the held experts' part of the sum. ``shared``
+    them and the result is the held experts' part of the sum (a call of
+    more than 128 tokens then sizes its row buffer for the held share:
+    ``ops/moe.py:window_sizes``). ``shared``
     experts (a count; one SwiGLU of ``shared * width``) are computed for
     every token by EVERY holder, unweighted or, with ``shared_gate``, times
     ``sigmoid(x @ shared_expert_gate)`` (one number a token): the result is

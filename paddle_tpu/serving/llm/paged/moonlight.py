@@ -59,7 +59,8 @@ from ..decode import jit_program
 from .decode import register_paged_decoder
 from .pool import PagedKVCache, paged_row_index, paged_write_rows
 from .sala import _largest_divisor
-from .trinity import _sample, _tick_counters
+from .trinity import (_sample, _tick_counters, _window_walks,
+                      note_window_walks)
 
 #: the most pages of one step of the chunk's walk over the prefix (a tile's
 #: scores are ``T x heads x TILE_PAGES * page`` floats: 67 MB at 1,024 x 16
@@ -249,12 +250,13 @@ def build_moonlight_paged_chunk_fn(cfg: MoonlightConfig, max_top_k: int):
 
     chunk(params, tokens [1, T], start, n_valid, is_last, arena, tables,
           lengths, finished, slot, temperature, top_k, do_sample, eos, key)
-      -> (arena, lengths, finished, next_token [1])
+      -> (arena, lengths, finished, next_token [1], window walks [2])
 
     ``lengths[slot]`` becomes ``start + n_valid``; the token sampled from
     the last real row is the prompt's first generated one when ``is_last``
     (and then the slot's ``finished`` flag is the sample's; before that it
-    stays set, which keeps the decode step off the slot)."""
+    stays set, which keeps the decode step off the slot). The walks are
+    ``trinity._window_walks`` of the chunk's expert layers."""
 
     def _chunk(params, tokens, start, n_valid, is_last, arena, tables,
                lengths, finished, slot, temperature, top_k, do_sample, eos,
@@ -262,7 +264,9 @@ def build_moonlight_paged_chunk_fn(cfg: MoonlightConfig, max_top_k: int):
         t = tokens.shape[1]
         view = PagedChunk(cfg, arena, tables, slot, start, n_valid)
         positions = (start + jnp.arange(t, dtype=jnp.int32))[None]
-        h, _ = moonlight_hidden(cfg, params, tokens, positions, view)
+        h, counts = moonlight_hidden(cfg, params, tokens, positions, view)
+        walks = _window_walks(counts, t, cfg.num_experts_per_tok,
+                              cfg.n_routed_experts)
         last = jax.lax.dynamic_index_in_dim(
             h[0], jnp.maximum(n_valid - 1, 0), axis=0)         # [1, hidden]
         nxt, fin = _sample(params, last, False,
@@ -270,7 +274,7 @@ def build_moonlight_paged_chunk_fn(cfg: MoonlightConfig, max_top_k: int):
                            max_top_k)
         lengths = lengths.at[slot].set(start + n_valid)
         finished = finished.at[slot].set(jnp.where(is_last, fin[0], True))
-        return view.arena, lengths, finished, nxt
+        return view.arena, lengths, finished, nxt, walks
 
     return _chunk
 
@@ -330,6 +334,8 @@ class MoonlightPagedDecoder:
         #: ``paged_attention``'s argument: the value's and the rotary width
         self.latent = (self.spec.kv_lora_rank, self.spec.qk_rope_head_dim)
         self.row_width = latent_row_width(self.spec)
+        #: the window walks of the chunks no tick has counted yet
+        self._walks = []
         self._key = ("moonlight-paged", self.spec, self.max_top_k,
                      self.page_size, self.attn_impl)
 
@@ -385,6 +391,7 @@ class MoonlightPagedDecoder:
         stat_add("moe_load_max", int(extras[1]))
         stat_add("moe_pairs_routed", n_active * self.spec.num_experts_per_tok
                  * self.spec.num_expert_layers)
+        note_window_walks(self._walks, stat_add)
 
     def note_lengths(self, seq_lens, stat_add):
         """A decode tick over sequences of ``seq_lens`` tokens (the new one
@@ -422,12 +429,13 @@ class MoonlightPagedDecoder:
         ``slot`` behind its ``start`` cached tokens: ``(next token [1],
         finished)``."""
         fn = self.chunk_fn(tokens.shape[1])
-        arena, lengths, finished, nxt = fn(
+        arena, lengths, finished, nxt, walks = fn(
             params, tokens, jnp.asarray(start, jnp.int32),
             jnp.asarray(n_valid, jnp.int32), jnp.asarray(is_last, bool),
             kv.k, kv.block_tables, kv.lengths, finished,
             jnp.asarray(slot, jnp.int32), *samp_vecs, key)
         kv.swap(arena, kv.v, lengths)
+        self._walks.append(walks)
         return nxt, finished
 
     def prefill(self, kv: PagedKVCache, params, tokens, true_lens,

@@ -69,7 +69,8 @@ from ..decode import jit_program
 from .decode import register_paged_decoder
 from .pool import PagedKVCache, paged_row_index
 from .sala import _largest_divisor
-from .trinity import _sample, _tick_counters
+from .trinity import (_sample, _tick_counters, _window_walks,
+                      note_window_walks)
 
 #: query rows of the chunk's attention computed at once, and the most pages
 #: of one step of its walk over the slot's pages (scores of ``Q_ROWS x heads
@@ -281,12 +282,13 @@ def build_qwen3next_paged_chunk_fn(cfg: Qwen3NextConfig, max_top_k: int):
     chunk(params, tokens [1, T], start, n_valid, is_last, kvbuf, state,
           tables, lengths, finished, slot, temperature, top_k, do_sample,
           eos, key)
-      -> (kvbuf, state, lengths, finished, next_token [1])
+      -> (kvbuf, state, lengths, finished, next_token [1], window walks [2])
 
     ``lengths[slot]`` becomes ``start + n_valid``; the token sampled from
     the last real row is the prompt's first generated one when ``is_last``
     (and then the slot's ``finished`` flag is the sample's; before that it
-    stays set, which keeps the decode step off the slot)."""
+    stays set, which keeps the decode step off the slot). The walks are
+    ``trinity._window_walks`` of the chunk's expert layers."""
 
     def _chunk(params, tokens, start, n_valid, is_last, kvbuf, state, tables,
                lengths, finished, slot, temperature, top_k, do_sample, eos,
@@ -294,7 +296,9 @@ def build_qwen3next_paged_chunk_fn(cfg: Qwen3NextConfig, max_top_k: int):
         t = tokens.shape[1]
         view = PagedChunk(cfg, kvbuf, state, tables, slot, start, n_valid)
         positions = (start + jnp.arange(t, dtype=jnp.int32))[None]
-        h, _ = qwen3next_hidden(cfg, params, tokens, positions, view)
+        h, counts = qwen3next_hidden(cfg, params, tokens, positions, view)
+        walks = _window_walks(counts, t, cfg.num_experts_per_tok,
+                              cfg.num_experts)
         last = jax.lax.dynamic_index_in_dim(
             h[0], jnp.maximum(n_valid - 1, 0), axis=0)         # [1, hidden]
         nxt, fin = _sample(params, last, False,
@@ -302,7 +306,7 @@ def build_qwen3next_paged_chunk_fn(cfg: Qwen3NextConfig, max_top_k: int):
                            max_top_k)
         lengths = lengths.at[slot].set(start + n_valid)
         finished = finished.at[slot].set(jnp.where(is_last, fin[0], True))
-        return view.kvbuf, view.state, lengths, finished, nxt
+        return view.kvbuf, view.state, lengths, finished, nxt, walks
 
     return _chunk
 
@@ -368,6 +372,8 @@ class Qwen3NextPagedDecoder:
         self.attn_impl = attn_impl
         self.page_size = int(page_size)
         self.num_pages = None if num_pages is None else int(num_pages)
+        #: the window walks of the chunks no tick has counted yet
+        self._walks = []
         self._key = ("qwen3next-paged", self.spec, self.max_top_k,
                      self.page_size, self.attn_impl)
 
@@ -421,6 +427,7 @@ class Qwen3NextPagedDecoder:
         stat_add("moe_pairs_routed", n_active * self.spec.num_experts_per_tok
                  * self.spec.num_expert_layers)
         stat_add("gdn.step_rows", n_active * len(self.spec.linear_layers))
+        note_window_walks(self._walks, stat_add)
 
     def note_chunk(self, start: int, n_valid: int, pages_per_seq: int,
                    stat_add):
@@ -457,12 +464,13 @@ class Qwen3NextPagedDecoder:
         ``slot`` behind its ``start`` cached tokens: ``(next token [1],
         finished)``."""
         fn = self.chunk_fn(tokens.shape[1])
-        k, state, lengths, finished, nxt = fn(
+        k, state, lengths, finished, nxt, walks = fn(
             params, tokens, jnp.asarray(start, jnp.int32),
             jnp.asarray(n_valid, jnp.int32), jnp.asarray(is_last, bool),
             kv.k, kv.state, kv.block_tables, kv.lengths, finished,
             jnp.asarray(slot, jnp.int32), *samp_vecs, key)
         kv.swap(k, kv.v, lengths, state)
+        self._walks.append(walks)
         return nxt, finished
 
     def prefill(self, kv: PagedKVCache, params, tokens, true_lens,

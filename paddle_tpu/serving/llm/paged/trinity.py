@@ -46,6 +46,7 @@ import numpy as np
 
 from ....models.trinity import (TrinityConfig, TrinityForCausalLM,
                                 trinity_hidden)
+from ....ops import moe as _moe
 from ....ops.paged_attention import paged_attention
 from ...cache import default_cache
 from ..decode import jit_program, sample_next
@@ -207,6 +208,27 @@ def _tick_counters(counts):
     return jnp.stack([jnp.sum(counts > 0), jnp.max(counts)]).astype(jnp.int32)
 
 
+def _window_walks(counts, num_tokens: int, top_k: int, num_experts: int):
+    """``[2]`` int32: the windows of their row buffers a program's expert
+    layers walked, summed, and the calls that had a window to walk
+    (``ops/moe.py:window_passes``; 0 and 0 where a call of these sizes takes
+    its whole buffer)."""
+    passes = [p for p in (_moe.window_passes(c, num_tokens, top_k,
+                                             num_experts) for c in counts)
+              if p is not None]
+    return jnp.stack([sum(passes, jnp.int32(0)), jnp.int32(len(passes))])
+
+
+def note_window_walks(pending: list, stat_add):
+    """Count the ``_window_walks`` of the chunks dispatched before the tick
+    whose fetch has just ended (the lane is serial: they are done), and
+    forget them."""
+    for passes, calls in jax.device_get(pending):  # noqa: PTA002 -- two int32 a chunk that ended before the tick's fetch did: one copy of ready values, no wait
+        stat_add("moe.window_passes", int(passes))
+        stat_add("moe.window_calls", int(calls))
+    pending.clear()
+
+
 def build_trinity_paged_decode_step(cfg: TrinityConfig, max_top_k: int,
                                     attn_impl: str = "gather"):
     """The RAW paged decode step of this family.
@@ -245,12 +267,13 @@ def build_trinity_paged_chunk_fn(cfg: TrinityConfig, max_top_k: int):
 
     chunk(params, tokens [1, T], start, n_valid, is_last, ks, vs, tables,
           lengths, finished, slot, temperature, top_k, do_sample, eos, key)
-      -> (ks, vs, lengths, finished, next_token [1])
+      -> (ks, vs, lengths, finished, next_token [1], window walks [2])
 
     ``lengths[slot]`` becomes ``start + n_valid``; the token sampled from
     the last real row is the prompt's first generated one when ``is_last``
     (and then the slot's ``finished`` flag is the sample's; before that it
-    stays set, which keeps the decode step off the slot)."""
+    stays set, which keeps the decode step off the slot). The walks are
+    ``_window_walks`` of the chunk's expert layers."""
 
     def _chunk(params, tokens, start, n_valid, is_last, ks, vs, tables,
                lengths, finished, slot, temperature, top_k, do_sample, eos,
@@ -258,7 +281,9 @@ def build_trinity_paged_chunk_fn(cfg: TrinityConfig, max_top_k: int):
         t = tokens.shape[1]
         view = PagedChunk(cfg, ks, vs, tables, slot, start, n_valid)
         positions = (start + jnp.arange(t, dtype=jnp.int32))[None]
-        h, _ = trinity_hidden(cfg, params, tokens, positions, view)
+        h, counts = trinity_hidden(cfg, params, tokens, positions, view)
+        walks = _window_walks(counts, t, cfg.num_experts_per_tok,
+                              cfg.num_experts)
         last = jax.lax.dynamic_index_in_dim(
             h[0], jnp.maximum(n_valid - 1, 0), axis=0)         # [1, hidden]
         nxt, fin = _sample(params, last, False,
@@ -266,7 +291,8 @@ def build_trinity_paged_chunk_fn(cfg: TrinityConfig, max_top_k: int):
                            max_top_k)
         lengths = lengths.at[slot].set(start + n_valid)
         finished = finished.at[slot].set(jnp.where(is_last, fin[0], True))
-        return tuple(view.ks), tuple(view.vs), lengths, finished, nxt
+        return (tuple(view.ks), tuple(view.vs), lengths, finished, nxt,
+                walks)
 
     return _chunk
 
@@ -326,6 +352,8 @@ class TrinityPagedDecoder:
         #: rows one program writes at most (``check_config`` sets it from
         #: the engine's chunk or largest bucket): a window group's bound
         self.span: Optional[int] = None
+        #: the window walks of the chunks no tick has counted yet
+        self._walks = []
         self._key = ("trinity-paged", self.spec, self.max_top_k,
                      self.page_size, self.attn_impl)
 
@@ -385,6 +413,7 @@ class TrinityPagedDecoder:
         stat_add("moe_load_max", int(extras[1]))
         stat_add("moe_pairs_routed", n_active * self.spec.num_experts_per_tok
                  * self.spec.num_expert_layers)
+        note_window_walks(self._walks, stat_add)
 
     def note_lengths(self, seq_lens, stat_add):
         """A decode tick over sequences of ``seq_lens`` tokens (the new one
@@ -432,12 +461,13 @@ class TrinityPagedDecoder:
         finished)``."""
         fn = self.chunk_fn(tokens.shape[1])
         ks, vs, tables = self._arenas(kv)
-        ks, vs, lengths, finished, nxt = fn(
+        ks, vs, lengths, finished, nxt, walks = fn(
             params, tokens, jnp.asarray(start, jnp.int32),
             jnp.asarray(n_valid, jnp.int32), jnp.asarray(is_last, bool),
             ks, vs, tables, kv.lengths, finished,
             jnp.asarray(slot, jnp.int32), *samp_vecs, key)
         kv.swap_groups(ks, vs, lengths)
+        self._walks.append(walks)
         return nxt, finished
 
     def prefill(self, kv: PagedKVCache, params, tokens, true_lens,

@@ -381,3 +381,196 @@ class TestSoftmaxRoutingAndAGatedSharedExpert:
             np.asarray(swiglu(f[t], w1[e], w3[e], w2[e]))
             for t, e in enumerate(np.asarray(idx)[:, j])]) for j in range(2))
         np.testing.assert_allclose(out, dense, atol=2e-5, rtol=0)
+
+
+class TestTheRowBufferIsAWindowSizedForTheHeldShare:
+    """``ops/moe.py:window_sizes``: a call of more than 128 tokens that holds
+    a share of the router's experts lays its pairs out in tiles sized for
+    what a held expert expects and walks a window of them as often as the
+    routing needs; every other call takes the whole buffer as it always
+    did."""
+
+    #: (cell, tokens, top_k, held, of, rows of the buffer, rows of a tile)
+    CHUNKS = [("serve-qwen3next-longdoc", 1024, 10, 64, 512, 4608, 32),
+              ("serve-trinity-mixedctx", 1024, 8, 16, 128, 4096, 128),
+              ("serve-moonlight-longgen", 1024, 6, 8, 64, 2560, 128)]
+    #: (cell, tokens, top_k, held, of): the whole buffer, no window
+    WHOLE = [("serve-qwen3next-longdoc", 32, 10, 64, 512),
+             ("serve-trinity-mixedctx", 32, 8, 16, 128),
+             ("serve-moonlight-longgen", 48, 6, 8, 64),
+             ("serve-lfm2moe-decode", 32, 4, 32, 32),
+             ("serve-lfm2moe-decode", 128, 4, 32, 32),
+             ("serve-lfm2moe-decode", 256, 4, 32, 32),
+             ("serve-lfm2moe-decode", 512, 4, 32, 32),
+             ("nn.MoE", 1024, 10, 512, 512)]
+
+    @staticmethod
+    def _tick_rows(cell):
+        """What ``benchmark/costs_lfm2.py:decode_moe`` finds a tick's
+        kernels by."""
+        from benchmark import costs_lfm2, costs_moonlight, spec
+        c = spec.load_cell(spec.load_benchmark(), cell)
+        if "n_routed_experts" in c["config_data"]:  # as its readers do
+            c = costs_moonlight.as_lfm2({"cell": c})["cell"]
+        return costs_lfm2.decode_rows(
+            c["config_data"], c["traffic_data"]["engine"]["num_slots"])
+
+    @staticmethod
+    def _layout_shapes(t, k, n, e, lo=0):
+        """``group_layout``'s arrays as shapes, beside its two statics."""
+        from paddle_tpu.ops import moe
+        static = {}
+
+        def arrays(idx):
+            lay = moe.group_layout(idx, n, lo, num_experts=e)
+            static.update(tm=lay.pop("tm"), window=lay.pop("window"))
+            return lay
+        return dict(jax.eval_shape(
+            arrays, jax.ShapeDtypeStruct((t, k), jnp.int32)), **static)
+
+    @staticmethod
+    def _whiles(fn, *args):
+        """Loops of ``fn`` lowered as the chip's compiler is given it (off
+        the chip an interpreted kernel is loops of its own)."""
+        return jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text().count("stablehlo.while")
+
+    @staticmethod
+    def _weights(rng, n, h=16, f=8):
+        w1, w3 = (0.3 * rng.randn(n, h, f).astype(np.float32)
+                  for _ in range(2))
+        return w1, w3, 0.3 * rng.randn(n, f, h).astype(np.float32)
+
+    @staticmethod
+    def _dense(f, idx, wts, w1, w3, w2, lo):
+        """Every held expert on every row, weighted where it was chosen."""
+        from paddle_tpu.models.lfm2 import swiglu
+        idx, wts = np.asarray(idx), np.asarray(wts)
+        return sum(
+            (wts * (idx == lo + e)).sum(-1, keepdims=True)
+            * np.asarray(swiglu(f, w1[e], w3[e], w2[e]))
+            for e in range(w1.shape[0]))
+
+    @pytest.mark.parametrize("cell,t,k,n,e,rows,tm", CHUNKS)
+    def test_a_chunks_buffer_follows_the_held_share(self, cell, t, k, n, e,
+                                                    rows, tm):
+        from paddle_tpu.ops import moe
+        assert moe.window_sizes(t, k, n, e) == (tm, rows // tm)
+        lay = self._layout_shapes(t, k, n, e, lo=n)
+        assert (lay["tm"], lay["window"]) == (tm, rows // tm)
+        # every held pair has a row, whatever the routing: the parent's
+        # bound at this tile, in whole windows
+        worst = moe.max_tiles(t, k, n, tm)
+        assert lay["src"].shape[0] // tm >= worst > lay["window"]
+        assert lay["src"].shape[0] % rows == 0
+        # under the buffer for all the pairs in tiles of 128, and never a
+        # tick's row count (the benchmark finds a tick's kernels by it)
+        assert rows < moe.max_tiles(t, k, n, 128) * 128
+        assert rows != self._tick_rows(cell)
+        # one loop more than the layout's own: the walk over the windows
+        s, h, f = jax.ShapeDtypeStruct, 16, 8
+        assert self._whiles(
+            lambda x, gate, w1, w3, w2: moe.moe_feed_forward(
+                x, gate, None, w1, w3, w2, top_k=k, route="softmax",
+                interpret=False),
+            s((t, h), jnp.float32), s((h, e), jnp.float32),
+            s((n, h, f), jnp.float32), s((n, h, f), jnp.float32),
+            s((n, f, h), jnp.float32)) == 2
+
+    @pytest.mark.parametrize("cell,t,k,n,e", WHOLE)
+    def test_every_other_call_takes_the_whole_buffer_and_no_loop(
+            self, cell, t, k, n, e):
+        from paddle_tpu.ops import moe
+        assert moe.window_sizes(t, k, n, e) is None
+        s = jax.ShapeDtypeStruct
+        lay = self._layout_shapes(t, k, n, e)
+        tm = moe.row_tile(t)
+        tiles = min(t * k // tm + n, n * -(-t // tm))   # the parent's bound
+        assert (lay["tm"], lay["window"]) == (tm, tiles)
+        assert lay["src"].shape == (tiles * tm,)
+        if t <= 48:     # a tick: the rows its kernels are found by
+            assert tiles * tm == self._tick_rows(cell)
+        h, f = 16, 8
+        args = (s((t, h), jnp.float32), s((h, e), jnp.float32),
+                s((n, h, f), jnp.float32), s((n, h, f), jnp.float32),
+                s((n, f, h), jnp.float32))
+
+        def layer(x, gate, w1, w3, w2):
+            return moe.moe_feed_forward(x, gate, None, w1, w3, w2, top_k=k,
+                                        route="softmax", interpret=False)
+
+        def layout(x, gate):
+            idx, _ = moe.route_softmax_topk(x, gate, k)
+            return moe.group_layout(idx, n, 0)
+
+        # the one loop is the layout's own (``searchsorted``), the parent's
+        assert self._whiles(layer, *args) == self._whiles(
+            layout, *args[:2]) == 1
+
+    @pytest.mark.parametrize("route", ["sigmoid", "softmax"])
+    @pytest.mark.parametrize("held", [(8, 8), (16, 32), (0, 64)])
+    def test_a_chunk_equals_the_dense_sum_over_the_held(self, held, route):
+        """An eighth (a window, tiles of 64), a half (tiles of 64, the window
+        is the whole of them) and all the experts (today's path)."""
+        from paddle_tpu.ops import moe
+        lo, n = held
+        rng = np.random.RandomState(n)
+        f = rng.randn(256, 16).astype(np.float32)
+        gate = rng.randn(16, 64).astype(np.float32)
+        bias = (0.1 * rng.randn(64).astype(np.float32)
+                if route == "sigmoid" else None)
+        w1, w3, w2 = self._weights(rng, n)
+        sizes = moe.window_sizes(256, 10, n, 64)
+        assert sizes == {8: (64, 18), 32: (64, 72), 64: None}[n]
+        out, counts = jax.jit(lambda *a: moe.moe_feed_forward(
+            *a, top_k=10, expert_lo=lo, route=route))(f, gate, bias, w1, w3,
+                                                      w2)
+        if route == "sigmoid":
+            idx, wts = moe.route_sigmoid_topk(f, gate, bias, 10)
+        else:
+            idx, wts = moe.route_softmax_topk(f, gate, 10)
+        held_pairs = int(((np.asarray(idx) >= lo)
+                          & (np.asarray(idx) < lo + n)).sum())
+        assert int(counts.sum()) == held_pairs
+        np.testing.assert_allclose(
+            out, self._dense(f, idx, wts, w1, w3, w2, lo), atol=3e-5, rtol=0)
+        passes = moe.window_passes(counts, 256, 10, 64)
+        assert (passes is None) == (n == 64)
+        assert n == 64 or int(passes) == 1
+
+    @pytest.mark.parametrize("rig", ["every_pair_held", "one_hot_expert",
+                                     "no_pair_held"])
+    def test_an_overflow_costs_a_pass_and_never_a_row(self, rig):
+        """512 tokens, 2 of 64 experts each, 8 held: 16 rows an expert are
+        expected, so tiles of 32 in a window of 16 tiles. A router that
+        sends EVERY pair to the held experts fills 32-40; an expert that
+        takes every token fills 16 alone, across two windows."""
+        from paddle_tpu.ops import moe
+        rng = np.random.RandomState(11)
+        lo, n, t, k, e = 16, 8, 512, 2, 64
+        f = rng.randn(t, 16).astype(np.float32)
+        f[:, 0] = 1.0       # its row of the gate moves a score for all
+        gate = rng.randn(16, e).astype(np.float32)
+        if rig == "every_pair_held":
+            gate[0, lo:lo + n] += 40.0
+        elif rig == "one_hot_expert":
+            gate[0, lo + 3] += 40.0
+        else:
+            gate[0, lo:lo + n] -= 40.0
+        w1, w3, w2 = self._weights(rng, n)
+        assert moe.window_sizes(t, k, n, e) == (32, 16)
+        out, counts = jax.jit(lambda *a: moe.moe_feed_forward(
+            *a, top_k=k, expert_lo=lo, route="softmax"))(f, gate, None, w1,
+                                                         w3, w2)
+        idx, wts = moe.route_softmax_topk(f, gate, k)
+        counts = np.asarray(counts)
+        passes = int(moe.window_passes(jnp.asarray(counts), t, k, e))
+        if rig == "every_pair_held":
+            assert counts.sum() == t * k and passes >= 2
+        elif rig == "one_hot_expert":
+            assert counts[3] == t and passes == 2
+        else:
+            assert counts.sum() == 0 and passes == 0
+            assert not np.asarray(out).any()
+        np.testing.assert_allclose(
+            out, self._dense(f, idx, wts, w1, w3, w2, lo), atol=3e-5, rtol=0)

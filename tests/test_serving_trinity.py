@@ -138,6 +138,47 @@ def test_prompts_enter_together_and_the_counters_say_what_the_window_spared(
         eng.drain(timeout=30)
 
 
+def test_a_chunk_past_its_window_of_expert_tiles_walks_it_twice_and_counts_it():
+    """2 of 8 experts held and chunks of 144 tokens: 36 rows an expert are
+    expected, so tiles of 64 in a window of 4 (``ops/moe.py:window_sizes``).
+    A selection bias that sends EVERY pair to the two held experts fills 6:
+    each chunk's four expert layers walk their window twice, the chunk
+    program says so beside its token, the tick after it counts it, and the
+    tokens are those of chunks of 16, which have no window."""
+    from benchmark import trinity_adapter
+    from paddle_tpu.ops import moe
+    cfg = toy_config(num_experts=2)
+    cfg["share"] = dict(cfg["share"], experts_held=[0, 2])
+    net = trinity_adapter.build_net(cfg)
+    trinity_adapter.load_weights(net, cfg, SEED)
+    for name, p in net.named_parameters():
+        if name.endswith("expert_bias"):
+            p.set_value(np.where(np.arange(8) < 2, 10.0, 0.0).astype(
+                np.float32))
+    net.eval()
+    assert moe.window_sizes(144, 2, 2, 8) == (64, 4)
+    assert moe.window_sizes(16, 2, 2, 8) is None
+    rng = np.random.default_rng(4)
+    prompt = rng.integers(0, cfg["vocab_size"], 150).astype(np.int32)
+    served = {}
+    for chunk in (144, 16):
+        eng = _engine(net, "gather", chunk, max_seq=192, num_pages=52)
+        try:
+            served[chunk] = eng.generate(prompt, max_new_tokens=8)["tokens"]
+            counters, _ = _stats(eng)
+        finally:
+            eng.drain(timeout=30)
+        if chunk == 16:     # a call of at most 128 tokens has no window
+            assert not counters.get("moe.window_calls")
+            continue
+        # two chunks (144 and 6 of 144) and the warm-up's one, of four
+        # expert layers each; the tick after the last has read them all
+        assert counters["prefill_chunks"] == 2
+        assert counters["moe.window_calls"] == 12
+        assert counters["moe.window_passes"] == 24
+    assert served[144] == served[16]
+
+
 def test_both_groups_give_everything_back(seeded):
     """After finish, after a deadline in the middle of a prompt, every page
     of both groups is free again, and the next tenant of a slot starts from
