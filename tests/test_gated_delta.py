@@ -8,6 +8,8 @@ system a chunk in place of a correction a token): outputs of spread 0.1 and
 states of spread one agree with a float32 recurrence to 2e-5 absolute; a
 float64 recurrence lies as far from either.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -15,8 +17,8 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu  # noqa: F401 -- sets the package's matmul precision
-from paddle_tpu.ops.gated_delta import (CHUNK, gated_delta_chunked,
-                                        gated_delta_step)
+from paddle_tpu.ops.gated_delta import (BLOCK, CHUNK, _unit_lower_inverse,
+                                        gated_delta_chunked, gated_delta_step)
 
 ATOL = 2e-5
 
@@ -129,3 +131,88 @@ def test_value_heads_read_their_key_head_and_bad_shapes_are_refused():
     with pytest.raises(ValueError, match="key heads"):
         gated_delta_chunked(q[:, :, :1].repeat(3, 2), k[:, :, :1].repeat(3, 2),
                             v, g, beta, state, jnp.asarray([8]))
+
+
+def inverse_a_row_at_a_time(a):
+    """What ``gated_delta_chunked`` ran before it solved by blocks: ``C``
+    steps, each a multiply and a sum over the WHOLE inverse built so far. The
+    oracle of the block solve, and the loop the lowered program must not
+    hold."""
+    c = a.shape[-1]
+
+    def row(i, inv):
+        a_i = jax.lax.dynamic_index_in_dim(a, i, axis=-2, keepdims=False)
+        new = -jnp.sum(a_i[..., :, None] * inv, axis=-2)
+        new = new + (jnp.arange(c) == i).astype(a.dtype)
+        return jax.lax.dynamic_update_index_in_dim(inv, new, i, axis=-2)
+
+    return jax.lax.fori_loop(0, c, row, jnp.zeros_like(a))
+
+
+def systems(seed, c, g_token, lead=(2, 3), d=128):
+    """``A`` as the operator builds it (module docstring), float64, at the
+    cell's size of entry: keys of norm 1 that lean on one direction (entries
+    up to 0.9, where independent keys of 128 give 0.1), ``beta`` near 1,
+    ``g = g_token`` a token."""
+    rng = np.random.default_rng(seed)
+    k = rng.standard_normal(lead + (c, d))
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+    k = 0.6 * k + 0.4 * k[..., :1, :] * rng.choice([-1.0, 1.0], lead + (c, 1))
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+    beta = 1.0 / (1.0 + np.exp(-rng.standard_normal(lead + (c,)) - 3.0))
+    gam = np.cumsum(np.full(lead + (c,), g_token), axis=-1)
+    below = np.tril(np.ones((c, c), bool), -1)
+    decay = np.exp(np.where(below, gam[..., :, None] - gam[..., None, :], 0.0))
+    return np.where(below, beta[..., :, None] * decay
+                    * (k @ np.swapaxes(k, -1, -2)), 0.0)
+
+
+@pytest.mark.parametrize("g_token", [0.0, -0.05, -40.0])
+@pytest.mark.parametrize("c", [1, 5, BLOCK, BLOCK + 1, 3 * BLOCK, CHUNK])
+def test_the_block_solve_is_the_inverse_and_agrees_with_the_row_loop(
+        c, g_token):
+    """``g = 0`` keeps every entry of ``A`` (up to 0.6 and of either sign);
+    -40 a token is the overflow test's decay, under which the first
+    subdiagonal is ``exp(-40)`` of itself and what lies under it less."""
+    a64 = systems(7 * c, c, g_token)
+    a = jnp.asarray(a64, jnp.float32)
+    got = np.asarray(jax.jit(_unit_lower_inverse)(a), np.float64)
+    rows = np.asarray(jax.jit(inverse_a_row_at_a_time)(a), np.float64)
+    assert got.shape == a.shape
+    assert (got[..., ~np.tril(np.ones((c, c), bool))] == 0).all()
+    assert (np.diagonal(got, axis1=-2, axis2=-1) == 1).all()
+    eye = np.broadcast_to(np.eye(c), a.shape)
+    if g_token == 0.0 and c >= BLOCK:
+        assert np.abs(a64).max() > 0.5
+    # float32 rounding: 2^-24 a sum, sums as long as a row, entries up to 1
+    tol = c * 2.0 ** -24
+    assert np.abs(rows).max() == 1.0
+    np.testing.assert_allclose((eye + np.asarray(a, np.float64)) @ got, eye,
+                               atol=tol, rtol=0)
+    np.testing.assert_allclose(got, rows, atol=tol, rtol=0)
+
+
+def loops(lowered):
+    """``(trips, carried types)`` of each ``while`` of a lowered program's
+    text whose bound is a constant, as ``fori_loop`` and ``scan`` make
+    them."""
+    return [(int(m[2]), m[1]) for m in re.finditer(
+        r"stablehlo\.while\(.*?\) : (.*?)\n\s*cond \{\n\s*%\S+ = "
+        r"stablehlo\.constant dense<(\d+)> : tensor<i32>", lowered)]
+
+
+def test_no_loop_of_a_chunks_rows_over_a_whole_system_is_lowered():
+    """A 1,024-row call: the scan over its 16 chunks is there (the rows of a
+    diagonal block unroll); ``CHUNK`` trips round a ``[.., CHUNK, CHUNK]``
+    carry (8 MB at the served shapes, read a trip) are not. The reader finds
+    that loop in the oracle, so it would find it here."""
+    whole = f"x{CHUNK}x{CHUNK}xf32>"
+    q, k, v, g, beta, state = inputs(2, 1, 16 * CHUNK)
+    held = loops(jax.jit(inverse_a_row_at_a_time).lower(
+        jnp.zeros((1, 4, 16, CHUNK, CHUNK))).as_text())
+    assert [(n, whole in kinds) for n, kinds in held] == [(CHUNK, True)]
+    found = loops(jax.jit(gated_delta_chunked).lower(
+        q, k, v, g, beta, state, jnp.asarray([16 * CHUNK])).as_text())
+    assert (16, True) in [(n, whole in kinds) for n, kinds in found]  # scan
+    assert not [(n, kinds) for n, kinds in found
+                if n >= CHUNK and whole in kinds]
