@@ -7,18 +7,22 @@ mirror the slot decode programs with the block table threaded through,
 ``prefix`` shares prefix pages by refcount (COW on divergence), and
 ``batcher`` admits on pages-at-current-lengths. Select with
 ``LLMEngineConfig(kv_layout="paged")``. The engine picks the decoder family
-from the model it is given (``paged_decoder_class``): GPT by default,
-``lfm2`` for the LFM2-MoE family, whose cache also holds a per-slot
-convolution state beside the pages, ``sala`` for MiniCPM-SALA, whose cache
-holds pages for its sparse layers only, their compressed keys by page and a
-linear-attention state a slot, ``trinity`` for the Trinity family, whose
-window and full attention layers keep different pages of one sequence (two
-page groups in one cache), ``moonlight`` for the Moonlight family, whose
-latent attention keeps ONE row a token and layer for all its heads (one arena,
-an absorbed decode step and an expanded chunk program over it), ``qwen3next``
-for the Qwen3-Next family, whose cache holds pages for its full-attention
-layers only (one layer in four) and, a slot and linear layer, a gated
-delta-rule state and a short convolution's last inputs.
+from the model it is given (``paged_decoder_class``), GPT by default, and asks
+it a declared protocol (``decode.PagedDecoderProtocol``: abilities as class
+attributes, counter hooks that default to nothing), never ``hasattr``. Every
+family but GPT is a ``decode.PagedFamilyDecoder``, which owns the
+constructor, the refusals, ``check_config`` and the calls that run the
+programs; a family's module brings its two cache views, its two programs and
+a class that declares its cache (``new_kv``, the arrays a program takes and
+how they go back), its counters and its ``prefix_sig``: ``lfm2`` (a
+convolution state a slot beside the pages), ``sala`` (pages for the sparse
+layers only, their compressed keys by page, a linear state a slot),
+``trinity`` (window and full layers keep different pages of one sequence: two
+page groups), ``moonlight`` (latent attention: ONE row a token and layer for
+all heads, absorbed in the step and expanded in the chunk), ``qwen3next``
+(pages for one layer in four and, a slot and linear layer, a gated delta-rule
+state and a short convolution's last inputs). A new family registers itself
+(``register_paged_decoder``) and is imported here; no other file changes.
 """
 from .batcher import PagedBatcher
 from .decode import (GPTPagedDecoder, paged_decoder_class,

@@ -123,8 +123,7 @@ class PagedBatcher(ContinuousBatcher):
         if self.attn_recurrence is not None:
             self._stat_set("paged_attn.recurrence_mxu",
                            int(self.attn_recurrence == "mxu"))
-        if hasattr(decoder, "publish_gauges"):   # a family's own state
-            decoder.publish_gauges(self.kv, self._stat_set)
+        decoder.publish_gauges(self.kv, self._stat_set)  # a family's state
 
     # -- introspection -------------------------------------------------------
     @property
@@ -406,8 +405,7 @@ class PagedBatcher(ContinuousBatcher):
         # each request's length as this step sees it, its new row included
         lens = [req.seq_len + self._behind(ahead, slot, req)
                 for slot, req in self._reqs.items()]
-        if hasattr(self.decoder, "note_lengths"):   # a family's own walks
-            self.decoder.note_lengths(lens, self._stat_add)
+        self.decoder.note_lengths(lens, self._stat_add)  # a family's walks
         self._stat_add("paged_attn.pages_live",
                        sum((n - 1) // page + 1 for n in lens))
         self._stat_add("paged_attn.pages_table",
@@ -528,7 +526,7 @@ class PagedBatcher(ContinuousBatcher):
     def supports_export(self) -> bool:
         """The paged substrate can ship sequences as page payloads, for a
         decoder family whose whole per-sequence state is pages."""
-        return getattr(self.decoder, "supports_export", True)
+        return self.decoder.supports_export
 
     def export_all(self):
         """Snapshot-and-detach every live sequence into host-side

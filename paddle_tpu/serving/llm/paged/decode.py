@@ -1,4 +1,7 @@
-"""Paged prefill + decode step builders and the GPTPagedDecoder façade.
+"""Paged prefill + decode step builders, the GPTPagedDecoder façade, and what
+every other served family shares: the protocol the engine asks of a decoder
+(:class:`PagedDecoderProtocol`), the one façade the families inherit
+(:class:`PagedFamilyDecoder`) and the helpers their programs have in common.
 
 Same contracts as ``serving/llm/decode.py`` with ONE extra device input
 threaded through every program: the ``[num_slots, pages_per_seq]`` block
@@ -45,6 +48,8 @@ import jax
 import jax.numpy as jnp
 
 from ....models.gpt import FullSequence, gpt_hidden, stack_kv
+from ....ops import moe as _moe
+from ...cache import default_cache
 from ..decode import (GPTDecodeSpec, GPTStaticDecoder, _AUDIT_SPEC,
                       _AUDIT_TOP_K, _audit_params, jit_program, last_rows,
                       sample_next)
@@ -192,6 +197,269 @@ def get_paged_tail_prefill_fn(spec: GPTDecodeSpec, max_top_k: int,
         donate=(4, 5))
 
 
+# -- what the served families share ------------------------------------------
+
+def _largest_divisor(n: int, cap: int) -> int:
+    """The largest divisor of ``n`` that is at most ``cap``."""
+    return max(p for p in range(1, min(cap, n) + 1) if n % p == 0)
+
+
+def _sample(params, hidden, frozen, samp, key, max_top_k):
+    """``sample_next`` against a family's own head (``[hidden, V]``: the
+    transpose of a transpose folds away)."""
+    return sample_next({"tok": params["head"].T}, hidden, frozen, *samp, key,
+                       max_top_k)
+
+
+def _tick_counters(counts):
+    """``[2]`` int32: the held experts that received a token, summed over
+    the expert layers, and the fullest one's tokens in any layer."""
+    counts = jnp.stack(counts) if counts else jnp.zeros((1, 1), jnp.int32)
+    return jnp.stack([jnp.sum(counts > 0), jnp.max(counts)]).astype(jnp.int32)
+
+
+def _window_walks(counts, num_tokens: int, top_k: int, num_experts: int):
+    """``[2]`` int32: the windows of their row buffers a program's expert
+    layers walked, summed, and the calls that had a window to walk
+    (``ops/moe.py:window_passes``; 0 and 0 where a call of these sizes takes
+    its whole buffer)."""
+    passes = [p for p in (_moe.window_passes(c, num_tokens, top_k,
+                                             num_experts) for c in counts)
+              if p is not None]
+    return jnp.stack([sum(passes, jnp.int32(0)), jnp.int32(len(passes))])
+
+
+def note_window_walks(pending: list, stat_add):
+    """Count the ``_window_walks`` of the chunks dispatched before the tick
+    whose fetch has just ended (the lane is serial: they are done), and
+    forget them."""
+    for passes, calls in jax.device_get(pending):  # noqa: PTA002 -- two int32 a chunk that ended before the tick's fetch did: one copy of ready values, no wait
+        stat_add("moe.window_passes", int(passes))
+        stat_add("moe.window_calls", int(calls))
+    pending.clear()
+
+
+def note_expert_tick(spec, extras, n_active: int, stat_add):
+    """A tick's expert counters, from the ``_tick_counters`` fetched behind
+    its tokens and the pairs ``n_active`` slots routed."""
+    stat_add("moe_experts_active", int(extras[0]))
+    stat_add("moe_load_max", int(extras[1]))
+    stat_add("moe_pairs_routed", n_active * spec.num_experts_per_tok
+             * spec.num_expert_layers)
+
+
+def grouped_walk(q_heads: int, kv):
+    """``tuner.space.paged_recurrence``'s arguments for the plain walk over
+    an arena ``[P+1, layers, page, KV heads, row]``: the query heads that
+    share a KV head, on that head's rows."""
+    _, _, page, kv_heads, row = kv.k.shape
+    return (q_heads // kv_heads, kv_heads, page, row, kv.k.dtype.itemsize,
+            1 if kv.fused_kv else 2)
+
+
+class PagedDecoderProtocol:
+    """What ``PagedBatcher`` and ``LLMEngine`` ask of a paged decoder beside
+    its programs (``new_kv``, ``params``, ``prefix_sig``, ``check_config``,
+    ``prefill``, ``decode_step`` and, where ``prefills_in_chunks``,
+    ``chunk_prefill``): abilities as class attributes, hooks that do nothing
+    until a family has something to say. Nobody probes a decoder with
+    ``hasattr``: what is not declared here is not asked."""
+
+    kv_layout = "paged"
+    #: sequences can be shipped as page payloads (``export_sequence`` /
+    #: ``import_sequence``): the family's whole per-sequence state is pages
+    supports_export = False
+    #: the family has a chunk program, so ``LLMEngineConfig.prefill_chunk``
+    #: may be set and every admission is ``chunk_prefill``
+    prefills_in_chunks = False
+
+    def plain_walk(self, kv):
+        """The arguments of ``tuner.space.paged_recurrence`` for the plain
+        walk ``paged_attn`` runs over this family's arena on the kernel
+        lane, or None where none runs (every walk over selected pages)."""
+        return None
+
+    def publish_gauges(self, kv, stat_set):
+        """The family's own gauges, set once when the batcher is built."""
+
+    def note_lengths(self, seq_lens, stat_add):
+        """A decode tick over sequences of ``seq_lens`` tokens (the new one
+        included) is about to be dispatched: the family's own walks."""
+
+    def note_chunk(self, start: int, n_valid: int, pages_per_seq: int,
+                   stat_add):
+        """A chunk of ``n_valid`` tokens behind ``start`` was dispatched."""
+
+    def note_tick(self, extras, n_active: int, stat_add):
+        """A tick's fetch has ended: ``extras`` is what the step packed
+        behind its tokens (called only where it packed something)."""
+
+
+class PagedFamilyDecoder(PagedDecoderProtocol):
+    """The façade ``PagedBatcher`` drives, for every family but GPT: the
+    constructor and its refusals, ``check_config``, the compiled programs'
+    cache and the three calls that run them (``prefill``, the chunk at
+    offset 0; ``chunk_prefill``; ``decode_step``), written once over what a
+    family declares:
+
+    - ``family`` (its name in messages and in the cache key), ``vocab`` (the
+      configuration field ``max_top_k`` is clipped to), ``unserved_why``
+      (the sentence ``check_config`` gives for ``unserved``), ``tick_fetch``
+      / ``chunk_walks`` (whether a step packs counters behind its tokens and
+      a chunk returns its experts' window walks);
+    - ``setup()`` for what only it checks or keeps, ``new_kv``,
+      ``prefix_sig``, the protocol's hooks;
+    - ``step_program()`` / ``chunk_program()``: the jitted ``get_*`` it runs
+      (``admits_chunk`` refuses a chunk length the program cannot take);
+    - ``cache_arrays(kv)``: what a program takes of the cache, in the
+      program's own order (the block tables last), and ``install(kv,
+      arrays, lengths)``: how the arrays it returns go back."""
+
+    family = ""
+    vocab = "vocab_held"
+    #: what ``kv_dtype`` is the dtype of, in the refusal's words
+    holds = "KV"
+    unserved = (("prefix_cache", False), ("spec_k", 0))
+    unserved_why = ""
+    tick_fetch = True
+    chunk_walks = True
+
+    def __init__(self, model, max_top_k: int = 64, exec_cache=None,
+                 mesh=None, weight_dtype: str = "float32",
+                 kv_dtype: str = "float32", page_size: int = 16,
+                 num_pages: Optional[int] = None,
+                 attn_impl: str = "auto"):
+        if mesh is not None:
+            raise NotImplementedError(
+                f"the {self.family} paged decoder does not serve over a "
+                f"mesh yet")
+        if weight_dtype != "float32" or kv_dtype != "float32":
+            raise NotImplementedError(
+                f"the {self.family} paged decoder serves float32 weights "
+                f"and {self.holds} only (got weight_dtype={weight_dtype!r}, "
+                f"kv_dtype={kv_dtype!r})")
+        if attn_impl not in ("auto", "gather", "kernel"):
+            raise ValueError(
+                f"attn_impl must be 'auto', 'gather' or 'kernel', got "
+                f"{attn_impl!r}")
+        self.spec = model.config
+        self._model = model
+        self.max_top_k = max(0, min(int(max_top_k),
+                                    getattr(self.spec, self.vocab)))
+        self.exec_cache = (exec_cache if exec_cache is not None
+                           else default_cache())
+        if attn_impl == "auto":
+            attn_impl = ("kernel" if jax.default_backend() == "tpu"
+                         else "gather")
+        self.attn_impl = attn_impl
+        self.page_size = int(page_size)
+        self.num_pages = None if num_pages is None else int(num_pages)
+        #: the window walks of the chunks no tick has counted yet
+        self._walks = []
+        self._key = (self.family.lower().replace("-", "") + "-paged",
+                     self.spec, self.max_top_k, self.page_size,
+                     self.attn_impl)
+        self.setup()
+
+    def setup(self):
+        """What only this family checks or keeps (the constructor's last
+        step: ``spec``, ``page_size`` and ``attn_impl`` are set)."""
+
+    def check_config(self, config):
+        """The engine options this family does not serve yet."""
+        for name, off in self.unserved:
+            if getattr(config, name) != off:
+                raise NotImplementedError(
+                    f"the {self.family} paged decoder does not support "
+                    f"{name} yet ({self.unserved_why})")
+        chunk = config.prefill_chunk
+        if self.prefills_in_chunks and chunk is not None \
+                and chunk % config.page_size:
+            raise ValueError(
+                f"prefill_chunk {chunk} must be a multiple of the page "
+                f"size {config.page_size}: a chunk starts on a page")
+
+    @property
+    def model(self):
+        return self._model
+
+    def params(self):
+        return self._model.param_tree()
+
+    def check_max_seq(self, max_seq: int):
+        """``new_kv``'s refusal of more rows than the model has positions."""
+        if max_seq > self.spec.max_position_embeddings:
+            raise ValueError(
+                f"max_seq {max_seq} exceeds the model's "
+                f"{self.spec.max_position_embeddings} positions")
+
+    # -- compiled-program access --------------------------------------------
+    def step_program(self):
+        raise NotImplementedError
+
+    def chunk_program(self):
+        raise NotImplementedError
+
+    def admits_chunk(self, chunk_len: int):
+        """Raises for a chunk length the chunk program cannot take."""
+
+    def decode_fn(self, num_slots: int, max_seq: int):
+        return self.exec_cache.get_or_compile(
+            self._key + ("decode", num_slots, max_seq), self.step_program)
+
+    def chunk_fn(self, chunk_len: int):
+        self.admits_chunk(chunk_len)
+        return self.exec_cache.get_or_compile(
+            self._key + ("chunk", chunk_len), self.chunk_program)
+
+    # -- the calls the batcher makes ----------------------------------------
+    def cache_arrays(self, kv: PagedKVCache) -> tuple:
+        raise NotImplementedError
+
+    def install(self, kv: PagedKVCache, arrays, lengths):
+        raise NotImplementedError
+
+    def chunk_prefill(self, kv: PagedKVCache, params, tokens, start: int,
+                      n_valid: int, is_last: bool, slot: int, finished,
+                      samp_vecs, key):
+        """Run ``tokens`` ``[1, T]`` (the first ``n_valid`` real) of slot
+        ``slot`` behind its ``start`` cached tokens: ``(next token [1],
+        finished)``."""
+        out = self.chunk_fn(tokens.shape[1])(
+            params, tokens, jnp.asarray(start, jnp.int32),
+            jnp.asarray(n_valid, jnp.int32), jnp.asarray(is_last, bool),
+            *self.cache_arrays(kv), kv.lengths, finished,
+            jnp.asarray(slot, jnp.int32), *samp_vecs, key)
+        n = len(out) - self.chunk_walks
+        *arrays, lengths, finished, nxt = out[:n]
+        self.install(kv, arrays, lengths)
+        self._walks.extend(out[n:])
+        return nxt, finished
+
+    def prefill(self, kv: PagedKVCache, params, tokens, true_lens,
+                slot_ids, finished, samp_vecs, key):
+        """A whole prompt: the chunk at offset 0 (one request a call)."""
+        if tokens.shape[0] != 1:
+            raise NotImplementedError(
+                f"the {self.family} paged decoder prefills one request a "
+                f"call")
+        return self.chunk_prefill(kv, params, tokens, 0, true_lens[0], True,
+                                  slot_ids[0], finished, samp_vecs, key)
+
+    def decode_step(self, kv: PagedKVCache, params, finished, last_tokens,
+                    samp_vecs, key):
+        """Advance every slot one token: ``(next tokens, finished)`` and,
+        where ``tick_fetch``, the tokens with the tick's counters behind
+        them (what the host fetches in their place)."""
+        out = self.decode_fn(kv.num_slots, kv.max_seq)(
+            params, *self.cache_arrays(kv), kv.lengths, finished,
+            last_tokens, *samp_vecs, key)
+        n = len(out) - self.tick_fetch
+        *arrays, lengths, finished, nxt = out[:n]
+        self.install(kv, arrays, lengths)
+        return (nxt, finished, *out[n:])
+
+
 #: (model class, decoder class) of the families served on pages beside GPT
 _PAGED_DECODERS = []
 
@@ -199,9 +467,9 @@ _PAGED_DECODERS = []
 def register_paged_decoder(model_cls, decoder_cls):
     """Serve instances of ``model_cls`` through ``decoder_cls`` on the
     paged engine. A decoder class takes ``GPTPagedDecoder``'s constructor
-    arguments and answers the calls ``PagedBatcher`` makes (``new_kv``,
-    ``prefill``, ``decode_step``, ``params``, ``prefix_sig``,
-    ``check_config``)."""
+    arguments and is a :class:`PagedDecoderProtocol`; a
+    :class:`PagedFamilyDecoder` brings its views, its cache and its counters
+    and inherits the rest."""
     _PAGED_DECODERS.append((model_cls, decoder_cls))
 
 
@@ -209,30 +477,10 @@ def plain_walk_recurrence(decoder, kv) -> Optional[str]:
     """``"mxu"`` or ``"vpu"``: the recurrence ``paged_attn``'s plain walk
     runs over this cache's pages (``tuner.space.paged_recurrence``, from
     the call's shapes alone, so one answer an engine); None where no plain
-    walk runs: on the gather lane, and for a family whose every walk is
-    over selected pages of a head-major arena."""
+    walk runs: on the gather lane, and for a family that declares none."""
     from ....tuner.space import paged_recurrence
-    if getattr(decoder, "attn_impl", None) != "kernel":
-        return None
-    spec = decoder.spec
-    if getattr(decoder, "latent", None) is not None:
-        # latent rows: every query head on the one row a token keeps
-        _, _, page, row = kv.k.shape
-        return paged_recurrence(spec.num_attention_heads, 1, page, row,
-                                kv.k.dtype.itemsize, 1)
-    if getattr(decoder, "head_major_walk", False):
-        # a head-major arena walked plainly: a KV head a call, its query
-        # heads on the head's own fused rows
-        _, _, page, row = kv.k.shape
-        return paged_recurrence(
-            spec.num_attention_heads // spec.num_key_value_heads, 1, page,
-            row, kv.k.dtype.itemsize, 1)
-    if getattr(kv.k, "ndim", 0) != 5:
-        return None
-    q_heads = getattr(spec, "num_attention_heads", None) or spec.num_heads
-    _, _, page, kv_heads, row = kv.k.shape
-    return paged_recurrence(q_heads // kv_heads, kv_heads, page, row,
-                            kv.k.dtype.itemsize, 1 if kv.fused_kv else 2)
+    walk = decoder.plain_walk(kv) if decoder.attn_impl == "kernel" else None
+    return None if walk is None else paged_recurrence(*walk)
 
 
 def paged_decoder_class(model):
@@ -243,7 +491,7 @@ def paged_decoder_class(model):
     return GPTPagedDecoder
 
 
-class GPTPagedDecoder(GPTStaticDecoder):
+class GPTPagedDecoder(GPTStaticDecoder, PagedDecoderProtocol):
     """GPTStaticDecoder with the KV substrate swapped for pages: same
     model façade, same ExecutableCache accounting, but ``new_kv``
     returns a :class:`PagedKVCache` and every compiled program threads
@@ -251,7 +499,8 @@ class GPTPagedDecoder(GPTStaticDecoder):
     on TPU (dense arenas) and the gather lane elsewhere; the lane taken
     is ``self.attn_impl`` and the engine's ``stats()["paged_attn_impl"]``."""
 
-    kv_layout = "paged"
+    #: a sequence's whole state is K and V pages
+    supports_export = True
 
     def __init__(self, model, max_top_k: int = 64, exec_cache=None,
                  mesh=None, slot_axis: str = "model",
@@ -291,6 +540,9 @@ class GPTPagedDecoder(GPTStaticDecoder):
     def check_config(config):
         """Raises for an engine option this family does not serve (GPT
         serves them all)."""
+
+    def plain_walk(self, kv):
+        return grouped_walk(self.spec.num_heads, kv)
 
     def new_kv(self, num_slots: int, max_seq: int) -> PagedKVCache:
         if max_seq > self.spec.max_position_embeddings:

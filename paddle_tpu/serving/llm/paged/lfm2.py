@@ -24,15 +24,13 @@ tick's one fetch brings them, two counters: the distinct experts that
 received a token (summed over the expert layers) and the fullest expert's
 tokens in any layer.
 
-What this family does not do yet raises ``NotImplementedError`` at
-construction: a mesh, ``kv_layout="slot"``, ``prefix_cache``, ``spec_k > 0``,
-int8 weights or KV; the engine refuses sequence export/import for it
-(``supports_export``).
+The façade is ``paged/decode.py:PagedFamilyDecoder``, which also refuses what
+no family but GPT serves yet (a mesh, int8, ``prefix_cache``, ``spec_k``,
+sequence export); the class here declares what is this family's own.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,9 +38,9 @@ import jax.numpy as jnp
 from ....ops.paged_attention import paged_attention
 from ....models.lfm2 import (FullSequence, LFM2Config, LFM2ForCausalLM,
                              lfm2_hidden)
-from ...cache import default_cache
 from ..decode import jit_program, last_rows, sample_next
-from .decode import register_paged_decoder
+from .decode import (PagedFamilyDecoder, grouped_walk, note_expert_tick,
+                     register_paged_decoder)
 from .pool import (PagedKVCache, paged_gather_rows, paged_row_index,
                    paged_write_prompts, paged_write_rows)
 
@@ -164,73 +162,25 @@ def get_lfm2_paged_prefill_fn(cfg: LFM2Config, max_top_k: int,
         donate=(3, 4))
 
 
-class LFM2PagedDecoder:
-    """The façade ``PagedBatcher`` drives, for an ``LFM2ForCausalLM``: the
-    same calls as ``GPTPagedDecoder`` (``new_kv``, ``prefill``,
-    ``decode_step``, ``params``, ``prefix_sig``)."""
+class LFM2PagedDecoder(PagedFamilyDecoder):
+    """``PagedFamilyDecoder`` for an ``LFM2ForCausalLM``: whole prompts
+    through its own prefill program (no chunk program), the convolution
+    state beside the pages."""
 
-    kv_layout = "paged"
-    #: the convolution state has no export/import path yet
-    supports_export = False
+    family = "LFM2"
+    vocab = "vocab_size"
+    unserved_why = ("the convolution state has no prefix-reuse or rollback "
+                    "path")
 
-    def __init__(self, model, max_top_k: int = 64, exec_cache=None,
-                 mesh=None, weight_dtype: str = "float32",
-                 kv_dtype: str = "float32", page_size: int = 16,
-                 num_pages: Optional[int] = None,
-                 attn_impl: str = "auto"):
-        if mesh is not None:
-            raise NotImplementedError(
-                "the LFM2 paged decoder does not serve over a mesh yet")
-        if weight_dtype != "float32" or kv_dtype != "float32":
-            raise NotImplementedError(
-                "the LFM2 paged decoder serves float32 weights and KV "
-                f"only (got weight_dtype={weight_dtype!r}, "
-                f"kv_dtype={kv_dtype!r})")
-        if attn_impl not in ("auto", "gather", "kernel"):
-            raise ValueError(
-                f"attn_impl must be 'auto', 'gather' or 'kernel', got "
-                f"{attn_impl!r}")
-        self.spec: LFM2Config = model.config
+    def setup(self):
         if not (self.spec.attn_layers and self.spec.conv_layers):
             raise NotImplementedError(
                 "the LFM2 paged decoder needs at least one attention and "
                 "one convolution layer")
-        self._model = model
-        self.max_top_k = max(0, min(int(max_top_k), self.spec.vocab_size))
-        self.exec_cache = (exec_cache if exec_cache is not None
-                           else default_cache())
-        if attn_impl == "auto":
-            attn_impl = ("kernel" if jax.default_backend() == "tpu"
-                         else "gather")
-        self.attn_impl = attn_impl
-        self.page_size = int(page_size)
-        self.num_pages = None if num_pages is None else int(num_pages)
-        self._key = ("lfm2-paged", self.spec, self.max_top_k,
-                     self.page_size, self.attn_impl)
-
-    @staticmethod
-    def check_config(config):
-        """The engine options this family does not serve yet."""
-        for name, off in (("prefix_cache", False), ("spec_k", 0)):
-            if getattr(config, name) != off:
-                raise NotImplementedError(
-                    f"the LFM2 paged decoder does not support {name} yet "
-                    f"(the convolution state has no prefix-reuse or "
-                    f"rollback path)")
-
-    @property
-    def model(self):
-        return self._model
-
-    def params(self):
-        return self._model.param_tree()
 
     def new_kv(self, num_slots: int, max_seq: int) -> PagedKVCache:
         c = self.spec
-        if max_seq > c.max_position_embeddings:
-            raise ValueError(
-                f"max_seq {max_seq} exceeds the model's "
-                f"{c.max_position_embeddings} positions")
+        self.check_max_seq(max_seq)
         return PagedKVCache(
             num_slots, len(c.attn_layers), max_seq, c.num_key_value_heads,
             c.head_dim, dtype=self.params()["tok"].dtype,
@@ -239,27 +189,24 @@ class LFM2PagedDecoder:
                                           c.conv_L_cache - 1,
                                           c.hidden_size))}, fused_kv=True)
 
+    def plain_walk(self, kv: PagedKVCache):
+        return grouped_walk(self.spec.num_attention_heads, kv)
+
     def publish_gauges(self, kv: PagedKVCache, stat_set):
         stat_set("conv_state_bytes", kv.state_bytes())
 
     def note_tick(self, extras, n_active: int, stat_add):
-        """The tick's counters, from the values fetched behind the tokens."""
-        stat_add("moe_experts_active", int(extras[0]))
-        stat_add("moe_load_max", int(extras[1]))
-        stat_add("moe_pairs_routed", n_active * self.spec.num_experts_per_tok
-                 * self.spec.num_expert_layers)
+        note_expert_tick(self.spec, extras, n_active, stat_add)
 
     def prefix_sig(self, kv: PagedKVCache):
         c = self.spec
         return (len(c.attn_layers), c.num_key_value_heads, c.head_dim,
                 str(kv.dtype), self.page_size)
 
-    # -- compiled-program access --------------------------------------------
-    def decode_fn(self, num_slots: int, max_seq: int):
-        return self.exec_cache.get_or_compile(
-            self._key + ("decode", num_slots, max_seq),
-            lambda: get_lfm2_paged_decode_step(
-                self.spec, self.max_top_k, self.page_size, self.attn_impl))
+    # -- its programs and what they take of the cache ------------------------
+    def step_program(self):
+        return get_lfm2_paged_decode_step(self.spec, self.max_top_k,
+                                          self.page_size, self.attn_impl)
 
     def prefill_fn(self, batch: int, prompt_len: int):
         return self.exec_cache.get_or_compile(
@@ -267,26 +214,21 @@ class LFM2PagedDecoder:
             lambda: get_lfm2_paged_prefill_fn(self.spec, self.max_top_k,
                                               self.page_size))
 
+    def cache_arrays(self, kv: PagedKVCache):
+        return kv.k, kv.state["conv"], kv.block_tables
+
+    def install(self, kv: PagedKVCache, arrays, lengths):
+        k, conv = arrays
+        kv.swap(k, kv.v, lengths, {"conv": conv})
+
     def prefill(self, kv: PagedKVCache, params, tokens, true_lens,
                 slot_ids, finished, samp_vecs, key):
         fn = self.prefill_fn(tokens.shape[0], tokens.shape[1])
-        k, state, lengths, finished, nxt = fn(
-            params, tokens, true_lens, kv.k, kv.state["conv"],
-            kv.block_tables, kv.lengths, finished, slot_ids, *samp_vecs, key)
-        kv.swap(k, kv.v, lengths, {"conv": state})
+        *arrays, lengths, finished, nxt = fn(
+            params, tokens, true_lens, *self.cache_arrays(kv), kv.lengths,
+            finished, slot_ids, *samp_vecs, key)
+        self.install(kv, arrays, lengths)
         return nxt, finished
-
-    def decode_step(self, kv: PagedKVCache, params, finished, last_tokens,
-                    samp_vecs, key):
-        """Advance every slot one token: ``(next tokens, finished,
-        fetch)``, ``fetch`` the tokens with the tick's counters behind
-        them (what the host fetches)."""
-        fn = self.decode_fn(kv.num_slots, kv.max_seq)
-        k, state, lengths, finished, nxt, fetch = fn(
-            params, kv.k, kv.state["conv"], kv.block_tables, kv.lengths,
-            finished, last_tokens, *samp_vecs, key)
-        kv.swap(k, kv.v, lengths, {"conv": state})
-        return nxt, finished, fetch
 
 
 register_paged_decoder(LFM2ForCausalLM, LFM2PagedDecoder)

@@ -38,15 +38,13 @@ The decode step also returns, packed behind the next tokens so that the
 tick's one fetch brings them, the expert layer's two counters over the HELD
 experts (as the Trinity step does).
 
-What this family does not do yet raises ``NotImplementedError`` at
-construction: a mesh, ``kv_layout="slot"``, ``prefix_cache``, ``spec_k >
-0``, int8 weights or KV; the engine refuses sequence export/import for it
-(``supports_export``).
+The façade is ``paged/decode.py:PagedFamilyDecoder``, which also refuses what
+no family but GPT serves yet (a mesh, int8, ``prefix_cache``, ``spec_k``,
+sequence export); the class here declares what is this family's own.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -54,13 +52,11 @@ import jax.numpy as jnp
 from ....models.moonlight import (MoonlightConfig, MoonlightForCausalLM,
                                   moonlight_hidden, split_ukv)
 from ....ops.paged_attention import paged_attention
-from ...cache import default_cache
 from ..decode import jit_program
-from .decode import register_paged_decoder
+from .decode import (PagedFamilyDecoder, _largest_divisor, _sample,
+                     _tick_counters, _window_walks, note_expert_tick,
+                     note_window_walks, register_paged_decoder)
 from .pool import PagedKVCache, paged_row_index, paged_write_rows
-from .sala import _largest_divisor
-from .trinity import (_sample, _tick_counters, _window_walks,
-                      note_window_walks)
 
 #: the most pages of one step of the chunk's walk over the prefix (a tile's
 #: scores are ``T x heads x TILE_PAGES * page`` floats: 67 MB at 1,024 x 16
@@ -256,7 +252,7 @@ def build_moonlight_paged_chunk_fn(cfg: MoonlightConfig, max_top_k: int):
     the last real row is the prompt's first generated one when ``is_last``
     (and then the slot's ``finished`` flag is the sample's; before that it
     stays set, which keeps the decode step off the slot). The walks are
-    ``trinity._window_walks`` of the chunk's expert layers."""
+    ``_window_walks`` of the chunk's expert layers."""
 
     def _chunk(params, tokens, start, n_valid, is_last, arena, tables,
                lengths, finished, slot, temperature, top_k, do_sample, eos,
@@ -293,86 +289,35 @@ def get_moonlight_paged_chunk_fn(cfg: MoonlightConfig, max_top_k: int):
                        donate=(5,))
 
 
-class MoonlightPagedDecoder:
-    """The façade ``PagedBatcher`` drives, for a ``MoonlightForCausalLM``:
-    the same calls as ``GPTPagedDecoder`` (``new_kv``, ``prefill``,
-    ``decode_step``, ``params``, ``prefix_sig``), and ``chunk_prefill``,
-    which lets the batcher admit a prompt a chunk at a time."""
+class MoonlightPagedDecoder(PagedFamilyDecoder):
+    """``PagedFamilyDecoder`` for a ``MoonlightForCausalLM``: ONE arena of
+    latent rows, all the heads' (``pool.py``, "Latent rows")."""
 
-    kv_layout = "paged"
-    #: a latent row has no K and V pages to put in a manifest yet
-    supports_export = False
+    family = "Moonlight"
+    holds = "latent rows"
+    prefills_in_chunks = True
+    unserved_why = ("a shared or rolled-back page would hold latent rows, "
+                    "which the prefix store and the speculative step read "
+                    "as K and V pages")
 
-    def __init__(self, model, max_top_k: int = 64, exec_cache=None,
-                 mesh=None, weight_dtype: str = "float32",
-                 kv_dtype: str = "float32", page_size: int = 16,
-                 num_pages: Optional[int] = None,
-                 attn_impl: str = "auto"):
-        if mesh is not None:
-            raise NotImplementedError(
-                "the Moonlight paged decoder does not serve over a mesh yet")
-        if weight_dtype != "float32" or kv_dtype != "float32":
-            raise NotImplementedError(
-                "the Moonlight paged decoder serves float32 weights and "
-                f"latent rows only (got weight_dtype={weight_dtype!r}, "
-                f"kv_dtype={kv_dtype!r})")
-        if attn_impl not in ("auto", "gather", "kernel"):
-            raise ValueError(
-                f"attn_impl must be 'auto', 'gather' or 'kernel', got "
-                f"{attn_impl!r}")
-        self.spec: MoonlightConfig = model.config
-        self._model = model
-        self.max_top_k = max(0, min(int(max_top_k), self.spec.vocab_held))
-        self.exec_cache = (exec_cache if exec_cache is not None
-                           else default_cache())
-        if attn_impl == "auto":
-            attn_impl = ("kernel" if jax.default_backend() == "tpu"
-                         else "gather")
-        self.attn_impl = attn_impl
-        self.page_size = int(page_size)
-        self.num_pages = None if num_pages is None else int(num_pages)
+    def setup(self):
         #: ``paged_attention``'s argument: the value's and the rotary width
         self.latent = (self.spec.kv_lora_rank, self.spec.qk_rope_head_dim)
         self.row_width = latent_row_width(self.spec)
-        #: the window walks of the chunks no tick has counted yet
-        self._walks = []
-        self._key = ("moonlight-paged", self.spec, self.max_top_k,
-                     self.page_size, self.attn_impl)
-
-    def check_config(self, config):
-        """The engine options this family does not serve yet."""
-        for name, off in (("prefix_cache", False), ("spec_k", 0)):
-            if getattr(config, name) != off:
-                raise NotImplementedError(
-                    f"the Moonlight paged decoder does not support {name} "
-                    f"yet (a shared or rolled-back page would hold latent "
-                    f"rows, which the prefix store and the speculative "
-                    f"step read as K and V pages)")
-        chunk = config.prefill_chunk
-        if chunk is not None and chunk % config.page_size:
-            raise ValueError(
-                f"prefill_chunk {chunk} must be a multiple of the page "
-                f"size {config.page_size}: a chunk starts on a page")
-
-    @property
-    def model(self):
-        return self._model
-
-    def params(self):
-        return self._model.param_tree()
 
     def new_kv(self, num_slots: int, max_seq: int) -> PagedKVCache:
-        c = self.spec
-        if max_seq > c.max_position_embeddings:
-            raise ValueError(
-                f"max_seq {max_seq} exceeds the model's "
-                f"{c.max_position_embeddings} positions")
-        # ONE arena of latent rows, all the heads' (pool.py, "Latent rows")
+        self.check_max_seq(max_seq)
         return PagedKVCache(
-            num_slots, c.num_hidden_layers, max_seq, 1, self.row_width,
-            dtype=self.params()["tok"].dtype, page_size=self.page_size,
-            num_pages=self.num_pages, fused_kv=True,
-            row_shape=(self.row_width,))
+            num_slots, self.spec.num_hidden_layers, max_seq, 1,
+            self.row_width, dtype=self.params()["tok"].dtype,
+            page_size=self.page_size, num_pages=self.num_pages,
+            fused_kv=True, row_shape=(self.row_width,))
+
+    def plain_walk(self, kv: PagedKVCache):
+        # every query head on the one row a token keeps
+        _, _, page, row = kv.k.shape
+        return (self.spec.num_attention_heads, 1, page, row,
+                kv.k.dtype.itemsize, 1)
 
     def expanded_row_nbytes(self, itemsize: int = 4) -> int:
         """What a token and layer would hold as per-head keys and values."""
@@ -386,11 +331,7 @@ class MoonlightPagedDecoder:
                  self.expanded_row_nbytes(kv.dtype.itemsize))
 
     def note_tick(self, extras, n_active: int, stat_add):
-        """The tick's counters, from the values fetched behind the tokens."""
-        stat_add("moe_experts_active", int(extras[0]))
-        stat_add("moe_load_max", int(extras[1]))
-        stat_add("moe_pairs_routed", n_active * self.spec.num_experts_per_tok
-                 * self.spec.num_expert_layers)
+        note_expert_tick(self.spec, extras, n_active, stat_add)
         note_window_walks(self._walks, stat_add)
 
     def note_lengths(self, seq_lens, stat_add):
@@ -410,54 +351,19 @@ class MoonlightPagedDecoder:
         return ("latent", self.latent, self.row_width,
                 self.spec.num_hidden_layers, str(kv.dtype), self.page_size)
 
-    # -- compiled-program access --------------------------------------------
-    def decode_fn(self, num_slots: int, max_seq: int):
-        return self.exec_cache.get_or_compile(
-            self._key + ("decode", num_slots, max_seq),
-            lambda: get_moonlight_paged_decode_step(
-                self.spec, self.max_top_k, self.attn_impl))
+    # -- its programs and what they take of the cache ------------------------
+    def step_program(self):
+        return get_moonlight_paged_decode_step(self.spec, self.max_top_k,
+                                               self.attn_impl)
 
-    def chunk_fn(self, chunk_len: int):
-        return self.exec_cache.get_or_compile(
-            self._key + ("chunk", chunk_len),
-            lambda: get_moonlight_paged_chunk_fn(self.spec, self.max_top_k))
+    def chunk_program(self):
+        return get_moonlight_paged_chunk_fn(self.spec, self.max_top_k)
 
-    def chunk_prefill(self, kv: PagedKVCache, params, tokens, start: int,
-                      n_valid: int, is_last: bool, slot: int, finished,
-                      samp_vecs, key):
-        """Run ``tokens`` ``[1, T]`` (the first ``n_valid`` real) of slot
-        ``slot`` behind its ``start`` cached tokens: ``(next token [1],
-        finished)``."""
-        fn = self.chunk_fn(tokens.shape[1])
-        arena, lengths, finished, nxt, walks = fn(
-            params, tokens, jnp.asarray(start, jnp.int32),
-            jnp.asarray(n_valid, jnp.int32), jnp.asarray(is_last, bool),
-            kv.k, kv.block_tables, kv.lengths, finished,
-            jnp.asarray(slot, jnp.int32), *samp_vecs, key)
-        kv.swap(arena, kv.v, lengths)
-        self._walks.append(walks)
-        return nxt, finished
+    def cache_arrays(self, kv: PagedKVCache):
+        return kv.k, kv.block_tables
 
-    def prefill(self, kv: PagedKVCache, params, tokens, true_lens,
-                slot_ids, finished, samp_vecs, key):
-        """A whole prompt: the chunk at offset 0 (one request a call)."""
-        if tokens.shape[0] != 1:
-            raise NotImplementedError(
-                "the Moonlight paged decoder prefills one request a call")
-        return self.chunk_prefill(kv, params, tokens, 0, true_lens[0], True,
-                                  slot_ids[0], finished, samp_vecs, key)
-
-    def decode_step(self, kv: PagedKVCache, params, finished, last_tokens,
-                    samp_vecs, key):
-        """Advance every slot one token: ``(next tokens, finished,
-        fetch)``, ``fetch`` the tokens with the tick's counters behind
-        them (what the host fetches)."""
-        fn = self.decode_fn(kv.num_slots, kv.max_seq)
-        arena, lengths, finished, nxt, fetch = fn(
-            params, kv.k, kv.block_tables, kv.lengths, finished, last_tokens,
-            *samp_vecs, key)
-        kv.swap(arena, kv.v, lengths)
-        return nxt, finished, fetch
+    def install(self, kv: PagedKVCache, arrays, lengths):
+        kv.swap(*arrays, kv.v, lengths)
 
 
 register_paged_decoder(MoonlightForCausalLM, MoonlightPagedDecoder)

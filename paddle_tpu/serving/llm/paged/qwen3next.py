@@ -46,16 +46,13 @@ The decode step also returns, packed behind the next tokens so that the
 tick's one fetch brings them, the expert layers' two counters over the HELD
 experts (as the Trinity step does).
 
-What this family does not do yet raises ``NotImplementedError`` at
-construction: a mesh, ``kv_layout="slot"``, ``prefix_cache``, ``spec_k >
-0``, int8 weights or KV (a shared prefix or a rolled-back draft would need a
-SNAPSHOT of the states at that row, which nothing keeps); the engine refuses
-sequence export/import for it (``supports_export``).
+The façade is ``paged/decode.py:PagedFamilyDecoder``, which also refuses what
+no family but GPT serves yet (a mesh, int8, ``prefix_cache``, ``spec_k``,
+sequence export); the class here declares what is this family's own.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -64,13 +61,11 @@ from ....models.qwen3next import (Qwen3NextConfig, Qwen3NextForCausalLM,
                                   causal_conv, qwen3next_hidden)
 from ....ops.gated_delta import CHUNK, gated_delta_chunked, gated_delta_step
 from ....ops.paged_attention import paged_attention
-from ...cache import default_cache
 from ..decode import jit_program
-from .decode import register_paged_decoder
+from .decode import (PagedFamilyDecoder, _largest_divisor, _sample,
+                     _tick_counters, _window_walks, note_expert_tick,
+                     note_window_walks, register_paged_decoder)
 from .pool import PagedKVCache, paged_row_index
-from .sala import _largest_divisor
-from .trinity import (_sample, _tick_counters, _window_walks,
-                      note_window_walks)
 
 #: query rows of the chunk's attention computed at once, and the most pages
 #: of one step of its walk over the slot's pages (scores of ``Q_ROWS x heads
@@ -288,7 +283,7 @@ def build_qwen3next_paged_chunk_fn(cfg: Qwen3NextConfig, max_top_k: int):
     the last real row is the prompt's first generated one when ``is_last``
     (and then the slot's ``finished`` flag is the sample's; before that it
     stays set, which keeps the decode step off the slot). The walks are
-    ``trinity._window_walks`` of the chunk's expert layers."""
+    ``_window_walks`` of the chunk's expert layers."""
 
     def _chunk(params, tokens, start, n_valid, is_last, kvbuf, state, tables,
                lengths, finished, slot, temperature, top_k, do_sample, eos,
@@ -325,93 +320,40 @@ def get_qwen3next_paged_chunk_fn(cfg: Qwen3NextConfig, max_top_k: int):
                        donate=(5, 6))
 
 
-class Qwen3NextPagedDecoder:
-    """The façade ``PagedBatcher`` drives, for a ``Qwen3NextForCausalLM``:
-    the same calls as ``GPTPagedDecoder`` (``new_kv``, ``prefill``,
-    ``decode_step``, ``params``, ``prefix_sig``), and ``chunk_prefill``,
-    which lets the batcher admit a prompt a chunk at a time."""
+class Qwen3NextPagedDecoder(PagedFamilyDecoder):
+    """``PagedFamilyDecoder`` for a ``Qwen3NextForCausalLM``: head-major
+    pages for the full layers, a delta-rule state and a convolution window a
+    slot and linear layer."""
 
-    kv_layout = "paged"
-    #: the rule's states and the convolution windows have no export/import
-    #: path yet
-    supports_export = False
-    #: the arena is head-major: the plain walk runs a KV head a call
-    #: (``paged/decode.py:plain_walk_recurrence``)
-    head_major_walk = True
+    family = "Qwen3-Next"
+    prefills_in_chunks = True
+    unserved_why = ("a shared prefix or a rolled-back draft needs a "
+                    "snapshot of the linear layers' states at that row, and "
+                    "nothing keeps one")
 
-    def __init__(self, model, max_top_k: int = 64, exec_cache=None,
-                 mesh=None, weight_dtype: str = "float32",
-                 kv_dtype: str = "float32", page_size: int = 16,
-                 num_pages: Optional[int] = None,
-                 attn_impl: str = "auto"):
-        if mesh is not None:
-            raise NotImplementedError(
-                "the Qwen3-Next paged decoder does not serve over a mesh "
-                "yet")
-        if weight_dtype != "float32" or kv_dtype != "float32":
-            raise NotImplementedError(
-                "the Qwen3-Next paged decoder serves float32 weights and KV "
-                f"only (got weight_dtype={weight_dtype!r}, "
-                f"kv_dtype={kv_dtype!r})")
-        if attn_impl not in ("auto", "gather", "kernel"):
-            raise ValueError(
-                f"attn_impl must be 'auto', 'gather' or 'kernel', got "
-                f"{attn_impl!r}")
-        self.spec: Qwen3NextConfig = model.config
+    def setup(self):
         if not (self.spec.full_layers and self.spec.linear_layers):
             raise NotImplementedError(
                 "the Qwen3-Next paged decoder needs at least one full and "
                 "one linear layer")
-        self._model = model
-        self.max_top_k = max(0, min(int(max_top_k), self.spec.vocab_held))
-        self.exec_cache = (exec_cache if exec_cache is not None
-                           else default_cache())
-        if attn_impl == "auto":
-            attn_impl = ("kernel" if jax.default_backend() == "tpu"
-                         else "gather")
-        self.attn_impl = attn_impl
-        self.page_size = int(page_size)
-        self.num_pages = None if num_pages is None else int(num_pages)
-        #: the window walks of the chunks no tick has counted yet
-        self._walks = []
-        self._key = ("qwen3next-paged", self.spec, self.max_top_k,
-                     self.page_size, self.attn_impl)
-
-    @staticmethod
-    def check_config(config):
-        """The engine options this family does not serve yet."""
-        for name, off in (("prefix_cache", False), ("spec_k", 0)):
-            if getattr(config, name) != off:
-                raise NotImplementedError(
-                    f"the Qwen3-Next paged decoder does not support {name} "
-                    f"yet (a shared prefix or a rolled-back draft needs a "
-                    f"snapshot of the linear layers' states at that row, "
-                    f"and nothing keeps one)")
-        chunk = config.prefill_chunk
-        if chunk is not None and chunk % config.page_size:
-            raise ValueError(
-                f"prefill_chunk {chunk} must be a multiple of the page "
-                f"size {config.page_size}: a chunk starts on a page")
-
-    @property
-    def model(self):
-        return self._model
-
-    def params(self):
-        return self._model.param_tree()
 
     def new_kv(self, num_slots: int, max_seq: int) -> PagedKVCache:
         c = self.spec
-        if max_seq > c.max_position_embeddings:
-            raise ValueError(
-                f"max_seq {max_seq} exceeds the model's "
-                f"{c.max_position_embeddings} positions")
+        self.check_max_seq(max_seq)
         hkv, d = c.num_key_value_heads, c.head_dim
         return PagedKVCache(
             num_slots, len(c.full_layers) * hkv, max_seq, 1, d,
             dtype=self.params()["tok"].dtype, page_size=self.page_size,
             num_pages=self.num_pages, fused_kv=True, row_shape=(2 * d,),
             state_rows=state_rows(c))
+
+    def plain_walk(self, kv: PagedKVCache):
+        # the head-major arena walked plainly: a KV head a call, its query
+        # heads on the head's own fused rows
+        _, _, page, row = kv.k.shape
+        c = self.spec
+        return (c.num_attention_heads // c.num_key_value_heads, 1, page, row,
+                kv.k.dtype.itemsize, 1)
 
     def publish_gauges(self, kv: PagedKVCache, stat_set):
         stat_set("gdn_state_bytes", kv.state_bytes())
@@ -420,12 +362,8 @@ class Qwen3NextPagedDecoder:
                  kv.row_nbytes() * self.spec.num_key_value_heads)
 
     def note_tick(self, extras, n_active: int, stat_add):
-        """The tick's counters, from the values fetched behind the tokens;
-        every active slot stepped every linear layer's state."""
-        stat_add("moe_experts_active", int(extras[0]))
-        stat_add("moe_load_max", int(extras[1]))
-        stat_add("moe_pairs_routed", n_active * self.spec.num_experts_per_tok
-                 * self.spec.num_expert_layers)
+        """Every active slot stepped every linear layer's state."""
+        note_expert_tick(self.spec, extras, n_active, stat_add)
         stat_add("gdn.step_rows", n_active * len(self.spec.linear_layers))
         note_window_walks(self._walks, stat_add)
 
@@ -441,58 +379,26 @@ class Qwen3NextPagedDecoder:
                 c.num_key_value_heads, c.head_dim, str(kv.dtype),
                 self.page_size)
 
-    # -- compiled-program access --------------------------------------------
-    def decode_fn(self, num_slots: int, max_seq: int):
-        return self.exec_cache.get_or_compile(
-            self._key + ("decode", num_slots, max_seq),
-            lambda: get_qwen3next_paged_decode_step(
-                self.spec, self.max_top_k, self.attn_impl))
+    # -- its programs and what they take of the cache ------------------------
+    def step_program(self):
+        return get_qwen3next_paged_decode_step(self.spec, self.max_top_k,
+                                               self.attn_impl)
 
-    def chunk_fn(self, chunk_len: int):
+    def chunk_program(self):
+        return get_qwen3next_paged_chunk_fn(self.spec, self.max_top_k)
+
+    def admits_chunk(self, chunk_len: int):
         if chunk_len > CHUNK and chunk_len % CHUNK:
             raise ValueError(
                 f"a chunk of {chunk_len} tokens is over {CHUNK} and no "
                 f"multiple of it (the gated delta rule's chunk)")
-        return self.exec_cache.get_or_compile(
-            self._key + ("chunk", chunk_len),
-            lambda: get_qwen3next_paged_chunk_fn(self.spec, self.max_top_k))
 
-    def chunk_prefill(self, kv: PagedKVCache, params, tokens, start: int,
-                      n_valid: int, is_last: bool, slot: int, finished,
-                      samp_vecs, key):
-        """Run ``tokens`` ``[1, T]`` (the first ``n_valid`` real) of slot
-        ``slot`` behind its ``start`` cached tokens: ``(next token [1],
-        finished)``."""
-        fn = self.chunk_fn(tokens.shape[1])
-        k, state, lengths, finished, nxt, walks = fn(
-            params, tokens, jnp.asarray(start, jnp.int32),
-            jnp.asarray(n_valid, jnp.int32), jnp.asarray(is_last, bool),
-            kv.k, kv.state, kv.block_tables, kv.lengths, finished,
-            jnp.asarray(slot, jnp.int32), *samp_vecs, key)
+    def cache_arrays(self, kv: PagedKVCache):
+        return kv.k, kv.state, kv.block_tables
+
+    def install(self, kv: PagedKVCache, arrays, lengths):
+        k, state = arrays
         kv.swap(k, kv.v, lengths, state)
-        self._walks.append(walks)
-        return nxt, finished
-
-    def prefill(self, kv: PagedKVCache, params, tokens, true_lens,
-                slot_ids, finished, samp_vecs, key):
-        """A whole prompt: the chunk at offset 0 (one request a call)."""
-        if tokens.shape[0] != 1:
-            raise NotImplementedError(
-                "the Qwen3-Next paged decoder prefills one request a call")
-        return self.chunk_prefill(kv, params, tokens, 0, true_lens[0], True,
-                                  slot_ids[0], finished, samp_vecs, key)
-
-    def decode_step(self, kv: PagedKVCache, params, finished, last_tokens,
-                    samp_vecs, key):
-        """Advance every slot one token: ``(next tokens, finished,
-        fetch)``, ``fetch`` the tokens with the tick's counters behind
-        them (what the host fetches)."""
-        fn = self.decode_fn(kv.num_slots, kv.max_seq)
-        k, state, lengths, finished, nxt, fetch = fn(
-            params, kv.k, kv.state, kv.block_tables, kv.lengths, finished,
-            last_tokens, *samp_vecs, key)
-        kv.swap(k, kv.v, lengths, state)
-        return nxt, finished, fetch
 
 
 register_paged_decoder(Qwen3NextForCausalLM, Qwen3NextPagedDecoder)

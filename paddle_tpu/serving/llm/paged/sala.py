@@ -39,15 +39,13 @@ computes and what the selection needs are both counted, see
 ``SALAPagedDecoder.note_chunk``). Offset and true length are arguments: one
 compiled program serves every chunk of every prompt.
 
-What this family does not do yet raises ``NotImplementedError`` at
-construction: a mesh, ``kv_layout="slot"``, ``prefix_cache``, ``spec_k >
-0``, int8 weights or KV; the engine refuses sequence export/import for it
-(``supports_export``).
+The façade is ``paged/decode.py:PagedFamilyDecoder``, which also refuses what
+no family but GPT serves yet (a mesh, int8, ``prefix_cache``, ``spec_k``,
+sequence export); the class here declares what is this family's own.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -58,9 +56,9 @@ from ....models.sala import (MiniCPMSALAForCausalLM, SALAConfig,
                              selected_blocks)
 from ....ops.linear_attention import decayed_linear_attention
 from ....ops.paged_attention import paged_attention
-from ...cache import default_cache
-from ..decode import jit_program, sample_next
-from .decode import register_paged_decoder
+from ..decode import jit_program
+from .decode import (PagedFamilyDecoder, _largest_divisor, _sample,
+                     register_paged_decoder)
 from .pool import PagedKVCache, paged_row_index
 
 #: query rows of the chunk's sparse attention computed at once, and the most
@@ -68,11 +66,6 @@ from .pool import PagedKVCache, paged_row_index
 #: heads x TILE_PAGES * page`` floats)
 Q_ROWS, TILE_PAGES = 256, 32
 _NEG = -1e30
-
-
-def _largest_divisor(n: int, cap: int) -> int:
-    """The largest divisor of ``n`` that is at most ``cap``."""
-    return max(p for p in range(1, min(cap, n) + 1) if n % p == 0)
 
 
 def _head_rows(cfg: SALAConfig, ai: int):
@@ -300,13 +293,6 @@ class PagedChunk:
         return y
 
 
-def _sample(params, hidden, frozen, samp, key, max_top_k):
-    """``sample_next`` against this family's own head (``[hidden, V]``:
-    the transpose of a transpose folds away)."""
-    return sample_next({"tok": params["head"].T}, hidden, frozen, *samp, key,
-                       max_top_k)
-
-
 def build_sala_paged_decode_step(cfg: SALAConfig, max_top_k: int,
                                  attn_impl: str = "gather"):
     """The RAW paged decode step of this family.
@@ -381,84 +367,32 @@ def get_sala_paged_chunk_fn(cfg: SALAConfig, max_top_k: int):
                        donate=(5, 6))
 
 
-class SALAPagedDecoder:
-    """The façade ``PagedBatcher`` drives, for a ``MiniCPMSALAForCausalLM``:
-    the same calls as ``GPTPagedDecoder`` (``new_kv``, ``prefill``,
-    ``decode_step``, ``params``, ``prefix_sig``), and ``chunk_prefill``,
-    which lets the batcher admit a prompt a chunk at a time."""
+class SALAPagedDecoder(PagedFamilyDecoder):
+    """``PagedFamilyDecoder`` for a ``MiniCPMSALAForCausalLM``: pages for the
+    sparse layers, their compressed keys by page and a linear state a slot;
+    every walk is over selected pages, so it declares no plain one."""
 
-    kv_layout = "paged"
-    #: the linear states and compressed keys have no export/import path yet
-    supports_export = False
+    family = "SALA"
+    vocab = "vocab_size"
+    prefills_in_chunks = True
+    unserved_why = ("the linear states and the compressed keys have no "
+                    "prefix-reuse or rollback path")
+    tick_fetch = chunk_walks = False
 
-    def __init__(self, model, max_top_k: int = 64, exec_cache=None,
-                 mesh=None, weight_dtype: str = "float32",
-                 kv_dtype: str = "float32", page_size: int = 16,
-                 num_pages: Optional[int] = None,
-                 attn_impl: str = "auto"):
-        if mesh is not None:
-            raise NotImplementedError(
-                "the SALA paged decoder does not serve over a mesh yet")
-        if weight_dtype != "float32" or kv_dtype != "float32":
-            raise NotImplementedError(
-                "the SALA paged decoder serves float32 weights and KV "
-                f"only (got weight_dtype={weight_dtype!r}, "
-                f"kv_dtype={kv_dtype!r})")
-        if attn_impl not in ("auto", "gather", "kernel"):
-            raise ValueError(
-                f"attn_impl must be 'auto', 'gather' or 'kernel', got "
-                f"{attn_impl!r}")
-        self.spec: SALAConfig = model.config
+    def setup(self):
         if not (self.spec.sparse_layers and self.spec.linear_layers):
             raise NotImplementedError(
                 "the SALA paged decoder needs at least one sparse and one "
                 "linear layer")
-        if int(page_size) != self.spec.sparse_block_size:
+        if self.page_size != self.spec.sparse_block_size:
             raise ValueError(
-                f"page_size {page_size} must be the selection block "
+                f"page_size {self.page_size} must be the selection block "
                 f"({self.spec.sparse_block_size}): a selected block is a "
                 f"page")
-        self._model = model
-        self.max_top_k = max(0, min(int(max_top_k), self.spec.vocab_size))
-        self.exec_cache = (exec_cache if exec_cache is not None
-                           else default_cache())
-        if attn_impl == "auto":
-            attn_impl = ("kernel" if jax.default_backend() == "tpu"
-                         else "gather")
-        self.attn_impl = attn_impl
-        self.page_size = int(page_size)
-        self.num_pages = None if num_pages is None else int(num_pages)
-        self._key = ("sala-paged", self.spec, self.max_top_k,
-                     self.page_size, self.attn_impl)
-
-    @staticmethod
-    def check_config(config):
-        """The engine options this family does not serve yet."""
-        for name, off in (("prefix_cache", False), ("spec_k", 0)):
-            if getattr(config, name) != off:
-                raise NotImplementedError(
-                    f"the SALA paged decoder does not support {name} yet "
-                    f"(the linear states and the compressed keys have no "
-                    f"prefix-reuse or rollback path)")
-        chunk = config.prefill_chunk
-        if chunk is not None and chunk % config.page_size:
-            raise ValueError(
-                f"prefill_chunk {chunk} must be a multiple of the page "
-                f"size {config.page_size}: a chunk starts on a page")
-
-    @property
-    def model(self):
-        return self._model
-
-    def params(self):
-        return self._model.param_tree()
 
     def new_kv(self, num_slots: int, max_seq: int) -> PagedKVCache:
         c = self.spec
-        if max_seq > c.max_position_embeddings:
-            raise ValueError(
-                f"max_seq {max_seq} exceeds the model's "
-                f"{c.max_position_embeddings} positions")
+        self.check_max_seq(max_seq)
         hkv, d = c.num_key_value_heads, c.head_dim
         return PagedKVCache(
             num_slots, len(c.sparse_layers) * hkv, max_seq, 1, d,
@@ -512,57 +446,28 @@ class SALAPagedDecoder:
         return (len(c.sparse_layers), c.num_key_value_heads, c.head_dim,
                 str(kv.dtype), self.page_size)
 
-    # -- compiled-program access --------------------------------------------
-    def decode_fn(self, num_slots: int, max_seq: int):
-        return self.exec_cache.get_or_compile(
-            self._key + ("decode", num_slots, max_seq),
-            lambda: get_sala_paged_decode_step(
-                self.spec, self.max_top_k, self.attn_impl))
+    # -- its programs and what they take of the cache ------------------------
+    def step_program(self):
+        return get_sala_paged_decode_step(self.spec, self.max_top_k,
+                                          self.attn_impl)
 
-    def chunk_fn(self, chunk_len: int):
+    def chunk_program(self):
+        return get_sala_paged_chunk_fn(self.spec, self.max_top_k)
+
+    def admits_chunk(self, chunk_len: int):
         st = self.spec.sparse_kernel_stride
         if chunk_len % st or (chunk_len > 128 and chunk_len % 128):
             raise ValueError(
                 f"a chunk of {chunk_len} tokens is no multiple of the "
                 f"compression stride {st}, or is over 128 and no multiple "
                 f"of 128 (the linear layers' sub-chunk)")
-        return self.exec_cache.get_or_compile(
-            self._key + ("chunk", chunk_len),
-            lambda: get_sala_paged_chunk_fn(self.spec, self.max_top_k))
 
-    def chunk_prefill(self, kv: PagedKVCache, params, tokens, start: int,
-                      n_valid: int, is_last: bool, slot: int, finished,
-                      samp_vecs, key):
-        """Run ``tokens`` ``[1, T]`` (the first ``n_valid`` real) of slot
-        ``slot`` behind its ``start`` cached tokens: ``(next token [1],
-        finished)``."""
-        fn = self.chunk_fn(tokens.shape[1])
-        k, state, lengths, finished, nxt = fn(
-            params, tokens, jnp.asarray(start, jnp.int32),
-            jnp.asarray(n_valid, jnp.int32), jnp.asarray(is_last, bool),
-            kv.k, kv.state, kv.block_tables, kv.lengths, finished,
-            jnp.asarray(slot, jnp.int32), *samp_vecs, key)
+    def cache_arrays(self, kv: PagedKVCache):
+        return kv.k, kv.state, kv.block_tables
+
+    def install(self, kv: PagedKVCache, arrays, lengths):
+        k, state = arrays
         kv.swap(k, kv.v, lengths, state)
-        return nxt, finished
-
-    def prefill(self, kv: PagedKVCache, params, tokens, true_lens,
-                slot_ids, finished, samp_vecs, key):
-        """A whole prompt: the chunk at offset 0 (one request a call)."""
-        if tokens.shape[0] != 1:
-            raise NotImplementedError(
-                "the SALA paged decoder prefills one request a call")
-        return self.chunk_prefill(kv, params, tokens, 0, true_lens[0], True,
-                                  slot_ids[0], finished, samp_vecs, key)
-
-    def decode_step(self, kv: PagedKVCache, params, finished, last_tokens,
-                    samp_vecs, key):
-        """Advance every slot one token: ``(next tokens, finished)``."""
-        fn = self.decode_fn(kv.num_slots, kv.max_seq)
-        k, state, lengths, finished, nxt = fn(
-            params, kv.k, kv.state, kv.block_tables, kv.lengths, finished,
-            last_tokens, *samp_vecs, key)
-        kv.swap(k, kv.v, lengths, state)
-        return nxt, finished
 
 
 register_paged_decoder(MiniCPMSALAForCausalLM, SALAPagedDecoder)

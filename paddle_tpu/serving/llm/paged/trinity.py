@@ -30,10 +30,9 @@ tick's one fetch brings them, the expert layer's two counters over the HELD
 experts: those that received a token (summed over the expert layers) and the
 fullest one's tokens in any layer.
 
-What this family does not do yet raises ``NotImplementedError`` at
-construction: a mesh, ``kv_layout="slot"``, ``prefix_cache``, ``spec_k >
-0``, int8 weights or KV; the engine refuses sequence export/import for it
-(``supports_export``).
+The façade is ``paged/decode.py:PagedFamilyDecoder``, which also refuses what
+no family but GPT serves yet (a mesh, int8, ``prefix_cache``, ``spec_k``,
+sequence export); the class here declares what is this family's own.
 """
 from __future__ import annotations
 
@@ -46,14 +45,14 @@ import numpy as np
 
 from ....models.trinity import (TrinityConfig, TrinityForCausalLM,
                                 trinity_hidden)
-from ....ops import moe as _moe
 from ....ops.paged_attention import paged_attention
-from ...cache import default_cache
-from ..decode import jit_program, sample_next
-from .decode import register_paged_decoder
+from ..decode import jit_program
+from .decode import (PagedFamilyDecoder, _largest_divisor, _sample,
+                     _tick_counters, _window_walks, grouped_walk,
+                     note_expert_tick, note_window_walks,
+                     register_paged_decoder)
 from .pool import (PagedKVCache, PageGroup, paged_gather_rows,
                    paged_row_index, paged_write_rows, window_page_bound)
-from .sala import _largest_divisor
 
 #: query rows of the chunk's attention computed at once, and the most pages
 #: of one step of its walk over the slot's pages (scores of ``Q_ROWS x heads
@@ -194,41 +193,6 @@ class PagedChunk(_Groups):
         return out.reshape(1, t, hq, d)
 
 
-def _sample(params, hidden, frozen, samp, key, max_top_k):
-    """``sample_next`` against this family's own head (``[hidden, V]``:
-    the transpose of a transpose folds away)."""
-    return sample_next({"tok": params["head"].T}, hidden, frozen, *samp, key,
-                       max_top_k)
-
-
-def _tick_counters(counts):
-    """``[2]`` int32: the held experts that received a token, summed over
-    the expert layers, and the fullest one's tokens in any layer."""
-    counts = jnp.stack(counts) if counts else jnp.zeros((1, 1), jnp.int32)
-    return jnp.stack([jnp.sum(counts > 0), jnp.max(counts)]).astype(jnp.int32)
-
-
-def _window_walks(counts, num_tokens: int, top_k: int, num_experts: int):
-    """``[2]`` int32: the windows of their row buffers a program's expert
-    layers walked, summed, and the calls that had a window to walk
-    (``ops/moe.py:window_passes``; 0 and 0 where a call of these sizes takes
-    its whole buffer)."""
-    passes = [p for p in (_moe.window_passes(c, num_tokens, top_k,
-                                             num_experts) for c in counts)
-              if p is not None]
-    return jnp.stack([sum(passes, jnp.int32(0)), jnp.int32(len(passes))])
-
-
-def note_window_walks(pending: list, stat_add):
-    """Count the ``_window_walks`` of the chunks dispatched before the tick
-    whose fetch has just ended (the lane is serial: they are done), and
-    forget them."""
-    for passes, calls in jax.device_get(pending):  # noqa: PTA002 -- two int32 a chunk that ended before the tick's fetch did: one copy of ready values, no wait
-        stat_add("moe.window_passes", int(passes))
-        stat_add("moe.window_calls", int(calls))
-    pending.clear()
-
-
 def build_trinity_paged_decode_step(cfg: TrinityConfig, max_top_k: int,
                                     attn_impl: str = "gather"):
     """The RAW paged decode step of this family.
@@ -311,75 +275,27 @@ def get_trinity_paged_chunk_fn(cfg: TrinityConfig, max_top_k: int):
                        donate=(5, 6))
 
 
-class TrinityPagedDecoder:
-    """The façade ``PagedBatcher`` drives, for a ``TrinityForCausalLM``: the
-    same calls as ``GPTPagedDecoder`` (``new_kv``, ``prefill``,
-    ``decode_step``, ``params``, ``prefix_sig``), and ``chunk_prefill``,
-    which lets the batcher admit a prompt a chunk at a time."""
+class TrinityPagedDecoder(PagedFamilyDecoder):
+    """``PagedFamilyDecoder`` for a ``TrinityForCausalLM``: two page groups
+    in one cache, so its programs take and return the groups' tuples."""
 
-    kv_layout = "paged"
-    #: two groups' pages of one sequence have no export/import path yet
-    supports_export = False
+    family = "Trinity"
+    prefills_in_chunks = True
+    unserved_why = ("two page groups hold different pages of one prefix, "
+                    "and a rollback would have to re-map released pages")
 
-    def __init__(self, model, max_top_k: int = 64, exec_cache=None,
-                 mesh=None, weight_dtype: str = "float32",
-                 kv_dtype: str = "float32", page_size: int = 16,
-                 num_pages: Optional[int] = None,
-                 attn_impl: str = "auto"):
-        if mesh is not None:
-            raise NotImplementedError(
-                "the Trinity paged decoder does not serve over a mesh yet")
-        if weight_dtype != "float32" or kv_dtype != "float32":
-            raise NotImplementedError(
-                "the Trinity paged decoder serves float32 weights and KV "
-                f"only (got weight_dtype={weight_dtype!r}, "
-                f"kv_dtype={kv_dtype!r})")
-        if attn_impl not in ("auto", "gather", "kernel"):
-            raise ValueError(
-                f"attn_impl must be 'auto', 'gather' or 'kernel', got "
-                f"{attn_impl!r}")
-        self.spec: TrinityConfig = model.config
-        self._model = model
-        self.max_top_k = max(0, min(int(max_top_k), self.spec.vocab_held))
-        self.exec_cache = (exec_cache if exec_cache is not None
-                           else default_cache())
-        if attn_impl == "auto":
-            attn_impl = ("kernel" if jax.default_backend() == "tpu"
-                         else "gather")
-        self.attn_impl = attn_impl
-        self.page_size = int(page_size)
-        self.num_pages = None if num_pages is None else int(num_pages)
+    def setup(self):
         #: rows one program writes at most (``check_config`` sets it from
         #: the engine's chunk or largest bucket): a window group's bound
         self.span: Optional[int] = None
-        #: the window walks of the chunks no tick has counted yet
-        self._walks = []
-        self._key = ("trinity-paged", self.spec, self.max_top_k,
-                     self.page_size, self.attn_impl)
 
     def check_config(self, config):
-        """The engine options this family does not serve yet; and what the
-        engine's prefill writes at once, which sizes the window group."""
-        for name, off in (("prefix_cache", False), ("spec_k", 0)):
-            if getattr(config, name) != off:
-                raise NotImplementedError(
-                    f"the Trinity paged decoder does not support {name} yet "
-                    f"(two page groups hold different pages of one prefix, "
-                    f"and a rollback would have to re-map released pages)")
+        """And what the engine's prefill writes at once, which sizes the
+        window group."""
+        super().check_config(config)
         chunk = config.prefill_chunk
-        if chunk is not None and chunk % config.page_size:
-            raise ValueError(
-                f"prefill_chunk {chunk} must be a multiple of the page "
-                f"size {config.page_size}: a chunk starts on a page")
         self.span = chunk if chunk is not None else max(
             config.prefill_buckets)
-
-    @property
-    def model(self):
-        return self._model
-
-    def params(self):
-        return self._model.param_tree()
 
     def window_pages(self, num_slots: int, max_seq: int) -> int:
         """Pages of the window group's pool: every slot at its bound, and a
@@ -390,10 +306,7 @@ class TrinityPagedDecoder:
 
     def new_kv(self, num_slots: int, max_seq: int) -> PagedKVCache:
         c = self.spec
-        if max_seq > c.max_position_embeddings:
-            raise ValueError(
-                f"max_seq {max_seq} exceeds the model's "
-                f"{c.max_position_embeddings} positions")
+        self.check_max_seq(max_seq)
         groups = [PageGroup(layers, window,
                             self.num_pages if window is None
                             else self.window_pages(num_slots, max_seq))
@@ -403,16 +316,15 @@ class TrinityPagedDecoder:
             c.head_dim, dtype=self.params()["tok"].dtype,
             page_size=self.page_size, groups=groups)
 
+    def plain_walk(self, kv: PagedKVCache):
+        return grouped_walk(self.spec.num_attention_heads, kv)
+
     def publish_gauges(self, kv: PagedKVCache, stat_set):
         stat_set("kv_group_bytes.full", kv.group_bytes(windowed=False))
         stat_set("kv_group_bytes.window", kv.group_bytes(windowed=True))
 
     def note_tick(self, extras, n_active: int, stat_add):
-        """The tick's counters, from the values fetched behind the tokens."""
-        stat_add("moe_experts_active", int(extras[0]))
-        stat_add("moe_load_max", int(extras[1]))
-        stat_add("moe_pairs_routed", n_active * self.spec.num_experts_per_tok
-                 * self.spec.num_expert_layers)
+        note_expert_tick(self.spec, extras, n_active, stat_add)
         note_window_walks(self._walks, stat_add)
 
     def note_lengths(self, seq_lens, stat_add):
@@ -427,70 +339,28 @@ class TrinityPagedDecoder:
                  int((live - first).sum()) * layers)
         stat_add("window_attn.pages_live", int(live.sum()) * layers)
 
-    def note_chunk(self, start: int, n_valid: int, pages_per_seq: int,
-                   stat_add):
-        """A chunk has no counters of this family's own."""
-
     def prefix_sig(self, kv: PagedKVCache):
         c = self.spec
         return (page_groups(c), c.num_key_value_heads, c.head_dim,
                 str(kv.dtype), self.page_size)
 
-    # -- compiled-program access --------------------------------------------
-    def decode_fn(self, num_slots: int, max_seq: int):
-        return self.exec_cache.get_or_compile(
-            self._key + ("decode", num_slots, max_seq),
-            lambda: get_trinity_paged_decode_step(
-                self.spec, self.max_top_k, self.attn_impl))
+    # -- its programs and what they take of the cache ------------------------
+    def step_program(self):
+        return get_trinity_paged_decode_step(self.spec, self.max_top_k,
+                                             self.attn_impl)
 
-    def chunk_fn(self, chunk_len: int):
-        return self.exec_cache.get_or_compile(
-            self._key + ("chunk", chunk_len),
-            lambda: get_trinity_paged_chunk_fn(self.spec, self.max_top_k))
+    def chunk_program(self):
+        return get_trinity_paged_chunk_fn(self.spec, self.max_top_k)
 
     @staticmethod
-    def _arenas(kv: PagedKVCache):
+    def cache_arrays(kv: PagedKVCache):
         return (tuple(g.k for g in kv.groups), tuple(g.v for g in kv.groups),
                 tuple(g.block_tables for g in kv.groups))
 
-    def chunk_prefill(self, kv: PagedKVCache, params, tokens, start: int,
-                      n_valid: int, is_last: bool, slot: int, finished,
-                      samp_vecs, key):
-        """Run ``tokens`` ``[1, T]`` (the first ``n_valid`` real) of slot
-        ``slot`` behind its ``start`` cached tokens: ``(next token [1],
-        finished)``."""
-        fn = self.chunk_fn(tokens.shape[1])
-        ks, vs, tables = self._arenas(kv)
-        ks, vs, lengths, finished, nxt, walks = fn(
-            params, tokens, jnp.asarray(start, jnp.int32),
-            jnp.asarray(n_valid, jnp.int32), jnp.asarray(is_last, bool),
-            ks, vs, tables, kv.lengths, finished,
-            jnp.asarray(slot, jnp.int32), *samp_vecs, key)
-        kv.swap_groups(ks, vs, lengths)
-        self._walks.append(walks)
-        return nxt, finished
+    _arenas = cache_arrays      # the name tests/test_serving_trinity.py uses
 
-    def prefill(self, kv: PagedKVCache, params, tokens, true_lens,
-                slot_ids, finished, samp_vecs, key):
-        """A whole prompt: the chunk at offset 0 (one request a call)."""
-        if tokens.shape[0] != 1:
-            raise NotImplementedError(
-                "the Trinity paged decoder prefills one request a call")
-        return self.chunk_prefill(kv, params, tokens, 0, true_lens[0], True,
-                                  slot_ids[0], finished, samp_vecs, key)
-
-    def decode_step(self, kv: PagedKVCache, params, finished, last_tokens,
-                    samp_vecs, key):
-        """Advance every slot one token: ``(next tokens, finished,
-        fetch)``, ``fetch`` the tokens with the tick's counters behind
-        them (what the host fetches)."""
-        fn = self.decode_fn(kv.num_slots, kv.max_seq)
-        ks, vs, tables = self._arenas(kv)
-        ks, vs, lengths, finished, nxt, fetch = fn(
-            params, ks, vs, tables, kv.lengths, finished, last_tokens,
-            *samp_vecs, key)
-        kv.swap_groups(ks, vs, lengths)
-        return nxt, finished, fetch
+    def install(self, kv: PagedKVCache, arrays, lengths):
+        kv.swap_groups(*arrays, lengths)
 
 
 register_paged_decoder(TrinityForCausalLM, TrinityPagedDecoder)

@@ -1079,7 +1079,7 @@ class LLMEngine(DrainableEngineBase):
                 attn_impl=self._config.paged_attn_impl)
             self._decoder.check_config(self._config)
             if self._config.prefill_chunk is not None \
-                    and not hasattr(self._decoder, "chunk_prefill"):
+                    and not self._decoder.prefills_in_chunks:
                 raise NotImplementedError(
                     f"{type(self._decoder).__name__} has no chunked "
                     f"prefill yet: leave prefill_chunk unset")

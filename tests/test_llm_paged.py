@@ -2,6 +2,8 @@
 paged decode/prefill/spec programs, COW prefix sharing, page-granular
 admission — and the contracts the slot path must keep (double-free
 hardening, bitwise decode parity)."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -1269,3 +1271,170 @@ class TestAuditEntrypoint:
         from paddle_tpu.core.audit import load_default_entrypoints
         eps = load_default_entrypoints()
         assert "llm_paged_decode_step" in eps
+
+
+# -- the seam between the engine and a decoder family -------------------------
+
+@functools.lru_cache(maxsize=None)
+def _toy_family(name):
+    """``(decoder class, an unseeded toy model, its page size)``: the
+    rehearsal widths of the family's benchmark configuration. Nothing here
+    runs a program, so the weights are whatever the layers start with (and
+    one model a family serves every test below)."""
+    import importlib
+    import json
+    import os
+    from benchmark import spec as bench_spec
+    from paddle_tpu.serving.llm import paged
+    if name == "GPT":
+        return GPTPagedDecoder, _tiny_model(), 8
+    adapter, config, cls = {
+        "LFM2": ("lfm2_adapter", "lfm2-8b-a1b", paged.LFM2PagedDecoder),
+        "SALA": ("sala_adapter", "minicpm-sala", paged.SALAPagedDecoder),
+        "Trinity": ("trinity_adapter", "trinity-mini",
+                    paged.TrinityPagedDecoder),
+        "Moonlight": ("moonlight_adapter", "moonlight-16b-a3b",
+                      paged.MoonlightPagedDecoder),
+        "Qwen3-Next": ("qwen3next_adapter", "qwen3-next-80b-a3b",
+                       paged.Qwen3NextPagedDecoder)}[name]
+    with open(os.path.join(bench_spec.HERE, "configs",
+                           config + ".json")) as f:
+        cfg = json.load(f)
+    cfg = bench_spec._merged(cfg, cfg["rehearsal"])
+    net = importlib.import_module("benchmark." + adapter).build_net(cfg)
+    net.eval()
+    return cls, net, 8      # SALA's selection block at these widths is 8
+
+
+FAMILIES = ["LFM2", "SALA", "Trinity", "Moonlight", "Qwen3-Next"]
+
+
+class TestDecoderProtocol:
+    """What ``PagedBatcher`` and ``LLMEngine`` ask of a decoder is declared
+    (``paged/decode.py:PagedDecoderProtocol``), never probed."""
+
+    @pytest.mark.parametrize("name", ["GPT"] + FAMILIES)
+    def test_every_decoder_declares_the_protocol(self, name):
+        import inspect
+        import re
+        from paddle_tpu.serving.llm.paged import PagedBatcher
+        from paddle_tpu.serving.llm.paged.decode import (
+            PagedDecoderProtocol, PagedFamilyDecoder)
+        cls, _, _ = _toy_family(name)
+        assert issubclass(cls, PagedDecoderProtocol)
+        assert issubclass(cls, PagedFamilyDecoder) == (name != "GPT")
+        assert cls.kv_layout == "paged"
+        assert cls.supports_export is (name == "GPT")
+        assert cls.prefills_in_chunks is (name not in ("GPT", "LFM2"))
+        hooks = ["plain_walk", "publish_gauges", "note_lengths",
+                 "note_chunk", "note_tick"]
+        calls = ["check_config", "new_kv", "params", "prefix_sig", "prefill",
+                 "decode_step"]
+        if cls.prefills_in_chunks:
+            calls.append("chunk_prefill")
+        for hook in hooks + calls:
+            assert callable(getattr(cls, hook)), hook
+        # the hooks take what the protocol's take
+        for hook in hooks:
+            assert list(inspect.signature(getattr(cls, hook)).parameters) \
+                == list(inspect.signature(
+                    getattr(PagedDecoderProtocol, hook)).parameters), hook
+        if name != "GPT":
+            # a family's class holds what is its own; the calls are the base's
+            own = set(vars(cls))
+            assert not own & {"__init__", "model", "params", "decode_fn",
+                              "chunk_prefill", "decode_step"}
+            assert ("check_config" in own) == (name == "Trinity")
+            assert cls.family == name
+        # and the engine reads the declaration: no probe of a decoder but
+        # the guard against a slot decoder handed in by mistake, and the
+        # lane of a slot engine's decoder, which declares nothing
+        probe = re.compile(
+            r"(?:hasattr|getattr)\(\s*(?:self\.)?_?decoder,\s*\"(\w+)\"")
+        assert probe.findall(inspect.getsource(PagedBatcher)) \
+            == ["kv_layout"]
+        assert set(probe.findall(inspect.getsource(LLMEngine))) \
+            <= {"attn_impl"}
+
+    @pytest.mark.parametrize("name", FAMILIES)
+    def test_what_a_family_does_not_serve_raises_in_its_name(self, name):
+        cls, net, page = _toy_family(name)
+        the = f"the {name} paged decoder "
+        with pytest.raises(NotImplementedError,
+                           match=the + "does not serve over a mesh yet"):
+            cls(net, mesh=object())
+        for dtypes in ({"weight_dtype": "int8"}, {"kv_dtype": "int8"}):
+            with pytest.raises(NotImplementedError,
+                               match=the + "serves float32 weights and .* "
+                               "only .*'int8'"):
+                cls(net, page_size=page, **dtypes)
+        with pytest.raises(ValueError, match="attn_impl must be 'auto', "
+                           "'gather' or 'kernel', got 'flash'"):
+            cls(net, page_size=page, attn_impl="flash")
+        dec = cls(net, page_size=page, max_top_k=10 ** 6)
+        assert dec.attn_impl == "gather"            # "auto" off the chip
+        assert dec.max_top_k == getattr(dec.spec, cls.vocab)
+        kw = dict(kv_layout="paged", page_size=page, max_seq=32,
+                  warmup=False)
+        dec.check_config(LLMEngineConfig(**kw))
+        for option in ({"prefix_cache": True}, {"spec_k": 2}):
+            with pytest.raises(NotImplementedError,
+                               match=the + f"does not support "
+                               f"{next(iter(option))} yet \\(.+\\)"):
+                dec.check_config(LLMEngineConfig(**kw, **option))
+        if cls.prefills_in_chunks:
+            with pytest.raises(ValueError, match="prefill_chunk 12 must be "
+                               "a multiple of the page size 8"):
+                dec.check_config(LLMEngineConfig(**kw, prefill_chunk=12))
+        else:
+            with pytest.raises(NotImplementedError,
+                               match=cls.__name__ + " has no chunked"):
+                LLMEngine(net, LLMEngineConfig(**kw, prefill_chunk=8))
+        with pytest.raises(ValueError, match="exceeds the model's"):
+            dec.new_kv(2, dec.spec.max_position_embeddings + page)
+
+    @pytest.mark.parametrize("name", ["GPT"] + FAMILIES)
+    def test_the_recurrence_is_the_tuners_for_the_familys_arena(self, name):
+        """``paged_attn_recurrence`` and its gauge against
+        ``tuner.space.paged_recurrence`` called by hand with each arena's
+        shape as the family's docstring gives it."""
+        from paddle_tpu.tuner.space import paged_recurrence
+        _, net, page = _toy_family(name)
+        c = getattr(net, "config", None)
+        by_hand = {
+            # [P+1, L, page, H, D] twice: a query head a KV head
+            # (``_tiny_model``: 4 heads of 32 / 4)
+            "GPT": lambda: paged_recurrence(1, 4, page, 8, 4, 2),
+            # [P+1, La, page, Hkv, 2D]
+            "LFM2": lambda: paged_recurrence(
+                c.num_attention_heads // c.num_key_value_heads,
+                c.num_key_value_heads, page, 2 * c.head_dim, 4, 1),
+            "SALA": lambda: None,       # every walk is over selected pages
+            # [P+1, layers, page, Hkv, D] twice, the first group's
+            "Trinity": lambda: paged_recurrence(
+                c.num_attention_heads // c.num_key_value_heads,
+                c.num_key_value_heads, page, c.head_dim, 4, 2),
+            # [P+1, L, page, row]: every query head on a token's one row
+            "Moonlight": lambda: paged_recurrence(
+                c.num_attention_heads, 1, page,
+                -(-c.latent_row // 128) * 128, 4, 1),
+            # [P+1, L * Hkv, page, 2D]: a KV head a call
+            "Qwen3-Next": lambda: paged_recurrence(
+                c.num_attention_heads // c.num_key_value_heads, 1, page,
+                2 * c.head_dim, 4, 1)}[name]()
+        for impl, want in (("kernel", by_hand), ("gather", None)):
+            eng = LLMEngine(net, LLMEngineConfig(
+                kv_layout="paged", num_slots=2, max_seq=32, page_size=page,
+                num_pages=10, prefill_buckets=[16], max_top_k=4,
+                paged_attn_impl=impl, warmup=False),
+                registry=StatRegistry())
+            try:
+                st = eng.stats()
+                assert st["paged_attn_impl"] == impl
+                assert st["paged_attn_recurrence"] == want
+                gauge = st["stats"].get(
+                    eng.config.stat_prefix + ".paged_attn.recurrence_mxu")
+                assert gauge == (None if want is None
+                                 else int(want == "mxu"))
+            finally:
+                eng.drain(timeout=10)

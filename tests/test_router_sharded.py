@@ -515,7 +515,10 @@ class TestAcceptance2x4:
             futs = [router.submit([x]) for x in payloads]
             for fut, want in zip(futs, serial):
                 got, = fut.result(timeout=120)
-                assert np.array_equal(got, want)
+                # a row of a padded batch against the row alone: equal to
+                # the last places (this CPU backend rounds the two
+                # executables apart by an ulp)
+                np.testing.assert_array_max_ulp(got, want, maxulp=4)
             # the replicas compiled their own GSPMD executables (distinct
             # sharded cache keys; the reference's unsharded compiles all
             # happened before this window)
@@ -533,7 +536,7 @@ class TestAcceptance2x4:
                 assert router.healthz()["status"] == "degraded"
                 for x, want in zip(payloads[:4], serial[:4]):
                     got, = router.submit([x]).result(timeout=120)
-                    assert np.array_equal(got, want)
+                    np.testing.assert_array_max_ulp(got, want, maxulp=4)
                 # ... and resurrects from the health-stamped checkpoint
                 assert _wait_for(lambda: r0.state == HEALTHY, timeout=120)
             assert r0.stats()["restarts"] == 1
@@ -541,7 +544,7 @@ class TestAcceptance2x4:
             assert _wait_for(
                 lambda: router.healthz()["status"] == "ok", timeout=60)
             got, = router.submit([payloads[0]]).result(timeout=120)
-            assert np.array_equal(got, serial[0])
+            np.testing.assert_array_max_ulp(got, serial[0], maxulp=4)
         finally:
             router.drain(timeout=60)
         with pytest.raises(EngineDraining):

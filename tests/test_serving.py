@@ -401,9 +401,11 @@ class TestServingE2E:
             futs = list(ex.map(lambda x: eng.submit([x]), payloads))
         outs = [f.result(60) for f in futs]
 
-        # bitwise-identical to the serial Predictor
+        # the serial Predictor's rows, to the last places: a row of a
+        # padded batch and the same row alone go through executables of
+        # different shapes, which this CPU backend rounds apart by an ulp
         for (out,), ref in zip(outs, serial):
-            assert np.array_equal(out, ref)
+            np.testing.assert_array_max_ulp(out, ref, maxulp=4)
         # at least one batch actually coalesced >= 2 requests
         assert reg.get("serving.coalesced_batches") >= 1
         # zero cache misses after warmup: every batch hit a bucketed shape
