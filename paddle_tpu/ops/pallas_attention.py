@@ -27,7 +27,7 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import ad_checkpoint, lax
 
 from .dispatch import apply
 from ..core.pallas_mode import resolve_interpret
@@ -494,9 +494,20 @@ def _fa_core(qb, kb, vb, causal, sc, bq, bk, interpret, true_kv):
     return out
 
 
+# what the backward needs of the forward kernel and only the kernel can make
+# again: a checkpoint whose policy saves these names (fleet.utils.recompute)
+# keeps them, and its backward pass launches no second flash_fwd
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
+
+
 def _fa_core_fwd(qb, kb, vb, causal, sc, bq, bk, interpret, true_kv):
     out, lse = _fa_fwd_with_lse(qb, kb, vb, causal, sc, bq, bk, interpret,
                                 true_kv)
+    # the returned out and the residual out are the ONE named value: a name
+    # on the residual copy alone leaves the block's backward asking for the
+    # unnamed one, and the second launch comes back
+    out = ad_checkpoint.checkpoint_name(out, RESIDUAL_NAMES[0])
+    lse = ad_checkpoint.checkpoint_name(lse, RESIDUAL_NAMES[1])
     return out, (qb, kb, vb, out, lse)
 
 

@@ -350,3 +350,67 @@ def test_no_loop_carries_a_statistic_and_dkv_turns_no_tile(dtype):
     # two of the four take a tile, as their left operand, [bk, bq]
     assert sum(tuple(e.invars[0].aval.shape) == (bk, bq)
                for e in dots) == 2
+
+
+# -- what recompute keeps of the kernel (PR 47) -------------------------------
+
+def _recomputed_stack(attn_impl, layers, dtype):
+    """(loss(raws, x), raws, x): ``layers`` encoder blocks, each through
+    ``fleet.utils.recompute``, as a pure function of the parameters."""
+    from paddle_tpu.distributed.fleet.utils import recompute
+    from paddle_tpu.jit.functionalize import build_pure
+    paddle.seed(3)
+    blocks = [paddle.nn.TransformerEncoderLayer(
+        32, 2, 64, dropout=0.0, normalize_before=True, attn_impl=attn_impl)
+        for _ in range(layers)]
+    params = [p for blk in blocks for p in blk.parameters()]
+
+    def stack(h):
+        for blk in blocks:
+            h = recompute(blk.forward, h)
+        return h
+    pure, _ = build_pure(stack, params)
+
+    def loss(raws, x):
+        out, = pure(raws, [x], jax.random.PRNGKey(0), None)
+        return out.astype(jnp.float32).sum()
+    return (loss, [p._data.astype(dtype) for p in params],
+            jnp.ones((2, 128, 32), dtype))
+
+
+@pytest.mark.parametrize("layers", [1, 2])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_recompute_runs_each_flash_kernel_once_a_layer(dtype, layers):
+    """The gradient of a recomputed block launches ``flash_fwd`` once: the
+    checkpoint's policy keeps the kernel's named ``out`` and ``lse``
+    (``RESIDUAL_NAMES``), so the backward pass does not make them again.
+    A bare ``jax.checkpoint`` holds two a layer. Counted on the live part
+    of the jaxpr: ``recompute`` traces a block once more for the structure
+    of its result, and nothing reads that trace."""
+    from jax.interpreters import partial_eval as pe
+    loss, raws, x = _recomputed_stack("flash", layers, dtype)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss))(raws, x).jaxpr
+    live, _ = pe.dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))
+    calls = [str(e.params["name"]) for e in _eqns(live)
+             if e.primitive.name == "pallas_call"]
+    assert sorted(calls) == sorted(
+        ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"] * layers)
+
+
+@pytest.mark.parametrize("attn_impl,kept", [
+    ("dense", []), ("flash", ["f32[4,128,16]", "f32[4,1,128]"])],
+    ids=["dense", "flash"])
+def test_recompute_keeps_the_blocks_inputs_and_the_kernels_names(
+        capsys, attn_impl, kept):
+    """A block with no flash call inside holds no name, and its checkpoint
+    saves what a bare one saved, the block's inputs; with the kernel inside
+    it saves ``out`` [heads, rows, head size] and ``lse`` [heads, 1, rows]
+    besides, and nothing else."""
+    from jax.ad_checkpoint import print_saved_residuals
+    loss, raws, x = _recomputed_stack(attn_impl, 1, "float32")
+    print_saved_residuals(loss, raws, x)
+    saved = capsys.readouterr().out.strip().splitlines()
+    inputs = [line for line in saved if " from the argument " in line]
+    assert len(inputs) >= len(raws)          # a bias may not be needed
+    assert [line.split()[0] for line in saved
+            if line not in inputs] == kept

@@ -345,3 +345,50 @@ def test_gpt_generate_kv_cache_equals_recompute():
         np.array([[7], [8]], np.int32)), cache=cache)
     assert tuple(logits2.shape) == (2, 1, 32)
     assert int(cache[0].k.shape[2]) == 4
+
+
+@pytest.mark.parametrize("layers", [1, 2])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_gpt_recompute_equals_plain_exactly(causal, layers):
+    """Per-block ``fleet.utils.recompute`` changes what a compiled train
+    step keeps, never what it computes: loss and every parameter's
+    gradient of a float32 GPT on the flash path are the plain step's, bit
+    for bit. The checkpoint keeps the kernel's ``out`` and ``lse``, and the
+    kept ``out`` is the value a second launch would have made. Evaluated
+    operation by operation (``jax.disable_jit``), so ``np.array_equal``:
+    compiled as one program the CPU fuses the two steps differently and a
+    last digit of a sum may round the other way."""
+    import jax
+    from paddle_tpu.distributed.fleet.utils import recompute
+    from paddle_tpu.jit.functionalize import build_pure
+    from paddle_tpu.models import (GPTConfig, GPTForCausalLM,
+                                   GPTPretrainingCriterion)
+    paddle.seed(4)
+    net = GPTForCausalLM(GPTConfig(
+        vocab_size=64, hidden_size=32, num_layers=layers, num_heads=2,
+        max_position_embeddings=128, hidden_dropout_prob=0.0,
+        attention_dropout_prob=0.0, attn_impl="flash"))
+    if not causal:                          # the same stack, no mask
+        net.gpt.decoder.forward = (
+            lambda h, src_mask=None, __f=net.gpt.decoder.forward:
+            __f(h, src_mask=None))
+    names, params = zip(*net.named_parameters())
+    raws = [p._data for p in params]
+    ids = np.random.RandomState(0).randint(0, 64, (2, 128)).astype("int64")
+    pure, _ = build_pure(
+        lambda x: GPTPretrainingCriterion()(net(x), x), list(params))
+
+    def loss_and_grads():
+        with jax.disable_jit():
+            return jax.value_and_grad(lambda rs: pure(
+                rs, [ids], jax.random.PRNGKey(0), None)[0])(raws)
+    plain_loss, plain = loss_and_grads()
+    for blk in net.gpt.decoder.layers:
+        blk.forward = (lambda *a, __f=blk.forward, **k:
+                       recompute(__f, *a, **k))
+    loss, grads = loss_and_grads()
+    assert np.array_equal(np.asarray(loss), np.asarray(plain_loss))
+    assert float(plain_loss) > 0 and len(grads) == len(names)
+    for name, g, want in zip(names, grads, plain):
+        assert np.abs(np.asarray(want)).max() > 0 or "k_proj.bias" in name
+        assert np.array_equal(np.asarray(g), np.asarray(want)), name
