@@ -44,6 +44,11 @@ BLACK_LIST = {
 # because fp16 statistics overflow — fp16 keeps that behavior here).
 NORM_OPS = {"layer_norm", "batch_norm", "instance_norm", "group_norm",
             "norm"}
+# So does the hard-label softmax cross entropy (nn/functional/loss.py): its
+# sums and its loss are f32 from the logits as given, and those logits are
+# what it keeps for backward. Cast up before it, the cast's f32 [N, V]
+# result would be kept in their place.
+F32_INSIDE_OPS = NORM_OPS | {"softmax_cross_entropy_rows"}
 
 _STATE = {"enabled": False, "dtype": None, "level": "O1",
           "custom_white": set(), "custom_black": set()}
@@ -56,8 +61,8 @@ def _amp_hook(op_name: str, tensors: List[Tensor]) -> List[Tensor]:
     white = (WHITE_LIST | _STATE["custom_white"]) - _STATE["custom_black"]
     black = BLACK_LIST | _STATE["custom_black"]
     if np.dtype(low) == np.dtype("float16"):
-        black = black | NORM_OPS
-    elif op_name in NORM_OPS and op_name not in _STATE["custom_black"]:
+        black = black | F32_INSIDE_OPS
+    elif op_name in F32_INSIDE_OPS and op_name not in _STATE["custom_black"]:
         return tensors  # bf16-neutral: f32 stats happen inside the op
     if _STATE["level"] == "O2":
         cast_low = op_name not in black

@@ -176,7 +176,12 @@ class GPTPretrainingCriterion(Layer):
     merge into a relayout (S-1 is no multiple of the sublane tile), and at
     B=4, S=4096, V=50304 the TPU compiler spent ~427 s on it against ~4 s
     for this form (PERF.md, PR 21). Same mean over the same B*(S-1)
-    positions."""
+    positions.
+
+    Kept for the backward pass (``F.cross_entropy``, PR 48): the logits as
+    the head's product gave them (bf16 ``[16384, 50304]`` under the train
+    cell's autocast) and one float32 ``lse`` a row, not a float32 table of
+    log-probabilities, 3.07 GiB at that size and a pass of its own."""
 
     def forward(self, logits, labels):
         v = logits.shape[-1]

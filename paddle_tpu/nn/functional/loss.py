@@ -24,23 +24,68 @@ def _reduce(out, reduction):
     return out
 
 
+@jax.custom_vjp
+def _lse_less_pick(logits, safe):
+    """``logsumexp(logits) - logits[safe]`` over the last axis, a float32 a
+    row, with sums and pick taken in float32 from the logits cast up in the
+    pass that reads them. Kept for the backward pass: the logits in the
+    dtype they came in, one float32 ``lse`` a row and the labels. JAX's own
+    rule for ``log_softmax`` keeps its float32 result, an array of the
+    logits' shape."""
+    return _lse_less_pick_fwd(logits, safe)[0]
+
+
+def _lse_less_pick_fwd(logits, safe):
+    lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+    picked = jnp.take_along_axis(logits, safe[..., None], -1)[..., 0]
+    return lse - picked.astype(jnp.float32), (logits, lse, safe)
+
+
+def _lse_less_pick_bwd(kept, g):
+    logits, lse, safe = kept
+    p = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+    onehot = safe[..., None] == jnp.arange(logits.shape[-1])
+    # rounded to the logits' dtype: the transpose of the cast up in forward
+    return (g[..., None] * (p - onehot)).astype(logits.dtype), None
+
+
+_lse_less_pick.defvjp(_lse_less_pick_fwd, _lse_less_pick_bwd)
+
+
 def cross_entropy(input, label, weight=None, ignore_index=-100, reduction="mean",
                   soft_label=False, axis=-1, use_softmax=True, name=None):
     """reference: operators/softmax_with_cross_entropy_op.cc +
-    python/paddle/nn/functional/loss.py cross_entropy."""
+    python/paddle/nn/functional/loss.py cross_entropy.
+
+    Hard labels over the last axis with a softmax and no class weights (the
+    form every language-model head takes) go through ``_lse_less_pick``,
+    which keeps for the backward pass the logits as they were given and one
+    float32 ``lse`` a row: at 16,384 rows of 50,304 columns the float32
+    table of log-probabilities that ``log_softmax`` would keep is 3.07 GiB
+    and a pass of its own to write. Its loss is float32 whatever the logits'
+    dtype, so autocast leaves its logits as they are (``amp.F32_INSIDE_OPS``).
+    Every other form is different mathematics and keeps ``log_softmax``."""
+    lean = (use_softmax and not soft_label and weight is None
+            and axis in (-1, len(input.shape) - 1))
+
     def impl(logits, lab, *w):
-        logp = jax.nn.log_softmax(logits, axis=axis) if use_softmax \
-            else jnp.log(jnp.maximum(logits, 1e-30))
+        if not lean:
+            logp = jax.nn.log_softmax(logits, axis=axis) if use_softmax \
+                else jnp.log(jnp.maximum(logits, 1e-30))
         if soft_label:
             loss = -jnp.sum(lab * logp, axis=axis)
         else:
             lab_i = lab.astype(jnp.int32)
-            if lab_i.ndim == logp.ndim:  # [N,...,1] hard labels
+            if lab_i.ndim == logits.ndim:  # [N,...,1] hard labels
                 lab_i = jnp.squeeze(lab_i, axis)
             valid = lab_i != ignore_index
             safe = jnp.where(valid, lab_i, 0)
-            picked = jnp.take_along_axis(logp, jnp.expand_dims(safe, axis), axis)
-            loss = -jnp.squeeze(picked, axis)
+            if lean:
+                loss = _lse_less_pick(logits, safe)
+            else:
+                picked = jnp.take_along_axis(logp, jnp.expand_dims(safe, axis),
+                                             axis)
+                loss = -jnp.squeeze(picked, axis)
             if w:
                 cw = jnp.take(w[0], safe)
                 loss = loss * cw
@@ -53,7 +98,8 @@ def cross_entropy(input, label, weight=None, ignore_index=-100, reduction="mean"
                 return jnp.sum(loss) / denom
         return _reduce(loss, reduction)
     args = [input, label] + ([weight] if weight is not None else [])
-    return apply("softmax_with_cross_entropy", impl, *args)
+    return apply("softmax_cross_entropy_rows" if lean
+                 else "softmax_with_cross_entropy", impl, *args)
 
 
 def softmax_with_cross_entropy(logits, label, soft_label=False,
